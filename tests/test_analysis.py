@@ -434,9 +434,11 @@ def test_fused_engines_lint_knob():
     from timewarp_tpu.interp.jax_engine.fused_sparse import \
         FusedSparseEngine
     sc = gossip(1024, burst=True)
-    eng = FusedSparseEngine(sc, FixedDelay(1000), lint="error")
+    eng = FusedSparseEngine(sc, FixedDelay(1000), lint="error",
+                            interpret=True)
     assert eng.lint_report is not None and eng.lint_report.ok
-    eng = FusedSparseEngine(sc, FixedDelay(1000), lint="off")
+    eng = FusedSparseEngine(sc, FixedDelay(1000), lint="off",
+                            interpret=True)
     assert eng.lint_report is None
 
 
@@ -445,10 +447,10 @@ def test_sharded_fused_engine_lint_knob():
         ShardedFusedSparseEngine, make_mesh)
     sc = gossip(8192, burst=True)       # 1024 nodes/shard kernel floor
     eng = ShardedFusedSparseEngine(sc, FixedDelay(1000), make_mesh(8),
-                                   lint="error")
+                                   lint="error", interpret=True)
     assert eng.lint_report is not None and eng.lint_report.ok
     eng = ShardedFusedSparseEngine(sc, FixedDelay(1000), make_mesh(8),
-                                   lint="off")
+                                   lint="off", interpret=True)
     assert eng.lint_report is None
 
 
@@ -456,9 +458,10 @@ def test_fused_ring_engine_lint_knob():
     from timewarp_tpu.interp.jax_engine.fused_ring import \
         FusedRingEngine
     sc = token_ring(8192, with_observer=False)  # 8x1024 block floor
-    eng = FusedRingEngine(sc, FixedDelay(1000), lint="error")
+    eng = FusedRingEngine(sc, FixedDelay(1000), lint="error",
+                          interpret=True)
     assert eng.lint_report is not None and eng.lint_report.ok
-    eng = FusedRingEngine(sc, FixedDelay(1000), lint="off")
+    eng = FusedRingEngine(sc, FixedDelay(1000), lint="off", interpret=True)
     assert eng.lint_report is None
 
 

@@ -10,7 +10,11 @@ import bench
 
 
 @pytest.mark.parametrize("cfg", sorted(bench.CONFIGS))
-def test_bench_config_runs(cfg):
+def test_bench_config_runs(cfg, monkeypatch):
+    # the --smoke path: gates on, kernels under the Pallas
+    # interpreter, rates discarded — the measured path refuses to
+    # time anything but a TPU (test_bench_main_refuses_without_a_chip)
+    monkeypatch.setattr(bench, "_SMOKE", True)
     # the fused-sparse configs sit at the kernel's 1024-lane scope
     # floor (2048 = the --smoke shape, gate included)
     n = {"token_ring_dense": 512, "token_ring_dense_xla": 512,
@@ -95,10 +99,32 @@ def test_bench_config_runs(cfg):
             > extra["record_events"]["deliveries"]["events"]
 
 
+def test_bench_main_refuses_without_a_chip(capsys, monkeypatch):
+    """The measured path times a TPU or nothing: on the CPU platform
+    the tests run on, ``bench.main()`` exits before any config runs
+    and prints no line (a CPU or interpreter timing is never written
+    as speed)."""
+    monkeypatch.setenv("TW_BENCH_CONFIG", "token_ring_dense")
+    monkeypatch.setenv("TW_BENCH_NODES", "256")
+    monkeypatch.setattr("sys.argv", ["bench.py"])
+    monkeypatch.setattr(bench, "_run_config", lambda *a: pytest.fail(
+        "a config ran on a non-TPU backend"))
+    with pytest.raises(SystemExit, match="refusing to time"):
+        bench.main()
+    assert capsys.readouterr().out == ""
+    # a device outside the peaks table is an error, never a default
+    with pytest.raises(SystemExit, match="no published peak"):
+        bench._device_peak("hbm_gbps")
+
+
 def test_bench_main_prints_one_json_line(capsys, monkeypatch):
     monkeypatch.setenv("TW_BENCH_CONFIG", "token_ring_dense")
     monkeypatch.setenv("TW_BENCH_NODES", "256")
     monkeypatch.setenv("TW_BENCH_STEPS", "32")
+    monkeypatch.setattr("sys.argv", ["bench.py"])
+    # the line's shape is the subject here, not its numbers: steer
+    # the chip refusal aside in the test (256 nodes runs the XLA ring)
+    monkeypatch.setattr(bench, "_require_chip", lambda what: None)
     bench.main()
     out = capsys.readouterr().out.strip().splitlines()
     assert len(out) == 1

@@ -1,4 +1,4 @@
-"""Cross-world parity legs beyond token-ring (VERDICT r4 item 7):
+"""Cross-world parity legs beyond token-ring:
 ping-pong and gossip — each baseline scenario executed as a
 generator program over the full net stack (dialog/transfer over the
 emulated byte fabric, under the pure DES) AND as its batched twin

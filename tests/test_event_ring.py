@@ -1,4 +1,4 @@
-"""Device-side event ring buffer (VERDICT r4 item 4): the batched
+"""Device-side event ring buffer: the batched
 engine can record per-event ``(time, node, kind, src, payload)``
 tuples on-device and they must equal the host oracle's
 ``record_events=True`` stream record-for-record — so a digest mismatch

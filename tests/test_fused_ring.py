@@ -10,7 +10,7 @@ On this CPU test platform the kernel runs under the pallas
 interpreter (same DMA/loop semantics, no Mosaic); the real-chip
 compile and the same equality check run in the bench
 (bench.py token_ring_dense) and were verified on hardware in round 5
-(PERF_r05.md: 6.5e9 msg/s, state-equal at 2^20).
+(docs/engines.md "Measured on a v5e": 6.5e9 msg/s, state-equal at 2^20).
 """
 
 import numpy as np
@@ -44,7 +44,7 @@ def test_fused_equals_edge_bit_for_bit():
                     end_us=60_000, with_observer=False, mailbox_cap=4)
     link = FixedDelay(500)
     ref = EdgeEngine(sc, link, cap=2)
-    fus = FusedRingEngine(sc, link, cap=2)
+    fus = FusedRingEngine(sc, link, cap=2, interpret=True)
     rs, fs = ref.init_state(), fus.init_state()
     for k in (1, 2, 7, 40, 130):
         rs = ref.run_quiet(k, rs)
@@ -60,7 +60,7 @@ def test_fused_equals_edge_sparse_tokens_and_think():
                     end_us=80_000, with_observer=False, mailbox_cap=4)
     link = FixedDelay(700)
     ref = EdgeEngine(sc, link, cap=2)
-    fus = FusedRingEngine(sc, link, cap=2)
+    fus = FusedRingEngine(sc, link, cap=2, interpret=True)
     rs, fs = ref.init_state(), fus.init_state()
     for k in (3, 10, 60):
         rs = ref.run_quiet(k, rs)
@@ -72,17 +72,31 @@ def test_fused_scope_guards():
     sc = token_ring(N, n_tokens=N, think_us=0, bootstrap_us=1_000,
                     end_us=60_000, with_observer=False, mailbox_cap=4)
     with pytest.raises(ValueError, match="FixedDelay"):
-        FusedRingEngine(sc, UniformDelay(1, 5), cap=2)
+        FusedRingEngine(sc, UniformDelay(1, 5), cap=2, interpret=True)
     with pytest.raises(ValueError, match="cap=2"):
-        FusedRingEngine(sc, FixedDelay(500), cap=3)
+        FusedRingEngine(sc, FixedDelay(500), cap=3, interpret=True)
     small = token_ring(64, n_tokens=64, think_us=0, bootstrap_us=1_000,
                        end_us=60_000, with_observer=False,
                        mailbox_cap=4)
     with pytest.raises(ValueError, match="multiple"):
-        FusedRingEngine(small, FixedDelay(500), cap=2)
+        FusedRingEngine(small, FixedDelay(500), cap=2, interpret=True)
     obs = token_ring(N, n_tokens=N, think_us=0, bootstrap_us=1_000,
                      end_us=60_000, with_observer=True, mailbox_cap=8)
     # the observer adds node N+1, so this trips the block-shape guard
     # before the lean-dense one — either way it is rejected
     with pytest.raises(ValueError, match="multiple|lean dense"):
-        FusedRingEngine(obs, FixedDelay(500), cap=2)
+        FusedRingEngine(obs, FixedDelay(500), cap=2, interpret=True)
+
+
+def test_fused_ring_refuses_without_a_tpu():
+    """The default is the kernel compiled by Mosaic: with no TPU and
+    no explicit interpreter request the constructor raises — never a
+    quiet fall to the interpreter on the backend's name."""
+    import jax
+    assert jax.default_backend() != "tpu"
+    sc = token_ring(N, n_tokens=N, think_us=0, bootstrap_us=1_000,
+                    end_us=60_000, with_observer=False, mailbox_cap=4)
+    with pytest.raises(RuntimeError, match="needs a TPU"):
+        FusedRingEngine(sc, FixedDelay(500), cap=2)
+    assert FusedRingEngine(sc, FixedDelay(500), cap=2,
+                           interpret=True).interpret

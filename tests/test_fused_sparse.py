@@ -48,7 +48,7 @@ _assert_state_equal = assert_states_equal
 
 def _check(sc, link, horizons, tag, **kw):
     ref = JaxEngine(sc, link, **kw)
-    fus = FusedSparseEngine(sc, link, **kw)
+    fus = FusedSparseEngine(sc, link, **kw, interpret=True)
     rs, fs = ref.init_state(), fus.init_state()
     for k in horizons:
         rs = ref.run_quiet(k, rs)
@@ -113,7 +113,8 @@ def test_fused_event_ring_matches_general():
                 end_us=300_000, mailbox_cap=8)
     link = Quantize(UniformDelay(3_000, 9_000), 1_000)
     ref = JaxEngine(sc, link, window=3_000, record_events=4096)
-    fus = FusedSparseEngine(sc, link, window=3_000, record_events=4096)
+    fus = FusedSparseEngine(sc, link, window=3_000, record_events=4096,
+                            interpret=True)
     rstate = ref.run_quiet(40)
     fstate = fus.run_quiet(40)
     rev, rdrop = ref.events(rstate)
@@ -130,7 +131,7 @@ def test_fused_checkpoint_interchange(tmp_path):
     from timewarp_tpu.utils.checkpoint import load_state, save_state
     sc, link = _gossip()
     ref = JaxEngine(sc, link, window="auto")
-    fus = FusedSparseEngine(sc, link, window="auto")
+    fus = FusedSparseEngine(sc, link, window="auto", interpret=True)
     mid = ref.run_quiet(10)
     path = str(tmp_path / "mid.npz")
     save_state(path, mid, meta={"scenario": sc.name})
@@ -153,7 +154,8 @@ def test_fused_batch_cap_drops_are_counted():
     sc = gossip(N, fanout=8, think_us=2_000, burst=True,
                 end_us=1_000_000, mailbox_cap=16)
     link = Quantize(UniformDelay(8_000, 30_000), 1_000)
-    fus = FusedSparseEngine(sc, link, window="auto", max_batch=128)
+    fus = FusedSparseEngine(sc, link, window="auto", max_batch=128,
+                            interpret=True)
     fs = fus.run_quiet(40)
     ref = JaxEngine(sc, link, window="auto")
     rs = ref.run_quiet(40)
@@ -177,7 +179,7 @@ def test_fused_sharded_leg():
     link = Quantize(UniformDelay(3_000, 9_000), 1_000)
     ref = JaxEngine(sc, link, window=3_000)
     fus = ShardedFusedSparseEngine(sc, link, make_mesh(8),
-                                   window=3_000)
+                                   window=3_000, interpret=True)
     _, tr = ref.run(60)
     _, tf = fus.run(60)
     assert_traces_equal(tr, tf, "general-1dev", "sharded-fused-8dev")
@@ -193,17 +195,17 @@ def test_fused_scope_guards():
     small = gossip(100, fanout=4, burst=True, end_us=100_000)
     with pytest.raises(ValueError, match="multiple"):
         FusedSparseEngine(small, UniformDelay(2_000, 9_000),
-                          window=2_000)
+                          window=2_000, interpret=True)
     # droppy link
     with pytest.raises(ValueError, match="drop-free"):
         FusedSparseEngine(sc, WithDrop(UniformDelay(2_000, 9_000), .1),
-                          window="auto")
+                          window="auto", interpret=True)
     # non-commutative inbox (ordered token ring with observer)
     ring = token_ring(N - 1, n_tokens=8, think_us=1_000,
                       with_observer=True)
     with pytest.raises(ValueError, match="multiple|commutative"):
         FusedSparseEngine(ring, UniformDelay(2_000, 9_000),
-                          window=2_000)
+                          window=2_000, interpret=True)
     # un-lowerable link model (drop-free, so it reaches the registry)
     class _NoDropFn(FnDelay):
         @property
@@ -212,8 +214,26 @@ def test_fused_scope_guards():
 
     fn = _NoDropFn(lambda s, d, t, k: (t * 0 + 5_000, t < 0))
     with pytest.raises(ValueError, match="cannot lower"):
-        FusedSparseEngine(sc, fn, window=1)
+        FusedSparseEngine(sc, fn, window=1, interpret=True)
     # classic narrow regime (nothing to batch)
     steady = gossip(N, fanout=1, steady=True, end_us=100_000)
     with pytest.raises(ValueError, match="windowed"):
-        FusedSparseEngine(steady, UniformDelay(2_000, 9_000), window=1)
+        FusedSparseEngine(steady, UniformDelay(2_000, 9_000), window=1,
+                          interpret=True)
+
+
+def test_fused_sparse_refuses_without_a_tpu():
+    """Compiled kernel by default; the Pallas interpreter only on an
+    explicit request — with no TPU and no request both fused-sparse
+    engines raise (no strategy is picked from the backend's name)."""
+    import jax
+    assert jax.default_backend() != "tpu"
+    sc, link = _gossip()
+    with pytest.raises(RuntimeError, match="needs a TPU"):
+        FusedSparseEngine(sc, link, window="auto")
+    from timewarp_tpu.interp.jax_engine.sharded import (
+        ShardedFusedSparseEngine, make_mesh)
+    sc8 = gossip(8192, fanout=4, burst=True, end_us=100_000)
+    with pytest.raises(RuntimeError, match="needs a TPU"):
+        ShardedFusedSparseEngine(sc8, UniformDelay(2_000, 9_000),
+                                 make_mesh(8), window=2_000)

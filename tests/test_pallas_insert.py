@@ -195,7 +195,7 @@ def test_insert_cap_drops_are_counted():
 
 def test_insert_knob_resolution_and_env():
     """The documented TW_INSERT hatch (and the legacy TW_FLAT_SCATTER
-    alias it promotes, PERF_r05.md §3), the off-TPU auto-fallback, and
+    alias it promotes, docs/engines.md "Measured on a v5e"), the off-TPU auto-fallback, and
     the never-silent scope guards."""
     sc, link = _gossip()
     for var in ("TW_INSERT", "TW_FLAT_SCATTER"):
@@ -216,11 +216,18 @@ def test_insert_knob_resolution_and_env():
     finally:
         for var in ("TW_INSERT", "TW_FLAT_SCATTER"):
             os.environ.pop(var, None)
-    # "pallas" off-TPU: auto-fallback to xla, loudly recorded
+    # "pallas" with no TPU: refused — the compiled kernels need the
+    # chip, and no other strategy is ever picked from the backend's
+    # name (the interpreter is an explicit request)
     assert jax.default_backend() != "tpu"
-    e = JaxEngine(sc, link, window="auto", insert="pallas")
-    assert e.insert == "pallas" and e.insert_resolved == "xla"
-    assert "TPU" in e.insert_fallback or "tpu" in e.insert_fallback
+    with pytest.raises(RuntimeError, match="needs a TPU"):
+        JaxEngine(sc, link, window="auto", insert="pallas")
+    os.environ["TW_INSERT"] = "pallas"
+    try:
+        with pytest.raises(RuntimeError, match="needs a TPU"):
+            JaxEngine(sc, link, window="auto")
+    finally:
+        del os.environ["TW_INSERT"]
     # unknown mode
     with pytest.raises(ValueError, match="insert must be one of"):
         JaxEngine(sc, link, window="auto", insert="mosaic")
@@ -239,18 +246,20 @@ def test_insert_knob_resolution_and_env():
         e = JaxEngine(small, UniformDelay(2_000, 9_000), window=2_000)
         assert e.insert_resolved == "xla"
         assert "kernel scope" in e.insert_fallback
+        # the unused cap rides the recorded scope-fallback reason
+        e = JaxEngine(small, UniformDelay(2_000, 9_000), window=2_000,
+                      insert_cap=64)
+        assert e.insert_resolved == "xla"
+        assert "insert_cap" in e.insert_fallback
     finally:
         del os.environ["TW_INSERT"]
-    # insert_cap without a REQUESTED pallas mode is a refused no-op…
+    # insert_cap without a REQUESTED pallas mode is a refused no-op,
+    # and with insert="pallas" and no TPU the refusal is the chip's
     with pytest.raises(ValueError, match="insert_cap"):
         JaxEngine(sc, link, window="auto", insert_cap=64)
-    # …but a chip script (insert="pallas", insert_cap=N) must keep
-    # constructing through the documented off-TPU auto-fallback, with
-    # the unused cap recorded on the fallback reason, never a crash
-    e = JaxEngine(sc, link, window="auto", insert="pallas",
+    with pytest.raises(RuntimeError, match="needs a TPU"):
+        JaxEngine(sc, link, window="auto", insert="pallas",
                   insert_cap=64)
-    assert e.insert_resolved == "xla"
-    assert "insert_cap" in e.insert_fallback
     # env hatch must NOT leak into engines that replace the insertion
     # stage themselves (fused/sharded subclasses resolve "xla")
     os.environ["TW_INSERT"] = "interpret"
@@ -258,7 +267,8 @@ def test_insert_knob_resolution_and_env():
         from timewarp_tpu.interp.jax_engine.fused_sparse import \
             FusedSparseEngine
         sc16, link16 = _gossip(mailbox_cap=16)
-        f = FusedSparseEngine(sc16, link16, window="auto")
+        f = FusedSparseEngine(sc16, link16, window="auto",
+                              interpret=True)
         assert f.insert_resolved == "xla"
         assert f._pallas_stage is None
     finally:

@@ -1,4 +1,4 @@
-"""Round-3 regression suite for the ADVICE/VERDICT findings:
+"""Round-3 regression suite for that round's review findings:
 
 - deadlock detection in the pure emulator (quiescence must not mask a
   parked-forever thread — ≙ GHC's BlockedIndefinitelyOnMVar, which the

@@ -115,8 +115,7 @@ def test_gossip_steady_mode_parity():
 def test_general_engine_overflow_parity_with_oracle():
     """Contract #6 under load: when mailboxes overflow, the general
     engine must drop exactly the messages the oracle drops — overflow
-    counts AND the surviving trace stay bit-for-bit equal (VERDICT r2
-    item 7)."""
+    counts AND the surviving trace stay bit-for-bit equal."""
     import jax.numpy as jnp
     from timewarp_tpu.core.scenario import NEVER, Outbox, Scenario
     from timewarp_tpu.net.delays import FixedDelay

@@ -100,10 +100,11 @@ def test_fused_sparse_full_mode_bit_identical():
     sc = gossip(2048, fanout=3, burst=True, end_us=120_000,
                 mailbox_cap=16)
     link = Quantize(UniformDelay(3000, 9000), 1000)
-    off = FusedSparseEngine(sc, link, window="auto", lint="off")
+    off = FusedSparseEngine(sc, link, window="auto", lint="off",
+                            interpret=True)
     f0, t0 = off.run(16)
     eng = FusedSparseEngine(sc, link, window="auto", lint="off",
-                            telemetry="full")
+                            telemetry="full", interpret=True)
     f1, t1 = eng.run(16)
     assert_traces_equal(t0, t1, "off", "fused full")
     assert_states_equal(f0, f1, "fused-sparse telemetry=full")
@@ -208,7 +209,8 @@ def test_fused_ring_refuses_telemetry_with_guidance():
                     bootstrap_us=1000, end_us=1 << 50,
                     with_observer=False, mailbox_cap=4)
     with pytest.raises(ValueError, match="EdgeEngine"):
-        FusedRingEngine(sc, FixedDelay(500), telemetry="counters")
+        FusedRingEngine(sc, FixedDelay(500), telemetry="counters",
+                        interpret=True)
 
 
 # ---------------------------------------------------------------------------

@@ -365,7 +365,8 @@ def test_fused_sparse_controller_pins_knobs():
     sc, link = _wave(n=1024, end_us=60_000)
     eng = FusedSparseEngine(sc, link, window="auto",
                             telemetry="counters", lint="off",
-                            controller=DispatchController(chunk=8))
+                            controller=DispatchController(chunk=8),
+                            interpret=True)
     assert not eng._dyn_ok, \
         "the fused kernel bakes the window — knobs must pin"
     assert eng.controller is not None

@@ -108,7 +108,7 @@ def test_fused_ring_refuses_verify_with_guidance():
                     bootstrap_us=1000, end_us=1 << 50,
                     with_observer=False, mailbox_cap=4)
     with pytest.raises(ValueError, match="EdgeEngine"):
-        FusedRingEngine(sc, FixedDelay(500), verify="guard")
+        FusedRingEngine(sc, FixedDelay(500), verify="guard", interpret=True)
 
 
 # ---------------------------------------------------------------------------

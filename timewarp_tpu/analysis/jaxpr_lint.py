@@ -388,7 +388,7 @@ def lint_step_jaxpr(sc: Scenario) -> LintReport:
             "inbox.src has no consumers in the jaxpr but "
             "inbox_src=True: the engines scatter the mailbox src plane "
             "(~1/3 of the dense random-delivery cost floor, "
-            "PERF_r04.md) for a field the step never reads. Declare "
+            "docs/engines.md) for a field the step never reads. Declare "
             "inbox_src=False"))
 
     # -- TW102/TW103: time-dtype taint ----------------------------------

@@ -121,9 +121,8 @@ def _resolved_insert() -> Tuple[str, bool]:
     window — the lint must predict the runtime's refusal, so it asks
     the same resolver (interp/jax_engine/pallas_insert.py)."""
     from ..interp.jax_engine.pallas_insert import resolve_insert
-    _, resolved, _, _ = resolve_insert(None, honor_env=True,
-                                       who="plan lint")
-    return resolved, resolved not in ("pallas", "interpret")
+    mode, _ = resolve_insert(None, honor_env=True, who="plan lint")
+    return mode, mode not in ("pallas", "interpret")
 
 
 def lint_run_config(cfg: RunConfig, *, deep: bool = True) -> LintReport:

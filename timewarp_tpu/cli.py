@@ -630,6 +630,10 @@ def main(argv=None) -> int:
     if argv is None:
         argv = sys.argv[1:]
     argv = list(argv)
+    # the persistent compile cache (utils/jaxconfig.py): an entry
+    # point's call, never an import's
+    from .utils.jaxconfig import enable_compile_cache
+    enable_compile_cache()
     if argv and argv[0] == "lint-pack":
         # fleet-scale pre-flight verification of a sweep pack
         # (analysis/plan_lint.py, TW6xx — docs/sweeps.md)
@@ -747,8 +751,8 @@ def main(argv=None) -> int:
                         "(default), 'xla2d' the 2D scatter form (the "
                         "promoted TW_FLAT_SCATTER hatch), 'pallas' "
                         "the fire-compaction + in-tile insertion "
-                        "kernels on TPU (auto-fallback to xla "
-                        "elsewhere), 'interpret' the kernels under "
+                        "kernels compiled for the TPU (refused "
+                        "where there is none), 'interpret' the kernels under "
                         "the Pallas interpreter; unset reads "
                         "TW_INSERT")
     p.add_argument("--insert-cap", type=int, default=None,

@@ -132,13 +132,19 @@ def bernoulli(bits, p: float):
 def normal_f32(b0, b1):
     """Standard normal via Box-Muller from two uint32 words (float32).
 
-    Transcendental lowering may differ across backends by an ulp —
-    integer models stay bit-exact; float models carry the documented
-    LogNormalDelay caveat (net/delays.py).
+    Transcendentals differ across backends, and by more than an ulp
+    (a v5e's float32 ``log`` is up to 3.7e-4 relative from the host
+    CPU's, docs/engines.md "The parity regime") — integer models stay
+    bit-exact everywhere; float models are exact within one backend
+    only (the documented LogNormalDelay caveat, net/delays.py).
     """
-    # 24-bit mantissa uniforms in (0, 1)
-    u1 = (b0 >> jnp.uint32(8)).astype(jnp.float32) * jnp.float32(2 ** -24) \
-        + jnp.float32(2 ** -25)
-    u2 = (b1 >> jnp.uint32(8)).astype(jnp.float32) * jnp.float32(2 ** -24)
+    # 24-bit mantissa uniforms in (0, 1). The draw goes uint32 ->
+    # int32 -> float32: Mosaic has no uint32 -> float32 cast (this
+    # runs in-kernel too, fused_sparse.py), and a value below 2^24 is
+    # exact either way, so the bits are the same on every path
+    def u24(b):
+        return (b >> jnp.uint32(8)).astype(jnp.int32).astype(jnp.float32)
+    u1 = u24(b0) * jnp.float32(2 ** -24) + jnp.float32(2 ** -25)
+    u2 = u24(b1) * jnp.float32(2 ** -24)
     r = jnp.sqrt(jnp.float32(-2.0) * jnp.log(u1))
     return r * jnp.cos(jnp.float32(2.0 * 3.141592653589793) * u2)

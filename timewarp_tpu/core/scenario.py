@@ -122,7 +122,7 @@ class Scenario:
     #: is not part of the scenario's semantics — e.g. a gossip adopt is
     #: a pure payload reduction). Engines then skip storing/scattering
     #: the mailbox src field (mailbox scatters are the dense
-    #: random-delivery cost floor on TPU, PERF_r04.md), ``inbox.src``
+    #: random-delivery cost floor on TPU, docs/engines.md "Measured on a v5e"), ``inbox.src``
     #: reads as 0, and ALL interpreters hash src as 0 in the RECV
     #: digest — the parity law still pins every delivered message's
     #: (dst, time, payload), just not its sender.

@@ -5,7 +5,7 @@ sweeps, link-model sweeps, Monte-Carlo fault studies (ROADMAP north
 star; the replica-sweep workload of Revati-style time-warp emulation,
 PAPERS.md). Per-superstep the general engine pays fixed N-width costs
 (sender-compaction sort, rung gathers, the [K, N] mailbox base —
-PERF_r05.md) that do not shrink with the instantaneous event count;
+docs/engines.md "Measured on a v5e") that do not shrink with the instantaneous event count;
 a leading **world axis B** amortizes them: one batched sort/gather/
 scatter serves B independent worlds.
 

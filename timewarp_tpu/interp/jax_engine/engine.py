@@ -22,7 +22,7 @@ core/scenario.py, and the emitted trace must equal the host oracle's
 bit-for-bit (tests/test_parity.py). Everything observable is integer;
 time is int64 µs.
 
-TPU cost notes (profiling/superstep_breakdown.md): int64 scatters are
+TPU cost notes (docs/engines.md "Measured on a v5e"): int64 scatters are
 pathological and random scatters are the dominant real cost, so
 mailbox deliver-times are stored as **int32 relative** to the rebased
 epoch (``EngineState.time``), inbox ordering and mailbox compaction are
@@ -36,7 +36,7 @@ from __future__ import annotations
 from functools import partial
 from typing import Any, NamedTuple, Optional, Tuple
 
-from ...utils import jaxconfig  # noqa: F401  (must precede jax use)
+from ...utils import jaxconfig  # must precede jax use
 
 import jax
 import jax.numpy as jnp
@@ -68,7 +68,7 @@ class EngineState(NamedTuple):
     Mailbox layout is ``[K, N]`` (minor dim = node axis — no lane
     padding, perfect VPU tiling; the [N, K] layout taxes every
     materialized intermediate ~128/K in memory traffic,
-    profiling/superstep_breakdown.md). Deliver-times are int32 µs
+    docs/engines.md "Measured on a v5e"). Deliver-times are int32 µs
     relative to ``time`` (the epoch is rebased every superstep); delays
     ≥ 2^31 µs are clamped and counted in ``bad_delay``.
     """
@@ -194,10 +194,10 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
     convention as the fused engine's VMEM slice).
     ``"xla"`` (default) keeps the flat
     1D scatters; ``"xla2d"`` the 2D [col, row] scatter form (the
-    promoted ``TW_FLAT_SCATTER`` escape hatch, PERF_r05.md §3);
+    promoted ``TW_FLAT_SCATTER`` escape hatch, docs/engines.md "Measured on a v5e");
     ``"pallas"`` runs the fire-compaction + in-tile insertion kernels
-    on TPU (auto-fallback to ``"xla"`` off-TPU, recorded in
-    ``insert_fallback``) — in the adaptive regime the fire-compaction
+    compiled for the TPU (with no TPU backend the constructor raises —
+    never a quiet change of strategy) — in the adaptive regime the fire-compaction
     kernel replaces the sender-compaction sort and rung-width gathers
     wholesale (``_route_firecompact``); ``"interpret"`` forces the
     kernels under the Pallas interpreter (the CPU test surface).
@@ -219,7 +219,7 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
     The fleet amortizes the superstep's fixed N-width costs (the
     sender-compaction sort, the [K, N] mailbox passes) into one
     batched op serving B worlds — the replica-sweep throughput lever
-    (PERF_r05.md). ``record_events`` is solo-only (the ring decoder is
+    (docs/engines.md "Measured on a v5e"). ``record_events`` is solo-only (the ring decoder is
     a single-run debug artifact — record world b's events by running
     it solo, which is bit-identical by the law above).
 
@@ -347,10 +347,18 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         # stage itself is built further down (it needs the resolved
         # window).
         from .pallas_insert import resolve_insert
-        (self.insert, self.insert_resolved, self.insert_fallback,
-         _ins_env) = resolve_insert(
+        self.insert, _ins_env = resolve_insert(
             insert, honor_env=type(self) is JaxEngine,
             who=type(self).__name__)
+        if self.insert == "pallas":
+            # the compiled kernels need the chip: no TPU is a
+            # refusal, never a quiet change of strategy
+            jaxconfig.require_tpu(
+                f"{type(self).__name__}: insert='pallas'")
+        #: what runs: the requested mode, unless an ENV-selected
+        #: kernel mode fell outside this scenario's kernel scope
+        #: (then "xla", with the reason in ``insert_fallback``)
+        self.insert_resolved, self.insert_fallback = self.insert, None
         #: whether this engine threads the dynamic window/rung scalars
         #: (controlled.py) — a kernel-window engine adapts chunk
         #: length only. The env-fallback path below may downgrade the
@@ -497,9 +505,9 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         # "xla" (flat scatters, the r5 default) | "xla2d" (2D [col,
         # row] scatter form — the promoted TW_FLAT_SCATTER escape
         # hatch) | "pallas" (fire-compaction + in-tile insertion
-        # kernels on TPU; auto-fallback to "xla" elsewhere, recorded
-        # in ``insert_fallback``) | "interpret" (the kernels under the
-        # Pallas interpreter — the CPU test surface). insert=None
+        # kernels compiled for the TPU; no TPU backend is a refusal)
+        # | "interpret" (the kernels under the Pallas interpreter —
+        # the CPU test surface). insert=None
         # reads the documented TW_INSERT env hatch (JaxEngine proper
         # only: subclasses that replace the insertion stage themselves
         # must not inherit it). Every strategy is bit-identical —
@@ -507,11 +515,6 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         # (Resolved ABOVE, before window validation — the kernel-
         # window engines must validate against the degraded floor.)
         # insert_cap sizes the pallas stage, so it needs a kernel mode
-        # — judged on the REQUESTED mode, not the resolved one: a
-        # script written for the chip (insert="pallas", insert_cap=N)
-        # must keep constructing through the documented off-TPU
-        # auto-fallback (the unused cap rides the recorded
-        # insert_fallback reason, never a crash)
         if insert_cap is not None \
                 and self.insert not in ("pallas", "interpret"):
             raise ValueError(
@@ -754,9 +757,9 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         per-destination rank -> target slot (r-th hole for commutative
         inboxes, append-after-kept otherwise) -> scatters in the form
         the ``insert`` knob selects: flat 1D (default — the 2D [col,
-        row] form costs ~7x on this chip, profiling/micro2_r05.py),
+        row] form costs ~7x on this chip, docs/engines.md per-op cost table),
         2D ``"xla2d"`` (no flat-reshape relayout copy of the tiled
-        mailbox — the promoted TW_FLAT_SCATTER hatch, PERF_r05.md §3),
+        mailbox — the promoted TW_FLAT_SCATTER hatch, docs/engines.md "Measured on a v5e"),
         or the Pallas insertion kernel (pallas_insert.py — streams the
         [K, N] planes through VMEM once). Non-fitting lanes get an
         out-of-range index and are dropped; returns the updated arrays
@@ -784,7 +787,7 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         if self.insert_resolved == "xla2d":
             # the 2D [col, row] scatter form: ~7x the flat form in
             # isolation on this chip, but no physical relayout copy of
-            # the tiled [K, N] operand (PERF_r05.md §3 measured the
+            # the tiled [K, N] operand (docs/engines.md "Measured on a v5e" measured the
             # two a wash in-engine) — kept selectable for hardware
             # where the relayout dominates. Non-fitting lanes get an
             # out-of-range row (K) and drop.
@@ -803,7 +806,7 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
             if sc.inbox_src:
                 # inbox_src=False skips this whole scatter — mailbox
                 # scatters ARE the dense random-delivery cost floor
-                # (PERF_r04.md), so dropping an unread field is ~1/3
+                # (docs/engines.md "Measured on a v5e"), so dropping an unread field is ~1/3
                 # of it
                 mb_src = mb_src.reshape(-1).at[flat].set(
                     src_s, mode="drop").reshape(K, n)
@@ -840,7 +843,7 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         # pack (validity, destination-range check) into ONE array so
         # the per-rung gather moves 1 + P arrays instead of 3 + P —
         # random-access volume is the branch's dominant cost on this
-        # chip (~4.5 ns/element, profiling/micro2_r05.py). Contract #6
+        # chip (~4.5 ns/element, docs/engines.md per-op cost table). Contract #6
         # corollary: out-of-range destinations are counted here,
         # globally, never silently dropped.
         dst32 = out.dst.astype(jnp.int32)                       # [M, N]

@@ -1,10 +1,10 @@
 """Pallas fused routing superstep for the *sparse* general engine —
-the gossip / praos path (round 6; VERDICT r5 item 1).
+the gossip / praos path (round 6).
 
 Round 5 proved the Pallas lever on the dense ring (fused_ring.py:
 6.5e9 msg/s/chip) but every dynamic-destination config still runs the
 XLA `JaxEngine` at 0.05-0.08x the north star, and the profiler says
-where the fat is (PERF_r05.md "Where the remaining praos fat is"): the
+where the fat is (docs/engines.md "Where the remaining praos fat is"): the
 free-rows [K, N] short-axis sort, the (1 + P) flat mailbox scatters
 with their tiled-layout relayout copies, and the per-stage HBM
 round-trips between them. This module fuses the post-compaction
@@ -17,7 +17,7 @@ sender-compacted message batch stays resident in VMEM:
   are still compacted by ONE single-operand N-sort and the batch is
   still ordered by ``(destination, window offset, sender-major rank)``
   in XLA (sorts are the one thing XLA does near-bandwidth;
-  PERF_r05.md cost table) — but the sorted batch is then handed to
+  docs/engines.md per-op cost table) — but the sorted batch is then handed to
   the kernel ONCE and never re-materialized per stage;
 - link delays are sampled **in-kernel** with the counter-based
   threefry of core/rng.py inlined as uint32 VPU ops (the same bits
@@ -62,15 +62,18 @@ insertion), drop-free links that lower to the in-kernel uint32/f32
 registry (`_lower_link`), windowed or wide-outbox workloads, and
 ``n_nodes`` divisible by the 1024-lane block shape.
 
-Hardware status: on non-TPU backends the kernel runs under the pallas
-interpreter (identical DMA/loop semantics — the exactness tests run
-there); the kernel is written inside fused_ring.py's probed remote-
-Mosaic constraint inventory (grid-free, int32-only, no scalar
-reductions, slot-unrolled DMA buffers), plus one construct that
-inventory does not cover — the per-slot gather from the resident
-batch — which needs a hardware probe before the ≥10x r5 target can
-be recorded (no chip is attached to this session; the in-bench gate
-will fail loudly rather than record a wrong number).
+Hardware status: the exactness tests run the kernel under the Pallas
+interpreter (``interpret=True``, an explicit request: identical
+DMA/loop semantics); the default is the kernel compiled by Mosaic, and
+with no TPU backend the constructor raises. The kernel is written
+inside fused_ring.py's round-5 constraint inventory (grid-free,
+int32-only, no scalar reductions, slot-unrolled DMA buffers), plus one
+construct that inventory does not cover — the per-slot gather from the
+resident batch — and **that gather does not lower under the installed
+Mosaic (JAX 0.9.0)**, which lowers gathers only in take_along_axis
+shape (docs/pallas_kernels.md; tests/test_chip_compile.py holds the
+compile as ``xfail(strict=True)`` until ROADMAP S2). The in-kernel
+delay draw does lower, after PR 21's cast repairs.
 
 ≙ the reference's event dispatch this batches:
 `/root/reference/src/Control/TimeWarp/Timed/TimedT.hs:234-286`.
@@ -78,7 +81,7 @@ will fail loudly rather than record a wrong number).
 
 from __future__ import annotations
 
-from ...utils import jaxconfig  # noqa: F401
+from ...utils.jaxconfig import require_tpu
 
 import jax
 import jax.numpy as jnp
@@ -97,7 +100,7 @@ from .engine import JaxEngine
 # import them from this module.
 from .pallas_insert import (_LANES, _ROWS, _VMEM_BUDGET,  # noqa: F401
                             _build_kernel, _fold_lanes, _fold_rows8,
-                            _fused_insert_call, _insertion_plan)
+                            _fused_insert_call, _insertion_plan, _umax)
 
 __all__ = ["FusedSparseEngine"]
 
@@ -122,7 +125,7 @@ def _lower_link(link: LinkModel):
             raise ValueError("Quantize quantum_us must be >= 1")
 
         def fn(src, dst, tl, th, key):
-            d = jnp.maximum(inner(src, dst, tl, th, key), jnp.uint32(1))
+            d = _umax(inner(src, dst, tl, th, key), jnp.uint32(1))
             qq = jnp.uint32(q)
             return ((d + qq - jnp.uint32(1)) // qq) * qq
         return nk, ((max(mx, 1) + q - 1) // q) * q, fn
@@ -172,7 +175,10 @@ def _lower_link(link: LinkModel):
             z = normal_f32(b0, b1)
             d = jnp.float32(med) * jnp.exp(jnp.float32(sig) * z)
             d = jnp.clip(d, jnp.float32(floor), jnp.float32(cap))
-            return jnp.round(d).astype(jnp.uint32)
+            # float32 -> int32 -> uint32: Mosaic has no float ->
+            # unsigned cast, and d is clipped to [floor, cap] with
+            # cap < 2^31, so the value is the same
+            return jnp.round(d).astype(jnp.int32).astype(jnp.uint32)
         return True, cap, fn
     raise ValueError(
         f"FusedSparseEngine cannot lower link model {link!r} into the "
@@ -202,7 +208,14 @@ class FusedSparseEngine(JaxEngine):
                  max_batch: int = 1 << 16,
                  lint: str = "warn", telemetry: str = "off",
                  controller=None, verify: str = "off",
-                 record: str = "off", record_cap=None) -> None:
+                 record: str = "off", record_cap=None,
+                 interpret: bool = False) -> None:
+        # the kernel is compiled by Mosaic: the Pallas interpreter is
+        # an explicit request (tests, bench.py --smoke), never a
+        # fallback — no TPU and no request is a refusal
+        self.interpret = bool(interpret)
+        if not self.interpret:
+            require_tpu(type(self).__name__)
         super().__init__(scenario, link, seed=seed, window=window,
                          route_cap=None, record_events=record_events,
                          lint=lint, telemetry=telemetry,
@@ -314,7 +327,8 @@ class FusedSparseEngine(JaxEngine):
                           jnp.int32(0), jnp.int32(0)])
         mrel, msrc, mpay, cnts = _fused_insert_call(
             self._kernel, self._S, n, K, P, sc.inbox_src, scal,
-            sd, woff_s, smrank_s, pay_s, mb_rel, mb_src, mb_payload)
+            sd, woff_s, smrank_s, pay_s, mb_rel, mb_src, mb_payload,
+            interpret=self.interpret)
         overflow_step = jnp.sum(cnts[0], dtype=jnp.int32)
         bad_delay_step = jnp.sum(cnts[1], dtype=jnp.int32)
         short_step = jnp.sum(cnts[2], dtype=jnp.int32)

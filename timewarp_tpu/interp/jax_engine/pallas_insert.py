@@ -1,9 +1,9 @@
 """Pallas mailbox-insertion kernels for the general engine's sparse
-path — fire-compaction + in-tile hole-ranked insertion (round 12; the
-ROADMAP's first open item and PERF_r05.md's "unexplored lever").
+path — fire-compaction + in-tile hole-ranked insertion (round 12).
 
-PERF_r05.md names the remaining praos fat precisely: ~15 ms/superstep
-at 2²⁰×8 dominated by the rung-width outbox gathers (~1.4 ms per
+docs/engines.md "Where the remaining praos fat is" (measured in round
+5, not re-measured) names it precisely: ~15 ms/superstep at 2²⁰×8
+dominated by the rung-width outbox gathers (~1.4 ms per
 65k-lane rung access, 3 arrays), the sender-compaction N-sort
 (1.0–1.6 ms), the free-rows short-axis sort, and the `[K, N]`
 elementwise base — and records that fire-compaction via XLA gathers is
@@ -26,7 +26,7 @@ Two kernels, one opt-in engine knob (``JaxEngine(insert=...)``):
   replaces the sender-compaction sort + per-rung gathers of
   ``JaxEngine._route_adaptive``: the ordering sort still runs in XLA,
   but at *compacted* width (a 131k-element sort is < 0.1 ms on this
-  chip — PERF_r05.md cost table), not at N.
+  chip — docs/engines.md per-op cost table), not at N.
 - **insertion** (:func:`_build_kernel` — shared with fused_sparse.py,
   which this module is now the home of): the double-buffered, grid-free
   kernel that streams the ``[K, N]`` mailbox planes through VMEM once
@@ -48,27 +48,31 @@ batched config). ``JaxEngine`` is itself pinned to the host oracle
 kernels.
 
 Knob resolution (:func:`resolve_insert`): ``insert=None`` reads the
-``TW_INSERT`` env hatch (the promotion of PERF_r05.md §3's
+``TW_INSERT`` env hatch (the promotion of round 5's
 ``TW_FLAT_SCATTER``, which is still honored as a legacy alias) and
-defaults to ``"xla"``; ``"pallas"`` auto-falls back to ``"xla"`` off
-TPU (recorded in ``engine.insert_fallback``, never silent) while
-``"interpret"`` forces the Pallas interpreter — the CPU test surface.
+defaults to ``"xla"``; ``"pallas"`` is the kernels compiled for the
+TPU and raises where JAX found none (no strategy is ever picked from
+the backend's name), while ``"interpret"`` asks for the Pallas
+interpreter explicitly — the CPU test surface.
 ``"xla2d"`` selects the 2D ``[col, row]`` scatter form of the XLA
 insertion stage (no flat-reshape relayout copy of the tiled mailbox —
-the escape hatch PERF_r05.md §3 kept for future hardware).
+the escape hatch round 5 kept for future hardware, docs/engines.md
+"Measured on a v5e").
 
-Hardware status: on non-TPU backends the kernels run under the pallas
-interpreter (identical DMA/loop semantics — the exactness tests run
-there). Both kernels are written inside the probed remote-Mosaic
+Hardware status: the exactness tests run the kernels under the Pallas
+interpreter (``insert="interpret"``: identical DMA/loop semantics);
+tests/test_chip_compile.py asks the TPU's compiler for both at 2^17
+nodes. Both kernels are written inside the round-5 Mosaic
 constraint inventory (grid-free, int32-only, no scalar reductions,
 ``pl.when``-unrolled DMA slots, roll-based lane crossings — the full
 list is consolidated in docs/pallas_kernels.md), plus the two
 constructs the inventory does not cover — the insertion kernel's
 per-slot gather from the resident batch (carried over from
 fused_sparse.py) and the compaction kernel's per-row scatter into the
-VMEM-resident output — which need a hardware probe before the chip
-numbers can be recorded (PERF_r06.md; the in-bench exactness gate
-fails loudly rather than recording a wrong number).
+VMEM-resident output. **Neither lowers under the installed Mosaic
+(JAX 0.9.0)**: gathers lower only in take_along_axis shape and
+scatter has no lowering rule (docs/pallas_kernels.md); both compile
+tests are ``xfail(strict=True)`` until ROADMAP S2 re-expresses them.
 
 ≙ the reference's event dispatch this batches:
 `/root/reference/src/Control/TimeWarp/Timed/TimedT.hs:234-286`.
@@ -103,9 +107,9 @@ _VMEM_BUDGET = 12 * 2**20
 #: the engine knob's legal values: "xla" = flat-index 1D scatters (the
 #: r5-measured default on this chip), "xla2d" = the 2D [col, row]
 #: scatter form (no tiled-relayout copy — the TW_FLAT_SCATTER escape
-#: hatch, promoted), "pallas" = the kernels on TPU (auto-fallback to
-#: "xla" elsewhere), "interpret" = the kernels under the Pallas
-#: interpreter on any backend (the test/CI surface)
+#: hatch, promoted), "pallas" = the kernels compiled for the TPU (a
+#: refusal where there is none), "interpret" = the kernels under the
+#: Pallas interpreter on any backend (the test/CI surface)
 INSERT_MODES = ("xla", "xla2d", "pallas", "interpret")
 _ENV_KNOB = "TW_INSERT"
 _LEGACY_ENV = "TW_FLAT_SCATTER"
@@ -113,14 +117,14 @@ _LEGACY_ENV = "TW_FLAT_SCATTER"
 
 def resolve_insert(requested: Optional[str], *, honor_env: bool,
                    who: str = "engine"):
-    """Resolve the ``insert=`` knob to the strategy that will actually
-    run: ``(requested, resolved, fallback_reason, from_env)``.
-    ``None`` reads the documented ``TW_INSERT`` env hatch (legacy
-    ``TW_FLAT_SCATTER=1`` maps to ``"xla"``, ``=0`` to ``"xla2d"`` —
-    PERF_r05.md §3, promoted) and defaults to ``"xla"``; ``"pallas"``
-    off-TPU auto-falls back to ``"xla"`` with the reason recorded (use
-    ``"interpret"`` to force the kernels under the Pallas
-    interpreter). ``from_env`` marks env-sourced modes: an env hatch
+    """Resolve the ``insert=`` knob to the strategy that was asked
+    for: ``(mode, from_env)``. ``None`` reads the documented
+    ``TW_INSERT`` env hatch (legacy ``TW_FLAT_SCATTER=1`` maps to
+    ``"xla"``, ``=0`` to ``"xla2d"``) and defaults to ``"xla"``.
+    Nothing here asks which backend is there: ``"pallas"`` is the
+    compiled kernels, and the engine that builds them refuses where
+    JAX found no TPU (``"interpret"`` asks for the Pallas interpreter
+    explicitly). ``from_env`` marks env-sourced modes: an env hatch
     must stay behavior-neutral, so kernel-scope violations fall back
     (recorded) instead of crashing runs that worked before the var was
     exported — explicit constructor/CLI requests still refuse loudly.
@@ -143,14 +147,7 @@ def resolve_insert(requested: Optional[str], *, honor_env: bool,
             f"{mode!r} ('xla' = flat scatters, 'xla2d' = 2D scatter "
             "form, 'pallas' = the Pallas insertion kernels, "
             "'interpret' = the kernels under the Pallas interpreter)")
-    resolved, reason = mode, None
-    if mode == "pallas" and jax.default_backend() != "tpu":
-        resolved = "xla"
-        reason = (f"no TPU backend ({jax.default_backend()}) — "
-                  "insert='pallas' auto-falls back to 'xla'; use "
-                  "insert='interpret' to force the kernels under the "
-                  "Pallas interpreter")
-    return mode, resolved, reason, from_env
+    return mode, from_env
 
 
 # ----------------------------------------------------------------------
@@ -158,6 +155,17 @@ def resolve_insert(requested: Optional[str], *, honor_env: bool,
 # lower in-kernel — the probed constraint inventory, fused_ring.py /
 # docs/pallas_kernels.md)
 # ----------------------------------------------------------------------
+
+def _umax(a, b):
+    """Unsigned max/min as compare + select: Mosaic legalizes the
+    unsigned compares but not ``arith.maxui`` / ``arith.minui``
+    (compiled for a described v5e, tests/test_chip_compile.py)."""
+    return jnp.where(a > b, a, b)
+
+
+def _umin(a, b):
+    return jnp.where(a < b, a, b)
+
 
 def _fold_lanes(x):
     """[R, 1024] int32 -> [R, 128] partial sums (unrolled adds)."""
@@ -191,7 +199,11 @@ def _lane_excl_prefix(v, lane):
     x = v
     s = 1
     while s < _LANES:
-        x = x + jnp.where(lane >= s, jnp.roll(x, s, axis=-1), 0)
+        # explicit int32 literals: under x64 a python int in a
+        # jnp op is an int64 constant first, and Mosaic's lowering
+        # of the int64 -> int32 convert recurses without end
+        x = x + jnp.where(lane >= jnp.int32(s),
+                          jnp.roll(x, s, axis=-1), jnp.int32(0))
         s *= 2
     return x - v
 
@@ -199,7 +211,7 @@ def _lane_excl_prefix(v, lane):
 def _row_total(incl):
     """Per-row total of an inclusive lane prefix, [R, 1024] -> [R, 1]:
     the last lane read through ``roll`` + lane 0 (last-lane slices
-    crash the remote Mosaic service; lane-0 reads of a rolled array
+    crashed the round-5 Mosaic; lane-0 reads of a rolled array
     are the fused_ring.py boundary idiom)."""
     return jnp.roll(incl, 1, axis=-1)[:, 0:1]
 
@@ -307,12 +319,12 @@ def _build_kernel(*, K, P, R, G, SR, n, M, W, inbox_src, mode,
                 b0, b1 = threefry2x32(a0, a1, lo, hi)
                 key = threefry2x32(b0, b1, slot, jnp.uint32(0))
             delay = delay_fn(srcp, dstp, lo, hi, key)
-            flight = jnp.maximum(delay, jnp.uint32(1))  # contract #4
+            flight = _umax(delay, jnp.uint32(1))        # contract #4
             dsum = woff_u + flight
             badm = valid & (dsum > jnp.uint32(_I32MAX - 1))
             shortm = (valid & (flight < jnp.uint32(W))) if W > 1 \
                 else jnp.zeros((SR, 128), bool)
-            drelp = jnp.minimum(
+            drelp = _umin(
                 dsum, jnp.uint32(_I32MAX - 1)).astype(jnp.int32)
             bad8 = _fold_rows8(badm.astype(jnp.int32))
             short8 = _fold_rows8(shortm.astype(jnp.int32))
@@ -464,15 +476,15 @@ def _build_kernel(*, K, P, R, G, SR, n, M, W, inbox_src, mode,
 
 def _fused_insert_call(kernel, S, n, K, P, inbox_src, scal, sd, a1, a2,
                        pay_s, mb_rel, mb_src, mb_payload, *,
-                       ordered=False, counts=None, interpret=None):
+                       interpret, ordered=False, counts=None):
     """Stack the sorted batch + per-node bucket planes and run the
     fused kernel once. ``sd`` is the sorted destination row (sentinel
     ``n`` = invalid); ``(a1, a2)`` are the mode's second/third resident
     planes — (woff, smrank) for in-kernel sampling, (drel, src) for
     pre-sampled insertion. ``ordered=True`` threads the per-node kept
     ``counts`` as one extra input plane (the append-mode target);
-    ``interpret`` overrides the backend-derived Pallas-interpreter
-    choice (the insert="interpret" knob). Returns the post-insertion
+    ``interpret`` runs the kernel under the Pallas interpreter (the
+    caller's explicit request, never derived here). Returns the post-insertion
     mailbox arrays plus the [3, 8, 128] counter partials."""
     SA = sd.shape[0]
     L = _LANES
@@ -517,16 +529,13 @@ def _fused_insert_call(kernel, S, n, K, P, inbox_src, scal, sd, a1, a2,
         kernel,
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
                   pl.BlockSpec(memory_space=pltpu.VMEM),
-                  pl.BlockSpec(memory_space=pltpu.ANY)],
-        out_specs=[pl.BlockSpec(memory_space=pltpu.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=[pl.BlockSpec(memory_space=pl.ANY),
                    pl.BlockSpec(memory_space=pltpu.VMEM)],
         out_shape=[
             jax.ShapeDtypeStruct((NPO, NR, L), jnp.int32),
             jax.ShapeDtypeStruct((3, 8, 128), jnp.int32)],
-        # non-TPU backends run the pallas interpreter — identical
-        # DMA/loop semantics, which is what the exactness tests pin
-        interpret=(jax.default_backend() != "tpu"
-                   if interpret is None else interpret),
+        interpret=interpret,
     )(scal, msgs, st_planes)
     mrel = out_planes[:K].reshape(K, n)
     mpay = out_planes[K:K + K * P].reshape(K, P, n)
@@ -670,7 +679,7 @@ def _fire_compact_call(kernel, S, n, M, P, W, pdst, woff_n, payload,
     SR = S // 128
     msgs, cnts = pl.pallas_call(
         kernel,
-        in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=[pl.BlockSpec(memory_space=pltpu.VMEM),
                    pl.BlockSpec(memory_space=pltpu.VMEM)],
         out_shape=[

@@ -22,7 +22,7 @@ from __future__ import annotations
 
 from typing import Optional
 
-from ...utils import jaxconfig  # noqa: F401
+from ...utils.jaxconfig import require_tpu
 
 import jax
 import jax.numpy as jnp
@@ -317,8 +317,13 @@ class ShardedFusedSparseEngine(ShardedEngine):
                  bucket_cap: Optional[int] = None,
                  window: int = 1, lint: str = "warn",
                  telemetry: str = "off", verify: str = "off",
-                 record: str = "off") -> None:
+                 record: str = "off", interpret: bool = False) -> None:
         _refuse_record(record, type(self).__name__)
+        # compiled kernel by default; the Pallas interpreter only on
+        # request (fused_sparse.py)
+        self.interpret = bool(interpret)
+        if not self.interpret:
+            require_tpu(type(self).__name__)
         super().__init__(scenario, link, mesh, axis=axis, seed=seed,
                          bucket_cap=bucket_cap, window=window,
                          route_cap=None, lint=lint, telemetry=telemetry,
@@ -348,5 +353,5 @@ class ShardedFusedSparseEngine(ShardedEngine):
             self._ins_kernel, self._S2, self.comm.n_local,
             sc.mailbox_cap, sc.payload_width, sc.inbox_src,
             jnp.zeros(4, jnp.int32), sd, drel_s, src_s, pay_s,
-            mb_rel, mb_src, mb_payload)
+            mb_rel, mb_src, mb_payload, interpret=self.interpret)
         return mrel, msrc, mpay, jnp.sum(cnts[0], dtype=jnp.int32)

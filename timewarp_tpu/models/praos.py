@@ -78,7 +78,7 @@ def praos(n: int, *,
     # the threshold rides IN THE STATE, not as a closed-over [n] table:
     # a vmapped `table[i]` lowers to an N-wide gather, and even
     # iota-indexed gathers cost ~9 ns/element on this chip (~9 ms at
-    # 1M nodes per superstep — profiling/micro2_r05.py); a state leaf
+    # 1M nodes per superstep — docs/engines.md per-op cost table); a state leaf
     # is a pure elementwise read
 
     def step_burst(state, inbox: Inbox, now, i, key):
@@ -205,7 +205,7 @@ def praos(n: int, *,
         commutative_inbox=True,
         # the adopt is a pure max-reduction over tip lengths and the
         # relayer id travels in payload[:, 1] — inbox.src is never
-        # read, so engines skip the mb_src scatter (PERF_r04.md)
+        # read, so engines skip the mb_src scatter (docs/engines.md "Measured on a v5e")
         inbox_src=False,
         meta={"slot_us": slot_us, "n_slots": n_slots,
               "leader_prob": leader_prob, "fanout": fanout,

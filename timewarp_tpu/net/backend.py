@@ -294,7 +294,7 @@ class EmulatedBackend(NetBackend):
         self._ports: Dict[NetworkAddress, _EmuListener] = {}
         self._conn_seq: Dict[Tuple[int, int], int] = {}
         self._ephemeral = 49152
-        #: explicit endpoint-name -> id mapping (VERDICT r4 item 3):
+        #: explicit endpoint-name -> id mapping:
         #: lets the fabric feed the link model the SAME ids the
         #: batched world uses (node indices), so one seeded link model
         #: draws identical delays in both worlds; unmapped names
@@ -322,8 +322,7 @@ class EmulatedBackend(NetBackend):
         is ~60 elementwise jnp ops, and dispatching them un-jitted
         costs real wall-clock per chunk — harmless to the virtual clock
         of the pure emulator, but enough to starve ms-scale timers
-        under the real-time interpreter (and worse through a
-        remote-device tunnel)."""
+        under the real-time interpreter."""
         import jax.numpy as jnp
         delay, drop = _jitted_draw(model)(
             jnp.uint32(self._s0), jnp.uint32(self._s1),

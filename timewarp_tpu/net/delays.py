@@ -11,7 +11,7 @@ and evaluates per-message in the host oracle with identical bits.
 
 Entropy is a pair of uint32 words from :mod:`timewarp_tpu.core.rng`
 (counter-derived per message, never a materialized key array — see
-profiling/superstep_breakdown.md for why). Models that use no
+docs/engines.md "Measured on a v5e" for why). Models that use no
 randomness declare ``needs_key = False`` so engines skip deriving
 entropy entirely.
 

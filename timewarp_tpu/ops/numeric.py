@@ -1,7 +1,7 @@
 """Engine-generic integer primitives shared by every batched engine.
 
 These are the "hot ops" of the TPU build in their XLA-native form —
-profiled and shaped for the VPU (profiling/superstep_breakdown.md):
+profiled and shaped for the VPU (docs/engines.md "Measured on a v5e"):
 pure elementwise/scan/sort building blocks, no gathers or scatters.
 SURVEY.md §2 records the design stance: XLA-compiled JAX *is* this
 framework's native layer; Pallas would only enter if a fused op beat
@@ -25,7 +25,7 @@ def group_rank(sorted_keys: jax.Array) -> jax.Array:
 
     Replaces ``searchsorted(keys, keys, 'left')`` in the routing path —
     on TPU searchsorted lowers to ~log2(S) chained gather rounds
-    (~1 ms each at 131k elements, profiling/superstep_breakdown.md)
+    (~1 ms each at 131k elements, docs/engines.md "Measured on a v5e")
     while the cummax scan is elementwise-cheap. Uses the ``lax.cummax``
     primitive: the hand-rolled ``associative_scan(maximum, …)`` tree it
     replaces wedged the TPU compile service for minutes-to-forever at

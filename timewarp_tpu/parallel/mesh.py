@@ -30,26 +30,13 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..interp.jax_engine.common import LocalComm, padded_scan
 
-try:  # newer jax exports shard_map at the top level
-    _shard_map = jax.shard_map
-except AttributeError:  # 0.4.x: experimental home
-    from jax.experimental.shard_map import shard_map as _shard_map
-# the replication-check kwarg was renamed check_rep -> check_vma in a
-# DIFFERENT release than the public promotion — read the signature
-# instead of inferring from where shard_map lives
-import inspect as _inspect
-
-_CHECK_KW = ("check_vma" if "check_vma"
-             in _inspect.signature(_shard_map).parameters
-             else "check_rep")
-
 
 def _smap(f, mesh, in_specs, out_specs):
-    """Version-portable ``shard_map`` with replication checking off
-    (the engines' collectives are hand-placed; the checker rejects the
-    boundary-slice ppermute pattern on some jax versions)."""
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **{_CHECK_KW: False})
+    """``jax.shard_map`` with replication checking off (the engines'
+    collectives are hand-placed; the checker rejects the
+    boundary-slice ppermute pattern)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 __all__ = ["AxisName", "Mesh", "MeshComm", "ShardedDriver", "axis_size",

@@ -36,11 +36,12 @@ that seed (world 0 against the solo column itself). Engines without
 the world axis record the refusal, never a silent absence.
 
 Usage: ``python tools/parity_tpu.py`` (writes PARITY_TPU.json at the
-repo root). Exits nonzero on any trace mismatch. If no accelerator is
-attached the artifact records the platform actually used.
-``--self-check`` (CI mode) runs the same comparison but does not
-overwrite the committed artifact — on a CPU-only runner the engines
-and oracle share a backend, so it degrades to an engine≡oracle gate.
+repo root). Exits nonzero on any trace mismatch, and refuses to write
+the artifact where JAX found no TPU. One process does all chip work.
+``--self-check`` (CI mode) runs the same comparison anywhere and
+writes nothing — on a CPU-only runner the engines and oracle share a
+backend and the fused-sparse column runs under the Pallas interpreter,
+so it degrades to an engine≡oracle gate.
 """
 
 import hashlib
@@ -51,7 +52,7 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
-from timewarp_tpu.utils import jaxconfig  # noqa: F401,E402
+from timewarp_tpu.utils import jaxconfig  # noqa: E402
 
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
@@ -81,7 +82,17 @@ def main() -> int:
         FixedDelay, Quantize, UniformDelay, WithDrop)
     from timewarp_tpu.trace.events import TraceMismatch, assert_traces_equal
 
+    self_check = "--self-check" in sys.argv
+    jaxconfig.enable_compile_cache()
+    jaxconfig.keep_host_cpu()
     platform = jax.devices()[0].platform
+    if platform != "tpu" and not self_check:
+        # the artifact is named for the TPU: a CPU run is never
+        # written under that name
+        raise SystemExit(
+            f"parity_tpu: JAX found {platform!r}, not a TPU — "
+            "PARITY_TPU.json is written from a chip run only (use "
+            "--self-check for the engine≡oracle gate on the CPU)")
     cpu = jax.devices("cpu")[0]
 
     wlink = Quantize(UniformDelay(3_000, 9_000), 1_000)  # min delay 3 ms
@@ -175,14 +186,36 @@ def main() -> int:
         # against the general engine. Out-of-scope configs record the
         # constructor's refusal, never a silent absence.
         fkw = {k: v for k, v in ekw.items() if k != "route_cap"}
+        # --self-check asks for the Pallas interpreter explicitly;
+        # the artifact run compiles the kernel for the chip, and a
+        # kernel the chip's compiler refuses is written into the
+        # column in the compiler's own words (tests/
+        # test_chip_compile.py pins the same refusal) — a column that
+        # could not run is not a parity mismatch
+        fused = ftrace = None
         try:
-            fused = FusedSparseEngine(sc, link, **fkw)
-        except ValueError as e:
+            fused = FusedSparseEngine(sc, link, interpret=self_check,
+                                      **fkw)
+        except ValueError as e:         # the constructor's scope guard
             entry["fused_sparse"] = {
                 "supported": False,
                 "reason": str(e).split(" (")[0]}
-        else:
-            _, ftrace = fused.run(steps)
+        try:
+            if fused is not None:
+                _, ftrace = fused.run(steps)
+        except (AssertionError, NotImplementedError,
+                RecursionError) as e:   # Mosaic's lowering
+            if self_check:
+                raise
+            import traceback
+            at = traceback.extract_tb(e.__traceback__)[-1]
+            entry["fused_sparse"] = {
+                "supported": False,
+                "reason": "the kernel does not lower for this chip: "
+                          f"{type(e).__name__} in {at.name} "
+                          f"({os.path.basename(at.filename)}:"
+                          f"{at.lineno}) {str(e)[:200]}".rstrip()}
+        if ftrace is not None:
             fent = {"supported": True, "sha": trace_sha(ftrace)}
             try:
                 assert_traces_equal(etrace, ftrace,
@@ -282,7 +315,7 @@ def main() -> int:
               f"{entry['delivered']} delivered, {fused_word}, "
               f"{bat_word}{flt_word})")
 
-    if "--self-check" not in sys.argv:
+    if not self_check:
         root = os.path.dirname(os.path.dirname(os.path.abspath(
             __file__)))
         with open(os.path.join(root, "PARITY_TPU.json"), "w") as f:
