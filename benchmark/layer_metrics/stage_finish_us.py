@@ -1,0 +1,11 @@
+"""Superstep, XLA: device microseconds a superstep under the scope
+``tw.finish``: assembling the next state and its counters (and the trace digests where a driver asks for them).
+Leaf operations by the ``op_name`` the program's ``jax.named_scope``
+gave them (``span_reduce.stage_ns``). Nothing to read where the run
+brings no spans or the program names no stage."""
+
+import span_reduce
+
+
+def read(trace, run):
+    return span_reduce.stage_us(trace, run, "tw.finish")

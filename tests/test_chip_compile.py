@@ -88,7 +88,14 @@ def test_fused_ring_superstep_lowers_at_2p20(one_chip, as_tpu):
         overflow=_sds(one_chip, ()),
         steps=_sds(one_chip, (), jnp.int64))
     compiled = jax.jit(eng._superstep).lower(st).compile()
-    assert compiled.as_text().count("tpu_custom_call") == 1
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1
+    # the kernel carries its name into the compiled program, under the
+    # stage scope a trace reduction finds it by
+    kernel, = [ln for ln in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln]
+    assert "%tw_ring_superstep" in kernel
+    assert "tw.ring_kernel/tw_ring_superstep" in kernel
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes >= 10 * n * 4
     assert mem.temp_size_in_bytes < (1 << 20)   # nothing staged in HBM

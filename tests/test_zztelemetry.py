@@ -265,6 +265,10 @@ def test_fault_dropped_counter_bites():
 # uniform last_run_stats
 # ---------------------------------------------------------------------------
 
+_STATS_KEYS = {"supersteps", "wall_seconds", "compiles", "dispatches",
+               "readbacks"}
+
+
 def test_last_run_stats_uniform_across_engines():
     sc, link = _ring()
     engines = [JaxEngine(sc, link, lint="off"),
@@ -272,7 +276,8 @@ def test_last_run_stats_uniform_across_engines():
     for eng in engines:
         _, trace = eng.run(STEPS)
         st = eng.last_run_stats
-        assert set(st) == {"supersteps", "wall_seconds", "compiles"}
+        assert set(st) == _STATS_KEYS
+        assert (st["dispatches"], st["readbacks"]) == (1, 1)
         assert st["supersteps"] == len(trace)
         assert st["wall_seconds"] > 0
         assert st["compiles"] >= 0
@@ -281,8 +286,9 @@ def test_last_run_stats_uniform_across_engines():
     orc = SuperstepOracle(sc, link, lint="off")
     trace = orc.run(STEPS)
     st = orc.last_run_stats
-    assert set(st) == {"supersteps", "wall_seconds", "compiles"}
+    assert set(st) == _STATS_KEYS
     assert st["supersteps"] == len(trace) and st["compiles"] == 0
+    assert (st["dispatches"], st["readbacks"]) == (0, 0)
 
 
 def test_stats_count_compiles_via_pow2_bucket():

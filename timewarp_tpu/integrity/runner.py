@@ -371,18 +371,11 @@ class VerifiedRunMixin:
                              mode=mode, chunk=ci, event="verified")
             ci += 1
 
-        if chunk_stats:
-            self._stats_merge(chunk_stats)
-        else:
-            # a zero-chunk run (already quiesced, or budget 0) must
-            # not leave a PREVIOUS run's stats behind for the digest
-            # fields below to graft onto — that record would be a
-            # chimera of old wall/superstep numbers and this run's
-            # digests
-            self.last_run_stats = {"supersteps": 0,
-                                   "wall_seconds": 0.0, "compiles": 0,
-                                   "chunks": 0,
-                                   "per_chunk_compiles": []}
+        # a zero-chunk run too (already quiesced, or budget 0): it
+        # must not leave a PREVIOUS run's stats behind for the digest
+        # fields below to graft onto — that record would be a chimera
+        # of old wall/superstep numbers and this run's digests
+        self._stats_merge(chunk_stats)
         if self.telemetry != "off":
             from ..obs.telemetry import concat_frames
             self.last_run_telemetry = concat_frames(frame_chunks)

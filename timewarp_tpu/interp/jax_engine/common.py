@@ -7,17 +7,39 @@ implementation run single-chip or sharded over a mesh
 from __future__ import annotations
 
 import time
+from contextlib import ExitStack, contextmanager
 from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ...obs.profiler import span
 from ...ops.numeric import I32MAX, group_rank, thi, tlo, u32sum
 
 __all__ = ["LocalComm", "StepOut", "I32MAX", "group_rank", "u32sum",
            "tlo", "thi", "padded_scan", "scan_pad",
-           "init_states_wake", "RunStatsMixin", "DynDispatch"]
+           "init_states_wake", "RunStatsMixin", "DynDispatch",
+           "STAGES", "Stages"]
+
+#: the superstep's stages, in order, as ``jax.named_scope`` names on
+#: the device work of every engine that adopts them (``engine.py``,
+#: ``fused_ring.py``; the ring's kernel sits under ``tw.ring_kernel``).
+#: A scope is metadata: it adds no equation, and a profile shows it in
+#: each operation's ``op_name`` (benchmark/span_reduce.py ``stage_ns``)
+STAGES = ("tw.next_event", "tw.deliver", "tw.fire", "tw.rebase",
+          "tw.route", "tw.finish")
+
+
+class Stages(ExitStack):
+    """Walks one superstep through its stages: ``stage(name)`` leaves
+    the scope it was in and enters ``name``; leaving the ``with``
+    closes the last. So a long function with early returns is divided
+    where its stages change, without an indent."""
+
+    def __call__(self, name: str) -> None:
+        self.close()
+        self.enter_context(jax.named_scope(name))
 
 
 class DynDispatch(NamedTuple):
@@ -185,26 +207,74 @@ class LocalComm:
         return jnp.asarray(table)
 
 
+class _DriverCall:
+    """One driver call's boundary with the chip: what it launched, what
+    it read back, and the host spans of both (``obs.profiler.span``,
+    all carrying the call's ``run`` number)."""
+
+    def __init__(self, eng, run: int):
+        self.eng, self.run = eng, run
+        self.t0, self.c0 = time.perf_counter(), eng._driver_compiles()
+        self.dispatches = self.readbacks = 0
+
+    def dispatch(self, fn, *args):
+        """Launch one executable, under ``tw.dispatch``: from entry to
+        the jitted driver's return (argument handling and enqueue)."""
+        self.dispatches += 1
+        with span("tw.dispatch", run=self.run):
+            return fn(*args)
+
+    def wait(self, steps_before, steps_after, *more):
+        """The blocking read that ends the device's work, under
+        ``tw.wait``: the step counters and whatever else the driver
+        reads back (``more``, returned on the host) in one transfer.
+        Sets ``last_run_stats``."""
+        self.readbacks += 1
+        with span("tw.wait", run=self.run):
+            before, after, *more = jax.device_get(
+                (steps_before, steps_after) + more)
+        d = np.asarray(after, np.int64) - np.asarray(before, np.int64)
+        self.eng.last_run_stats = {
+            "supersteps": int(d.sum()),
+            "wall_seconds": time.perf_counter() - self.t0,
+            "compiles": self.eng._driver_compiles() - self.c0,
+            "dispatches": self.dispatches, "readbacks": self.readbacks,
+        }
+        return more
+
+    def guard(self):
+        """``tw.guard`` around a verify or speculation guard that
+        runs: one more readback, whatever it reads."""
+        self.readbacks += 1
+        self.eng.last_run_stats["readbacks"] = self.readbacks
+        return span("tw.guard", run=self.run)
+
+
 class RunStatsMixin:
     """Uniform host-side driver accounting for every engine: after any
     ``run``/``run_quiet``, ``engine.last_run_stats`` holds::
 
         {"supersteps": int,    # executed this call (fleet total)
          "wall_seconds": float,
-         "compiles": int}      # driver executables compiled this call
+         "compiles": int,      # driver executables compiled this call
+         "dispatches": int,    # executables launched by the call
+         "readbacks": int}     # blocking host reads by the call
 
     Compile counting reads the jitted drivers' ``_cache_size`` (the
     same probe tests/test_world_batch.py pins the pow2 bucketing
     with), so a run that silently retraced is visible in its stats.
-    Host-side timing only — nothing here is compiled in, so the
-    telemetry zero-overhead law is untouched and the stats exist in
-    every telemetry mode including "off".
+    Host-side only — nothing here is compiled in, so the telemetry
+    zero-overhead law is untouched and the stats exist in every
+    telemetry mode including "off". Every driver goes through
+    :meth:`_driver_call`, so the spans of docs/observability.md have
+    one implementation.
     """
 
     #: the jitted driver attributes whose compile caches count
     _DRIVER_FNS = ("_run_scan", "_run_while")
 
     last_run_stats = None
+    _calls = 0                  # driver calls so far: the spans' `run`
 
     def _driver_compiles(self) -> int:
         n = 0
@@ -215,19 +285,13 @@ class RunStatsMixin:
                 n += cs()
         return n
 
-    def _stats_begin(self):
-        return time.perf_counter(), self._driver_compiles()
-
-    def _stats_end(self, begin, steps_before, steps_after) -> dict:
-        t0, c0 = begin
-        d = (np.asarray(jax.device_get(steps_after), np.int64)
-             - np.asarray(jax.device_get(steps_before), np.int64))
-        self.last_run_stats = {
-            "supersteps": int(d.sum()),
-            "wall_seconds": time.perf_counter() - t0,
-            "compiles": self._driver_compiles() - c0,
-        }
-        return self.last_run_stats
+    @contextmanager
+    def _driver_call(self, driver: str):
+        """The ``tw.<driver>`` span around one driver call; yields its
+        :class:`_DriverCall`."""
+        self._calls += 1
+        with span("tw." + driver, run=self._calls):
+            yield _DriverCall(self, self._calls)
 
     def _stats_merge(self, chunks) -> dict:
         """Fold per-chunk ``last_run_stats`` dicts into one run-level
@@ -244,6 +308,8 @@ class RunStatsMixin:
             "supersteps": sum(c["supersteps"] for c in chunks),
             "wall_seconds": sum(c["wall_seconds"] for c in chunks),
             "compiles": sum(c["compiles"] for c in chunks),
+            "dispatches": sum(c.get("dispatches", 0) for c in chunks),
+            "readbacks": sum(c.get("readbacks", 0) for c in chunks),
             "chunks": len(chunks),
             "per_chunk_compiles": [c["compiles"] for c in chunks],
         }

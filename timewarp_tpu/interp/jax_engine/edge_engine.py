@@ -764,15 +764,14 @@ class EdgeEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
     def run(self, max_steps: int,
             state: Optional[EdgeState] = None
             ) -> Tuple[EdgeState, SuperstepTrace]:
-        st = state if state is not None else self.init_state()
-        begin = self._stats_begin()
         # _pad_mult = 2 is the shadow verify mode's pow2-cache twin
         # (integrity/runner.py) — a distinct executable, same results
-        final, ys = self._run_scan(st,
-                                   scan_pad(max_steps) * self._pad_mult,
-                                   jnp.asarray(max_steps, jnp.int64))
-        ys = jax.device_get(ys)
-        self._stats_end(begin, st.steps, final.steps)
+        with self._driver_call("run") as call:
+            st = state if state is not None else self.init_state()
+            final, ys = call.dispatch(
+                self._run_scan, st, scan_pad(max_steps) * self._pad_mult,
+                jnp.asarray(max_steps, jnp.int64))
+            ys, = call.wait(st.steps, final.steps, ys)
         self._capture_flight(ys, st)
         self._capture_integrity(ys)
         self.last_run_telemetry = None
@@ -804,12 +803,13 @@ class EdgeEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
                   state: Optional[EdgeState] = None) -> EdgeState:
         """Traceless driver: one ``while_loop``, digests, counts, and
         telemetry planes not even compiled in."""
-        st = state if state is not None else self.init_state()
-        begin = self._stats_begin()
-        final = self._run_while(st, max_steps)
-        self._stats_end(begin, st.steps, final.steps)
-        if self.verify != "off":
-            # never silently unverified (JaxEngine.run_quiet twin)
-            from ...integrity.checks import final_state_guard
-            final_state_guard(final, type(self).__name__)
+        with self._driver_call("run_quiet") as call:
+            st = state if state is not None else self.init_state()
+            final = call.dispatch(self._run_while, st, max_steps)
+            call.wait(st.steps, final.steps)
+            if self.verify != "off":
+                # never silently unverified (JaxEngine.run_quiet twin)
+                from ...integrity.checks import final_state_guard
+                with call.guard():
+                    final_state_guard(final, type(self).__name__)
         return final

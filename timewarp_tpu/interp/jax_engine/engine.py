@@ -49,7 +49,7 @@ from ...trace.events import SuperstepTrace
 from ...trace.hashing import FIRED, RECV, SENT, mix32_jnp
 from .batched import BatchSpec, WorldIdentity, rebind_link
 from .common import I32MAX as _I32MAX
-from .common import LocalComm, RunStatsMixin, StepOut as _StepOut
+from .common import LocalComm, RunStatsMixin, Stages, StepOut as _StepOut
 from .common import group_rank
 from .common import padded_scan, scan_pad as _scan_pad
 from .common import thi as _thi, tlo as _tlo, u32sum as _u32sum
@@ -672,6 +672,7 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
 
     # -- one superstep ---------------------------------------------------
 
+    @jax.named_scope("exchange")
     def _exchange(self, ok, drel, src_f, dst_f, smrank, woff, pay_cols):
         """Hand routed messages to the device that owns their
         destination, returning ``(ok, drel, src, local_row, smrank,
@@ -714,6 +715,7 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         rungs.append(n)
         return rungs
 
+    @jax.named_scope("sample")
     def _sample_nodrop(self, src, dst, tmsg, slot, woff, ok):
         """Shared link-sampling tail for the no-drop routing paths
         (lazy and adaptive): derive per-message entropy, apply the
@@ -751,6 +753,7 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
                            jnp.int64(_I32MAX - 1)).astype(jnp.int32)
         return flight, drel, bad, short, strag
 
+    @jax.named_scope("insert")
     def _insert_sorted(self, mb_rel, mb_src, mb_payload, sd, ok_s,
                        drel_s, src_s, pay_s, free_rows, counts):
         """Shared mailbox insertion for destination-sorted messages:
@@ -1180,6 +1183,13 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
 
     def _superstep(self, st: EngineState, with_trace: bool
                    ) -> Tuple[EngineState, Optional[_StepOut]]:
+        with Stages() as stage:
+            return self._staged_superstep(st, with_trace, stage)
+
+    def _staged_superstep(self, st, with_trace, stage):
+        """One superstep, each numbered part under the scope ``stage``
+        names for it (common.py ``STAGES``)."""
+        stage("tw.next_event")
         sc, comm = self.scenario, self.comm
         K, M, P = sc.mailbox_cap, sc.max_out, sc.payload_width
         n = comm.n_local            # array width on this device
@@ -1298,6 +1308,7 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
                     jnp.int64(-1),
                     st.mb_rel, flight.TAG_PURGE, t_off=base))
 
+        stage("tw.deliver")
         # 2. deliverable messages: due at or before the node's own
         #    firing instant (== `<= shift32` when W == 1)
         deliver = mb_live & (st.mb_rel <= nrel[None, :]) & fire[None, :]
@@ -1343,6 +1354,7 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
                 payload=jnp.where(ib_valid[:, None, :], ib_pay, 0),
             )
 
+        stage("tw.fire")
         # 4. fire every node simultaneously, each at its own instant;
         # mask non-fired results. Entropy is derived elementwise
         # (core/rng.py), keyed by the node's own firing instant — the
@@ -1379,6 +1391,7 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
                 jnp.any(out_valid, axis=0), dtype=jnp.int32))
             self._t_rung = jnp.int32(-1)
 
+        stage("tw.rebase")
         # 5. drop delivered messages and rebase surviving deliver-times
         #    to the new epoch t. Two regimes:
         #    - commutative inbox: slot order is unobservable, so freed
@@ -1421,6 +1434,7 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
             free_rows = None
             counts = kept.sum(axis=0, dtype=jnp.int32)          # [N]
 
+        stage("tw.route")
         # 6. route outboxes — three regimes. Adaptive sender-compacted
         #    routing (class docstring) never materializes the
         #    S = N·max_out flattened arrays at all; the legacy paths
@@ -1462,6 +1476,7 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
             # (partition cuts + down-window deliveries); the fused
             # override and the unfaulted tail return the bare 10-tuple
             fault_route = res[10] if len(res) > 10 else jnp.int32(0)
+            stage("tw.finish")
             return self._finish_superstep(
                 st, live, states, wake, mb_rel, mb_src, mb_payload,
                 deliver, fire, node_ids, t, base, now_vec,
@@ -1668,6 +1683,7 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
                 sent_hash = comm.all_sum(
                     _u32sum(jnp.where(ok, sent_mix, 0)))
                 sent_count = comm.all_sum(jnp.sum(ok, dtype=jnp.int32))
+        stage("tw.finish")
         return self._finish_superstep(
             st, live, states, wake, mb_rel, mb_src, mb_payload,
             deliver, fire, node_ids, t, base, now_vec,
@@ -2149,17 +2165,16 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
                 "_dyn carries dispatch-controller knob values; build "
                 "the engine with controller= (docs/dispatch.md) or "
                 "speculate= (docs/speculation.md)")
-        st = state if state is not None else self.init_state()
-        budget, top = self._coerce_budget(max_steps)
-        begin = self._stats_begin()
         # _pad_mult = 2 is the shadow verify mode's pow2-cache twin
         # (integrity/runner.py): still a pow2 (the masked tail keeps
         # results bit-identical), but a DIFFERENT compiled executable
-        final, ys = self._run_scan(
-            st, _scan_pad(top) * self._pad_mult, budget, _dyn,
-            self._identity())
-        ys = jax.device_get(ys)
-        self._stats_end(begin, st.steps, final.steps)
+        with self._driver_call("run") as call:
+            st = state if state is not None else self.init_state()
+            budget, top = self._coerce_budget(max_steps)
+            final, ys = call.dispatch(
+                self._run_scan, st, _scan_pad(top) * self._pad_mult,
+                budget, _dyn, self._identity())
+            ys, = call.wait(st.steps, final.steps, ys)
         self._capture_telemetry(ys)
         self._capture_flight(ys, st)
         self._capture_integrity(ys)
@@ -2174,6 +2189,7 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
             np.asarray(ys.sent_hash)[m], np.asarray(ys.overflow)[m]))
         return final, SuperstepTrace.from_rows(rows)
 
+    @jax.named_scope("tw.next_event")
     def _next_event(self, carry: EngineState) -> jax.Array:
         """This device's next event time (NEVER = quiesced) — the
         while-loop condition shared by the local and sharded drivers."""
@@ -2206,22 +2222,26 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         — telemetry planes included (per-superstep rows need the scan
         driver; ``last_run_stats`` is still populated).
         Accepts per-world budgets like :meth:`run` (batched only)."""
-        st = state if state is not None else self.init_state()
-        budget, _ = self._coerce_budget(max_steps)
-        begin = self._stats_begin()
-        final = self._run_while(st, budget, self._identity())
-        self._stats_end(begin, st.steps, final.steps)
-        if self.verify != "off":
-            # never silently unverified: the quiet driver has no
-            # per-superstep rows, so the guard degrades to a final-
-            # state host check (integrity/checks.py) — per-superstep
-            # localization needs run()/run_verified
-            from ...integrity.checks import final_state_guard
-            final_state_guard(final, type(self).__name__)
-        # never silently mis-speculated: no per-superstep rows here
-        # either, so the violation check degrades to the short_delay
-        # counter delta (speculate/runner.py)
-        self._quiet_spec_guard(st, final)
+        with self._driver_call("run_quiet") as call:
+            st = state if state is not None else self.init_state()
+            budget, _ = self._coerce_budget(max_steps)
+            final = call.dispatch(self._run_while, st, budget,
+                                  self._identity())
+            call.wait(st.steps, final.steps)
+            if self.verify != "off":
+                # never silently unverified: the quiet driver has no
+                # per-superstep rows, so the guard degrades to a
+                # final-state host check (integrity/checks.py) — per-
+                # superstep localization needs run()/run_verified
+                from ...integrity.checks import final_state_guard
+                with call.guard():
+                    final_state_guard(final, type(self).__name__)
+            if self.speculate != "off":
+                # never silently mis-speculated: no per-superstep rows
+                # here either, so the violation check degrades to the
+                # short_delay counter delta (speculate/runner.py)
+                with call.guard():
+                    self._quiet_spec_guard(st, final)
         return final
 
     def _capture_telemetry(self, ys) -> None:

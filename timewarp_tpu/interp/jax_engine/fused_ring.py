@@ -441,10 +441,27 @@ class FusedRingEngine(RunStatsMixin):
     # -- one superstep ---------------------------------------------------
 
     def _superstep(self, fs: FusedRingState) -> FusedRingState:
+        p = fs.planes
+        with jax.named_scope("tw.next_event"):
+            t = jnp.minimum(jnp.minimum(p[_WAKE].min(), p[_QR0].min()),
+                            p[_QR1].min())
+        with jax.named_scope("tw.ring_kernel"):
+            out, counts = self._kernel_call(fs, t)
+        with jax.named_scope("tw.finish"):
+            return FusedRingState(
+                planes=out,
+                base=fs.base + t.astype(jnp.int64),
+                delivered=fs.delivered
+                + counts[0].sum(dtype=jnp.int64),
+                overflow=fs.overflow + counts[1].sum(dtype=jnp.int32),
+                steps=fs.steps + 1,
+            )
+
+    def _kernel_call(self, fs: FusedRingState, t):
+        """The kernel's scalar operands (the ring wrap: node N-1's
+        outbox this superstep) and its call."""
         MAXI = jnp.int32(_I32MAX)
         p = fs.planes
-        t = jnp.minimum(jnp.minimum(p[_WAKE].min(), p[_QR0].min()),
-                        p[_QR1].min())
         alive_now = (fs.base + t.astype(jnp.int64)) < self.end_us
 
         # ring wrap: node N-1's outbox this superstep (one element,
@@ -473,8 +490,8 @@ class FusedRingEngine(RunStatsMixin):
             jnp.int32(self.drel),
             w_due.astype(jnp.int32), w_val1 + 1])
 
-        out, counts = pl.pallas_call(
-            _superstep_kernel,
+        return pl.pallas_call(
+            _superstep_kernel, name="tw_ring_superstep",
             in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
                       pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=[pl.BlockSpec(memory_space=pl.ANY),
@@ -484,17 +501,10 @@ class FusedRingEngine(RunStatsMixin):
                 jax.ShapeDtypeStruct((2, _ROWS, 128), jnp.int32)],
             interpret=self.interpret,
         )(scal, p)
-        return FusedRingState(
-            planes=out,
-            base=fs.base + t.astype(jnp.int64),
-            delivered=fs.delivered
-            + counts[0].sum(dtype=jnp.int64),
-            overflow=fs.overflow + counts[1].sum(dtype=jnp.int32),
-            steps=fs.steps + 1,
-        )
 
     # -- driver ----------------------------------------------------------
 
+    @jax.named_scope("tw.next_event")
     def _next_event(self, fs: FusedRingState) -> jax.Array:
         p = fs.planes
         m = jnp.minimum(jnp.minimum(p[_WAKE].min(), p[_QR0].min()),
@@ -516,8 +526,8 @@ class FusedRingEngine(RunStatsMixin):
                                   lambda c: self._superstep(c), fs)
 
     def run_quiet(self, max_steps: int, state=None) -> FusedRingState:
-        fs = state if state is not None else self.init_state()
-        begin = self._stats_begin()
-        final = self._run_while(fs, max_steps)
-        self._stats_end(begin, fs.steps, final.steps)
+        with self._driver_call("run_quiet") as call:
+            fs = state if state is not None else self.init_state()
+            final = call.dispatch(self._run_while, fs, max_steps)
+            call.wait(fs.steps, final.steps)
         return final

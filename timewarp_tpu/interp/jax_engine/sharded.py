@@ -139,6 +139,7 @@ class ShardedEngine(ShardedDriver, JaxEngine):
 
     # -- the all_to_all exchange -----------------------------------------
 
+    @jax.named_scope("exchange")
     def _exchange(self, ok, drel, src_f, dst_f, smrank, woff, pay_cols):
         comm = self.comm
         D, nl, B = comm.n_shards, comm.n_local, self.bucket_cap
@@ -345,6 +346,7 @@ class ShardedFusedSparseEngine(ShardedEngine):
             inbox_src=sc.inbox_src, mode="drel", needs_key=False,
             s0=0, s1=0, delay_fn=None)
 
+    @jax.named_scope("insert")
     def _insert_sorted(self, mb_rel, mb_src, mb_payload, sd, ok_s,
                        drel_s, src_s, pay_s, free_rows, counts):
         from .pallas_insert import _fused_insert_call

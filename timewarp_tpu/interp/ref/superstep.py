@@ -425,10 +425,10 @@ class SuperstepOracle:
                          overflow_step))
         # the uniform driver-accounting surface every engine carries
         # (interp/jax_engine/common.py RunStatsMixin); the oracle is
-        # host Python, so compiles is 0 by definition
+        # host Python, so it compiles, launches and reads back nothing
         self.last_run_stats = {
             "supersteps": len(rows),
             "wall_seconds": _time.perf_counter() - _wall0,
-            "compiles": 0,
+            "compiles": 0, "dispatches": 0, "readbacks": 0,
         }
         return SuperstepTrace.from_rows(rows)

@@ -30,9 +30,12 @@ Layers:
   retries, checkpoints, journal fsyncs, jit compiles) on one process
   track, virtual-time superstep counters on another. Open the file at
   https://ui.perfetto.dev.
-- :mod:`~timewarp_tpu.obs.profiler` — optional ``jax.profiler``
-  session wrapping with named annotations (degrades to a no-op when
-  profiling is unavailable).
+- :mod:`~timewarp_tpu.obs.profiler` — where host and chip time go:
+  ``profile_session`` opens a ``jax.profiler`` session, ``span`` is
+  the one host-span primitive (the drivers' ``tw.<driver>``,
+  ``tw.dispatch``, ``tw.wait``, ``tw.guard``; ``tw.sweep.bucket``),
+  beside the superstep's ``jax.named_scope`` stages on the device
+  (``interp/jax_engine/common.py`` ``STAGES``).
 - :mod:`~timewarp_tpu.obs.flight` — the causal flight recorder:
   ``record="off"|"deliveries"|"full"`` on every scan-driver engine
   threads a bounded per-superstep event plane (delivered messages;
@@ -79,7 +82,7 @@ from .ledger import (LEDGER_SCHEMA, LedgerError, RunLedger,
 from .metrics import (METRICS_SCHEMA, MetricsRegistry, validate_line,
                       validate_metrics_file)
 from .perfetto import TraceBuilder
-from .profiler import annotate, profile_session
+from .profiler import profile_session, span
 from .query import (add_flight_flows, chain_lines, explain_delivery,
                     find_deliveries)
 from .regress import (Anomaly, CompareReport, Delta, compare_runs,
@@ -94,7 +97,7 @@ __all__ = [
     "decode_frames", "summarize_frames", "validate_mode",
     "METRICS_SCHEMA", "MetricsRegistry", "validate_line",
     "validate_metrics_file",
-    "TraceBuilder", "profile_session", "annotate",
+    "TraceBuilder", "profile_session", "span",
     "RECORD_MODES", "RecordRow", "FlightLog", "FlightWriter",
     "FlightRecorderMixin", "validate_record", "decode_flight",
     "concat_flight", "load_flight_jsonl",

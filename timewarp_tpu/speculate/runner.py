@@ -426,15 +426,9 @@ class SpeculativeRunMixin:
                              chunk=ci, window_us=dec.window_us,
                              outcome="committed")
             ci += 1
-        if chunk_stats:
-            self._stats_merge(chunk_stats)
-        else:
-            # a zero-chunk run must not leave a previous run's stats
-            # behind (the run_verified precedent)
-            self.last_run_stats = {"supersteps": 0,
-                                   "wall_seconds": 0.0, "compiles": 0,
-                                   "chunks": 0,
-                                   "per_chunk_compiles": []}
+        # a zero-chunk run too: it must not leave a previous run's
+        # stats behind (the run_verified precedent)
+        self._stats_merge(chunk_stats)
         if self.telemetry != "off":
             from ..obs.telemetry import concat_frames
             self.last_run_telemetry = concat_frames(frame_chunks)

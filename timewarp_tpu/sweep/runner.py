@@ -449,7 +449,7 @@ class BucketRunner:
         vec = np.where(active, np.minimum(remaining, chunk_len), 0)
         import time as _time
         from ..interp.jax_engine.common import scan_pad
-        from ..obs.profiler import annotate
+        from ..obs.profiler import span
         _t0 = _time.perf_counter()
         # speculate buckets shield the metrics stream while the chunk
         # runs (the run_verified/run_speculative discipline): the
@@ -461,7 +461,7 @@ class BucketRunner:
         if self._spec:
             eng.metrics = None
         try:
-            with annotate(f"sweep bucket {self.bucket.bucket_id}"):
+            with span("tw.sweep.bucket", bucket=self.bucket.bucket_id):
                 new_state, traces = eng.run(vec, state=st, **run_kw)
         except Exception as e:  # noqa: BLE001 — re-raised unless spec
             from ..speculate import SpeculationViolation
