@@ -22,6 +22,17 @@ rides the block loop's carry, and the ring wrap (node N-1 → node 0)
 is computed on one element outside the kernel and fed in as SMEM
 scalars.
 
+Beside the successor state the kernel writes three ``[8, 128]``
+lane-partials (no reduction to a scalar lowers inside it): messages
+delivered, mailbox overflows, and the **minimum of the successor's
+event times** (the ``_QR0``, ``_QR1`` and ``_WAKE`` planes as written,
+already relative to the new base). The quiet driver's ``while`` carries
+that minimum as the next superstep's time, so it scans the state once,
+before the loop, and its condition is two scalars: inside the loop
+nothing reads the planes but the kernel and the one-element reads of
+the ring wrap. int32 ``min`` is exact in any order, so the carried
+time is the scan's, bit for bit.
+
 Scope (validated in __init__): the dense-ring regime of the headline
 bench — the token-ring scenario without observer (models/
 token_ring.py lean form, ``commutative_inbox`` so no contract-#2 sort
@@ -80,7 +91,9 @@ def _block_compute(blk, t, alive, think, drel, cv, cx):
     """One [10, R, L] block of the fused superstep (pure values).
     ``cv``/``cx`` carry the previous flat lane's outbox (the ring
     shift's block boundary). Returns the output block, the updated
-    carry, and (delivered, overflow) partial sums."""
+    carry, the (delivered, overflow) partial sums and the partial
+    minimum of the output block's event times (``_QR0``, ``_QR1``,
+    ``_WAKE`` as written: the successor state's earliest event)."""
     MAXI = jnp.int32(_I32MAX)
     NEG = jnp.int32(-2**31)
     r0, r1 = blk[_QR0], blk[_QR1]
@@ -150,9 +163,10 @@ def _block_compute(blk, t, alive, think, drel, cv, cx):
     ins0 = in_v & free0
     ins1 = in_v & ~free0 & free1
     ovf = in_v & ~free0 & ~free1
+    o_r0 = jnp.where(ins0, drel, rel0)
+    o_r1 = jnp.where(ins1, drel, rel1)
     out = jnp.stack([
-        jnp.where(ins0, drel, rel0),
-        jnp.where(ins1, drel, rel1),
+        o_r0, o_r1,
         jnp.where(ins0, in_x, blk[_QV0]),
         jnp.where(ins1, in_x, blk[_QV1]),
         jnp.where(ins0, jnp.int32(TOKEN), blk[_QK0]),
@@ -161,17 +175,22 @@ def _block_compute(blk, t, alive, think, drel, cv, cx):
     ])
     # no scalar reductions: neither jnp.sum (int64 accumulator) nor
     # lax.reduce lowers inside this kernel — fold [R, 1024] counts
-    # into [R, 128] lane-partials with unrolled elementwise adds; the
-    # host side of the jit does the final sum
-    def fold(x):
-        x = x.reshape(x.shape[0], _LANES // 128, 128)
-        acc = x[:, 0]
-        for j in range(1, _LANES // 128):
-            acc = acc + x[:, j]
+    # into [R, 128] lane-partials with unrolled elementwise ops; the
+    # host side of the jit does the final sum (or min). Whole-vreg
+    # lane slices, not a reshape to [R, 8, 128]: that is a relayout,
+    # and with three of them the kernel's compute passed its HBM
+    # writes (64.0 us a superstep at 2^20 on a v5e against 56.8 by
+    # slices; PERF.md, PR 25)
+    def fold(x, op):
+        acc = x[:, :128]
+        for j in range(128, _LANES, 128):
+            acc = op(acc, x[:, j:j + 128])
         return acc
-    deliv = fold(d0.astype(jnp.int32) + d1.astype(jnp.int32))
-    novf = fold(ovf.astype(jnp.int32))
-    return out, cv2, cx2, deliv, novf
+    deliv = fold(d0.astype(jnp.int32) + d1.astype(jnp.int32), jnp.add)
+    novf = fold(ovf.astype(jnp.int32), jnp.add)
+    nxt = fold(jnp.minimum(o_wake, jnp.minimum(o_r0, o_r1)),
+               jnp.minimum)
+    return out, cv2, cx2, deliv, novf, nxt
 
 
 def _superstep_kernel(scal, st_ref, out_ref, cnt_ref):
@@ -228,7 +247,7 @@ def _superstep_kernel(scal, st_ref, out_ref, cnt_ref):
             # slot toggles in the carry: any python-int binary op on a
             # traced value (%, *, -) recurses in dtype promotion
             # inside this pallas trace, so everything is explicit
-            b, slot, cv, cx, deliv, novf = carry
+            b, slot, cv, cx, deliv, novf, nxt = carry
 
             @pl.when(b + ONE < GG)
             def _():
@@ -237,7 +256,7 @@ def _superstep_kernel(scal, st_ref, out_ref, cnt_ref):
 
             when_slot(slot, lambda sl: in_dma(sl, b).wait())
             blk = jnp.where(slot == ONE, in_buf1[:], in_buf0[:])
-            out, cv2, cx2, d, o = _block_compute(
+            out, cv2, cx2, d, o, m = _block_compute(
                 blk, t, alive, think, drel, cv, cx)
 
             @pl.when(b >= TWO)
@@ -250,7 +269,7 @@ def _superstep_kernel(scal, st_ref, out_ref, cnt_ref):
                 out_dma(sl, b).start()
             when_slot(slot, put)
             return (b + ONE, ONE - slot, cv2, cx2, deliv + d,
-                    novf + o)
+                    novf + o, jnp.minimum(nxt, m))
 
         # the first flat lane's boundary is the ring wrap, computed
         # outside on node N-1 and passed through scal. An explicit
@@ -260,15 +279,15 @@ def _superstep_kernel(scal, st_ref, out_ref, cnt_ref):
             lambda c: c[0] < GG, loop,
             (jnp.int32(0), jnp.int32(0), scal[4], scal[5],
              jnp.zeros((_ROWS, 128), jnp.int32),
-             jnp.zeros((_ROWS, 128), jnp.int32)))
-        carry = carry[2:]
+             jnp.zeros((_ROWS, 128), jnp.int32),
+             jnp.full((_ROWS, 128), _I32MAX, jnp.int32)))
 
         # drain the in-flight output DMAs (G is static: plain python
         # `if`, so a G==1 program never even traces a block -1 DMA)
         if G >= 2:
             out_dma(G % 2, jnp.int32(G - 2)).wait()
         out_dma((G - 1) % 2, jnp.int32(G - 1)).wait()
-        cnt_ref[:] = jnp.stack([carry[2], carry[3]])
+        cnt_ref[:] = jnp.stack(carry[4:])
 
     pl.run_scoped(
         body,
@@ -441,12 +460,26 @@ class FusedRingEngine(RunStatsMixin):
     # -- one superstep ---------------------------------------------------
 
     def _superstep(self, fs: FusedRingState) -> FusedRingState:
-        p = fs.planes
-        with jax.named_scope("tw.next_event"):
-            t = jnp.minimum(jnp.minimum(p[_WAKE].min(), p[_QR0].min()),
-                            p[_QR1].min())
+        return self._step(fs, self._earliest(fs.planes))[0]
+
+    @staticmethod
+    @jax.named_scope("tw.next_event")
+    def _earliest(p) -> jax.Array:
+        """The earliest pending event, int32 relative to the state's
+        base, by a scan of the three planes that hold event times."""
+        return jnp.minimum(jnp.minimum(p[_WAKE].min(), p[_QR0].min()),
+                           p[_QR1].min())
+
+    def _step(self, fs: FusedRingState, t):
+        """One superstep at the relative time ``t`` of the state's
+        earliest event. Returns the successor state and ITS earliest
+        event (relative to its base), which the kernel folds from the
+        planes as it writes them: the driver's loop carries it and
+        scans nothing."""
         with jax.named_scope("tw.ring_kernel"):
             out, counts = self._kernel_call(fs, t)
+        with jax.named_scope("tw.next_event"):
+            nxt = counts[2].min()
         with jax.named_scope("tw.finish"):
             return FusedRingState(
                 planes=out,
@@ -455,7 +488,7 @@ class FusedRingEngine(RunStatsMixin):
                 + counts[0].sum(dtype=jnp.int64),
                 overflow=fs.overflow + counts[1].sum(dtype=jnp.int32),
                 steps=fs.steps + 1,
-            )
+            ), nxt
 
     def _kernel_call(self, fs: FusedRingState, t):
         """The kernel's scalar operands (the ring wrap: node N-1's
@@ -497,18 +530,21 @@ class FusedRingEngine(RunStatsMixin):
             out_specs=[pl.BlockSpec(memory_space=pl.ANY),
                        pl.BlockSpec(memory_space=pltpu.VMEM)],
             out_shape=[
-                jax.ShapeDtypeStruct(p.shape, jnp.int32),
-                jax.ShapeDtypeStruct((2, _ROWS, 128), jnp.int32)],
+                # the successor's home is HBM, said outright: left to
+                # itself the compiler keeps the loop's whole carry in
+                # on-chip memory once nothing else in the loop reads
+                # it (84 of a v5e's 128 MiB at 2^20 nodes), and the
+                # superstep is then no longer the HBM-bound pass that
+                # the benchmark's byte count describes (PERF.md, PR 25)
+                pltpu.HBM(p.shape, jnp.int32),
+                jax.ShapeDtypeStruct((3, _ROWS, 128), jnp.int32)],
             interpret=self.interpret,
         )(scal, p)
 
     # -- driver ----------------------------------------------------------
 
-    @jax.named_scope("tw.next_event")
     def _next_event(self, fs: FusedRingState) -> jax.Array:
-        p = fs.planes
-        m = jnp.minimum(jnp.minimum(p[_WAKE].min(), p[_QR0].min()),
-                        p[_QR1].min())
+        m = self._earliest(fs.planes)
         return jnp.where(m >= _I32MAX, jnp.int64(NEVER),
                          fs.base + m.astype(jnp.int64))
 
@@ -519,11 +555,14 @@ class FusedRingEngine(RunStatsMixin):
         max_steps = jnp.asarray(max_steps, jnp.int64)
 
         def cond(c):
-            return (self._next_event(c) < NEVER) \
-                & (c.steps - start < max_steps)
+            st, t = c
+            return (t < _I32MAX) & (st.steps - start < max_steps)
 
-        return jax.lax.while_loop(cond,
-                                  lambda c: self._superstep(c), fs)
+        # the one scan of a run: from here the kernel reports each
+        # successor's earliest event, so the condition is two scalars
+        return jax.lax.while_loop(
+            cond, lambda c: self._step(*c),
+            (fs, self._earliest(fs.planes)))[0]
 
     def run_quiet(self, max_steps: int, state=None) -> FusedRingState:
         with self._driver_call("run_quiet") as call:
