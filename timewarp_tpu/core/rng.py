@@ -65,12 +65,22 @@ def threefry2x32(k0, k1, c0, c1) -> Tuple[jnp.ndarray, jnp.ndarray]:
 
 
 def seed_words(seed: int) -> Tuple[int, int]:
-    """Host-side: expand a Python int seed into two uint32 words."""
-    import numpy as np
-    s0 = np.uint32(seed & 0xFFFFFFFF)
-    s1 = np.uint32((seed >> 32) & 0xFFFFFFFF)
-    a, b = threefry2x32(s0, s1 ^ np.uint32(_GOLD), np.uint32(0), np.uint32(1))
-    return int(a), int(b)
+    """Host-side: expand a Python int seed into two uint32 words:
+    ``threefry2x32(seed_lo, seed_hi ^ _GOLD, 0, 1)`` in Python integers.
+    Not through the ``jnp`` block above: called eagerly, each add, shift
+    and xor of it is a device program, some 190 launches a seed and
+    1500 for a fleet of eight in ``rebind_identity`` (PERF.md, PR 27)."""
+    m = 0xFFFFFFFF
+    k0, k1 = seed & m, ((seed >> 32) & m) ^ _GOLD
+    x0, x1 = k0, (1 + k1) & m
+    ks = (k0, k1, k0 ^ k1 ^ _PARITY)
+    for g in range(5):
+        for r in (_ROT_A if g % 2 == 0 else _ROT_B):
+            x0 = (x0 + x1) & m
+            x1 = (((x1 << r) | (x1 >> (32 - r))) & m) ^ x0
+        x0 = (x0 + ks[(g + 1) % 3]) & m
+        x1 = (x1 + ks[(g + 2) % 3] + g + 1) & m
+    return x0, x1
 
 
 def _t_words(t):

@@ -6,8 +6,10 @@ star; the replica-sweep workload of Revati-style time-warp emulation,
 PAPERS.md). Per-superstep the general engine pays fixed N-width costs
 (sender-compaction sort, rung gathers, the [K, N] mailbox base —
 docs/engines.md "Measured on a v5e") that do not shrink with the instantaneous event count;
-a leading **world axis B** amortizes them: one batched sort/gather/
-scatter serves B independent worlds.
+a leading **world axis B** was meant to amortize them: one batched
+sort/gather/scatter serves B independent worlds. Measured on a v5e it
+does not yet (PERF.md, Findings PR 27: eight gossip worlds deliver 1/23
+of one solo wave's rate), because of the pinned top rung below.
 
 :class:`BatchSpec` declares the fleet: per-world engine seeds, plus an
 optional pytree of per-world link-model parameters (dotted attribute

@@ -240,6 +240,12 @@ class _DriverCall:
             "compiles": self.eng._driver_compiles() - self.c0,
             "dispatches": self.dispatches, "readbacks": self.readbacks,
         }
+        if d.ndim:
+            # a fleet: the same transfer holds every world's count. The
+            # loop steps every world until the last is quiet or out of
+            # budget, so its iterations are the largest of them
+            self.eng.last_run_stats.update(
+                world_supersteps=d.tolist(), fleet_iterations=int(d.max()))
         return more
 
     def guard(self):
@@ -259,6 +265,16 @@ class RunStatsMixin:
          "compiles": int,      # driver executables compiled this call
          "dispatches": int,    # executables launched by the call
          "readbacks": int}     # blocking host reads by the call
+
+    and, for a fleet (``batch=BatchSpec``) only::
+
+        {"world_supersteps": [int] * B,  # executed by each world
+         "fleet_iterations": int}        # the largest of them: what the
+                                         # driver's loop ran, each at the
+                                         # cost of all B worlds
+
+    so ``supersteps / (B * fleet_iterations)`` is the share of the
+    fleet's work spent on worlds that were still running.
 
     Compile counting reads the jitted drivers' ``_cache_size`` (the
     same probe tests/test_world_batch.py pins the pow2 bucketing

@@ -216,10 +216,10 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
     :class:`SuperstepTrace` per world. Slicing world b out of a
     batched run is **bit-identical** to the solo run with that seed
     and link — the batch exactness law (batched.py module docstring).
-    The fleet amortizes the superstep's fixed N-width costs (the
-    sender-compaction sort, the [K, N] mailbox passes) into one
-    batched op serving B worlds — the replica-sweep throughput lever
-    (docs/engines.md "Measured on a v5e"). ``record_events`` is solo-only (the ring decoder is
+    One batched op serves B worlds, but at the routing ladder's top
+    rung in every world: on a v5e eight gossip worlds deliver 1/23 of
+    one solo wave's rate (docs/engines.md "Multi-world batching";
+    ROADMAP U1). ``record_events`` is solo-only (the ring decoder is
     a single-run debug artifact — record world b's events by running
     it solo, which is bit-identical by the law above).
 
@@ -1912,7 +1912,9 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         ``self`` for the single trace vmap performs — the traced
         values ARE the per-world tracers, so the compiled program maps
         them; ``_superstep`` itself is unchanged (the whole point: one
-        superstep implementation, solo or fleet)."""
+        superstep implementation, solo or fleet). A stage's scope
+        entered under ``vmap`` reads ``vmap(tw.route)`` in an
+        operation's ``op_name`` (docs/observability.md)."""
         def world(st_w, s0, s1, lp, ft):
             prev = (self.s0, self.s1, self.link, self._ft)
             self.s0, self.s1 = s0, s1
