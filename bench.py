@@ -644,13 +644,13 @@ def bench_praos_1m_insert(n, steps):
 def bench_gossip_100k_b8(n, steps):
     """The gossip wave as a FLEET: 8 seed-swept worlds through one
     batched engine (engine.py ``batch=BatchSpec`` — the world axis).
-    Measured on a v5e at 2^17 (benchmark cell gossip_100k.fleet8:
-    ledger PR 26; PERF.md, Findings PR 27): the AGGREGATE rate is
-    307 000 delivered msg/s, 1/23 of the solo wave's 7.1e6. Under vmap
-    the routing ladder is pinned to its widest rung, so each of the
-    94 iterations costs all 8 worlds N*max_out width (278 ms, 34.8 ms
-    a world against the solo superstep's 1.47 ms): the fixed N-width
-    costs do not amortize across the batch, they multiply (ROADMAP U1).
+    Measured on a v5e at 2^17 (benchmark cell gossip_100k.fleet8;
+    PERF.md, Findings PR 28): the AGGREGATE rate is 6.7e6 delivered
+    msg/s, 0.95 of the solo wave's 7.1e6; each of the 94 iterations
+    steps all 8 worlds at one shared rung of the routing ladder
+    (12.9 ms, 1.61 ms a world against the solo superstep's 1.48 ms).
+    Until PR 28 the ladder was pinned to its widest rung under vmap
+    and the rate was 307 000 (ROADMAP U1).
     Gated in-bench by the batch exactness law before the measured run."""
     from timewarp_tpu.interp.jax_engine.engine import (BatchSpec,
                                                        JaxEngine)

@@ -111,11 +111,13 @@ def test_batched_resume_across_worlds():
             full[b].recv_hash)
 
 
-def test_batched_pins_top_rung_exactly():
-    """At n > 1024 the solo engine's adaptive routing ladder is live
-    (lax.switch over sender rungs) while the batched engine pins the
-    top rung — the law says rung choice is result-invisible, so the
-    slices must still match bit-for-bit."""
+def test_batched_shares_one_rung_exactly():
+    """At n > 1024 the routing ladder is live in the solo engine and
+    in the fleet alike: a solo world takes the smallest rung that
+    holds its own senders, a fleet one rung for all its worlds, the
+    smallest that holds the busiest's (engine.py ``_route_adaptive``).
+    The law says rung choice is result-invisible, so the slices must
+    still match bit-for-bit."""
     n = 2048
     sc = gossip(n, fanout=4, think_us=700, burst=True, end_us=60_000,
                 mailbox_cap=16)
@@ -123,6 +125,8 @@ def test_batched_pins_top_rung_exactly():
     assert len(JaxEngine._sender_rungs(n)) > 1  # ladder actually live
     eng = JaxEngine(sc, link, window=3_000, batch=BatchSpec(seeds=(0, 4)))
     fin = eng.run_quiet(8)
+    # the wave's first supersteps fit the narrow rung: the fleet took it
+    assert eng.last_run_stats["rung_lanes"] < 8 * n
     for b, s in enumerate((0, 4)):
         solo = JaxEngine(sc, link, seed=s, window=3_000).run_quiet(8)
         assert_states_equal(solo, world_slice(fin, b), f"world {b}")

@@ -6,10 +6,13 @@ star; the replica-sweep workload of Revati-style time-warp emulation,
 PAPERS.md). Per-superstep the general engine pays fixed N-width costs
 (sender-compaction sort, rung gathers, the [K, N] mailbox base —
 docs/engines.md "Measured on a v5e") that do not shrink with the instantaneous event count;
-a leading **world axis B** was meant to amortize them: one batched
-sort/gather/scatter serves B independent worlds. Measured on a v5e it
-does not yet (PERF.md, Findings PR 27: eight gossip worlds deliver 1/23
-of one solo wave's rate), because of the pinned top rung below.
+a leading **world axis B** amortizes one compile, one dispatch and one
+readback over B independent worlds. Measured on a v5e (PERF.md,
+Findings PR 28) eight gossip worlds of 2^17 nodes deliver 6.7e6 msg/s
+together, 0.95 of one solo wave's rate: the batched sorts, gathers and
+scatters cost each world what its solo superstep costs, no less. (Until
+PR 28 the fleet ran every world at the routing ladder's top rung and
+delivered 1/23 of that.)
 
 :class:`BatchSpec` declares the fleet: per-world engine seeds, plus an
 optional pytree of per-world link-model parameters (dotted attribute
@@ -26,10 +29,15 @@ the in-bench gates in bench.py; the batched column of
 tools/parity_tpu.py). It holds by construction: ``vmap`` of the
 integer superstep is the same arithmetic per world, per-world
 quiescence and step budgets are masked exactly like the solo drivers
-mask a finished run, and the adaptive routing ladder is pinned to its
-top rung under the batch (rungs are result-identical by design; under
-``vmap`` a batched ``lax.switch`` lowers to select-over-all-branches,
-so the ladder would cost every rung anyway).
+mask a finished run, and the adaptive routing ladder takes ONE rung
+for all the worlds of a superstep, the smallest that holds the busiest
+world's senders (rungs are result-identical by design, and any rung
+that fits the largest count fits every world). The index has to be
+shared: under ``vmap`` a ``lax.switch`` on a per-world index lowers to
+select-over-all-branches, every rung in every world, while an index
+reduced over the ``vmap``'s axis (``jax.lax.pmax``) is not batched and
+the switch stays one conditional (engine.py ``_route_adaptive``). A
+fleet whose worlds differ pays the busiest world's rung in all of them.
 
 Sweepable parameters are the ones ``LinkModel.sample`` uses
 *arithmetically* (delay bounds, medians, sigmas, quanta). Parameters

@@ -224,15 +224,16 @@ class _DriverCall:
         with span("tw.dispatch", run=self.run):
             return fn(*args)
 
-    def wait(self, steps_before, steps_after, *more):
+    def wait(self, steps_before, steps_after, *more, lanes=None):
         """The blocking read that ends the device's work, under
-        ``tw.wait``: the step counters and whatever else the driver
-        reads back (``more``, returned on the host) in one transfer.
-        Sets ``last_run_stats``."""
+        ``tw.wait``: the step counters, a fleet's ``rung_lanes``
+        counter (``lanes``) and whatever else the driver reads back
+        (``more``, returned on the host) in one transfer. Sets
+        ``last_run_stats``."""
         self.readbacks += 1
         with span("tw.wait", run=self.run):
-            before, after, *more = jax.device_get(
-                (steps_before, steps_after) + more)
+            before, after, lanes, *more = jax.device_get(
+                (steps_before, steps_after, lanes) + more)
         d = np.asarray(after, np.int64) - np.asarray(before, np.int64)
         self.eng.last_run_stats = {
             "supersteps": int(d.sum()),
@@ -246,6 +247,11 @@ class _DriverCall:
             # budget, so its iterations are the largest of them
             self.eng.last_run_stats.update(
                 world_supersteps=d.tolist(), fleet_iterations=int(d.max()))
+            if lanes is not None:
+                # one rung for all the worlds of a superstep: every
+                # world counted the same (a world-sharded fleet: the
+                # widest of its devices' sums)
+                self.eng.last_run_stats["rung_lanes"] = int(lanes.max())
         return more
 
     def guard(self):
@@ -269,12 +275,22 @@ class RunStatsMixin:
     and, for a fleet (``batch=BatchSpec``) only::
 
         {"world_supersteps": [int] * B,  # executed by each world
-         "fleet_iterations": int}        # the largest of them: what the
+         "fleet_iterations": int,        # the largest of them: what the
                                          # driver's loop ran, each at the
                                          # cost of all B worlds
+         "rung_lanes": int}              # the routing rung (in senders)
+                                         # the fleet took, summed over
+                                         # those iterations
 
     so ``supersteps / (B * fleet_iterations)`` is the share of the
-    fleet's work spent on worlds that were still running.
+    fleet's work spent on worlds that were still running, and
+    ``rung_lanes / (fleet_iterations * n_nodes)`` the share of the
+    routing ladder's full width it paid (engine.py
+    ``_route_adaptive``: one rung for all the worlds of a superstep;
+    1 where routing runs without the ladder). The count is carried
+    beside the state in the driver's loop and read in the call's one
+    transfer. The chunked drivers' merged record (``_stats_merge``)
+    keeps none of the three.
 
     Compile counting reads the jitted drivers' ``_cache_size`` (the
     same probe tests/test_world_batch.py pins the pow2 bucketing

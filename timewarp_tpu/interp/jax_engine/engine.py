@@ -60,6 +60,12 @@ from ...speculate.runner import SpeculativeRunMixin
 
 __all__ = ["JaxEngine", "EngineState", "BatchSpec"]
 
+#: the name of the fleet's ``vmap`` axis (``_vstep``): what a world
+#: reduces over when all worlds must agree (``_route_adaptive``'s
+#: rung). Not ``ShardedBatchedEngine``'s mesh axis: a world-sharded
+#: fleet reduces over a device's own worlds only, never over the mesh
+_FLEET_AXIS = "tw_fleet"
+
 
 class EngineState(NamedTuple):
     """The complete simulation state — one pytree, trivially
@@ -216,10 +222,13 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
     :class:`SuperstepTrace` per world. Slicing world b out of a
     batched run is **bit-identical** to the solo run with that seed
     and link — the batch exactness law (batched.py module docstring).
-    One batched op serves B worlds, but at the routing ladder's top
-    rung in every world: on a v5e eight gossip worlds deliver 1/23 of
-    one solo wave's rate (docs/engines.md "Multi-world batching";
-    ROADMAP U1). ``record_events`` is solo-only (the ring decoder is
+    One batched op serves B worlds, at ONE rung of the routing ladder
+    for all of them: the smallest that holds the busiest world's
+    senders (``_route_adaptive``; ``last_run_stats["rung_lanes"]``
+    sums the rungs taken). On a v5e eight gossip worlds deliver 0.95
+    of one solo wave's rate together (docs/engines.md "Multi-world
+    batching"; PERF.md, Findings PR 28). ``record_events`` is
+    solo-only (the ring decoder is
     a single-run debug artifact — record world b's events by running
     it solo, which is bit-identical by the law above).
 
@@ -1013,16 +1022,20 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
             return branch
 
         rungs = self._sender_rungs(n)
-        if len(rungs) == 1 or self.batch is not None:
-            # batched: pin the top rung. Under vmap a batched
-            # lax.switch lowers to select-over-ALL-branches, so the
-            # ladder would pay every rung for every world; the top
-            # rung is result-identical to any fitting rung by
-            # construction (only cost differs), so the exactness law
-            # is untouched.
+        if len(rungs) == 1:
             if self.telemetry != "off":
                 self._t_rung = jnp.int32(rungs[-1])
             return tail(rungs[-1])()
+        if self.batch is not None:
+            # a fleet takes ONE rung for all its worlds: the smallest
+            # that holds the busiest world's senders. The pmax over
+            # the vmap's axis (_vstep) is unbatched, so the switch
+            # below stays a conditional with every branch batched; on
+            # a per-world index it would lower to a select over ALL
+            # the branches, every rung in every world. Any rung that
+            # fits is result-identical by the ladder's construction,
+            # and the largest count fits every world
+            n_active = jax.lax.pmax(n_active, _FLEET_AXIS)
         idx = jnp.sum(n_active > jnp.asarray(rungs, jnp.int32))
         if self._dyn is not None:
             # controller rung pin (dispatch/): a traced FLOOR on the
@@ -1033,10 +1046,12 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
             pin = jnp.clip(self._dyn.rung_pin, jnp.int32(-1),
                            jnp.int32(len(rungs) - 1))
             idx = jnp.maximum(idx, pin.astype(idx.dtype))
-        if self.telemetry != "off":
+        if self.telemetry != "off" or self.batch is not None:
             # the rung the switch actually takes — recorded where the
-            # decision is made, so telemetry can never drift from it
-            self._t_rung = jnp.asarray(rungs, jnp.int32)[idx]
+            # decision is made, so telemetry (and a fleet's
+            # ``rung_lanes``, _vstep) can never drift from it
+            self._t_rung = self._fleet_rung = \
+                jnp.asarray(rungs, jnp.int32)[idx]
         return jax.lax.switch(idx, [tail(A) for A in rungs])
 
     def _route_firecompact(self, out, out_valid, now_vec, t, mb_rel,
@@ -1914,7 +1929,13 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         them; ``_superstep`` itself is unchanged (the whole point: one
         superstep implementation, solo or fleet). A stage's scope
         entered under ``vmap`` reads ``vmap(tw.route)`` in an
-        operation's ``op_name`` (docs/observability.md)."""
+        operation's ``op_name`` (docs/observability.md). The ``vmap``
+        names its axis so that ``_route_adaptive`` can take one rung
+        for all the worlds; the rung every world ran at is left on
+        ``self._rung_all`` (int32[B], one value B times) for the
+        drivers' ``rung_lanes`` counter (``_step_counted``)."""
+        n = self.comm.n_local
+
         def world(st_w, s0, s1, lp, ft):
             prev = (self.s0, self.s1, self.link, self._ft)
             self.s0, self.s1 = s0, s1
@@ -1922,13 +1943,18 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
                 self.link = rebind_link(self.link, lp)
             if ft is not None:
                 self._ft = ft
+            # routing without the ladder (the eager and lazy regimes,
+            # the kernel routes) has no rung to choose: it counts n
+            self._fleet_rung = jnp.int32(n)
             try:
-                return self._superstep(st_w, with_trace)
+                out = self._superstep(st_w, with_trace)
+                return out, self._fleet_rung
             finally:
                 self.s0, self.s1, self.link, self._ft = prev
-        return jax.vmap(world, in_axes=(0, 0, 0, 0,
-                                        None if ftv is None else 0))(
-            st, s0v, s1v, lpv, ftv)
+        out, self._rung_all = jax.vmap(
+            world, in_axes=(0, 0, 0, 0, None if ftv is None else 0),
+            axis_name=_FLEET_AXIS)(st, s0v, s1v, lpv, ftv)
+        return out
 
     def _identity(self) -> Optional[WorldIdentity]:
         """The fleet's per-world identity operand (batched.py
@@ -2042,6 +2068,31 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
                                        for x in tables))
         return True
 
+    def _fleet_carry(self, st):
+        """What a fleet's driver loops carry: the state and, beside
+        it, ``rung_lanes`` (int64[B]: for each world the sum, over the
+        loop's iterations so far, of the rung its superstep ran at;
+        ``last_run_stats``). World-leading like every state leaf, so
+        the world-sharded drivers lay it out the same way."""
+        return st, jnp.zeros_like(st.steps)
+
+    def _uncarry(self, carry):
+        """``(state, rung_lanes)`` of a driver loop's carry; a solo
+        engine's carry is its state alone."""
+        return carry if self.batch is not None else (carry, None)
+
+    def _step_counted(self, carry, with_trace: bool):
+        """``_step_all`` on a fleet's ``(state, rung_lanes)`` carry. An
+        iteration counts where some world stepped, as in
+        ``fleet_iterations``: a traced scan runs on to its padded
+        length after the last world is quiet."""
+        st, lanes = carry
+        new, y = self._step_all(st, with_trace)
+        rung = self._rung_all.astype(lanes.dtype)
+        if y is not None:
+            rung = jnp.where(jnp.any(y.valid), rung, 0)
+        return (new, lanes + rung), y
+
     def _any_world(self, x):
         """Whether any world (on any device) is still active — the
         while-loop liveness reduction. Identity single-chip; the
@@ -2060,28 +2111,32 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
                     (carry.steps - start_steps < max_steps)
         else:
             def cond(carry):
-                nxt = jax.vmap(self._next_event)(carry)
+                st = carry[0]
+                nxt = jax.vmap(self._next_event)(st)
                 active = (nxt < NEVER) & \
-                    (carry.steps - start_steps < max_steps)
+                    (st.steps - start_steps < max_steps)
                 return self._any_world(jnp.any(active))
         return cond
 
     def _while_body_fn(self, start_steps, max_steps):
         """The run_quiet loop body. Batched: budget-exhausted worlds
         are frozen leaf-wise (quiesced worlds are already frozen
-        inside ``_superstep`` by the ``live`` mask)."""
+        inside ``_superstep`` by the ``live`` mask); the carry is
+        ``_fleet_carry``'s, and every iteration counts its rung (the
+        frozen worlds ran at it too)."""
         if self.batch is None:
             def body(carry):
                 return self._step_all(carry, False)[0]
         else:
             def body(carry):
-                new = self._step_all(carry, False)[0]
-                act = carry.steps - start_steps < max_steps  # [B]
+                (new, lanes), _ = self._step_counted(carry, False)
+                st = carry[0]
+                act = st.steps - start_steps < max_steps  # [B]
                 return jax.tree.map(
                     lambda a, b: jnp.where(
                         act.reshape(act.shape + (1,) * (b.ndim - 1)),
                         b, a),
-                    carry, new)
+                    st, new), lanes
         return body
 
     # -- drivers ---------------------------------------------------------
@@ -2101,11 +2156,16 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         (traced ``WorldIdentity``, or None solo) is the fleet's
         per-world identity operand, bound the same way — admissions
         swap seeds/link values/fault tables without a retrace (the
-        serving layer's zero-recompile contract, docs/serving.md)."""
+        serving layer's zero-recompile contract, docs/serving.md).
+        Returns ``(carry, rows)``; a fleet's carry is
+        ``_fleet_carry``'s."""
         self._dyn = dyn
         self._ident_in = ident
         try:
-            return padded_scan(self._step_all, st, n_pad, max_steps)
+            if self.batch is None:
+                return padded_scan(self._step_all, st, n_pad, max_steps)
+            return padded_scan(self._step_counted, self._fleet_carry(st),
+                               n_pad, max_steps)
         finally:
             self._dyn = None
             self._ident_in = None
@@ -2173,10 +2233,11 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         with self._driver_call("run") as call:
             st = state if state is not None else self.init_state()
             budget, top = self._coerce_budget(max_steps)
-            final, ys = call.dispatch(
+            carry, ys = call.dispatch(
                 self._run_scan, st, _scan_pad(top) * self._pad_mult,
                 budget, _dyn, self._identity())
-            ys, = call.wait(st.steps, final.steps, ys)
+            final, lanes = self._uncarry(carry)
+            ys, = call.wait(st.steps, final.steps, ys, lanes=lanes)
         self._capture_telemetry(ys)
         self._capture_flight(ys, st)
         self._capture_integrity(ys)
@@ -2202,18 +2263,19 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
                       carry.time + mmin.astype(jnp.int64)))
 
     @partial(jax.jit, static_argnums=(0,))
-    def _run_while(self, st: EngineState, max_steps,
-                   ident=None) -> EngineState:
+    def _run_while(self, st: EngineState, max_steps, ident=None):
         # max_steps is traced (a device scalar), so benchmarking with
         # different budgets reuses one compiled executable; `ident`
-        # is the fleet identity operand, bound like _run_scan's
+        # is the fleet identity operand, bound like _run_scan's.
+        # Returns the loop's carry (a fleet's: _fleet_carry)
         start_steps = st.steps  # max_steps is per-call, same as run()
         max_steps = jnp.asarray(max_steps, jnp.int64)
         self._ident_in = ident
         try:
             return jax.lax.while_loop(
                 self._while_cond_fn(start_steps, max_steps),
-                self._while_body_fn(start_steps, max_steps), st)
+                self._while_body_fn(start_steps, max_steps),
+                st if self.batch is None else self._fleet_carry(st))
         finally:
             self._ident_in = None
 
@@ -2227,9 +2289,9 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         with self._driver_call("run_quiet") as call:
             st = state if state is not None else self.init_state()
             budget, _ = self._coerce_budget(max_steps)
-            final = call.dispatch(self._run_while, st, budget,
-                                  self._identity())
-            call.wait(st.steps, final.steps)
+            final, lanes = self._uncarry(call.dispatch(
+                self._run_while, st, budget, self._identity()))
+            call.wait(st.steps, final.steps, lanes=lanes)
             if self.verify != "off":
                 # never silently unverified: the quiet driver has no
                 # per-superstep rows, so the guard degrades to a

@@ -177,6 +177,14 @@ class ShardedDriver:
         on the world's device)."""
         return P()
 
+    def _carry_specs(self, specs):
+        """PartitionSpecs of a driver loop's carry: the state's, and
+        for the world-sharded fleet its ``rung_lanes`` counter beside
+        it (``JaxEngine._fleet_carry``), a world to an entry."""
+        if getattr(self, "worlds_local", None) is None:
+            return specs
+        return specs, P(self.axis)
+
     @partial(jax.jit, static_argnums=(0, 2))
     def _run_scan(self, st, n_pad: int, max_steps, dyn=None,
                   ident=None):
@@ -215,7 +223,13 @@ class ShardedDriver:
             self._dyn = dy
             self._ident_in = idn
             try:
-                return padded_scan(self._step_all, s, n_pad,
+                if Bl is None:
+                    return padded_scan(self._step_all, s, n_pad,
+                                       local_ms(ms))
+                # a fleet carries its rung_lanes counter beside the
+                # state (JaxEngine._fleet_carry), one entry a world
+                return padded_scan(self._step_counted,
+                                   self._fleet_carry(s), n_pad,
                                    local_ms(ms))
             finally:
                 self._dyn = None
@@ -223,12 +237,13 @@ class ShardedDriver:
 
         return _smap(body, self.mesh,
                      (specs, P(), dyn_specs, ident_specs),
-                     (specs, self._trace_spec()))(
+                     (self._carry_specs(specs), self._trace_spec()))(
             st, max_steps, dyn, ident)
 
     @partial(jax.jit, static_argnums=(0,))
     def _run_while(self, st, max_steps, ident=None):
         specs = self._state_specs(st)
+        Bl = getattr(self, "worlds_local", None)
         max_steps = jnp.asarray(max_steps, jnp.int64)
         ident_specs = jax.tree.map(lambda _: P(), ident)
 
@@ -238,9 +253,10 @@ class ShardedDriver:
                 start_steps = s.steps
                 return jax.lax.while_loop(
                     self._while_cond_fn(start_steps, ms),
-                    self._while_body_fn(start_steps, ms), s)
+                    self._while_body_fn(start_steps, ms),
+                    s if Bl is None else self._fleet_carry(s))
             finally:
                 self._ident_in = None
 
         return _smap(body_fn, self.mesh, (specs, P(), ident_specs),
-                     specs)(st, max_steps, ident)
+                     self._carry_specs(specs))(st, max_steps, ident)
