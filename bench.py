@@ -17,10 +17,6 @@ shape + platform), and ``git_sha``; ``--ledger DIR`` auto-appends
 every emitted line to the persistent run ledger
 (timewarp_tpu/obs/ledger.py — `timewarp-tpu ledger compare` is the
 cross-run regression gate over it).
-``gossip_100k_fused`` additionally runs the telemetry exactness +
-overhead gate (``_telemetry_gate``: counters-mode digests bit-equal
-to off, <= 5% traced-driver cost on chip) and reports
-``telemetry_overhead_frac``.
 
 Configs (select with TW_BENCH_CONFIG, default ``token_ring_dense``):
 
@@ -37,27 +33,12 @@ Configs (select with TW_BENCH_CONFIG, default ``token_ring_dense``):
   time-bucketed batching) on the general engine.
 - ``praos_1m`` — Ouroboros-Praos slot-leader consensus at 1M stake
   nodes, general engine, quantized lognormal links.
-- ``gossip_100k_fused`` / ``praos_1m_fused`` — the same two sparse
-  workloads on the fused-sparse Pallas engine (fused_sparse.py, round
-  6), gated in-bench by bit-exact state equality against the XLA
-  general engine before the measured run counts.
 - ``gossip_100k_b8`` / ``praos_1m_b4`` — the sparse workloads as
   multi-world FLEETS (engine.py ``batch=BatchSpec``, round 7): 8
   seed-swept gossip worlds / 4 link-swept praos worlds through one
   batched engine, reporting AGGREGATE delivered-msg/s/chip. Gated
   in-bench by the batch exactness law (world-b slice ≡ solo run,
   bit-for-bit) before the measured run counts.
-- ``gossip_100k_insert`` / ``praos_1m_insert`` — the general engine
-  with ``insert="pallas"`` (pallas_insert.py, round 12): the
-  fire-compaction kernel replaces the sender-compaction sort +
-  rung-width gathers and the in-tile insertion kernel replaces the
-  mailbox scatters. Gated in-bench by bit-exact state equality
-  against ``insert="xla"``; the JSON line additionally reports the
-  isolated per-superstep insert-stage time for both strategies and
-  the achieved-bytes / HBM-roofline fraction against the device's
-  published peak (``DEVICE_PEAKS``, keyed by ``device_kind``). Under
-  ``--smoke`` the kernels run under the Pallas interpreter
-  (``insert="interpret"``) and no fraction is reported.
 - ``sweep_hetero`` — the fault-tolerant sweep service (sweep/,
   docs/sweeps.md) on a heterogeneous pack with one injected transient
   failure: aggregate delivered-msg/s THROUGH the service (journal +
@@ -77,8 +58,8 @@ min/max in the JSON line — whole-run rates swing from run to run, so
 batched-vs-solo comparisons need it.
 
 ``python bench.py --smoke`` is the CI fast path: every config at tiny
-N with all in-bench exactness gates on (fused ring, fused sparse AND
-the batch exactness law), one JSON line per config — a kernel or
+N with all in-bench exactness gates on (fused ring AND the batch
+exactness law), one JSON line per config — a kernel or
 world-axis regression fails CI before a full bench round ever runs.
 """
 
@@ -166,16 +147,6 @@ def _emit(line):
                                   source="bench.py")
 
 
-#: published per-chip peaks, keyed by ``device_kind`` as JAX reports
-#: it. Source: Google Cloud documentation, "TPU v5e" (16 GB of HBM at
-#: 819 GB/s, 197 TFLOP/s bf16). A device that is not in the table is
-#: an error, not a default.
-DEVICE_PEAKS = {
-    # what JAX 0.9.0 reports for a v5e chip (chip_smoke.py, PR 21)
-    "TPU v5 lite": {"hbm_gbps": 819.0, "bf16_tflops": 197.0},
-}
-
-
 def _require_chip(what):
     """The measured path times a TPU or nothing: a rate from XLA:CPU
     or the Pallas interpreter is not speed and is never printed as
@@ -189,16 +160,6 @@ def _require_chip(what):
             f"{platform!r} — refusing to time it (run through the "
             "chip tool, or `python bench.py --smoke` for the "
             "exactness gates alone)")
-
-
-def _device_peak(key):
-    kind = jax.devices()[0].device_kind
-    if kind not in DEVICE_PEAKS:
-        raise SystemExit(
-            f"bench: no published peak for device_kind {kind!r} in "
-            "DEVICE_PEAKS — add it with its source; a roofline share "
-            "against a guessed peak is not reported")
-    return DEVICE_PEAKS[kind][key]
 
 
 def _measure(engine, steps, warm_steps=2):
@@ -381,158 +342,6 @@ def _assert_batched_exact(batched, solo_factory, gate_steps=12):
                             f"in-bench batch exactness gate, world {b}")
 
 
-def _telemetry_gate(make_engine, steps=24, reps=3):
-    """The telemetry exactness + overhead gate (obs/,
-    docs/observability.md): ``telemetry="counters"`` must be
-    bit-identical to ``"off"`` on the traced driver (states AND trace
-    rows), and its throughput cost must stay <= 5%. The exactness
-    half always asserts. The wall-clock half is strict (<= 5%) on
-    every measured run (which is a chip run — ``_require_chip``);
-    under ``--smoke`` the shapes are too small for the budget to mean
-    anything, so the bound loosens to a 2x catastrophic-regression
-    check and the ratio rides the JSON line for the record.
-    Returns the overhead fraction (median-of-``reps`` per side)."""
-    import statistics
-
-    from timewarp_tpu.trace.events import (assert_states_equal,
-                                           assert_traces_equal)
-    off, on = make_engine("off"), make_engine("counters")
-    f_off, tr_off = off.run(steps)
-    f_on, tr_on = on.run(steps)
-    assert_traces_equal(tr_off, tr_on, "telemetry-off",
-                        "telemetry-counters")
-    assert_states_equal(f_off, f_on, "telemetry exactness gate")
-
-    def med(engine, state):
-        walls = []
-        for _ in range(reps):
-            t0 = time.perf_counter()
-            engine.run(steps, state=state)
-            walls.append(time.perf_counter() - t0)
-        return statistics.median(walls)
-
-    w_off = med(off, f_off)       # warm states: compiles already paid
-    w_on = med(on, f_on)
-    overhead = w_on / w_off - 1.0
-    limit = 1.0 if _SMOKE else 0.05
-    assert overhead <= limit, (
-        f"telemetry='counters' costs {overhead:.1%} on the traced "
-        f"driver — over the {limit:.0%} budget (obs/ "
-        "zero-overhead contract)")
-    return overhead
-
-
-def _insert_mode():
-    """The ``insert=`` value for this run: the compiled kernels on a
-    measured run (no TPU is a refusal, pallas_insert.py), the Pallas
-    interpreter under ``--smoke`` (same semantics — the exactness
-    gate still gates; smoke rates are discarded)."""
-    return "interpret" if _SMOKE else "pallas"
-
-
-def _assert_engines_exact(eng, ref, tag, gate_steps=12):
-    """The ONE in-bench engine-pair exactness gate: ``ref`` must
-    reproduce ``eng``'s EngineState BIT-FOR-BIT over the gate horizon
-    before any measured run counts (the CPU-side laws live in
-    tests/test_fused_sparse.py / tests/test_pallas_insert.py; this
-    runs them on the bench hardware)."""
-    from timewarp_tpu.trace.events import assert_states_equal
-    es = eng.run_quiet(gate_steps)
-    rs = ref.run_quiet(gate_steps)
-    assert_states_equal(rs, es, tag)
-
-
-def _assert_insert_exact(pallas, ref, gate_steps=12):
-    """The insert= knob's gate: insert="xla" vs the pallas kernels."""
-    _assert_engines_exact(pallas, ref, "in-bench pallas-insert gate",
-                          gate_steps)
-
-
-def _insert_stage_stats(engine, ref, reps=8):
-    """Isolated per-superstep insert-stage timing + achieved-bytes /
-    HBM-roofline fraction for the BENCH_SCHEMA JSON line (ISSUE 8
-    satellite): a jitted call of each engine's own ``_insert_sorted``
-    on one synthetic destination-sorted batch at the pallas stage's
-    static width, against this scenario's empty mailbox. Bytes model:
-    every mailbox plane read + written once, the resident batch read
-    once — the kernel's streaming contract. The roofline constant is
-    the published HBM peak of the device the line ran on
-    (``DEVICE_PEAKS``; an unknown device is an error). Each rep pays
-    one host sync, so treat sub-ms values as upper bounds; under
-    ``--smoke`` no fraction is reported (an interpreter timing is not
-    a roofline statement)."""
-    import statistics
-
-    import jax.numpy as jnp
-    import numpy as np
-
-    sc = engine.scenario
-    n, K, P = sc.n_nodes, sc.mailbox_cap, sc.payload_width
-    S = engine._pallas_stage.S
-    rng = np.random.RandomState(0)
-    sd = jnp.asarray(np.sort(rng.randint(0, n, size=S))
-                     .astype(np.int32))
-    drel = jnp.asarray(rng.randint(1, 1 << 20, size=S)
-                       .astype(np.int32))
-    src = jnp.asarray(rng.randint(0, n, size=S).astype(np.int32))
-    pay = tuple(jnp.asarray(rng.randint(0, 1 << 20, size=S)
-                            .astype(np.int32)) for _ in range(P))
-    ok = sd < n
-    st = engine.init_state()
-    if sc.commutative_inbox:
-        # empty-mailbox free-slot table, in the engine's own dtype
-        # rule (engine.py _superstep step 5: int8 when K fits)
-        fr_dt = jnp.int8 if K <= 127 else jnp.int32
-        free_rows = jnp.broadcast_to(
-            jnp.arange(K, dtype=fr_dt)[:, None], (K, n))
-        counts = None
-    else:
-        free_rows = None
-        counts = jnp.zeros(n, jnp.int32)
-
-    def timed(eng):
-        # block on the FULL return (mb_rel, mb_src, mb_payload,
-        # overflow): keeping only one output would let XLA dead-code
-        # the src/payload scatters out of the xla leg while the
-        # pallas_call always runs whole — a structurally biased
-        # comparison
-        f = jax.jit(lambda mb_rel, mb_src, mb_pay: eng._insert_sorted(
-            mb_rel, mb_src, mb_pay, sd, ok, drel, src, pay,
-            free_rows, counts))
-        jax.block_until_ready(f(st.mb_rel, st.mb_src, st.mb_payload))
-        walls = []
-        for _ in range(max(3, reps)):
-            t0 = time.perf_counter()
-            jax.block_until_ready(
-                f(st.mb_rel, st.mb_src, st.mb_payload))
-            walls.append(time.perf_counter() - t0)
-        return statistics.median(walls)
-
-    t_pal, t_xla = timed(engine), timed(ref)
-    planes = K * (1 + P + (1 if sc.inbox_src else 0))
-    bytes_step = 2 * planes * n * 4 + (3 + P) * S * 4
-    frac = {}
-    if not _SMOKE:
-        gbps = _device_peak("hbm_gbps")
-        frac = {"insert_hbm_frac":
-                round(bytes_step / t_pal / (gbps * 1e9), 4),
-                "hbm_gbps_peak": gbps}
-    return {
-        "insert_stage_ms": round(t_pal * 1e3, 4),
-        "insert_stage_xla_ms": round(t_xla * 1e3, 4),
-        "insert_bytes_per_step": bytes_step,
-        "insert_resolved": engine.insert_resolved,
-        **frac,
-    }
-
-
-def _assert_fused_sparse_exact(fused, ref, gate_steps=12):
-    """The fused-sparse engine's gate: the XLA general engine vs the
-    fused kernel."""
-    _assert_engines_exact(fused, ref, "in-bench fused-sparse gate",
-                          gate_steps)
-
-
 def bench_gossip_100k(n, steps):
     """One full broadcast wave, measured start to quiescence (the
     while_loop exits when the epidemic dies, so a large step budget
@@ -550,95 +359,6 @@ def bench_gossip_100k(n, steps):
     _assert_wave_done(engine, fin, n)
     return (f"gossip broadcast wave to quiescence (lognormal links) "
             f"delivered-messages/sec/chip @{n} nodes", delivered / dt)
-
-
-def bench_gossip_100k_fused(n, steps):
-    """The same wave on the fused-sparse Pallas engine
-    (interp/jax_engine/fused_sparse.py): the compacted batch stays
-    VMEM-resident through sample → bucket → hole-ranked insertion and
-    the mailbox planes stream through the kernel once. Gated in-bench
-    by bit-exact state equality against the XLA general engine."""
-    from timewarp_tpu.interp.jax_engine.engine import JaxEngine
-    from timewarp_tpu.interp.jax_engine.fused_sparse import \
-        FusedSparseEngine
-
-    n = n or 100_000
-    sc, link = _gossip_wave(n)
-    # max_batch bounds the VMEM-resident batch (1<<18 messages = 32k
-    # burst senders/superstep); a wave peak beyond it lands in
-    # route_drop and fails _assert_wave_done loudly — never a silently
-    # wrong number
-    engine = FusedSparseEngine(sc, link, window="auto",
-                               max_batch=1 << 18, interpret=_SMOKE)
-    _assert_fused_sparse_exact(engine, JaxEngine(sc, link,
-                                                 window="auto"))
-    # the telemetry exactness + <= 5% overhead gate runs on THIS
-    # config (the acceptance surface, ISSUE 7): counters-mode digests
-    # must match off bit-for-bit before the measured run counts
-    overhead = _telemetry_gate(lambda mode: FusedSparseEngine(
-        sc, link, window="auto", max_batch=1 << 18, telemetry=mode,
-        lint="off", interpret=_SMOKE))
-    delivered, dt, fin = _measure(engine, steps or (1 << 20))
-    _assert_wave_done(engine, fin, n)
-    return (f"gossip broadcast wave to quiescence (fused-sparse "
-            f"pallas) delivered-messages/sec/chip @{n} nodes",
-            delivered / dt,
-            {"telemetry_overhead_frac": round(overhead, 4)})
-
-
-def bench_gossip_100k_insert(n, steps):
-    """The gossip wave on the general engine with ``insert="pallas"``
-    (pallas_insert.py): fire-compaction emits the compact fired batch
-    in one streamed pass (no sender-compaction N-sort, no rung
-    gathers) and the insertion kernel streams the mailbox planes
-    through VMEM once. Gated in-bench by bit-exact state equality
-    against ``insert="xla"``; reports the isolated insert-stage
-    timings + roofline fraction. Default n is 2^17 (the kernels'
-    1024-lane planes — 100k is not a multiple)."""
-    from timewarp_tpu.interp.jax_engine.engine import JaxEngine
-
-    n = n or (1 << 17)
-    sc, link = _gossip_wave(n)
-    # insert_cap bounds the VMEM-resident fire-compacted batch (the
-    # fused engine's max_batch analog); a wave peak beyond it lands in
-    # route_drop and fails _assert_wave_done loudly — never a silently
-    # wrong number
-    cap = min(1 << 18, n * sc.max_out)
-    engine = JaxEngine(sc, link, window="auto", insert=_insert_mode(),
-                       insert_cap=cap)
-    ref = JaxEngine(sc, link, window="auto")
-    _assert_insert_exact(engine, ref)
-    extra = _insert_stage_stats(engine, ref)
-    delivered, dt, fin = _measure(engine, steps or (1 << 20))
-    _assert_wave_done(engine, fin, n)
-    return (f"gossip broadcast wave to quiescence (pallas "
-            f"insert) delivered-messages/sec/chip @{n} nodes",
-            delivered / dt, extra)
-
-
-def bench_praos_1m_insert(n, steps):
-    """Praos on the general engine with ``insert="pallas"`` — the
-    profiled hotspot (docs/engines.md "Where the remaining praos fat is")
-    the kernels exist for. Same gates and stage stats as
-    gossip_100k_insert."""
-    from timewarp_tpu.interp.jax_engine.engine import JaxEngine
-
-    n = n or 1 << 20
-    sc, link = _praos_consensus(n)
-    cap = min(1 << 17, n * sc.max_out)
-    engine = JaxEngine(sc, link, window="auto", insert=_insert_mode(),
-                       insert_cap=cap)
-    ref = JaxEngine(sc, link, window="auto")
-    _assert_insert_exact(engine, ref)
-    extra = _insert_stage_stats(engine, ref)
-    delivered, dt, fin = _measure(engine, steps or 256, warm_steps=16)
-    assert int(fin.short_delay) == 0, \
-        "windowed run left the exact regime"
-    assert int(fin.route_drop) == 0, \
-        "fire-compacted batch cap dropped messages — raise insert_cap"
-    return (f"praos slot-leader consensus (pallas insert) "
-            f"delivered-messages/sec/chip @{n} stake nodes",
-            delivered / dt, extra)
 
 
 def bench_gossip_100k_b8(n, steps):
@@ -1319,29 +1039,6 @@ def bench_praos_1m(n, steps):
             delivered / dt)
 
 
-def bench_praos_1m_fused(n, steps):
-    """Praos on the fused-sparse Pallas engine, exactness-gated
-    against the XLA general engine in-bench (see
-    bench_gossip_100k_fused)."""
-    from timewarp_tpu.interp.jax_engine.engine import JaxEngine
-    from timewarp_tpu.interp.jax_engine.fused_sparse import \
-        FusedSparseEngine
-
-    n = n or 1 << 20
-    sc, link = _praos_consensus(n)
-    engine = FusedSparseEngine(sc, link, window="auto",
-                               max_batch=1 << 17, interpret=_SMOKE)
-    _assert_fused_sparse_exact(engine, JaxEngine(sc, link,
-                                                 window="auto"))
-    delivered, dt, fin = _measure(engine, steps or 256, warm_steps=16)
-    assert int(fin.short_delay) == 0, "windowed run left the exact regime"
-    assert int(fin.route_drop) == 0, \
-        "fused batch cap dropped messages — raise max_batch"
-    return (f"praos slot-leader consensus (fused-sparse pallas) "
-            f"delivered-messages/sec/chip @{n} stake nodes",
-            delivered / dt)
-
-
 def _verify_detection_gate(make_engine, budget=64, chunk=8):
     """The detection law, in-bench (integrity/, ISSUE 10 acceptance):
     one seeded flip injected between chunks of a digest-mode run must
@@ -1435,8 +1132,7 @@ def bench_gossip_100k_record(n, steps):
     ``record_deliveries``), cheap enough that even noisy CPU smoke
     windows must clear it. Below the SMOKE shape (the tier-1 tiny
     run) the measured windows are too short for the ratio to mean
-    anything, so — like ``_telemetry_gate`` and
-    ``gossip_100k_verify`` — the bound loosens to a catastrophic
+    anything, so — like ``gossip_100k_verify`` — the bound loosens to a catastrophic
     2x regression check and the honest ratio rides the JSON line.
     Full mode
     (sends + fault captures across the routing switch) rides the
@@ -1797,8 +1493,6 @@ CONFIGS = {
     "token_ring_dense_xla": bench_token_ring_dense_xla,
     "token_ring_observer": bench_token_ring_observer,
     "gossip_100k": bench_gossip_100k,
-    "gossip_100k_fused": bench_gossip_100k_fused,
-    "gossip_100k_insert": bench_gossip_100k_insert,
     "gossip_100k_b8": bench_gossip_100k_b8,
     "gossip_100k_chaos": bench_gossip_100k_chaos,
     "gossip_100k_auto": bench_gossip_100k_auto,
@@ -1807,8 +1501,6 @@ CONFIGS = {
     "gossip_100k_record": bench_gossip_100k_record,
     "gossip_steady_1m": bench_gossip_steady_1m,
     "praos_1m": bench_praos_1m,
-    "praos_1m_fused": bench_praos_1m_fused,
-    "praos_1m_insert": bench_praos_1m_insert,
     "praos_1m_b4": bench_praos_1m_b4,
     "sweep_hetero": bench_sweep_hetero,
     "sweep_hetero_auto": bench_sweep_hetero_auto,
@@ -1819,14 +1511,12 @@ CONFIGS = {
 
 #: --smoke shapes: every config tiny enough for a CPU CI runner, all
 #: in-bench exactness gates live (the fused ring's 8192-node floor
-#: pins that row's size; the fused-sparse rows gate at 2048)
+#: pins that row's size)
 SMOKE = {
     "token_ring_dense": (8192, 16),
     "token_ring_dense_xla": (4096, 32),
     "token_ring_observer": (1024, 32),
     "gossip_100k": (2048, 1 << 14),
-    "gossip_100k_fused": (2048, 1 << 14),
-    "gossip_100k_insert": (2048, 1 << 14),
     "gossip_100k_b8": (1024, 1 << 14),
     "gossip_100k_chaos": (1024, 1 << 14),
     "gossip_100k_auto": (1024, 1 << 14),
@@ -1835,8 +1525,6 @@ SMOKE = {
     "gossip_100k_record": (1024, 1 << 14),
     "gossip_steady_1m": (4096, 16),
     "praos_1m": (2048, 24),
-    "praos_1m_fused": (2048, 24),
-    "praos_1m_insert": (2048, 24),
     "praos_1m_b4": (1024, 24),
     "sweep_hetero": (256, 96),
     "sweep_hetero_auto": (256, 96),

@@ -159,7 +159,6 @@ def phase_gossip_cli():
         sc = gossip(GOSSIP_N, fanout=8, end_us=5_000_000, burst=True,
                     mailbox_cap=16)
         engine = JaxEngine(sc, parse_link(GOSSIP_LINK), window="auto")
-        assert engine.insert_resolved == "xla"
         fin, _ = load_state(ckpt, engine.init_state(),
                             expect_meta={"scenario": sc.name})
         bench._assert_wave_done(engine, fin, GOSSIP_N)

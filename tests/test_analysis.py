@@ -430,30 +430,6 @@ def test_engine_construction_lint_error_raises_on_defect():
         cls(bad, link)                          # warn: constructs fine
 
 
-def test_fused_engines_lint_knob():
-    from timewarp_tpu.interp.jax_engine.fused_sparse import \
-        FusedSparseEngine
-    sc = gossip(1024, burst=True)
-    eng = FusedSparseEngine(sc, FixedDelay(1000), lint="error",
-                            interpret=True)
-    assert eng.lint_report is not None and eng.lint_report.ok
-    eng = FusedSparseEngine(sc, FixedDelay(1000), lint="off",
-                            interpret=True)
-    assert eng.lint_report is None
-
-
-def test_sharded_fused_engine_lint_knob():
-    from timewarp_tpu.interp.jax_engine.sharded import (
-        ShardedFusedSparseEngine, make_mesh)
-    sc = gossip(8192, burst=True)       # 1024 nodes/shard kernel floor
-    eng = ShardedFusedSparseEngine(sc, FixedDelay(1000), make_mesh(8),
-                                   lint="error", interpret=True)
-    assert eng.lint_report is not None and eng.lint_report.ok
-    eng = ShardedFusedSparseEngine(sc, FixedDelay(1000), make_mesh(8),
-                                   lint="off", interpret=True)
-    assert eng.lint_report is None
-
-
 def test_fused_ring_engine_lint_knob():
     from timewarp_tpu.interp.jax_engine.fused_ring import \
         FusedRingEngine

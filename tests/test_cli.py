@@ -134,7 +134,7 @@ def test_cli_batched_run_and_guards(capsys, tmp_path):
     assert rows[0][0] == "world"
     assert len(rows) - 1 == sum(r3["supersteps"])
     # world-axis guards: actionable, never silent
-    for eng in ("oracle", "edge", "fused-sparse", "sharded"):
+    for eng in ("oracle", "edge", "sharded"):
         with pytest.raises(SystemExit, match="world axis"):
             main([*common, "--engine", eng, "--batch", "2"])
     with pytest.raises(SystemExit, match="world axis"):

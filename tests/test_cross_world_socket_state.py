@@ -8,9 +8,8 @@ transport delivers and counts per socket, the batched world delivers
 and counts per client — final counters and send counts equal; the
 batched twin itself holds the bit-exact oracle ≡ engine trace law
 like every other scenario (and appears in tools/parity_tpu.py /
-PARITY_TPU.json; its fused-sparse column at 1024 nodes runs here
-under the Pallas interpreter, the kernel does not lower for the chip
-yet)."""
+PARITY_TPU.json; its 1023-way hub fan-in is a case of
+tests/test_insert_law.py)."""
 
 import numpy as np
 import pytest
@@ -22,8 +21,7 @@ from timewarp_tpu.models.socket_state import roulette_sends, socket_state
 from timewarp_tpu.models.socket_state_net import socket_state_net
 from timewarp_tpu.net.backend import EmulatedBackend
 from timewarp_tpu.net.delays import FixedDelay, Quantize, UniformDelay
-from timewarp_tpu.trace.events import (assert_states_equal,
-                                       assert_traces_equal)
+from timewarp_tpu.trace.events import assert_traces_equal
 
 SEED = 3
 LINK = FixedDelay(3_000)
@@ -96,30 +94,3 @@ def test_socket_state_deadline_stops_counting():
     # first two pings of each client can be counted
     assert [int(v) for v in cnt] == [min(s, 2) for s in sends]
     assert sum(sends) > sum(min(s, 2) for s in sends)  # gate did bite
-
-
-def test_socket_state_fused_sparse_column():
-    """The 1024-node windowed shape the parity tool's fused-sparse
-    column runs (tools/parity_tpu.py --self-check): fused ≡ general,
-    state and trace, under the Pallas interpreter."""
-    from timewarp_tpu.interp.jax_engine.fused_sparse import \
-        FusedSparseEngine
-    sc = socket_state(n_clients=1023, seed=1, send_interval_us=20_000,
-                      server_life_us=2_000_000, mailbox_cap=64)
-    link = Quantize(UniformDelay(3_000, 9_000), 1_000)
-    ref = JaxEngine(sc, link, window=3_000)
-    fus = FusedSparseEngine(sc, link, window=3_000, interpret=True)
-    _, tr = ref.run(200)
-    _, tf = fus.run(200)
-    assert_traces_equal(tr, tf, "general", "fused-sparse")
-    rs = ref.run_quiet(200)
-    fs = fus.run_quiet(200)
-    assert_states_equal(rs, fs, "socket-state fused column")
-    # the 1023-way co-temporal fan-in overflows the hub mailbox by
-    # design (the hard regime for the kernel's hole accounting):
-    # every scheduled ping is either counted or in the overflow
-    # counter — never silently lost, and never double-counted
-    cnt = np.asarray(rs.states["cnt"])[0]
-    assert int(rs.overflow) > 0
-    assert int(cnt.sum()) + int(rs.overflow) == \
-        sum(roulette_sends(1023, 1))

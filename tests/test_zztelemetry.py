@@ -94,26 +94,6 @@ def test_batched_modes_bit_identical_per_world():
         assert len(frames[b]) == len(tr1[b])
 
 
-def test_fused_sparse_full_mode_bit_identical():
-    from timewarp_tpu.interp.jax_engine.fused_sparse import \
-        FusedSparseEngine
-    sc = gossip(2048, fanout=3, burst=True, end_us=120_000,
-                mailbox_cap=16)
-    link = Quantize(UniformDelay(3000, 9000), 1000)
-    off = FusedSparseEngine(sc, link, window="auto", lint="off",
-                            interpret=True)
-    f0, t0 = off.run(16)
-    eng = FusedSparseEngine(sc, link, window="auto", lint="off",
-                            telemetry="full", interpret=True)
-    f1, t1 = eng.run(16)
-    assert_traces_equal(t0, t1, "off", "fused full")
-    assert_states_equal(f0, f1, "fused-sparse telemetry=full")
-    fr = eng.last_run_telemetry
-    # the fused engine's rung is its static VMEM batch slice
-    assert set(np.unique(fr.data["rung"])) <= {-1, 2048}
-    assert (fr.data["mb_peak"] <= sc.mailbox_cap).all()
-
-
 def test_sharded_edge_full_mode_bit_identical():
     # covers the mesh path of the full-mode occupancy plane
     # (MeshComm.all_max) — its only caller

@@ -343,35 +343,6 @@ def test_edge_engine_controller_chunk_only():
                for d in eng.last_run_decisions)
 
 
-def test_pallas_insert_controller_takes_degraded_floor():
-    """A kernel-window engine (insert=interpret) cannot thread the
-    dynamic per-superstep window clamp, so under a controller it must
-    validate against the DEGRADED schedule-wide floor like any static
-    engine — an undegraded bound there would silently reorder
-    causally dependent events inside the degradation window."""
-    sc, link = _wave(n=1024, end_us=60_000)
-    sched = _shrink_sched()
-    eng = JaxEngine(sc, link, window="auto", faults=sched,
-                    insert="interpret", telemetry="counters",
-                    lint="off", controller=DispatchController(chunk=8))
-    assert not eng._dyn_ok
-    assert eng.window == sched.min_delay_floor(link.min_delay_us) \
-        == 2_000, "kernel-window engine must take the degraded floor"
-
-
-def test_fused_sparse_controller_pins_knobs():
-    from timewarp_tpu.interp.jax_engine.fused_sparse import \
-        FusedSparseEngine
-    sc, link = _wave(n=1024, end_us=60_000)
-    eng = FusedSparseEngine(sc, link, window="auto",
-                            telemetry="counters", lint="off",
-                            controller=DispatchController(chunk=8),
-                            interpret=True)
-    assert not eng._dyn_ok, \
-        "the fused kernel bakes the window — knobs must pin"
-    assert eng.controller is not None
-
-
 # -- the decision trace / controller object --------------------------------
 
 def test_decision_trace_validation_is_loud(tmp_path):

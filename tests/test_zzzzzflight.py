@@ -188,21 +188,6 @@ def test_sharded_batched_record_worlds_match_solo():
             f"sharded world {b} event plane != solo"
 
 
-def test_record_across_insert_strategies():
-    sc, link = _gossip()
-    logs = {}
-    for ins in ("xla", "xla2d"):
-        eng = JaxEngine(sc, link, window="auto", lint="off",
-                        insert=ins, record="full")
-        f, t = eng.run(STEPS)
-        logs[ins] = (f, t, eng.last_run_flight.keyset())
-    assert_traces_equal(logs["xla"][1], logs["xla2d"][1],
-                        "xla", "xla2d")
-    assert_states_equal(logs["xla"][0], logs["xla2d"][0],
-                        "insert strategies")
-    assert logs["xla"][2] == logs["xla2d"][2]
-
-
 def test_record_cap_overflow_counted_never_silent():
     sc, link = _gossip()
     eng = JaxEngine(sc, link, window="auto", lint="off",

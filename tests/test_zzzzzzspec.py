@@ -295,9 +295,6 @@ def test_speculate_guards():
     with pytest.raises(ValueError, match="does not exceed"):
         JaxEngine(sc, link, window="auto", lint="off",
                   speculate="fixed:500")     # == the floor
-    with pytest.raises(ValueError, match="kernel"):
-        JaxEngine(sc, link, window="auto", lint="off",
-                  speculate="auto", insert="interpret")
     eng = JaxEngine(sc, link, window="auto", lint="off")
     with pytest.raises(ValueError, match="speculating engine"):
         eng.run_speculative(100)

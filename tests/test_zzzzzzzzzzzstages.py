@@ -57,21 +57,18 @@ def _scopes(names) -> set:
     return out
 
 
-# the three routing regimes of JaxEngine._superstep, and what marks each
+# the two routing regimes of JaxEngine._superstep, and what marks each
 REGIMES = {
-    "adaptive": dict(window="auto", insert="xla"),
-    "firecompact": dict(window="auto", insert="interpret"),
-    "dense": dict(window=1, insert="xla", route_cap=64),
+    "adaptive": dict(window="auto"),
+    "dense": dict(window=1, route_cap=64),
 }
 
 
 @pytest.mark.parametrize("regime", sorted(REGIMES))
 def test_every_stage_is_a_scope_of_the_general_driver(regime):
-    sc, link = _gossip(2048 if regime == "firecompact" else 64)
+    sc, link = _gossip(64)
     eng = JaxEngine(sc, link, lint="off", **REGIMES[regime])
     assert eng._adaptive_regime() == (regime != "dense")
-    fire = eng._pallas_stage is not None and eng._pallas_stage.adaptive
-    assert fire == (regime == "firecompact")
     names = _op_names(eng, eng.init_state(), jnp.int64(8), eng._identity())
     scopes = _scopes(names)
     assert set(STAGES) <= scopes, sorted(set(STAGES) - scopes)
@@ -79,8 +76,7 @@ def test_every_stage_is_a_scope_of_the_general_driver(regime):
     assert any("/cond/tw.next_event" in n for n in names)
     # the parts that have a function of their own are nested scopes
     assert "tw.route/sample" in scopes
-    if regime != "firecompact":
-        assert "tw.route/insert" in scopes
+    assert "tw.route/insert" in scopes
     # a stage is never opened inside another
     assert not [s for s in scopes if s.count("tw.") > 1]
 

@@ -46,7 +46,7 @@ def _gossip(n=64):
 
 
 def _engine(**kw):
-    return JaxEngine(*_gossip(), window="auto", insert="xla", lint="off",
+    return JaxEngine(*_gossip(), window="auto", lint="off",
                      **kw)
 
 

@@ -28,11 +28,6 @@ This module mirrors those decisions without building a single engine:
   cannot exceed its conservative floor: at or below the floor the
   static window already proves exactness, and the engine refuses at
   construction — mid-bucket, after the pack was accepted.
-- **TW604** (error) — speculation on an insert strategy that bakes
-  the window into kernel arithmetic (``TW_INSERT=pallas|interpret``):
-  no dynamic clamp point, refused by the engine
-  (docs/speculation.md); the lint resolves the strategy exactly as
-  the runtime would, environment override included.
 - **TW605** (warning) — pad-growth rebuilds: a bucket whose
   fault-table row counts GROW along pack order. A batch sweep pads
   once, but serving-style mid-bucket admission (docs/serving.md)
@@ -115,16 +110,6 @@ def _fault_rows(cfg: RunConfig) -> Tuple[int, int, int]:
             len(sched.link_windows) + sched.pad[2])
 
 
-def _resolved_insert() -> Tuple[str, bool]:
-    """The insert strategy a sweep/serve JaxEngine would resolve right
-    now (env override included) and whether it threads the dynamic
-    window — the lint must predict the runtime's refusal, so it asks
-    the same resolver (interp/jax_engine/pallas_insert.py)."""
-    from ..interp.jax_engine.pallas_insert import resolve_insert
-    mode, _ = resolve_insert(None, honor_env=True, who="plan lint")
-    return mode, mode not in ("pallas", "interpret")
-
-
 def lint_run_config(cfg: RunConfig, *, deep: bool = True) -> LintReport:
     """Every statically decidable refusal for ONE config: the TW6xx
     window/speculation mirrors of engine validation, plus (``deep``)
@@ -145,21 +130,11 @@ def lint_run_config(cfg: RunConfig, *, deep: bool = True) -> LintReport:
     degraded = sched.min_delay_floor(link_floor) if sched is not None \
         else link_floor
     dyn = cfg.controller == "auto" or cfg.speculate != "off"
-    insert, dyn_ok = _resolved_insert()
-    if cfg.speculate != "off" and not dyn_ok:
-        rep.add(Finding(
-            "TW604", ERROR, who,
-            f"speculate={cfg.speculate!r} threads the dynamic "
-            f"per-superstep window, but the insert strategy resolves "
-            f"to {insert!r} (TW_INSERT), which bakes the window into "
-            "kernel arithmetic — no clamp point, refused at engine "
-            "construction; run speculation on the XLA insert "
-            "strategies (docs/speculation.md)"))
     # the engine's floor choice (engine.py window validation): static
-    # configs — and kernel-window engines regardless — take the
-    # fault-DEGRADED floor; dynamic-window configs keep the undegraded
-    # floor (the device clamp narrows per superstep)
-    floor = link_floor if (dyn and dyn_ok) else degraded
+    # configs take the fault-DEGRADED floor; dynamic-window configs
+    # keep the undegraded floor (the device clamp narrows per
+    # superstep)
+    floor = link_floor if dyn else degraded
     if cfg.window != "auto" and int(cfg.window) > 1 \
             and int(cfg.window) > floor:
         under = (f" (the fault schedule degrades the declared "

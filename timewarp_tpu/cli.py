@@ -129,13 +129,11 @@ def build_faults(args):
 
 #: engines the dispatch controller drives (dispatch/,
 #: docs/dispatch.md) — the chunk-capable jitted engines
-CONTROLLER_ENGINES = ("general", "edge", "fused-sparse",
-                      "sharded-batched")
+CONTROLLER_ENGINES = ("general", "edge", "sharded-batched")
 
 #: engines that speculate (speculate/, docs/speculation.md) — the
 #: chunk-capable engines that thread the DYNAMIC per-superstep window
-#: (edge runs classic W=1 supersteps; the fused/pallas kernels bake
-#: the window into kernel arithmetic — no clamp point, no rollback)
+#: (edge runs classic W=1 supersteps: no clamp point, no rollback)
 SPECULATE_ENGINES = ("general", "sharded-batched")
 
 
@@ -159,8 +157,7 @@ def build_controller(args):
 #: engines that carry the causal flight recorder (obs/flight.py) —
 #: the scan-driver engines whose events live on one host (the
 #: node-sharded engines refuse: events would scatter across shards)
-RECORD_ENGINES = ("general", "edge", "fused-sparse",
-                  "sharded-batched")
+RECORD_ENGINES = ("general", "edge", "sharded-batched")
 
 
 def build_engine(args, sc, link):
@@ -192,15 +189,8 @@ def build_engine(args, sc, link):
             f"--speculate threads the dynamic per-superstep window "
             f"through the XLA scan engines "
             f"({', '.join(SPECULATE_ENGINES)}); {args.engine} cannot "
-            "(edge runs classic supersteps; the fused/pallas kernels "
-            "bake the window; the oracle is host Python — "
-            "docs/speculation.md)")
-    if speculate != "off" and getattr(args, "insert", None) \
-            in ("pallas", "interpret"):
-        raise SystemExit(
-            "--speculate needs the dynamic window clamp; "
-            f"--insert {args.insert} bakes the window into kernel "
-            "arithmetic (docs/speculation.md)")
+            "(edge runs classic supersteps; the oracle is host "
+            "Python — docs/speculation.md)")
     if telemetry != "off" and args.engine == "oracle":
         raise SystemExit(
             "--telemetry threads on-device counter planes through the "
@@ -211,7 +201,7 @@ def build_engine(args, sc, link):
         raise SystemExit(
             f"--faults runs on {', '.join(FAULT_ENGINES)}; "
             f"{args.engine} has no fault masks wired into its "
-            "superstep (the fused kernels bypass the mask points)")
+            "superstep")
     # never-silent: reject knobs an engine would ignore rather than
     # letting cross-engine comparisons diverge mysteriously
     if batch is not None and args.engine not in BATCH_ENGINES:
@@ -230,8 +220,7 @@ def build_engine(args, sc, link):
             "--record-events is a solo-run debug ring; record world "
             "b's events by running that seed solo (bit-identical by "
             "the batch exactness law, batched.py)")
-    if args.engine not in ("general", "fused-sparse") \
-            and args.record_events:
+    if args.engine != "general" and args.record_events:
         raise SystemExit(
             f"--record-events is the general engine's device-side "
             f"ring; {args.engine} does not carry one (the oracle "
@@ -246,29 +235,7 @@ def build_engine(args, sc, link):
             and args.route_cap is not None):
         raise SystemExit(
             f"--route-cap applies to the XLA general engines only; "
-            f"{args.engine} has no XLA insertion stage to bound "
-            "(fused-sparse bounds its VMEM-resident batch with "
-            "--max-batch; sharded-fused sizes per-shard exchange "
-            "buckets via the API's bucket_cap)")
-    if args.engine not in ("fused-sparse",) \
-            and args.max_batch is not None:
-        raise SystemExit(
-            f"--max-batch sizes the fused-sparse engine's "
-            f"VMEM-resident batch; {args.engine} does not hold one")
-    # never-silent: the insert knob is the single-chip general
-    # engine's insertion-strategy selector (pallas_insert.py) — other
-    # engines replace the insertion stage themselves
-    if args.engine != "general" and getattr(args, "insert", None):
-        raise SystemExit(
-            f"--insert selects the general engine's insertion "
-            f"strategy (docs/engines.md); {args.engine} owns its "
-            "insertion stage (fused/sharded kernels)")
-    if args.engine != "general" and getattr(args, "insert_cap",
-                                            None) is not None:
-        raise SystemExit(
-            "--insert-cap sizes the general engine's fire-compacted "
-            f"batch (--insert pallas|interpret); {args.engine} does "
-            "not hold one")
+            f"{args.engine} has no XLA insertion stage to bound")
     if args.engine == "oracle":
         from .interp.ref.superstep import SuperstepOracle
         return SuperstepOracle(sc, link, seed=args.seed,
@@ -284,9 +251,6 @@ def build_engine(args, sc, link):
                              lint=args.lint, batch=batch,
                              faults=faults,
                              telemetry=telemetry,
-                             insert=getattr(args, "insert", None),
-                             insert_cap=getattr(args, "insert_cap",
-                                                None),
                              controller=controller,
                              verify=verify, record=record,
                              record_cap=record_cap,
@@ -315,18 +279,6 @@ def build_engine(args, sc, link):
             if speculate != "off":
                 raise SystemExit(str(e)) from None
             raise
-    if args.engine == "fused-sparse":
-        from .interp.jax_engine.fused_sparse import FusedSparseEngine
-        kw = {} if args.max_batch is None else {
-            "max_batch": args.max_batch}
-        return FusedSparseEngine(sc, link, seed=args.seed,
-                                 window=args.window,
-                                 record_events=args.record_events,
-                                 lint=args.lint, telemetry=telemetry,
-                                 controller=controller,
-                                 verify=verify, record=record,
-                                 record_cap=record_cap,
-                                 **kw)
     if args.engine == "edge":
         from .interp.jax_engine.edge_engine import EdgeEngine
         return EdgeEngine(sc, link, seed=args.seed, cap=args.edge_cap,
@@ -334,10 +286,9 @@ def build_engine(args, sc, link):
                           telemetry=telemetry, controller=controller,
                           verify=verify, record=record,
                           record_cap=record_cap)
-    if args.engine in ("sharded", "sharded-edge", "sharded-fused"):
+    if args.engine in ("sharded", "sharded-edge"):
         from .interp.jax_engine.sharded import (
-            ShardedEdgeEngine, ShardedEngine,
-            ShardedFusedSparseEngine, make_mesh)
+            ShardedEdgeEngine, ShardedEngine, make_mesh)
         mesh = make_mesh(args.devices)
         if args.engine == "sharded-edge":
             return ShardedEdgeEngine(sc, link, mesh, seed=args.seed,
@@ -345,11 +296,6 @@ def build_engine(args, sc, link):
                                      lint=args.lint,
                                      telemetry=telemetry,
                                      verify=verify)
-        if args.engine == "sharded-fused":
-            return ShardedFusedSparseEngine(
-                sc, link, mesh, seed=args.seed, window=args.window,
-                lint=args.lint, telemetry=telemetry,
-                verify=verify)
         return ShardedEngine(sc, link, mesh, seed=args.seed,
                              window=args.window,
                              route_cap=args.route_cap,
@@ -687,9 +633,8 @@ def main(argv=None) -> int:
     p.add_argument("scenario",
                    choices=["token-ring", "gossip", "praos", "ping-pong"])
     p.add_argument("--engine", default="general",
-                   choices=["oracle", "general", "fused-sparse",
-                            "edge", "sharded", "sharded-edge",
-                            "sharded-fused", "sharded-batched"])
+                   choices=["oracle", "general", "edge", "sharded",
+                            "sharded-edge", "sharded-batched"])
     p.add_argument("--nodes", type=int, default=64)
     p.add_argument("--steps", type=int, default=1000,
                    help="max supersteps to run")
@@ -739,28 +684,6 @@ def main(argv=None) -> int:
     p.add_argument("--route-cap", type=int, default=None,
                    help="static active-message budget for the insertion "
                         "stage (clipped messages are counted)")
-    p.add_argument("--max-batch", type=int, default=None,
-                   help="fused-sparse: VMEM-resident message batch "
-                        "bound per superstep (excess counted in "
-                        "route_drop, never silent)")
-    p.add_argument("--insert", default=None,
-                   choices=["xla", "xla2d", "pallas", "interpret"],
-                   help="general engine insertion strategy "
-                        "(docs/engines.md; every choice is "
-                        "bit-identical): 'xla' flat scatters "
-                        "(default), 'xla2d' the 2D scatter form (the "
-                        "promoted TW_FLAT_SCATTER hatch), 'pallas' "
-                        "the fire-compaction + in-tile insertion "
-                        "kernels compiled for the TPU (refused "
-                        "where there is none), 'interpret' the kernels under "
-                        "the Pallas interpreter; unset reads "
-                        "TW_INSERT")
-    p.add_argument("--insert-cap", type=int, default=None,
-                   help="--insert pallas|interpret: VMEM-resident "
-                        "fire-compacted batch bound in messages per "
-                        "superstep (default n_nodes*max_out = can "
-                        "never drop; excess counted in route_drop, "
-                        "never silent)")
     p.add_argument("--fanout", type=int, default=8)
     p.add_argument("--slots", type=int, default=10)
     p.add_argument("--leader-prob", type=float, default=0.05)
@@ -1385,9 +1308,9 @@ def bisect_main(argv) -> int:
                    choices=["token-ring", "gossip", "praos",
                             "ping-pong"])
     p.add_argument("--engine", default="general",
-                   choices=["general", "edge", "fused-sparse"])
+                   choices=["general", "edge"])
     p.add_argument("--engine-b", default=None,
-                   choices=["general", "edge", "fused-sparse"],
+                   choices=["general", "edge"],
                    help="compare --engine against THIS engine "
                         "(default: same engine — needs "
                         "--inject-flip to have anything to find)")
@@ -1447,23 +1370,11 @@ def bisect_main(argv) -> int:
                                  window=args.window, faults=faults,
                                  lint="off", record=record,
                                  record_cap=args.record_cap)
-            if engine_name == "edge":
-                from .interp.jax_engine.edge_engine import EdgeEngine
-                return EdgeEngine(sc, link, seed=args.seed,
-                                  cap=args.edge_cap, faults=faults,
-                                  lint="off", record=record,
-                                  record_cap=args.record_cap)
-            from .interp.jax_engine.fused_sparse import \
-                FusedSparseEngine
-            if faults is not None:
-                raise SystemExit(
-                    "fused-sparse has no fault masks (the kernels "
-                    "bypass the mask points); drop --faults or "
-                    "bisect the general engine")
-            return FusedSparseEngine(sc, link, seed=args.seed,
-                                     window=args.window, lint="off",
-                                     record=record,
-                                     record_cap=args.record_cap)
+            from .interp.jax_engine.edge_engine import EdgeEngine
+            return EdgeEngine(sc, link, seed=args.seed,
+                              cap=args.edge_cap, faults=faults,
+                              lint="off", record=record,
+                              record_cap=args.record_cap)
         return make
 
     inject_b = None

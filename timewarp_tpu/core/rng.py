@@ -149,9 +149,10 @@ def normal_f32(b0, b1):
     only (the documented LogNormalDelay caveat, net/delays.py).
     """
     # 24-bit mantissa uniforms in (0, 1). The draw goes uint32 ->
-    # int32 -> float32: Mosaic has no uint32 -> float32 cast (this
-    # runs in-kernel too, fused_sparse.py), and a value below 2^24 is
-    # exact either way, so the bits are the same on every path
+    # int32 -> float32: Mosaic has no uint32 -> float32 cast
+    # (docs/pallas_kernels.md), so a kernel that draws delays can
+    # call this as it stands; a value below 2^24 is exact either way,
+    # so the bits are the same on every path
     def u24(b):
         return (b >> jnp.uint32(8)).astype(jnp.int32).astype(jnp.float32)
     u1 = u24(b0) * jnp.float32(2 ** -24) + jnp.float32(2 ** -25)

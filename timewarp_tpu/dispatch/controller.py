@@ -291,8 +291,8 @@ class DispatchController:
                 obs["horizon_us"] = horizon
         else:
             # window is a static compile parameter on this engine
-            # (fused kernels bake it; the edge engine runs classic
-            # supersteps) — recorded as the pinned value
+            # (the edge engine runs classic supersteps) — recorded
+            # as the pinned value
             w = max(1, self._bound)
             obs["window"] = "static"
         if sig is not None:

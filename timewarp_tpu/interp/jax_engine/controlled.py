@@ -23,10 +23,9 @@ Laws (tests/test_zzzdispatch.py):
   there the replay law and the ``short_delay == 0`` evidence carry
   the guarantee).
 
-The mixin serves every chunk-capable engine; engines whose window or
-rung is a compile-time constant (EdgeEngine — classic supersteps;
-FusedSparseEngine and ``insert="pallas"`` — kernels bake the width)
-set ``_dyn_ok = False`` and adapt chunk length only, with the pinned
+The mixin serves every chunk-capable engine; an engine whose window is
+a compile-time constant (EdgeEngine: classic supersteps) keeps
+``_dyn_ok = False`` and adapts chunk length only, with the pinned
 knob values recorded in the trace.
 """
 
@@ -56,8 +55,8 @@ class ControlledRunMixin:
     #: constructed with)
     _dyn = None
     #: whether this engine threads dynamic window/rung scalars
-    #: (JaxEngine and its window-dynamic subclasses); False = the
-    #: controller adapts chunk length only
+    #: (JaxEngine and its subclasses); False = the controller adapts
+    #: chunk length only (EdgeEngine)
     _dyn_ok = False
     #: the emitted decision list of the last run_controlled call
     last_run_decisions = None

@@ -36,7 +36,7 @@ from __future__ import annotations
 from functools import partial
 from typing import Any, NamedTuple, Optional, Tuple
 
-from ...utils import jaxconfig  # must precede jax use
+from ...utils import jaxconfig  # noqa: F401  (must precede jax use)
 
 import jax
 import jax.numpy as jnp
@@ -106,7 +106,7 @@ class EngineState(NamedTuple):
     #: ``ev_count > capacity`` IS the overflow evidence (never silent).
     #: int64: a single scalar, and an int32 count would wrap negative
     #: past ~2.1e9 recorded events, corrupting ring write positions
-    #: (ADVICE r5) — ring *indices* stay int32 (capacity bounds them).
+    #: — ring *indices* stay int32 (capacity bounds them).
     ev_time: jax.Array     # int64[E]
     ev_meta: jax.Array     # int32[4, E]
     ev_count: jax.Array    # int64[]
@@ -190,27 +190,11 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
     knob needs hand-tuning. Event semantics, arrival order (contract
     #3) and digests are identical to the eager path.
 
-    Insertion strategy (``insert=``, round 12 — pallas_insert.py,
-    docs/engines.md): the mailbox-insertion stage is selectable and
-    **every choice is bit-identical** (state, traces, digests,
-    counters — under faults, with telemetry on, and on the world
-    axis; tests/test_pallas_insert.py. The one telemetry asymmetry:
-    the recorded ``rung`` column is strategy-denominated — ladder
-    rung vs the pallas path's static batch width — by the same
-    convention as the fused engine's VMEM slice).
-    ``"xla"`` (default) keeps the flat
-    1D scatters; ``"xla2d"`` the 2D [col, row] scatter form (the
-    promoted ``TW_FLAT_SCATTER`` escape hatch, docs/engines.md "Measured on a v5e");
-    ``"pallas"`` runs the fire-compaction + in-tile insertion kernels
-    compiled for the TPU (with no TPU backend the constructor raises —
-    never a quiet change of strategy) — in the adaptive regime the fire-compaction
-    kernel replaces the sender-compaction sort and rung-width gathers
-    wholesale (``_route_firecompact``); ``"interpret"`` forces the
-    kernels under the Pallas interpreter (the CPU test surface).
-    Unset, the knob reads the documented ``TW_INSERT`` env hatch.
-    ``insert_cap`` bounds the fire-compacted batch in messages
-    (default ``n_nodes * max_out`` — nothing can ever drop; a smaller
-    cap counts the excess in ``route_drop``, never silent).
+    Mailbox insertion has one form, ``_insert_sorted``'s flat 1D
+    scatters, held to the oracle by tests/test_insert_law.py
+    (docs/engines.md "Mailbox insertion"). ``insert`` is a vestigial
+    keyword: ``None`` and ``"xla"`` build the same engine, anything
+    else is refused (ROADMAP D2').
 
     Batched multi-world execution (``batch=BatchSpec``, batched.py):
     a leading world axis B through the whole engine. ``_superstep`` is
@@ -256,9 +240,12 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
     scalars — adapting never retraces, every decision is recorded,
     and replaying the decision trace is bit-identical on states,
     traces, digests, and checkpoints (the replay law,
-    tests/test_zzzdispatch.py). Engines with a Pallas insertion
-    stage adapt chunk length only (the kernels bake the window).
+    tests/test_zzzdispatch.py).
     """
+
+    #: this engine threads the controller's dynamic window/rung
+    #: scalars (controlled.py)
+    _dyn_ok = True
 
     def __init__(self, scenario: Scenario, link: LinkModel, *,
                  seed: int = 0, window=1,
@@ -269,7 +256,6 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
                  faults=None,
                  telemetry: str = "off",
                  insert: Optional[str] = None,
-                 insert_cap: Optional[int] = None,
                  controller=None,
                  verify: str = "off",
                  record: str = "off",
@@ -307,8 +293,8 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         # speculation-free jaxpr (the violation plane is a None
         # StepOut field, like telemetry); "auto"/"fixed:W" permit a
         # window BOUND wider than the provable link floor and thread
-        # the causality-violation plane — resolved below, after the
-        # insert strategy fixes _dyn_ok and the link floor is known
+        # the causality-violation plane — resolved below, once the
+        # link floor is known
         from ...speculate.plane import parse_speculate
         self.speculate, self._spec_w = parse_speculate(
             speculate, type(self).__name__)
@@ -348,33 +334,11 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         self.scenario = scenario  # before faults: the restart-reset
         self.link = link          # template stacks Scenario.init
         self._setup_faults(faults, scenario, lint)
-        # the insert strategy is resolved BEFORE window validation: a
-        # Pallas insertion stage bakes the window into kernel
-        # arithmetic, so those engines cannot thread the dynamic
-        # per-superstep window clamp — their window (controller or
-        # not) must validate against the DEGRADED floor below. The
-        # stage itself is built further down (it needs the resolved
-        # window).
-        from .pallas_insert import resolve_insert
-        self.insert, _ins_env = resolve_insert(
-            insert, honor_env=type(self) is JaxEngine,
-            who=type(self).__name__)
-        if self.insert == "pallas":
-            # the compiled kernels need the chip: no TPU is a
-            # refusal, never a quiet change of strategy
-            jaxconfig.require_tpu(
-                f"{type(self).__name__}: insert='pallas'")
-        #: what runs: the requested mode, unless an ENV-selected
-        #: kernel mode fell outside this scenario's kernel scope
-        #: (then "xla", with the reason in ``insert_fallback``)
-        self.insert_resolved, self.insert_fallback = self.insert, None
-        #: whether this engine threads the dynamic window/rung scalars
-        #: (controlled.py) — a kernel-window engine adapts chunk
-        #: length only. The env-fallback path below may downgrade the
-        #: resolved insert to "xla" later; that only makes the bound
-        #: chosen here CONSERVATIVE (degraded), never unsafe.
-        self._dyn_ok = self.insert_resolved not in ("pallas",
-                                                    "interpret")
+        if insert not in (None, "xla"):
+            raise ValueError(
+                f"insert={insert!r}: the flat XLA scatter is the one "
+                "insertion form; the other strategies were removed in "
+                "PR 29 (the kernels are at 193bc01)")
         if self._faulted:
             if route_cap is not None:
                 raise ValueError(
@@ -391,19 +355,16 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
             # narrows the effective window for exactly the supersteps
             # a degradation window overlaps, so the whole run is not
             # forced onto the schedule-wide conservative floor
-            # (docs/dispatch.md). An engine whose window is a kernel
-            # constant has no clamp point — it MUST take the degraded
-            # floor like any static engine. Speculating engines keep
+            # (docs/dispatch.md). Speculating engines keep
             # the undegraded floor the same way: run_speculative
             # always threads the dynamic window, so the device clamp
             # is in force (docs/speculation.md).
-            if (controller is None and self.speculate == "off") \
-                    or not self._dyn_ok:
+            if controller is None and self.speculate == "off":
                 link_floor = self.faults.min_delay_floor(link_floor)
         if isinstance(window, str) and window != "auto":
             # a typo'd "Auto"/"8ms" from a library caller would
             # otherwise fall through to `window < 1` and raise an
-            # opaque TypeError (ADVICE r5)
+            # opaque TypeError
             raise ValueError(
                 f"window must be an int µs count or the string "
                 f"'auto', got {window!r}")
@@ -453,13 +414,6 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         # chunk by chunk (run_speculative rolls back the rest).
         self.spec_floor = None
         if self.speculate != "off":
-            if not self._dyn_ok:
-                raise ValueError(
-                    f"speculate={speculate!r} threads the dynamic "
-                    f"per-superstep window; insert={self.insert!r} "
-                    "bakes the window into kernel arithmetic and has "
-                    "no clamp point — run speculation on the XLA "
-                    "insert strategies (docs/speculation.md)")
             if controller is not None:
                 raise ValueError(
                     "speculate and controller are both per-chunk "
@@ -510,60 +464,6 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
             self._lpv = {k: jnp.asarray(v) for k, v in
                          (batch.link_params or {}).items()}
         self.comm = LocalComm(scenario.n_nodes)
-        # insertion-strategy knob (pallas_insert.py, round 12):
-        # "xla" (flat scatters, the r5 default) | "xla2d" (2D [col,
-        # row] scatter form — the promoted TW_FLAT_SCATTER escape
-        # hatch) | "pallas" (fire-compaction + in-tile insertion
-        # kernels compiled for the TPU; no TPU backend is a refusal)
-        # | "interpret" (the kernels under the Pallas interpreter —
-        # the CPU test surface). insert=None
-        # reads the documented TW_INSERT env hatch (JaxEngine proper
-        # only: subclasses that replace the insertion stage themselves
-        # must not inherit it). Every strategy is bit-identical —
-        # the exactness law tests/test_pallas_insert.py pins.
-        # (Resolved ABOVE, before window validation — the kernel-
-        # window engines must validate against the degraded floor.)
-        # insert_cap sizes the pallas stage, so it needs a kernel mode
-        if insert_cap is not None \
-                and self.insert not in ("pallas", "interpret"):
-            raise ValueError(
-                "insert_cap sizes the Pallas insertion stage's "
-                f"VMEM-resident batch; insert={self.insert!r} has none")
-        self._pallas_stage = None
-        if self.insert_resolved in ("pallas", "interpret"):
-            from .pallas_insert import PallasInsertStage
-            try:
-                # _adaptive_regime is the same predicate _superstep's
-                # routing dispatch tests — one implementation, so the
-                # VMEM budget is validated at construction for the
-                # width that will actually run
-                self._pallas_stage = PallasInsertStage(
-                    scenario, scenario.n_nodes, window=self.window,
-                    interpret=self.insert_resolved == "interpret",
-                    adaptive=self._adaptive_regime(),
-                    insert_cap=insert_cap, route_cap=self.route_cap)
-            except ValueError as e:
-                # an ENV-selected mode must stay behavior-neutral: a
-                # stale TW_INSERT cannot hard-fail a scenario outside
-                # the kernels' scope (e.g. a sweep bucket with
-                # n_nodes % 1024 != 0) — fall back, loudly recorded.
-                # Explicit insert= requests still refuse loudly.
-                if not _ins_env:
-                    raise
-                self.insert_resolved = "xla"
-                self.insert_fallback = (
-                    f"TW_INSERT={self.insert} is outside this "
-                    f"scenario's kernel scope ({e}) — fell back to "
-                    "'xla'")
-        if insert_cap is not None and self.insert_fallback is not None:
-            self.insert_fallback += "; insert_cap is unused on the " \
-                "xla fallback path"
-        #: subclasses whose routing stage derives mailbox holes while
-        #: the block is already in VMEM (fused_sparse.py) set this to
-        #: skip the [K, N] free-rows sort entirely — the pallas
-        #: insertion stage ranks holes in-tile the same way
-        self._fused_holes = (self._pallas_stage is not None
-                             and scenario.commutative_inbox)
         # online adaptive dispatch (dispatch/, controlled.py): the
         # engine's `window` is then the dynamic knob's BOUND, and the
         # per-chunk values arrive as traced scalars (self._dyn) — no
@@ -578,13 +478,6 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         # so seeds/link values/fault tables are never baked into the
         # executable. None between driver calls (and always, solo).
         self._ident_in = None
-        # `_dyn_ok` was fixed BEFORE window validation (above): a
-        # Pallas insertion stage bakes the window into kernel
-        # arithmetic (the in-kernel short-delay counter compares
-        # against the compile-time W), so those engines adapt chunk
-        # length only — knob values are recorded pinned, and their
-        # window bound already took the degraded floor like any
-        # static engine
         self._bind_controller(controller)
 
     # -- faults (faults/: scheduled chaos inside the superstep) ----------
@@ -700,9 +593,8 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
     def _adaptive_regime(self) -> bool:
         """Whether routing takes the adaptive sender-compacted path
         (class docstring) — the ONE predicate shared by _superstep's
-        routing dispatch and the pallas insertion stage's
-        construction-time width sizing (drift here would validate the
-        VMEM budget for the wrong width). Evaluated per call because
+        routing dispatch and the dispatch controller's rung ladder
+        (dispatch/controller.py ``begin``). Evaluated per call because
         the sharded subclasses replace ``comm`` after construction."""
         return (self.route_cap is None
                 and not self.link.can_drop
@@ -767,23 +659,15 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
                        drel_s, src_s, pay_s, free_rows, counts):
         """Shared mailbox insertion for destination-sorted messages:
         per-destination rank -> target slot (r-th hole for commutative
-        inboxes, append-after-kept otherwise) -> scatters in the form
-        the ``insert`` knob selects: flat 1D (default — the 2D [col,
-        row] form costs ~7x on this chip, docs/engines.md per-op cost table),
-        2D ``"xla2d"`` (no flat-reshape relayout copy of the tiled
-        mailbox — the promoted TW_FLAT_SCATTER hatch, docs/engines.md "Measured on a v5e"),
-        or the Pallas insertion kernel (pallas_insert.py — streams the
-        [K, N] planes through VMEM once). Non-fitting lanes get an
-        out-of-range index and are dropped; returns the updated arrays
-        plus the local overflow count. All three forms are
-        bit-identical (tests/test_pallas_insert.py)."""
+        inboxes, append-after-kept otherwise) -> flat 1D scatters (the
+        2D [col, row] form costs ~7x on this chip, docs/engines.md
+        per-op cost table). Non-fitting lanes get an out-of-range
+        index and are dropped; returns the updated arrays plus the
+        local overflow count. Held to the oracle by
+        tests/test_insert_law.py."""
         sc = self.scenario
         K, P = sc.mailbox_cap, sc.payload_width
         n = self.comm.n_local
-        if self._pallas_stage is not None:
-            return self._pallas_stage.insert(
-                sd, drel_s, src_s, pay_s, mb_rel, mb_src, mb_payload,
-                counts)
         rank = group_rank(sd)
         if sc.commutative_inbox:
             # r-th incoming message takes the destination's r-th hole
@@ -796,40 +680,25 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
             pos = counts[jnp.clip(sd, 0, n - 1)] + rank
             fits = ok_s & (pos < K)
             col = jnp.clip(pos, 0, K - 1)
-        if self.insert_resolved == "xla2d":
-            # the 2D [col, row] scatter form: ~7x the flat form in
-            # isolation on this chip, but no physical relayout copy of
-            # the tiled [K, N] operand (docs/engines.md "Measured on a v5e" measured the
-            # two a wash in-engine) — kept selectable for hardware
-            # where the relayout dominates. Non-fitting lanes get an
-            # out-of-range row (K) and drop.
-            col2 = jnp.where(fits, col, jnp.int32(K))
-            mb_rel = mb_rel.at[col2, sd].set(drel_s, mode="drop")
-            if sc.inbox_src:
-                mb_src = mb_src.at[col2, sd].set(src_s, mode="drop")
-            for p in range(P):
-                mb_payload = mb_payload.at[col2, p, sd].set(
-                    pay_s[p], mode="drop")
-        else:
-            flat = jnp.where(fits, col * jnp.int32(n) + sd,
-                             jnp.int32(K * n))
-            mb_rel = mb_rel.reshape(-1).at[flat].set(
-                drel_s, mode="drop").reshape(K, n)
-            if sc.inbox_src:
-                # inbox_src=False skips this whole scatter — mailbox
-                # scatters ARE the dense random-delivery cost floor
-                # (docs/engines.md "Measured on a v5e"), so dropping an unread field is ~1/3
-                # of it
-                mb_src = mb_src.reshape(-1).at[flat].set(
-                    src_s, mode="drop").reshape(K, n)
-            mb_payload = mb_payload.reshape(-1)
-            for p in range(P):
-                flat_p = jnp.where(
-                    fits, (col * jnp.int32(P) + p) * jnp.int32(n) + sd,
-                    jnp.int32(K * P * n))
-                mb_payload = mb_payload.at[flat_p].set(pay_s[p],
-                                                       mode="drop")
-            mb_payload = mb_payload.reshape(K, P, n)
+        flat = jnp.where(fits, col * jnp.int32(n) + sd,
+                         jnp.int32(K * n))
+        mb_rel = mb_rel.reshape(-1).at[flat].set(
+            drel_s, mode="drop").reshape(K, n)
+        if sc.inbox_src:
+            # inbox_src=False skips this whole scatter — mailbox
+            # scatters ARE the dense random-delivery cost floor
+            # (docs/engines.md "Measured on a v5e"), so dropping an unread field is ~1/3
+            # of it
+            mb_src = mb_src.reshape(-1).at[flat].set(
+                src_s, mode="drop").reshape(K, n)
+        mb_payload = mb_payload.reshape(-1)
+        for p in range(P):
+            flat_p = jnp.where(
+                fits, (col * jnp.int32(P) + p) * jnp.int32(n) + sd,
+                jnp.int32(K * P * n))
+            mb_payload = mb_payload.at[flat_p].set(pay_s[p],
+                                                   mode="drop")
+        mb_payload = mb_payload.reshape(K, P, n)
         overflow = jnp.sum(ok_s & (pos >= K), dtype=jnp.int32)
         return mb_rel, mb_src, mb_payload, overflow
 
@@ -1006,9 +875,10 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
                     sent_hash = _u32sum(jnp.where(ok_s, sent_mix, 0))
                 else:
                     sent_hash = jnp.uint32(0)
-                # route_drop ≡ 0 here (the top rung is always n); the
-                # slot exists so fused_sparse.py's override can report
-                # its VMEM batch-cap drops through the same call site
+                # route_drop ≡ 0 here (the top rung is always n): the
+                # slot keeps this return the shape of the legacy
+                # paths' (where route_cap can drop), for the one
+                # unpacking in _staged_superstep
                 ret = (mrel, msrc, mpay, overflow_step, bad_dst_step,
                        bad_delay_step, short_step, jnp.int32(0),
                        sent_count, sent_hash)
@@ -1053,148 +923,6 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
             self._t_rung = self._fleet_rung = \
                 jnp.asarray(rungs, jnp.int32)[idx]
         return jax.lax.switch(idx, [tail(A) for A in rungs])
-
-    def _route_firecompact(self, out, out_valid, now_vec, t, mb_rel,
-                           mb_src, mb_payload, free_rows, counts,
-                           node_ids, with_trace):
-        """The ``insert="pallas"`` adaptive routing stage
-        (pallas_insert.py): the fire-compaction kernel streams the raw
-        pre-masked outbox planes once and emits the compact fired
-        batch directly — no sender-compaction N-sort, no rung-width
-        gathers, no ``lax.switch`` ladder. The ordering sort
-        (destination, window offset, sender-major rank), link sampling
-        (with every fault mask point), and the SENT digest then run in
-        XLA at *compacted* width, exactly mirroring
-        ``_route_adaptive``'s branches, and ``_insert_sorted``
-        dispatches the sorted batch into the in-tile insertion kernel.
-        Bit-identical to the ladder path: same message set (the
-        default ``insert_cap`` is n·max_out, so nothing can drop),
-        same sort keys, same entropy, same counters — only lanes that
-        are masked out everywhere differ (tests/test_pallas_insert.py,
-        including under faults and the world axis)."""
-        sc = self.scenario
-        M, P = sc.max_out, sc.payload_width
-        n = self.comm.n_local
-        n_glob = self.comm.n_global
-        W = self.window
-        rec_full = with_trace and self.record == "full"
-        stage = self._pallas_stage
-        if self.telemetry != "off":
-            # the pallas path's "rung" is its static compacted batch
-            # width, sender-denominated (the ladder analog)
-            self._t_rung = jnp.int32(stage.A)
-        # XLA pre-mask — identical to _route_adaptive's head: validity
-        # + destination-range check folded into one signed plane
-        # (contract #6 corollary: out-of-range destinations counted,
-        # never silently dropped), partition cuts killed before
-        # compaction (sample-independent; the oracle drops the same
-        # set)
-        dst32 = out.dst.astype(jnp.int32)                       # [M, N]
-        dst_okf = (dst32 >= 0) & (dst32 < n_glob)
-        bad_dst_step = jnp.sum(out_valid & ~dst_okf, dtype=jnp.int32)
-        pdst = jnp.where(out_valid & dst_okf, dst32, -1)        # [M, N]
-        fault_cut = jnp.int32(0)
-        if self._faulted and self._ft.part_group.shape[0]:
-            from ...faults.apply import cut_mask
-            cutm = (pdst >= 0) & cut_mask(
-                self._ft, node_ids[None, :], pdst, now_vec[None, :])
-            fault_cut = jnp.sum(cutm, dtype=jnp.int32)
-            self._rec_cut(rec_full, cutm, node_ids[None, :], pdst,
-                          now_vec[None, :])
-            pdst = jnp.where(cutm, jnp.int32(-1), pdst)
-        woff_n = (now_vec - t).astype(jnp.int32)                # [N]
-
-        # the kernel: compact fired batch at static width S (sentinel
-        # dst = n beyond the fired width; capacity drops counted —
-        # zero by construction at the default insert_cap)
-        dst_f, woff_f, smrank, pay_f, route_drop_step = stage.compact(
-            pdst, woff_n, out.payload)
-        ok = dst_f < jnp.int32(n)
-
-        if self._faulted:
-            # sample BEFORE the routing sort (the down-window drop
-            # needs deliver times before insertion ranks exist) —
-            # _route_adaptive's branch_faulted, at compacted width
-            from ...faults.apply import down_mask
-            src_l = smrank // jnp.int32(M)
-            tmsg_l = t + woff_f.astype(jnp.int64)
-            flight, drel, bad_delay_step, short_step, _ = \
-                self._sample_nodrop(src_l, dst_f, tmsg_l,
-                                    smrank % jnp.int32(M), woff_f, ok)
-            downm = ok & down_mask(self._ft, dst_f, tmsg_l + flight)
-            fault_down = jnp.sum(downm, dtype=jnp.int32)
-            ok2 = ok & ~downm
-            sent_count = jnp.sum(ok2, dtype=jnp.int32)
-            if with_trace:
-                dt_abs = tmsg_l + flight
-                sent_mix = mix32_jnp(SENT, src_l, dst_f,
-                                     _tlo(dt_abs), _thi(dt_abs),
-                                     pay_f[0])
-                sent_hash = _u32sum(jnp.where(ok2, sent_mix, 0))
-            else:
-                sent_hash = jnp.uint32(0)
-            sort_dst = jnp.where(ok2, dst_f, n)
-            if W > 1:
-                ops = jax.lax.sort(
-                    (sort_dst, woff_f, smrank, drel) + pay_f,
-                    dimension=0, num_keys=3)
-                sd, smrank_s, drel_s = ops[0], ops[2], ops[3]
-                pay_s = ops[4:]
-            else:
-                ops = jax.lax.sort(
-                    (sort_dst, smrank, drel) + pay_f,
-                    dimension=0, num_keys=2)
-                sd, smrank_s, drel_s = ops[0], ops[1], ops[2]
-                pay_s = ops[3:]
-            ok_s = sd < n
-            src_s = smrank_s // jnp.int32(M)
-            mrel, msrc, mpay, overflow_step = self._insert_sorted(
-                mb_rel, mb_src, mb_payload, sd, ok_s, drel_s,
-                src_s, pay_s, free_rows, counts)
-            ret = (mrel, msrc, mpay, overflow_step, bad_dst_step,
-                   bad_delay_step, short_step, route_drop_step,
-                   sent_count, sent_hash, fault_cut + fault_down)
-            if rec_full:
-                ret += (self._rec_sends(ok, downm, src_l, dst_f,
-                                        tmsg_l, tmsg_l + flight),)
-            return ret
-
-        sort_dst = jnp.where(ok, dst_f, n)
-        if W > 1:
-            ops = jax.lax.sort((sort_dst, woff_f, smrank) + pay_f,
-                               dimension=0, num_keys=3)
-            sd, woff_s, smrank_s = ops[0], ops[1], ops[2]
-            pay_s = ops[3:]
-        else:
-            ops = jax.lax.sort((sort_dst, smrank) + pay_f,
-                               dimension=0, num_keys=2)
-            sd, smrank_s = ops[0], ops[1]
-            woff_s = jnp.zeros_like(sd)
-            pay_s = ops[2:]
-        ok_s = sd < n
-        src_s = smrank_s // jnp.int32(M)
-        tmsg_s = t + woff_s.astype(jnp.int64)
-        flight_s, drel_s, bad_delay_step, short_step, _ = \
-            self._sample_nodrop(src_s, sd, tmsg_s,
-                                smrank_s % jnp.int32(M), woff_s, ok_s)
-        mrel, msrc, mpay, overflow_step = self._insert_sorted(
-            mb_rel, mb_src, mb_payload, sd, ok_s, drel_s,
-            src_s, pay_s, free_rows, counts)
-        sent_count = jnp.sum(ok, dtype=jnp.int32)
-        if with_trace:
-            dt_abs = tmsg_s + flight_s
-            sent_mix = mix32_jnp(SENT, src_s, sd, _tlo(dt_abs),
-                                 _thi(dt_abs), pay_s[0])
-            sent_hash = _u32sum(jnp.where(ok_s, sent_mix, 0))
-        else:
-            sent_hash = jnp.uint32(0)
-        ret = (mrel, msrc, mpay, overflow_step, bad_dst_step,
-               bad_delay_step, short_step, route_drop_step,
-               sent_count, sent_hash)
-        if rec_full:
-            ret += (self._rec_sends(ok_s, None, src_s, sd, tmsg_s,
-                                    tmsg_s + flight_s),)
-        return ret
 
     def _superstep(self, st: EngineState, with_trace: bool
                    ) -> Tuple[EngineState, Optional[_StepOut]]:
@@ -1423,19 +1151,14 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
             mb_rel = jnp.where(keep, st.mb_rel - shift32, _I32MAX)
             mb_src = st.mb_src          # stale in holes; validity is the
             mb_payload = st.mb_payload  # rel sentinel, never these
-            if self._fused_holes:
-                # the fused-sparse kernel ranks holes in-VMEM per
-                # block — no [K, N] free-slot sort is owed at all
-                free_rows = None
-            else:
-                #: free_rows[r, i] = row of node i's r-th free slot
-                #: (K = none)
-                # int8 free-slot table when K fits: 4x less sort
-                # bandwidth AND 4x smaller as a routing-switch operand
-                # (TPU conditionals move their operands)
-                fr_dt = jnp.int8 if K <= 127 else jnp.int32
-                free_rows = jax.lax.sort(
-                    jnp.where(keep, K, slots).astype(fr_dt), dimension=0)
+            #: free_rows[r, i] = row of node i's r-th free slot
+            #: (K = none)
+            # int8 free-slot table when K fits: 4x less sort
+            # bandwidth AND 4x smaller as a routing-switch operand
+            # (TPU conditionals move their operands)
+            fr_dt = jnp.int8 if K <= 127 else jnp.int32
+            free_rows = jax.lax.sort(
+                jnp.where(keep, K, slots).astype(fr_dt), dimension=0)
             counts = None
         else:
             ops2 = jax.lax.sort(
@@ -1460,15 +1183,7 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         #    instant (== t for W == 1), which keys the link entropy.
         adaptive = self._adaptive_regime()
         if adaptive:
-            # insert="pallas"/"interpret": fire-compaction replaces
-            # the sender-compaction sort + rung-gather ladder
-            # (pallas_insert.py) — result-identical by the insert
-            # exactness law, only the venue differs
-            route = self._route_adaptive \
-                if self._pallas_stage is None \
-                or not self._pallas_stage.adaptive \
-                else self._route_firecompact
-            res = route(
+            res = self._route_adaptive(
                 out, out_valid, now_vec, t, mb_rel, mb_src,
                 mb_payload, free_rows, counts, node_ids, with_trace)
             if rec_full:
@@ -1480,16 +1195,15 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
             spec_strag = None
             if self.speculate != "off":
                 # the causality plane's straggler min rode the switch
-                # return the same way (speculating engines always take
-                # _route_adaptive — the kernel routes refuse the knob)
+                # return the same way
                 spec_strag = res[-1]
                 res = res[:-1]
             (mb_rel, mb_src, mb_payload, overflow_step, bad_dst_step,
              bad_delay_step, short_step, route_drop_step, sent_count,
              sent_hash) = res[:10]
             # the faulted routing variant appends its fault-drop count
-            # (partition cuts + down-window deliveries); the fused
-            # override and the unfaulted tail return the bare 10-tuple
+            # (partition cuts + down-window deliveries); the
+            # unfaulted tail returns the bare 10-tuple
             fault_route = res[10] if len(res) > 10 else jnp.int32(0)
             stage("tw.finish")
             return self._finish_superstep(
@@ -2036,9 +1750,8 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         world_links = [batch.world_link(self.link, b)
                        for b in range(batch.B)]
         link_floor = min(lk.min_delay_us for lk in world_links)
-        if fleet is not None and (
-                (self.controller is None and self.speculate == "off")
-                or not self._dyn_ok):
+        if fleet is not None and self.controller is None \
+                and self.speculate == "off":
             link_floor = fleet.min_delay_floor(link_floor)
         floor_ref = (self.spec_floor if self.speculate != "off"
                      else self.window)

@@ -22,7 +22,7 @@ from __future__ import annotations
 
 from typing import Optional
 
-from ...utils.jaxconfig import require_tpu
+from ...utils import jaxconfig  # noqa: F401
 
 import jax
 import jax.numpy as jnp
@@ -38,7 +38,7 @@ from .edge_engine import EdgeEngine, EdgeState
 from .engine import EngineState, JaxEngine
 
 __all__ = ["MeshComm", "ShardedBatchedEngine", "ShardedEdgeEngine",
-           "ShardedEngine", "ShardedFusedSparseEngine", "make_mesh"]
+           "ShardedEngine", "make_mesh"]
 
 
 def _refuse_record(record: str, who: str) -> str:
@@ -298,62 +298,3 @@ class ShardedBatchedEngine(ShardedDriver, JaxEngine):
         # must not stop the others' (int32 psum — bool all-reduce
         # does not lower on the TPU path, see MeshComm.all_min)
         return jax.lax.psum(x.astype(jnp.int32), self.axis) > 0
-
-
-class ShardedFusedSparseEngine(ShardedEngine):
-    """The multi-chip windowed path's share of the fused-sparse lever
-    (fused_sparse.py): sampling, destination-shard bucketing, and the
-    ``all_to_all`` exchange are :class:`ShardedEngine`'s — message
-    placement is a collective, not a kernel concern — but each shard's
-    post-exchange *mailbox insertion* runs the fused Pallas kernel in
-    its pre-sampled mode: deliver-times arrive with the batch, holes
-    are ranked in-VMEM per block, and the local [K, n_local] mailbox
-    planes stream through the kernel exactly once (no free-rows sort,
-    no per-plane scatters — ``JaxEngine._fused_holes``). Semantics,
-    counters, and trace digests are bit-identical to
-    :class:`ShardedEngine` (tests/test_fused_sparse.py sharded leg)."""
-
-    def __init__(self, scenario: Scenario, link: LinkModel,
-                 mesh: Mesh, *, axis: AxisName = "nodes", seed: int = 0,
-                 bucket_cap: Optional[int] = None,
-                 window: int = 1, lint: str = "warn",
-                 telemetry: str = "off", verify: str = "off",
-                 record: str = "off", interpret: bool = False) -> None:
-        _refuse_record(record, type(self).__name__)
-        # compiled kernel by default; the Pallas interpreter only on
-        # request (fused_sparse.py)
-        self.interpret = bool(interpret)
-        if not self.interpret:
-            require_tpu(type(self).__name__)
-        super().__init__(scenario, link, mesh, axis=axis, seed=seed,
-                         bucket_cap=bucket_cap, window=window,
-                         route_cap=None, lint=lint, telemetry=telemetry,
-                         verify=verify)
-        # the kernel machinery's home since round 12 (pallas_insert.py;
-        # fused_sparse re-exports for older callers)
-        from .pallas_insert import _build_kernel, _insertion_plan
-        sc = scenario
-        nl = self.comm.n_local
-        # post-exchange batch width: one bucket per peer shard
-        self._S2, R, G = _insertion_plan(
-            sc, nl, self.comm.n_shards * self.bucket_cap,
-            who="ShardedFusedSparseEngine",
-            what_n="n_nodes per shard")
-        self._fused_holes = True
-        self._ins_kernel = _build_kernel(
-            K=sc.mailbox_cap, P=sc.payload_width, R=R, G=G,
-            SR=self._S2 // 128, n=nl, M=sc.max_out, W=self.window,
-            inbox_src=sc.inbox_src, mode="drel", needs_key=False,
-            s0=0, s1=0, delay_fn=None)
-
-    @jax.named_scope("insert")
-    def _insert_sorted(self, mb_rel, mb_src, mb_payload, sd, ok_s,
-                       drel_s, src_s, pay_s, free_rows, counts):
-        from .pallas_insert import _fused_insert_call
-        sc = self.scenario
-        mrel, msrc, mpay, cnts = _fused_insert_call(
-            self._ins_kernel, self._S2, self.comm.n_local,
-            sc.mailbox_cap, sc.payload_width, sc.inbox_src,
-            jnp.zeros(4, jnp.int32), sd, drel_s, src_s, pay_s,
-            mb_rel, mb_src, mb_payload, interpret=self.interpret)
-        return mrel, msrc, mpay, jnp.sum(cnts[0], dtype=jnp.int32)

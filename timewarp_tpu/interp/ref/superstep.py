@@ -77,7 +77,7 @@ class SuperstepOracle:
         if isinstance(window, str) and window != "auto":
             # mirror JaxEngine: a typo'd "Auto"/"8ms" from a library
             # caller must fail clearly, not as `window < 1`'s opaque
-            # str-vs-int TypeError (ADVICE r5)
+            # str-vs-int TypeError
             raise ValueError(
                 f"window must be an int µs count or the string "
                 f"'auto', got {window!r}")

@@ -142,7 +142,7 @@ def _jitted_draw(model: "LinkModel"):
             # execution): a traceable FnDelay that merely errors on a
             # degenerate concrete (0, 0, 0) probe input must not be
             # silently demoted to the eager per-call path for the
-            # whole run (ADVICE r5) — eval_shape only fails when the
+            # whole run — eval_shape only fails when the
             # sampler genuinely cannot trace (Python control flow on
             # src/dst/t, host readbacks, ...)
             u32 = jax.ShapeDtypeStruct((), jnp.uint32)

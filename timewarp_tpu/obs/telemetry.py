@@ -18,8 +18,8 @@ Modes:
 
 - ``"counters"`` — cheap scalars only: no reduction the superstep was
   not already paying for, plus one O(N) wake/mailbox min it shares
-  with the quiescence check. Bench-gated at <= 5% throughput cost on
-  the traced driver (bench.py gossip_100k_fused).
+  with the quiescence check. Its cost on the chip is not measured
+  yet (ROADMAP "Metrics still owed").
 - ``"full"`` — adds the mailbox occupancy plane ([K, N] / [E, C, N]
   reductions): total live entries and the per-node fill high-water
   mark. Costs one extra pass over the mailbox per superstep.
@@ -63,8 +63,8 @@ class TelemetryRow(NamedTuple):
     #: int32 — senders that emitted >= 1 valid outbox message
     active_senders: Any
     #: int32 — static width of the routing rung this superstep ran at
-    #: (the adaptive ladder's selected branch / the fused engine's
-    #: batch slice); -1 = the path has no rung ladder
+    #: (the adaptive ladder's selected branch); -1 = the path has
+    #: no rung ladder
     rung: Any
     #: int32 — messages dropped by engine routing capacity this step
     route_drop: Any

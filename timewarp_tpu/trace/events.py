@@ -87,9 +87,9 @@ def assert_traces_equal(a: SuperstepTrace, b: SuperstepTrace,
 def assert_states_equal(a, b, tag: str = "") -> None:
     """Bit-for-bit EngineState (or any NamedTuple-of-arrays pytree
     whose ``states`` field is a dict of arrays) comparison — the
-    exactness law the fused engines are held to against the XLA
-    general engine (tests/test_fused_sparse.py, the in-bench gates).
-    One copy, so every caller asserts the same law."""
+    exactness law between two engines that share a state layout
+    (sharded against one-device, batched slice against solo, the
+    in-bench gates). One copy, so every caller asserts the same law."""
     import jax
     suffix = f" ({tag})" if tag else ""
     for name in a._fields:

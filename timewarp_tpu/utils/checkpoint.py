@@ -23,12 +23,11 @@ ev_count`` widened so event counts past ~2.1e9 cannot wrap — README
 "Compatibility notes"); every int32 value is exactly representable in
 int64, so a pre-widening checkpoint resumes bit-identically.
 
-Engine interchange needs no conversion here: the fused-sparse engine
-(interp/jax_engine/fused_sparse.py) shares ``EngineState`` bit-for-bit
-with ``JaxEngine``, so a checkpoint saved under either resumes under
-the other (tests/test_fused_sparse.py) — unlike the fused *ring*
-engine, whose packed layout needs its own ``to_edge_state`` /
-``from_edge_state`` pair (fused_ring.py).
+Engine interchange needs no conversion here: ``JaxEngine`` and its
+sharded forms share ``EngineState`` bit-for-bit, so a checkpoint saved
+under one resumes under another (tests/test_sharded.py) — unlike the
+fused *ring* engine, whose packed layout needs its own
+``to_edge_state`` / ``from_edge_state`` pair (fused_ring.py).
 
 Batched (multi-world) states need nothing special either: the world
 axis is a leading dim on every leaf, the template (the batched

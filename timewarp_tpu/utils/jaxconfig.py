@@ -52,13 +52,13 @@ def keep_host_cpu() -> None:
 def require_tpu(what: str) -> None:
     """Refuse to run ``what`` (a compiled Pallas kernel path) where
     JAX's default backend is not a TPU. The interpreter is an explicit
-    request (``interpret=True`` / ``insert="interpret"``), never a
-    fallback: a run that finds no chip must say so."""
+    request (``interpret=True``), never a fallback: a run that finds
+    no chip must say so."""
     backend = jax.default_backend()
     if backend != "tpu":
         raise RuntimeError(
             f"{what} runs compiled Mosaic kernels and needs a TPU "
             f"backend; JAX found {backend!r}. Ask for the Pallas "
-            "interpreter explicitly (interpret=True on the fused "
-            "engines, insert='interpret' on JaxEngine) to run the "
-            "kernels' semantics elsewhere — never as a timing")
+            "interpreter explicitly (interpret=True on "
+            "FusedRingEngine) to run the kernel's semantics "
+            "elsewhere — never as a timing")

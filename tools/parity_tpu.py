@@ -15,13 +15,6 @@ window with route_cap (all integer link models), plus — round 6 —
 socket-state (BASELINE config 3's batched twin, models/socket_state.py)
 at the baseline shape and at the 1024-node windowed hub-fan-in shape.
 
-Every config also carries a **fused-sparse column**: the
-FusedSparseEngine (interp/jax_engine/fused_sparse.py) is constructed
-with the same knobs and its trace compared bit-for-bit against the
-general engine's. Configs outside the fused engine's scope (non-1024
-node counts, droppy links, route_cap, ...) record the constructor's
-refusal reason instead — the column is never silently absent.
-
 Round 9 adds a **faulted column** on the gossip row: the same
 config re-run under a mixed fault schedule (reset crash + partition +
 degradation window, faults/) through both the oracle and the general
@@ -40,8 +33,7 @@ repo root). Exits nonzero on any trace mismatch, and refuses to write
 the artifact where JAX found no TPU. One process does all chip work.
 ``--self-check`` (CI mode) runs the same comparison anywhere and
 writes nothing — on a CPU-only runner the engines and oracle share a
-backend and the fused-sparse column runs under the Pallas interpreter,
-so it degrades to an engine≡oracle gate.
+backend, so it degrades to an engine≡oracle gate.
 """
 
 import hashlib
@@ -70,8 +62,6 @@ def main() -> int:
     from timewarp_tpu.interp.jax_engine.edge_engine import EdgeEngine
     from timewarp_tpu.interp.jax_engine.engine import (BatchSpec,
                                                        JaxEngine)
-    from timewarp_tpu.interp.jax_engine.fused_sparse import \
-        FusedSparseEngine
     from timewarp_tpu.interp.ref.superstep import SuperstepOracle
     from timewarp_tpu.models.gossip import gossip
     from timewarp_tpu.models.ping_pong import ping_pong
@@ -132,17 +122,16 @@ def main() -> int:
             socket_state(n_clients=3, seed=24, send_interval_us=50_000,
                          server_life_us=120_000),
             wlink, JaxEngine, 400, {}),
-        # the 1024-node windowed hub-fan-in shape: the fused-sparse
-        # engine's scope floor (1024-lane mailbox planes), and the
-        # hard regime for its hole accounting — a 1023-way
-        # co-temporal fan-in overflowing the hub mailbox
+        # the 1024-node windowed hub-fan-in shape: the hard regime
+        # for insertion's hole accounting — a 1023-way co-temporal
+        # fan-in overflowing the hub mailbox
         "socket-state-1024-windowed": (
             socket_state(n_clients=1023, seed=1,
                          send_interval_us=20_000,
                          server_life_us=2_000_000, mailbox_cap=64),
             wlink, JaxEngine, 250, {"window": 3_000}),
-        # the fused engine's bench shape family at artifact scale:
-        # burst gossip at 1024 nodes under the 3 ms window
+        # the bench's wave family at artifact scale: burst gossip at
+        # 1024 nodes under the 3 ms window
         "gossip-1024-burst-windowed": (
             gossip(1024, fanout=4, think_us=700, burst=True,
                    end_us=400_000, mailbox_cap=16),
@@ -178,55 +167,6 @@ def main() -> int:
             entry["equal"] = False
             entry["mismatch"] = str(e)
             out["ok"] = False
-
-        # fused-sparse column (round 6): same knobs — except
-        # route_cap, the XLA insertion stage's capacity contract; the
-        # fused engine bounds its VMEM-resident batch with max_batch
-        # (default: no superstep here can drop) — trace bit-for-bit
-        # against the general engine. Out-of-scope configs record the
-        # constructor's refusal, never a silent absence.
-        fkw = {k: v for k, v in ekw.items() if k != "route_cap"}
-        # --self-check asks for the Pallas interpreter explicitly;
-        # the artifact run compiles the kernel for the chip, and a
-        # kernel the chip's compiler refuses is written into the
-        # column in the compiler's own words (tests/
-        # test_chip_compile.py pins the same refusal) — a column that
-        # could not run is not a parity mismatch
-        fused = ftrace = None
-        try:
-            fused = FusedSparseEngine(sc, link, interpret=self_check,
-                                      **fkw)
-        except ValueError as e:         # the constructor's scope guard
-            entry["fused_sparse"] = {
-                "supported": False,
-                "reason": str(e).split(" (")[0]}
-        try:
-            if fused is not None:
-                _, ftrace = fused.run(steps)
-        except (AssertionError, NotImplementedError,
-                RecursionError) as e:   # Mosaic's lowering
-            if self_check:
-                raise
-            import traceback
-            at = traceback.extract_tb(e.__traceback__)[-1]
-            entry["fused_sparse"] = {
-                "supported": False,
-                "reason": "the kernel does not lower for this chip: "
-                          f"{type(e).__name__} in {at.name} "
-                          f"({os.path.basename(at.filename)}:"
-                          f"{at.lineno}) {str(e)[:200]}".rstrip()}
-        if ftrace is not None:
-            fent = {"supported": True, "sha": trace_sha(ftrace)}
-            try:
-                assert_traces_equal(etrace, ftrace,
-                                    f"general-{platform}",
-                                    f"fused-sparse-{platform}")
-                fent["equal"] = True
-            except TraceMismatch as e:
-                fent["equal"] = False
-                fent["mismatch"] = str(e)
-                out["ok"] = False
-            entry["fused_sparse"] = fent
 
         # faulted column (round 9): the gossip row re-run under a
         # mixed crash+partition+degradation schedule — oracle ≡
@@ -299,10 +239,6 @@ def main() -> int:
                           "is the general engine's lever)"}
 
         out["configs"][name] = entry
-        fus = entry["fused_sparse"]
-        fused_word = ("fused-sparse out of scope" if not fus["supported"]
-                      else "fused-sparse "
-                      + ("OK" if fus["equal"] else "MISMATCH"))
         bat = entry["batched"]
         bat_word = ("batched out of scope" if not bat["supported"]
                     else "batched "
@@ -312,7 +248,7 @@ def main() -> int:
             ", faulted " + ("OK" if flt["equal"] else "MISMATCH"))
         print(f"{name}: {'OK' if entry['equal'] else 'MISMATCH'} "
               f"({entry['supersteps']} supersteps, "
-              f"{entry['delivered']} delivered, {fused_word}, "
+              f"{entry['delivered']} delivered, "
               f"{bat_word}{flt_word})")
 
     if not self_check:
