@@ -95,3 +95,27 @@ def test_fused_ring_superstep_lowers_at_2p20(one_chip, as_tpu):
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes >= 10 * n * 4
     assert mem.temp_size_in_bytes < (1 << 20)   # nothing staged in HBM
+
+
+def test_hole_select_compiles_for_v5e_without_a_sort(one_chip):
+    """A commutative inbox's free slots at the benchmark's shape
+    (``[24, 2^17]`` mailbox, ``free_bits``) and the bit select at the
+    ladder's first rung's lanes and at two words (``nth_set_bit``):
+    the chip's compiler takes the popcounts, and the program it makes
+    holds no sort (the ``[K, N]`` sort of free rows they replaced in
+    PR 30 was the general engine's largest single operation)."""
+    from timewarp_tpu.ops.numeric import free_bits, nth_set_bit
+    n, lanes = 1 << 17, 1024 * 8
+
+    def slots(keep, rank, dst):
+        words = free_bits(keep)
+        return nth_set_bit([w[dst] for w in words], rank, keep.shape[0])
+
+    for K in (24, 40):
+        compiled = jax.jit(slots).lower(
+            _sds(one_chip, (K, n), jnp.bool_), _sds(one_chip, (lanes,)),
+            _sds(one_chip, (lanes,))).compile()
+        text = compiled.as_text()
+        assert "popcnt" in text
+        assert " sort(" not in text
+        assert compiled.memory_analysis().temp_size_in_bytes < K * n

@@ -17,11 +17,21 @@ form (PR 29; they are at 193bc01), walked against the oracle instead:
   tail), ``eager`` (a ``WithDrop`` link), ``lazy`` (``route_cap`` above
   the load);
 - inbox: commutative (the gossip burst: the r-th message takes the
-  destination's r-th hole) and ordered (the observer token ring,
+  destination's r-th hole; and the same at two words of holes, below)
+  and ordered (the observer token ring,
   ``max_out`` 2: append after the kept messages);
 - mailbox: fits, and too small for the fan-in (``overflow`` > 0 is
   asserted, and the surviving messages must still be the oracle's);
 - n: 1024, and 1000 (a width that is no multiple of a lane or a tile).
+
+Since PR 30 a commutative inbox's holes are ``ceil(K/32)`` uint32
+words a node and the r-th hole is a bit select (ops/numeric.py;
+tests/test_free_bits.py is the primitive's own law). So the matrix
+has a third inbox, a wave of fanout 40 into mailboxes of two words,
+fitting (64 slots, 52 used) and not (40); and one run is replayed
+with the sorted free-slot table of the parent in the primitive's
+place, the two mailboxes compared *slot for slot*, which no observer
+could tell apart if they differed.
 
 Then praos (``needs_key``, payload width 2, a lognormal link), the
 socket-state hub (1023 clients into one mailbox), a two-world faulted
@@ -48,7 +58,9 @@ import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
 
+from test_free_bits import free_rows_by_sort
 from timewarp_tpu.core.scenario import NEVER
 from timewarp_tpu.faults import (FaultFleet, FaultSchedule, NodeCrash,
                                  Partition)
@@ -179,6 +191,14 @@ def _burst(n, K):
                   end_us=1_000_000, mailbox_cap=K)
 
 
+def _wide_burst(n, K):
+    """Fanout 40: up to 52 messages pending at one node of 1024, so a
+    mailbox's holes past row 31 (its second uint32 word of free
+    slots, PR 30) are taken."""
+    return gossip(n, fanout=40, think_us=2_000, burst=True,
+                  end_us=1_000_000, mailbox_cap=K)
+
+
 def _observer_ring(n, K):
     sc = token_ring(n - 1, n_tokens=64, think_us=1_000,
                     bootstrap_us=1_000, with_observer=True,
@@ -187,11 +207,13 @@ def _observer_ring(n, K):
     return sc
 
 
+_WAVE_LINK = Quantize(UniformDelay(8_000, 30_000), 1_000)
+
 #: inbox -> (scenario of n nodes and K slots, its drop-free link, the
 #: mailbox that fits, the one that does not)
 INBOX = {
-    "commutative": (_burst, Quantize(UniformDelay(8_000, 30_000), 1_000),
-                    24, 2),
+    "commutative": (_burst, _WAVE_LINK, 24, 2),
+    "commutative-two-words": (_wide_burst, _WAVE_LINK, 64, 40),
     "ordered": (_observer_ring, UniformDelay(1_000, 5_000), 96, 2),
 }
 
@@ -212,13 +234,75 @@ SITE = {
 def test_insertion_equals_oracle(site, inbox, mailbox, n):
     make, link, fits, small = INBOX[inbox]
     sc = make(n, fits if mailbox == "fits" else small)
-    assert sc.commutative_inbox == (inbox == "commutative")
+    assert sc.commutative_inbox == inbox.startswith("commutative")
     relink, kw, adaptive = SITE[site]
     eng, orc = pair(sc, relink(link), window="auto", **kw(sc))
     assert eng.window > 1 and eng._adaptive_regime() == adaptive
     st = hold_to_oracle(f"{site}-{inbox}-{mailbox}-n{n}", eng, orc, (8, 8))
     assert int(st.delivered) > 64       # the load is there
     assert (int(st.overflow) > 0) == (mailbox == "overflows")
+    if inbox == "commutative-two-words":
+        assert -(-sc.mailbox_cap // 32) == 2
+        if mailbox == "fits":
+            # the second word was needed: some node holds a message
+            # past row 31 while an earlier row of it is a hole again
+            used = np.asarray(st.mb_rel) != I32MAX
+            assert (used[32:].any(axis=0) & ~used[:32].all(axis=0)).any()
+
+
+# ---------------------------------------------------------------------------
+# the slot itself: a run replayed with the parent's sorted table
+# ---------------------------------------------------------------------------
+
+def _table_in_place_of_the_words(monkeypatch):
+    """The parent's pair in the primitive's place, through the seam
+    ``_insert_sorted`` has (``nth_set_bit([w[dst] for w in holes], rank,
+    K)``): ``holes`` is the sorted ``[K, N]`` table of free rows, its
+    "words" are the table's rows, and the select is the table's entry
+    ``[rank, dst]``, K past the last row. Returns what counts the
+    lookups traced."""
+    from timewarp_tpu.interp.jax_engine import engine as engine_mod
+    traced = []
+
+    def lookup(rows, rank, none):
+        K = len(rows)
+        assert none == K
+        traced.append(K)
+        lane = jnp.arange(rank.shape[0])
+        return jnp.where(
+            rank < K, jnp.stack(rows)[jnp.clip(rank, 0, K - 1), lane], K)
+
+    monkeypatch.setattr(engine_mod, "free_bits",
+                        lambda keep: free_rows_by_sort(keep, jnp))
+    monkeypatch.setattr(engine_mod, "nth_set_bit", lookup)
+    return traced
+
+
+@pytest.mark.parametrize("K,make", [(24, _burst), (40, _wide_burst)],
+                         ids=["one-word", "two-words"])
+def test_every_slot_is_the_one_the_sorted_table_gave(K, make, monkeypatch):
+    """Bit-equal, slot for slot: the raw mailbox arrays (holes' stale
+    words included) and every other leaf of the state, at two
+    horizons, with overflow in the two-word case."""
+    sc, link = make(1024, K), _WAVE_LINK
+    eng = JaxEngine(sc, link, window="auto", lint="off")
+    states = []
+    st = eng.init_state()
+    for k in (10, 6):
+        st, _ = eng.run(k, st)
+        states.append(jax.device_get(st))
+    traced = _table_in_place_of_the_words(monkeypatch)
+    ref = JaxEngine(sc, link, window="auto", lint="off")
+    st = ref.init_state()
+    for want in states:
+        st, _ = ref.run(int(want.steps) - int(st.steps), st)
+        got = jax.device_get(st)
+        for name, a, b in zip(got._fields, got, want):
+            for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
+                assert np.array_equal(x, y), (name, int(want.steps))
+    assert traced and set(traced) == {K}, "the table was never read"
+    assert (np.asarray(want.mb_rel) != I32MAX).sum() > 1024
+    assert (int(want.overflow) > 0) == (K == 40)
 
 
 # ---------------------------------------------------------------------------

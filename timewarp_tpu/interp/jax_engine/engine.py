@@ -45,6 +45,7 @@ import numpy as np
 from ...core.rng import fire_bits, msg_bits, seed_words
 from ...core.scenario import NEVER, Inbox, Outbox, Scenario
 from ...net.delays import LinkModel
+from ...ops.numeric import free_bits, nth_set_bit
 from ...trace.events import SuperstepTrace
 from ...trace.hashing import FIRED, RECV, SENT, mix32_jnp
 from .batched import BatchSpec, WorldIdentity, rebind_link
@@ -192,9 +193,13 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
 
     Mailbox insertion has one form, ``_insert_sorted``'s flat 1D
     scatters, held to the oracle by tests/test_insert_law.py
-    (docs/engines.md "Mailbox insertion"). ``insert`` is a vestigial
-    keyword: ``None`` and ``"xla"`` build the same engine, anything
-    else is refused (ROADMAP D2').
+    (docs/engines.md "Mailbox insertion"). A commutative inbox's free
+    slots are bit words (``ceil(mailbox_cap / 32)`` uint32 a node,
+    built in ``tw.rebase``) and a message's slot the rank-th set bit
+    of its destination's words: no sort along the mailbox's slots
+    (tests/test_free_bits.py, tests/test_superstep_sorts.py).
+    ``insert`` is a vestigial keyword: ``None`` and ``"xla"`` build
+    the same engine, anything else is refused (ROADMAP D2').
 
     Batched multi-world execution (``batch=BatchSpec``, batched.py):
     a leading world axis B through the whole engine. ``_superstep`` is
@@ -656,12 +661,13 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
 
     @jax.named_scope("insert")
     def _insert_sorted(self, mb_rel, mb_src, mb_payload, sd, ok_s,
-                       drel_s, src_s, pay_s, free_rows, counts):
+                       drel_s, src_s, pay_s, holes, counts):
         """Shared mailbox insertion for destination-sorted messages:
         per-destination rank -> target slot (r-th hole for commutative
-        inboxes, append-after-kept otherwise) -> flat 1D scatters (the
-        2D [col, row] form costs ~7x on this chip, docs/engines.md
-        per-op cost table). Non-fitting lanes get an out-of-range
+        inboxes: the r-th set bit of the destination's ``holes`` words,
+        ops/numeric.py ``nth_set_bit``; append-after-kept otherwise)
+        -> flat 1D scatters (the 2D [col, row] form costs ~7x on this
+        chip, docs/engines.md per-op cost table). Non-fitting lanes get an out-of-range
         index and are dropped; returns the updated arrays plus the
         local overflow count. Held to the oracle by
         tests/test_insert_law.py."""
@@ -670,10 +676,12 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         n = self.comm.n_local
         rank = group_rank(sd)
         if sc.commutative_inbox:
-            # r-th incoming message takes the destination's r-th hole
-            prow = free_rows[jnp.clip(rank, 0, K - 1),
-                             jnp.clip(sd, 0, n - 1)].astype(jnp.int32)
-            fits = ok_s & (rank < K) & (prow < K)
+            # r-th incoming message takes the destination's r-th hole:
+            # one 1D gather a word, then a bit select on these lanes
+            # (K when the mailbox holds r holes or fewer)
+            sdc = jnp.clip(sd, 0, n - 1)
+            prow = nth_set_bit([w[sdc] for w in holes], rank, K)
+            fits = ok_s & (prow < K)
             col = jnp.clip(prow, 0, K - 1)
             pos = jnp.where(fits, jnp.int32(0), jnp.int32(K))
         else:
@@ -703,7 +711,7 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         return mb_rel, mb_src, mb_payload, overflow
 
     def _route_adaptive(self, out, out_valid, now_vec, t, mb_rel,
-                        mb_src, mb_payload, free_rows, counts,
+                        mb_src, mb_payload, holes, counts,
                         node_ids, with_trace):
         """Sender-compacted adaptive-width routing + insertion (class
         docstring): compact active sender ids with ONE single-operand
@@ -818,7 +826,7 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
                 src_s = smrank_s // jnp.int32(M)
                 mrel, msrc, mpay, overflow_step = self._insert_sorted(
                     mb_rel, mb_src, mb_payload, sd, ok_s, drel_s,
-                    src_s, pay_s, free_rows, counts)
+                    src_s, pay_s, holes, counts)
                 ret = (mrel, msrc, mpay, overflow_step, bad_dst_step,
                        bad_delay_step, short_step, jnp.int32(0),
                        sent_count, sent_hash, fault_cut + fault_down)
@@ -866,7 +874,7 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
                                         woff_s, ok_s)
                 mrel, msrc, mpay, overflow_step = self._insert_sorted(
                     mb_rel, mb_src, mb_payload, sd, ok_s, drel_s,
-                    src_s, pay_s, free_rows, counts)
+                    src_s, pay_s, holes, counts)
                 sent_count = jnp.sum(ok, dtype=jnp.int32)
                 if with_trace:
                     dt_abs = tmsg_s + flight_s
@@ -1139,9 +1147,11 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         #    to the new epoch t. Two regimes:
         #    - commutative inbox: slot order is unobservable, so freed
         #      slots become *holes* (elementwise — no [K, N] compaction
-        #      sort) and insertion targets the r-th free slot via a
-        #      single-operand sort of free-slot rows. Overflow semantics
-        #      are bit-identical: rank >= #free ⇔ counts + rank >= K.
+        #      sort, and no sort of the free rows either: a node's
+        #      holes are ceil(K/32) uint32 words, and insertion finds
+        #      the r-th hole by bit select on the routing rung's
+        #      lanes). Overflow semantics are bit-identical:
+        #      rank >= #free ⇔ counts + rank >= K.
         #    - ordered inbox: the variadic compaction sort keeps arrival
         #      order materialized in slot order (contract #2's tiebreak).
         keep = mb_live & ~deliver
@@ -1151,14 +1161,10 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
             mb_rel = jnp.where(keep, st.mb_rel - shift32, _I32MAX)
             mb_src = st.mb_src          # stale in holes; validity is the
             mb_payload = st.mb_payload  # rel sentinel, never these
-            #: free_rows[r, i] = row of node i's r-th free slot
-            #: (K = none)
-            # int8 free-slot table when K fits: 4x less sort
-            # bandwidth AND 4x smaller as a routing-switch operand
-            # (TPU conditionals move their operands)
-            fr_dt = jnp.int8 if K <= 127 else jnp.int32
-            free_rows = jax.lax.sort(
-                jnp.where(keep, K, slots).astype(fr_dt), dimension=0)
+            #: holes[k // 32, i] bit k % 32 = row k of node i is free;
+            #: 4 bytes a node (K <= 32) as a routing-switch operand
+            #: (TPU conditionals move their operands)
+            holes = free_bits(keep)
             counts = None
         else:
             ops2 = jax.lax.sort(
@@ -1169,7 +1175,7 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
             mb_rel = jnp.where(kept, ops2[2] - shift32, _I32MAX)
             mb_src = ops2[3]
             mb_payload = jnp.stack(ops2[4:4 + P], axis=1)
-            free_rows = None
+            holes = None
             counts = kept.sum(axis=0, dtype=jnp.int32)          # [N]
 
         stage("tw.route")
@@ -1185,7 +1191,7 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         if adaptive:
             res = self._route_adaptive(
                 out, out_valid, now_vec, t, mb_rel, mb_src,
-                mb_payload, free_rows, counts, node_ids, with_trace)
+                mb_payload, holes, counts, node_ids, with_trace)
             if rec_full:
                 # the routing tail's send-event buffer rode the
                 # return (it crosses a lax.switch boundary) — merge
@@ -1388,7 +1394,7 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
             pay_s = ops3[3:]
         mb_rel, mb_src, mb_payload, overflow_local = self._insert_sorted(
             mb_rel, mb_src, mb_payload, sd, ok_s, drel_s, src_s, pay_s,
-            free_rows, counts)
+            holes, counts)
         overflow_step = comm.all_sum(overflow_local) + bucket_ovf
 
         sent_count = sent_hash = None
