@@ -988,19 +988,31 @@ def bench_gossip_steady_1m(n, steps):
     """Rumor-mongering steady state: every infected node relays to one
     pseudo-random peer per 1 ms round — the dense dynamic-destination
     regime of the general engine (1M messages per superstep at 1M
-    nodes, every one through the all-destination routing path)."""
+    nodes, every one through the eager all-destination routing path:
+    ``window`` 1 and one outbox slot, so no ladder).
+
+    ``mailbox_cap`` 24, not the 8 this row had until PR 31: a push is in
+    flight 1-5 rounds and a node receives one a round on average, so
+    some three are in flight to a node at once and 8 slots dropped
+    0.4 % of the messages, silently (PERF.md, Findings PR 31). The
+    benchmark's cell ``gossip_steady_1m.rounds`` is this row with the
+    plain reference beside it."""
     from timewarp_tpu.interp.jax_engine.engine import JaxEngine
     from timewarp_tpu.models.gossip import gossip
     from timewarp_tpu.net.delays import Quantize, UniformDelay
 
     n = n or 1 << 20
     sc = gossip(n, fanout=1, think_us=1_000, gossip_interval=1_000,
-                end_us=(1 << 50), steady=True, mailbox_cap=8)
+                end_us=(1 << 50), steady=True, mailbox_cap=24)
     link = Quantize(UniformDelay(500, 4_500), 1_000)
     engine = JaxEngine(sc, link)
     # warm through the infection ramp-up so the measured window is the
-    # steady state (seed node infects ~2^k nodes by round k)
-    delivered, dt, _ = _measure(engine, steps or 256, warm_steps=64)
+    # steady state: at 2^20 every node holds the rumor after superstep
+    # 57-61 (PERF.md, Findings PR 31), so 64 left three supersteps
+    delivered, dt, fin = _measure(engine, steps or 256, warm_steps=128)
+    assert int(fin.overflow) == 0, "a mailbox overflowed: messages lost"
+    assert int(fin.short_delay) == 0, "a flight shorter than the window"
+    assert int(fin.route_drop) == 0, "routing dropped messages"
     return (f"gossip steady-state (rumor mongering) "
             f"delivered-messages/sec/chip @{n} nodes", delivered / dt)
 

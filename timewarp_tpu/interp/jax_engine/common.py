@@ -26,7 +26,11 @@ __all__ = ["LocalComm", "StepOut", "I32MAX", "group_rank", "u32sum",
 #: the device work of every engine that adopts them (``engine.py``,
 #: ``fused_ring.py``; the ring's kernel sits under ``tw.ring_kernel``).
 #: A scope is metadata: it adds no equation, and a profile shows it in
-#: each operation's ``op_name`` (benchmark/span_reduce.py ``stage_ns``)
+#: each operation's ``op_name`` (benchmark/span_reduce.py ``stage_ns``).
+#: Nested in ``tw.route`` (``engine.py``): ``sample`` (the no-drop
+#: paths' link draw), ``exchange``, ``sort`` (the eager and the lazy
+#: regime's one variadic sort by destination; the adaptive ladder's
+#: sorts are the stage's own) and ``insert``
 STAGES = ("tw.next_event", "tw.deliver", "tw.fire", "tw.rebase",
           "tw.route", "tw.finish")
 

@@ -1272,14 +1272,15 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         if lazy:
             ok = v_f & dst_ok
             sort_dst = jnp.where(ok, dst_f, n)
-            if W > 1:
-                opsL = jax.lax.sort(
-                    (sort_dst, woff, smrank) + pay_cols,
-                    dimension=0, num_keys=3)
-            else:
-                opsL = jax.lax.sort(
-                    (sort_dst, smrank) + pay_cols, dimension=0,
-                    num_keys=2)
+            with jax.named_scope("sort"):
+                if W > 1:
+                    opsL = jax.lax.sort(
+                        (sort_dst, woff, smrank) + pay_cols,
+                        dimension=0, num_keys=3)
+                else:
+                    opsL = jax.lax.sort(
+                        (sort_dst, smrank) + pay_cols, dimension=0,
+                        num_keys=2)
             opsL, route_drop_step = slice_cap(opsL, ok)
             if W > 1:
                 sd, woff_s, smrank_s = opsL[0], opsL[1], opsL[2]
@@ -1378,15 +1379,16 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
             #    sentinel (sd < n ⇔ ok) and the sender from the rank
             #    key (src = smrank // M).
             sort_dst = jnp.where(ok_r, row_r, n)  # invalid -> row n
-            if W > 1:
-                ops3 = jax.lax.sort(
-                    (sort_dst, woff_r, smrank_r, drel_r) + pay_r,
-                    dimension=0, num_keys=3)
-                ops3 = ops3[:1] + ops3[2:]  # drop woff; layout as below
-            else:
-                ops3 = jax.lax.sort(
-                    (sort_dst, smrank_r, drel_r) + pay_r,
-                    dimension=0, num_keys=2)
+            with jax.named_scope("sort"):
+                if W > 1:
+                    ops3 = jax.lax.sort(
+                        (sort_dst, woff_r, smrank_r, drel_r) + pay_r,
+                        dimension=0, num_keys=3)
+                    ops3 = ops3[:1] + ops3[2:]  # drop woff; layout below
+                else:
+                    ops3 = jax.lax.sort(
+                        (sort_dst, smrank_r, drel_r) + pay_r,
+                        dimension=0, num_keys=2)
             ops3, route_drop_step = slice_cap(ops3, ok_r)
             sd, drel_s = ops3[0], ops3[2]
             ok_s = sd < n
