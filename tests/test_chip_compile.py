@@ -119,3 +119,25 @@ def test_hole_select_compiles_for_v5e_without_a_sort(one_chip):
         assert "popcnt" in text
         assert " sort(" not in text
         assert compiled.memory_analysis().temp_size_in_bytes < K * n
+
+
+def test_fill_holes_compiles_for_v5e_without_an_index(one_chip):
+    """The node side of a commutative insertion (PR 32) at the
+    benchmark's wave shape, one word of holes and two, two planes: the
+    chip's compiler takes the row shifts inside a tile (1, 2 and 4
+    rows of the ``(8, 128)`` layout), and the program it makes holds
+    no gather, scatter or sort."""
+    from timewarp_tpu.ops.numeric import I32MAX, fill_holes, free_bits
+    n = 1 << 17
+
+    def fill(keep, rel, pay, mb_rel, mb_pay):
+        return fill_holes(free_bits(keep), [list(rel), list(pay)],
+                          [list(mb_rel), list(mb_pay)], I32MAX)
+
+    for K in (24, 40):
+        plane = _sds(one_chip, (K, n))
+        text = jax.jit(fill).lower(_sds(one_chip, (K, n), jnp.bool_),
+                                   plane, plane, plane, plane
+                                   ).compile().as_text()
+        for op in (" gather(", " scatter(", " sort("):
+            assert op not in text, (K, op)

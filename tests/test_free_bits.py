@@ -1,14 +1,17 @@
 """The law of the hole words: ``free_bits`` + ``nth_set_bit``
-(ops/numeric.py) against the sorted free-slot table they replaced.
+(ops/numeric.py) against the sorted free-slot table they replaced,
+and ``fill_holes`` (PR 32: the same decision taken a node at a time,
+on the node's own lanes) against a loop over every node's slots.
 
 Until PR 30 ``tw.rebase`` built, for a commutative inbox, the
 ascending list of every node's free rows with one ``[K, N]`` sort
 (``lax.sort(where(keep, K, slots), dimension=0)``), and
 ``_insert_sorted`` read ``free_rows[rank, dst]`` from it. That table
-lives on here as the plain reference (``free_rows_by_sort``, also what
-tests/test_insert_law.py replays a run against): the words and the bit
-select must give its entry for every column and every rank, the ranks
-past the free count (→ K) included.
+lives on here as the plain reference (``free_rows_by_sort``): the
+words and the bit select must give its entry for every column and
+every rank, the ranks past the free count (→ K) included. The bit
+select has no caller in the program since PR 32; it is the reference
+tests/test_insert_law.py holds the program's slots to.
 """
 
 import numpy as np
@@ -17,7 +20,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from timewarp_tpu.ops.numeric import free_bits, nth_set_bit
+from timewarp_tpu.ops.numeric import (I32MAX, fill_holes, free_bits,
+                                      nth_set_bit)
 
 #: one word, its edges (31, 32, 33), two words, four (127), five (130)
 KS = (1, 8, 24, 31, 32, 33, 64, 127, 130)
@@ -87,3 +91,69 @@ def test_ranks_far_past_the_word_give_none():
     rank = np.array([24, 31, 32, 33, 1000, 2**20, 2**31 - 1], np.int32)
     dst = np.zeros_like(rank)
     assert (rows_by_bit_select(keep, rank, dst) == 24).all()
+
+
+# ---------------------------------------------------------------------------
+# fill_holes: staged rows 0, 1, 2, … into each node's holes, in order
+# ---------------------------------------------------------------------------
+
+def fill_by_loop(keep, staged, old, nothing):
+    """Node by node, slot by slot: the ``h``-th hole takes row ``h``
+    of every staged plane if the key plane staged something there."""
+    K, n = keep.shape
+    out = [o.copy() for o in old]
+    for i in range(n):
+        h = 0
+        for k in range(K):
+            if keep[k, i]:
+                continue
+            if staged[0][h, i] != nothing:
+                for o, s in zip(out, staged):
+                    o[k, i] = s[h, i]
+            h += 1
+    return out
+
+
+@pytest.mark.parametrize("fill", ["all_free", "none_free", "random"])
+@pytest.mark.parametrize("K", KS, ids="K{}".format)
+def test_fill_holes_equals_the_loop(K, fill):
+    """Three planes (the key and two riders) over columns that stage
+    0 … K rows each: fewer than the holes (the holes past the staged
+    count keep what they held, on every plane), as many, and more (the
+    rows past the last hole go nowhere)."""
+    keep = _keep(fill, K, seed=2000 + K)
+    rng = np.random.default_rng(3000 + K)
+    count = rng.integers(0, K + 1, N)
+    count[:3] = (0, K, K // 2)
+    key = rng.integers(0, 10**6, (K, N)).astype(np.int32)
+    key[np.arange(K)[:, None] >= count[None, :]] = I32MAX
+    i32 = lambda: rng.integers(-2**31, 2**31, (K, N)).astype(np.int32)
+    staged, old = [key, i32(), i32()], [i32(), i32(), i32()]
+    got = jax.jit(lambda keep, staged, old: [jnp.stack(rows) for rows in fill_holes(
+        free_bits(keep), staged, old, I32MAX)])(keep, staged, old)
+    want = fill_by_loop(keep, staged, old, I32MAX)
+    for p, (g, w) in enumerate(zip(got, want)):
+        assert g.dtype == np.int32 and g.shape == (K, N)
+        assert np.array_equal(g, w), (
+            f"K={K} {fill} plane {p}: {np.argwhere(g != w)[:5].tolist()}")
+    moved = np.minimum(count, (~keep).sum(axis=0)).sum()
+    assert sum((g != o).sum() for g, o in zip(got, old)) <= 3 * moved
+    if fill == "none_free":
+        assert all(np.array_equal(g, o) for g, o in zip(got, old))
+    if fill == "all_free":
+        # no occupied slot below any hole: nothing moves a row
+        assert np.array_equal(got[0], np.where(key != I32MAX, key, old[0]))
+
+
+def test_fill_holes_lowers_without_an_index():
+    """Elementwise on the node's lanes: no gather, no scatter, no
+    sort, whatever the word count."""
+    for K in (24, 40):
+        keep = jax.ShapeDtypeStruct((K, N), bool)
+        plane = jax.ShapeDtypeStruct((K, N), np.int32)
+        text = jax.jit(lambda keep, a, b: fill_holes(
+            free_bits(keep), [list(a)], [list(b)], I32MAX)).lower(
+                keep, plane, plane).as_text()
+        for op in ("gather", "scatter", "sort", "dynamic_slice"):
+            assert f"stablehlo.{op}" not in text, (K, op)
+        assert "stablehlo.select" in text

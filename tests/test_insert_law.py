@@ -25,13 +25,19 @@ form (PR 29; they are at 193bc01), walked against the oracle instead:
 - n: 1024, and 1000 (a width that is no multiple of a lane or a tile).
 
 Since PR 30 a commutative inbox's holes are ``ceil(K/32)`` uint32
-words a node and the r-th hole is a bit select (ops/numeric.py;
+words a node, and since PR 32 its arrivals are staged by rank and
+every node fills its holes from them (ops/numeric.py ``fill_holes``;
 tests/test_free_bits.py is the primitive's own law). So the matrix
 has a third inbox, a wave of fanout 40 into mailboxes of two words,
-fitting (64 slots, 52 used) and not (40); and one run is replayed
-with the sorted free-slot table of the parent in the primitive's
-place, the two mailboxes compared *slot for slot*, which no observer
-could tell apart if they differed.
+fitting (64 slots, 52 used) and not (40). And the *slot* is held too,
+which no observer could tell apart if it differed: runs on the
+ladder, on the eager path and as a fleet are replayed by an engine
+that inserts the parent's way (the hole words gathered onto the
+message lanes, the rank-th set bit as the slot: a test-local copy),
+every leaf of the state compared bit for bit, the stale words in
+holes included; and one call of the insertion on lanes built to hold
+every case of the overflow (ranks past K at one node, fewer holes
+than arrivals, none) against the same copy.
 
 Then praos (``needs_key``, payload width 2, a lognormal link), the
 socket-state hub (1023 clients into one mailbox), a two-world faulted
@@ -60,12 +66,11 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from test_free_bits import free_rows_by_sort
 from timewarp_tpu.core.scenario import NEVER
 from timewarp_tpu.faults import (FaultFleet, FaultSchedule, NodeCrash,
                                  Partition)
 from timewarp_tpu.interp.jax_engine.batched import BatchSpec, world_slice
-from timewarp_tpu.interp.jax_engine.common import I32MAX
+from timewarp_tpu.interp.jax_engine.common import I32MAX, group_rank
 from timewarp_tpu.interp.jax_engine.engine import JaxEngine
 from timewarp_tpu.interp.ref.superstep import SuperstepOracle
 from timewarp_tpu.models.gossip import gossip
@@ -74,6 +79,7 @@ from timewarp_tpu.models.socket_state import socket_state
 from timewarp_tpu.models.token_ring import token_ring
 from timewarp_tpu.net.delays import (LogNormalDelay, Quantize,
                                      UniformDelay, WithDrop)
+from timewarp_tpu.ops.numeric import nth_set_bit
 from timewarp_tpu.trace.events import (assert_states_equal,
                                        assert_traces_equal)
 
@@ -251,58 +257,191 @@ def test_insertion_equals_oracle(site, inbox, mailbox, n):
 
 
 # ---------------------------------------------------------------------------
-# the slot itself: a run replayed with the parent's sorted table
+# the slot itself: the parent's program, slot for slot
 # ---------------------------------------------------------------------------
 
-def _table_in_place_of_the_words(monkeypatch):
-    """The parent's pair in the primitive's place, through the seam
-    ``_insert_sorted`` has (``nth_set_bit([w[dst] for w in holes], rank,
-    K)``): ``holes`` is the sorted ``[K, N]`` table of free rows, its
-    "words" are the table's rows, and the select is the table's entry
-    ``[rank, dst]``, K past the last row. Returns what counts the
-    lookups traced."""
-    from timewarp_tpu.interp.jax_engine import engine as engine_mod
-    traced = []
+def parent_insert_sorted(self, mb_rel, mb_src, mb_payload, sd, ok_s,
+                         drel_s, src_s, pay_s, holes, counts):
+    """``_insert_sorted``'s commutative branch as it stood at 5d73265
+    (PR 30's form): the destination's hole words by one 1D gather a
+    word on the message lanes, the rank-th set bit of them as the
+    slot, flat scatters into the mailbox's own planes. The plain
+    reference of what slot a message takes, and of what a hole that
+    gets nothing keeps."""
+    sc = self.scenario
+    K, P = sc.mailbox_cap, sc.payload_width
+    n = self.comm.n_local
+    rank = group_rank(sd)
+    sdc = jnp.clip(sd, 0, n - 1)
+    prow = nth_set_bit([w[sdc] for w in holes], rank, K)
+    fits = ok_s & (prow < K)
+    col = jnp.clip(prow, 0, K - 1)
+    flat = jnp.where(fits, col * jnp.int32(n) + sd, jnp.int32(K * n))
+    mb_rel = mb_rel.reshape(-1).at[flat].set(
+        drel_s, mode="drop").reshape(K, n)
+    if sc.inbox_src:
+        mb_src = mb_src.reshape(-1).at[flat].set(
+            src_s, mode="drop").reshape(K, n)
+    mb_payload = mb_payload.reshape(-1)
+    for p in range(P):
+        flat_p = jnp.where(
+            fits, (col * jnp.int32(P) + p) * jnp.int32(n) + sd,
+            jnp.int32(K * P * n))
+        mb_payload = mb_payload.at[flat_p].set(pay_s[p], mode="drop")
+    mb_payload = mb_payload.reshape(K, P, n)
+    overflow = jnp.sum(ok_s & ~fits, dtype=jnp.int32)
+    return mb_rel, mb_src, mb_payload, overflow
 
-    def lookup(rows, rank, none):
-        K = len(rows)
-        assert none == K
-        traced.append(K)
-        lane = jnp.arange(rank.shape[0])
-        return jnp.where(
-            rank < K, jnp.stack(rows)[jnp.clip(rank, 0, K - 1), lane], K)
 
-    monkeypatch.setattr(engine_mod, "free_bits",
-                        lambda keep: free_rows_by_sort(keep, jnp))
-    monkeypatch.setattr(engine_mod, "nth_set_bit", lookup)
-    return traced
+class ParentInsert(JaxEngine):
+    """The engine with the parent's insertion at all three call sites:
+    the eager and the lazy path call ``_insert_sorted``; a ladder rung
+    calls ``_stage_by_rank`` and the nodes ``_fill_staged`` after the
+    switch, so here the rung inserts into the mailbox ``_route_adaptive``
+    was handed and the fill passes that through."""
+    traced = 0
+
+    def _insert_sorted(self, *a):
+        type(self).traced += 1
+        return parent_insert_sorted(self, *a)
+
+    def _route_adaptive(self, out, out_valid, now_vec, t, mb_rel, mb_src,
+                        mb_payload, holes, counts, *a):
+        self._mailbox = (mb_rel, mb_src, mb_payload, holes, counts)
+        return super()._route_adaptive(out, out_valid, now_vec, t, mb_rel,
+                                       mb_src, mb_payload, holes, counts, *a)
+
+    def _stage_by_rank(self, *lanes):
+        return self._insert_sorted(*self._mailbox[:3], *lanes,
+                                   *self._mailbox[3:])
+
+    def _fill_staged(self, mb_rel, mb_src, mb_payload, holes, *inserted):
+        return inserted
 
 
-@pytest.mark.parametrize("K,make", [(24, _burst), (40, _wide_burst)],
-                         ids=["one-word", "two-words"])
-def test_every_slot_is_the_one_the_sorted_table_gave(K, make, monkeypatch):
+def _steady(n, K):
+    """One slot, ``window`` 1: ``_adaptive_regime()`` is false and the
+    eager path inserts at full width (the steady cell's program)."""
+    return gossip(n, fanout=1, think_us=1_000, gossip_interval=1_000,
+                  end_us=200_000, steady=True, mailbox_cap=K)
+
+
+_STEADY_LINK = Quantize(UniformDelay(1_000, 5_000), 1_000)
+
+#: id -> (scenario, link, engine keywords, the two horizons, whether
+#: ``overflow`` must be positive at the second)
+SLOT_CASES = {
+    "one-word": (lambda: _burst(1024, 24), _WAVE_LINK,
+                 {"window": "auto"}, (10, 6), False),
+    "two-words": (lambda: _wide_burst(1024, 40), _WAVE_LINK,
+                  {"window": "auto"}, (10, 6), True),
+    "n1000": (lambda: _burst(1000, 24), _WAVE_LINK,
+              {"window": "auto"}, (10, 6), False),
+    "holes-fewer-than-arrivals": (lambda: _burst(1024, 6), _WAVE_LINK,
+                                  {"window": "auto"}, (10, 6), True),
+    "fleet-of-three": (lambda: _burst(1024, 24), _WAVE_LINK,
+                       {"window": "auto",
+                        "batch": BatchSpec(seeds=(0, 4, 9))},
+                       (10, 6), False),
+    "eager": (lambda: _steady(1024, 24), _STEADY_LINK, {}, (24, 24),
+              False),
+    "eager-overflows": (lambda: _steady(1000, 3), _STEADY_LINK, {},
+                        (24, 24), True),
+}
+
+
+@pytest.mark.parametrize("case", list(SLOT_CASES))
+def test_every_slot_is_the_one_the_parent_gave(case):
     """Bit-equal, slot for slot: the raw mailbox arrays (holes' stale
     words included) and every other leaf of the state, at two
-    horizons, with overflow in the two-word case."""
-    sc, link = make(1024, K), _WAVE_LINK
-    eng = JaxEngine(sc, link, window="auto", lint="off")
+    horizons, against the engine that inserts the parent's way
+    (``ParentInsert``): on the ladder and on the eager path, with and
+    without overflow; and a fleet, which keeps the parent's form in
+    the program too (``_stages_by_rank``) and so is held to this
+    file's copy of it."""
+    make, link, kw, horizons, overflows = SLOT_CASES[case]
+    sc = make()
+    eng = JaxEngine(sc, link, lint="off", **kw)
+    assert eng._adaptive_regime() == ("window" in kw)
+    assert eng._stages_by_rank() == ("batch" not in kw)
     states = []
     st = eng.init_state()
-    for k in (10, 6):
+    for k in horizons:
         st, _ = eng.run(k, st)
         states.append(jax.device_get(st))
-    traced = _table_in_place_of_the_words(monkeypatch)
-    ref = JaxEngine(sc, link, window="auto", lint="off")
+    before = ParentInsert.traced
+    ref = ParentInsert(sc, link, lint="off", **kw)
     st = ref.init_state()
-    for want in states:
-        st, _ = ref.run(int(want.steps) - int(st.steps), st)
+    for k, want in zip(horizons, states):
+        st, _ = ref.run(k, st)
         got = jax.device_get(st)
         for name, a, b in zip(got._fields, got, want):
             for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
-                assert np.array_equal(x, y), (name, int(want.steps))
-    assert traced and set(traced) == {K}, "the table was never read"
-    assert (np.asarray(want.mb_rel) != I32MAX).sum() > 1024
-    assert (int(want.overflow) > 0) == (K == 40)
+                assert np.array_equal(x, y), (name, k)
+    assert ParentInsert.traced > before, "the parent's form never ran"
+    assert (np.asarray(want.mb_rel) != I32MAX).sum() > sc.n_nodes
+    assert (int(np.max(want.overflow)) > 0) == overflows
+
+
+# -- one call of the insertion, on lanes built for it ----------------------
+
+def _lanes(n, K, P, S, seed):
+    """A mailbox and one superstep's sorted arrivals with every case
+    of the overflow in it: node 1 gets ``K + 5`` arrivals (ranks past
+    K: no mailbox holds them), node 2 has two holes and five arrivals
+    (fewer holes than arrivals, more than none), node 3 no hole and
+    three arrivals, node 4 all holes and exactly K arrivals, node 5
+    holes and nothing; every other node 0-3 arrivals into a random
+    mailbox. ``S`` lanes, the invalid ones (row ``n``) last."""
+    rng = np.random.default_rng(seed)
+    keep = rng.random((K, n)) < rng.random((1, n))
+    keep[:, 1] = rng.random(K) < 0.5
+    keep[:, 2] = True
+    keep[rng.choice(K, 2, replace=False), 2] = False
+    keep[:, 3] = True
+    keep[:, 4] = False
+    keep[:, 5] = rng.random(K) < 0.5
+    arrivals = rng.integers(0, 4, n)
+    arrivals[1:6] = (K + 5, 5, 3, K, 0)
+    sd = np.repeat(np.arange(n), arrivals)[:S]
+    sd = np.concatenate([sd, np.full(S - len(sd), n)]).astype(np.int32)
+    mb_rel = np.where(keep, rng.integers(0, 10**6, (K, n)),
+                      I32MAX).astype(np.int32)
+    i32 = lambda *shape: rng.integers(-2**31, 2**31, shape).astype(np.int32)
+    return (mb_rel, i32(K, n), i32(K, P, n), sd, sd < n,
+            rng.integers(0, 10**6, S).astype(np.int32), i32(S),
+            tuple(i32(S) for _ in range(P)), keep)
+
+
+@pytest.mark.parametrize("inbox_src", [False, True], ids=["nosrc", "src"])
+@pytest.mark.parametrize("P", [1, 2], ids="P{}".format)
+@pytest.mark.parametrize("n", [1024, 1000], ids="n{}".format)
+@pytest.mark.parametrize("K", [24, 40], ids=["one-word", "two-words"])
+def test_one_insertion_equals_the_parents(K, n, P, inbox_src):
+    """``_insert_sorted`` on built lanes against the parent's form:
+    the three planes bit-equal and ``overflow`` the same number, which
+    here is known: the arrivals past each node's holes."""
+    import dataclasses
+    from timewarp_tpu.ops.numeric import free_bits
+    sc = dataclasses.replace(_burst(n, K), payload_width=P,
+                             inbox_src=inbox_src)
+    eng = JaxEngine(sc, _WAVE_LINK, window="auto", lint="off")
+    *lanes, keep = _lanes(n, K, P, 2 * n, seed=K + n + P)
+    sd = lanes[3]
+    arrivals = np.bincount(sd[sd < n], minlength=n)
+    lost = np.maximum(arrivals - (~keep).sum(axis=0), 0)
+    assert lost[1] >= 5 and lost[2] == 3 and lost[3] == 3 and lost[4] == 0
+
+    def both(*lanes):
+        holes = free_bits(jnp.asarray(keep))
+        return (eng._insert_sorted(*lanes, holes, None),
+                parent_insert_sorted(eng, *lanes, holes, None))
+    got, want = jax.jit(both)(*lanes)
+    for name, x, y in zip(("mb_rel", "mb_src", "mb_payload", "overflow"),
+                          got, want):
+        assert np.array_equal(x, y), name
+    assert int(got[3]) == lost.sum() > 0
+    assert (np.asarray(got[1]) == lanes[1]).all() == (not inbox_src)
 
 
 # ---------------------------------------------------------------------------

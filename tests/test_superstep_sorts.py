@@ -10,6 +10,14 @@ The last is gone: a node's holes are bit words and the r-th hole a bit
 select (ops/numeric.py, tests/test_free_bits.py). An ordered inbox
 never had it, and keeps its five (two ``[K, N]`` ones: the inbox's
 ordering and the mailbox's compaction).
+
+Since PR 32 a solo engine's commutative ``tw.route/insert`` reads
+nothing of the node side on the message lanes: its arrivals are staged
+by rank in buffers of their own (one scatter a field) and every node
+fills its holes from them elementwise (ops/numeric.py ``fill_holes``).
+The parent's one gather a hole word under that scope is gone, on the
+ladder and on the eager path; a fleet keeps it (``JaxEngine.
+_stages_by_rank`` says why); the sorts are what they were.
 """
 
 import re
@@ -77,3 +85,62 @@ def test_sorts_of_one_superstep(inbox, fleet):
     # the holes are there in its place: popcounts, and only where a
     # commutative inbox is
     assert ("stablehlo.popcnt" in text) == (inbox == "commutative")
+
+
+# ---------------------------------------------------------------------------
+# tw.route/insert: scatters into staging planes, and no gather
+# ---------------------------------------------------------------------------
+
+def _scopes_of(text: str, op: str):
+    """The ``jax.named_scope`` path of every ``stablehlo.<op>`` of a
+    module lowered with ``debug_info=True``: the name of the ``loc``
+    alias that ends the operation (a scatter's and a sort's follows
+    its region, whose own lines carry locations too)."""
+    names = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, flags=re.M))
+    tail = r"[^\n]*\(\{\n.*?\n\s*\}\) [^\n]*?" \
+        if op in ("scatter", "sort") else r"\b[^\n]*?"
+    return [names.get(alias, "") for alias in re.findall(
+        rf"stablehlo\.{op}\"?{tail}loc\((#loc\d+)\)$", text,
+        flags=re.S | re.M)]
+
+
+def _steady():
+    """One slot and ``window`` 1: the eager routing path, no ladder
+    (the steady cell's program)."""
+    sc = gossip(N, fanout=1, think_us=1_000, gossip_interval=1_000,
+                end_us=200_000, steady=True, mailbox_cap=K)
+    return sc, Quantize(UniformDelay(1_000, 5_000), 1_000), {}
+
+
+def _wave():
+    return _commutative() + ({"window": "auto"},)
+
+
+@pytest.mark.parametrize("fleet", [False, True], ids=["solo", "batch"])
+@pytest.mark.parametrize("path", ["ladder", "eager"])
+def test_a_solo_insert_gathers_nothing(path, fleet):
+    sc, link, kw = {"ladder": _wave, "eager": _steady}[path]()
+    eng = JaxEngine(sc, link, lint="off", **kw,
+                    batch=BatchSpec(seeds=(0, 1, 2)) if fleet else None)
+    assert eng._adaptive_regime() == (path == "ladder")
+    text = jax.jit(lambda st: eng._step_all(st, False)).lower(
+        eng.init_state()).as_text(debug_info=True)
+
+    def under_insert(op):
+        return [s for s in _scopes_of(text, op)
+                if "tw.route" in s and "/insert/" in s]
+    # one scatter a field (deliver time, one payload word; no
+    # ``inbox_src``), in every rung of the ladder: a solo engine's
+    # into staging buffers, with no gather beside them; a fleet's into
+    # the mailbox, behind the parent's one gather a hole word
+    rungs = len(eng._sender_rungs(N)) if path == "ladder" else 1
+    assert eng._stages_by_rank() == (not fleet)
+    assert len(under_insert("scatter")) == rungs * (1 + sc.payload_width)
+    assert len(under_insert("gather")) == (rungs if fleet else 0)
+    assert under_insert("sort") == []
+    assert len(under_insert("popcnt")) >= 1     # the holes are counted
+    # the ladder's own gathers (the rung's senders) are still found
+    # by the same reading, so "none" above is no blind spot
+    if path == "ladder":
+        assert any("tw.route" in s for s in _scopes_of(text, "gather"))
+    assert len(_sort_operands(text)) == (3 if path == "ladder" else 1)

@@ -45,7 +45,7 @@ import numpy as np
 from ...core.rng import fire_bits, msg_bits, seed_words
 from ...core.scenario import NEVER, Inbox, Outbox, Scenario
 from ...net.delays import LinkModel
-from ...ops.numeric import free_bits, nth_set_bit
+from ...ops.numeric import fill_holes, free_bits, nth_set_bit
 from ...trace.events import SuperstepTrace
 from ...trace.hashing import FIRED, RECV, SENT, mix32_jnp
 from .batched import BatchSpec, WorldIdentity, rebind_link
@@ -191,13 +191,22 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
     knob needs hand-tuning. Event semantics, arrival order (contract
     #3) and digests are identical to the eager path.
 
-    Mailbox insertion has one form, ``_insert_sorted``'s flat 1D
-    scatters, held to the oracle by tests/test_insert_law.py
-    (docs/engines.md "Mailbox insertion"). A commutative inbox's free
-    slots are bit words (``ceil(mailbox_cap / 32)`` uint32 a node,
-    built in ``tw.rebase``) and a message's slot the rank-th set bit
-    of its destination's words: no sort along the mailbox's slots
-    (tests/test_free_bits.py, tests/test_superstep_sorts.py).
+    Mailbox insertion has one form, ``_insert_sorted``, held to the
+    oracle by tests/test_insert_law.py (docs/engines.md "Mailbox
+    insertion"). A commutative inbox's free slots are bit words
+    (``ceil(mailbox_cap / 32)`` uint32 a node, built in
+    ``tw.rebase``). A solo engine stages its arrivals by their rank
+    at the destination in buffers of their own (one flat 1D scatter a
+    field: the message lanes read nothing of the node side), and
+    every node then moves its staged rows into its holes in ascending
+    order, elementwise on its own lanes (``fill_holes``): each message
+    ends in the slot the rank-th set bit of its destination's words
+    names, with no sort along the mailbox's slots and no gather
+    (tests/test_free_bits.py, tests/test_superstep_sorts.py). A fleet
+    finds that slot on the message lanes (the words gathered at the
+    destination, ``nth_set_bit``) and scatters into the mailbox, as
+    an ordered inbox does after its kept messages
+    (``_stages_by_rank`` says why).
     ``insert`` is a vestigial keyword: ``None`` and ``"xla"`` build
     the same engine, anything else is refused (ROADMAP D2').
 
@@ -659,21 +668,122 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
                            jnp.int64(_I32MAX - 1)).astype(jnp.int32)
         return flight, drel, bad, short, strag
 
+    def _stage_by_rank(self, sd, ok_s, drel_s, src_s, pay_s):
+        """A commutative inbox's insertion, the message lanes' half:
+        the r-th arrival at node d this superstep (``group_rank`` of
+        the sorted destinations) goes to ``r * n + d`` of a fresh flat
+        buffer of ``K * n`` words a field, by one 1D scatter each (the
+        2D [col, row] form costs ~7x on this chip, docs/engines.md
+        per-op cost table). Nothing of the node side is read here:
+        which slot the r-th arrival takes only the node needs to know
+        (``_fill_staged``). A deliver time's "nothing" is the hole's
+        own ``_I32MAX`` (a sampled one is clamped under it). Returns
+        ``(rel, src or None, payload words, over)``; ``over`` counts
+        the arrivals past the K-th at one node, which no mailbox can
+        hold. Lanes that do not fit get an out-of-range index and are
+        dropped."""
+        sc = self.scenario
+        K, P = sc.mailbox_cap, sc.payload_width
+        n = self.comm.n_local
+        rank = group_rank(sd)
+        fits = ok_s & (rank < K)
+        flat = jnp.where(fits, rank * jnp.int32(n) + sd,
+                         jnp.int32(K * n))
+
+        def stage(x, nothing):
+            return jnp.full((K * n,), nothing, x.dtype).at[flat].set(
+                x, mode="drop")
+        rel = stage(drel_s, _I32MAX)
+        # inbox_src=False skips this whole scatter — mailbox scatters
+        # ARE the dense random-delivery cost floor (docs/engines.md
+        # "Measured on a v5e"), so dropping an unread field is ~1/3
+        # of it
+        src = stage(src_s, 0) if sc.inbox_src else None
+        pay = tuple(stage(pay_s[p], 0) for p in range(P))
+        over = jnp.sum(ok_s & (rank >= K), dtype=jnp.int32)
+        return rel, src, pay, over
+
+    def _fill_staged(self, mb_rel, mb_src, mb_payload, holes, rel, src,
+                     pay, over):
+        """The node lanes' half: every node moves its staged rows 0,
+        1, 2, … into its holes in ascending order (ops/numeric.py
+        ``fill_holes``: elementwise, no index), so each message ends
+        in the slot the rank-th set bit of its destination's hole
+        words names. A hole that gets nothing keeps its ``_I32MAX``
+        and its stale source and payload words. A node's arrivals past
+        its holes are the rest of the overflow count (their ranks have
+        no gap, so the staged deliver times count them).
+
+        Every row is a 1D array of its own here, cut from the flat
+        view of its plane: a staged row is a contiguous piece of its
+        buffer, and a row raised by the fill is another row named, not
+        a shift along the sublanes of a tiled ``[K, n]`` array. Only
+        the result goes back to the tiled form (``reshape``: the one
+        relayout a flat scatter into the mailbox paid too)."""
+        sc = self.scenario
+        K, P = sc.mailbox_cap, sc.payload_width
+        n = self.comm.n_local
+
+        def rows(flat, stride=1, first=0):
+            return [flat[(k * stride + first) * n:
+                         (k * stride + first + 1) * n] for k in range(K)]
+        staged, old = [rows(rel)], [rows(mb_rel.reshape(-1))]
+        if sc.inbox_src:
+            staged.append(rows(src))
+            old.append(rows(mb_src.reshape(-1)))
+        staged += [rows(w) for w in pay]
+        old += [rows(mb_payload.reshape(-1), P, p) for p in range(P)]
+        new = fill_holes(holes, staged, old, _I32MAX)
+        if sc.inbox_src:
+            mb_src = jnp.concatenate(new[1]).reshape(K, n)
+        words = new[len(new) - P:]
+        mb_payload = jnp.concatenate(
+            [words[p][k] for k in range(K) for p in range(P)]
+        ).reshape(K, P, n)
+        arrived = sum((r != _I32MAX).astype(jnp.int32)
+                      for r in staged[0])
+        free = sum(jax.lax.population_count(w).astype(jnp.int32)
+                   for w in holes)
+        overflow = over + jnp.sum(jnp.maximum(arrived - free, 0),
+                                  dtype=jnp.int32)
+        return (jnp.concatenate(new[0]).reshape(K, n), mb_src,
+                mb_payload, overflow)
+
+    def _stages_by_rank(self) -> bool:
+        """Whether insertion takes the staged form (``_stage_by_rank``
+        + ``_fill_staged``): a commutative inbox of a solo engine. A
+        fleet keeps the form that asks the node side from the message
+        lanes: under its world axis a row of the fill is a ``[B, n]``
+        plane, and the flat views the staged form lives on are
+        transpositions of the tiled ``[B, K, n]`` mailbox (on the
+        chip a fleet's iteration took 15.0 ms staged against 10.1:
+        PERF.md, Findings PR 32)."""
+        return self.scenario.commutative_inbox and self.batch is None
+
     @jax.named_scope("insert")
     def _insert_sorted(self, mb_rel, mb_src, mb_payload, sd, ok_s,
                        drel_s, src_s, pay_s, holes, counts):
-        """Shared mailbox insertion for destination-sorted messages:
-        per-destination rank -> target slot (r-th hole for commutative
-        inboxes: the r-th set bit of the destination's ``holes`` words,
-        ops/numeric.py ``nth_set_bit``; append-after-kept otherwise)
-        -> flat 1D scatters (the 2D [col, row] form costs ~7x on this
-        chip, docs/engines.md per-op cost table). Non-fitting lanes get an out-of-range
-        index and are dropped; returns the updated arrays plus the
-        local overflow count. Held to the oracle by
+        """Shared mailbox insertion for destination-sorted messages.
+        A solo engine's commutative inbox stages its arrivals by rank
+        in buffers of their own and lets every node fill its holes
+        from them (``_stage_by_rank``, ``_fill_staged``: the message
+        lanes read nothing of the node side). Otherwise:
+        per-destination rank -> target slot (a fleet's commutative
+        inbox: the r-th hole, the r-th set bit of the destination's
+        ``holes`` words, ops/numeric.py ``nth_set_bit``; an ordered
+        inbox: append-after-kept) -> flat 1D scatters into the
+        mailbox; non-fitting lanes get an out-of-range index and are
+        dropped. Returns the updated arrays plus the local overflow
+        count. Both commutative forms put every message in the same
+        slot; held to the oracle, and to each other, by
         tests/test_insert_law.py."""
         sc = self.scenario
         K, P = sc.mailbox_cap, sc.payload_width
         n = self.comm.n_local
+        if self._stages_by_rank():
+            return self._fill_staged(
+                mb_rel, mb_src, mb_payload, holes,
+                *self._stage_by_rank(sd, ok_s, drel_s, src_s, pay_s))
         rank = group_rank(sd)
         if sc.commutative_inbox:
             # r-th incoming message takes the destination's r-th hole:
@@ -693,10 +803,6 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         mb_rel = mb_rel.reshape(-1).at[flat].set(
             drel_s, mode="drop").reshape(K, n)
         if sc.inbox_src:
-            # inbox_src=False skips this whole scatter — mailbox
-            # scatters ARE the dense random-delivery cost floor
-            # (docs/engines.md "Measured on a v5e"), so dropping an unread field is ~1/3
-            # of it
             mb_src = mb_src.reshape(-1).at[flat].set(
                 src_s, mode="drop").reshape(K, n)
         mb_payload = mb_payload.reshape(-1)
@@ -757,6 +863,29 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         # precomputed int32 in-window offsets: the branches gather one
         # int32 word per sender instead of an int64
         woff_n = (now_vec - t).astype(jnp.int32)                # [N]
+
+        staged = self._stages_by_rank()
+
+        def insert(sd, ok_s, drel_s, src_s, pay_s):
+            # a rung's part of insertion. In the staged form a rung
+            # stages its arrivals only, and the nodes fill their holes
+            # once, after the switch (`filled`): no [K, N] mailbox
+            # plane crosses the conditional, which moves its operands.
+            # The other forms scatter into the mailbox inside the rung
+            if not staged:
+                return self._insert_sorted(
+                    mb_rel, mb_src, mb_payload, sd, ok_s, drel_s,
+                    src_s, pay_s, holes, counts)
+            with jax.named_scope("insert"):
+                return self._stage_by_rank(sd, ok_s, drel_s, src_s,
+                                           pay_s)
+
+        def filled(ret):
+            if not staged:
+                return ret
+            with jax.named_scope("insert"):
+                return self._fill_staged(mb_rel, mb_src, mb_payload,
+                                         holes, *ret[:4]) + ret[4:]
 
         def tail(A):
             def gather(A):
@@ -824,9 +953,8 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
                     pay_s = ops[3:]
                 ok_s = sd < n
                 src_s = smrank_s // jnp.int32(M)
-                mrel, msrc, mpay, overflow_step = self._insert_sorted(
-                    mb_rel, mb_src, mb_payload, sd, ok_s, drel_s,
-                    src_s, pay_s, holes, counts)
+                mrel, msrc, mpay, overflow_step = insert(
+                    sd, ok_s, drel_s, src_s, pay_s)
                 ret = (mrel, msrc, mpay, overflow_step, bad_dst_step,
                        bad_delay_step, short_step, jnp.int32(0),
                        sent_count, sent_hash, fault_cut + fault_down)
@@ -872,9 +1000,8 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
                     self._sample_nodrop(src_s, sd, tmsg_s,
                                         smrank_s % jnp.int32(M),
                                         woff_s, ok_s)
-                mrel, msrc, mpay, overflow_step = self._insert_sorted(
-                    mb_rel, mb_src, mb_payload, sd, ok_s, drel_s,
-                    src_s, pay_s, holes, counts)
+                mrel, msrc, mpay, overflow_step = insert(
+                    sd, ok_s, drel_s, src_s, pay_s)
                 sent_count = jnp.sum(ok, dtype=jnp.int32)
                 if with_trace:
                     dt_abs = tmsg_s + flight_s
@@ -903,7 +1030,7 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         if len(rungs) == 1:
             if self.telemetry != "off":
                 self._t_rung = jnp.int32(rungs[-1])
-            return tail(rungs[-1])()
+            return filled(tail(rungs[-1])())
         if self.batch is not None:
             # a fleet takes ONE rung for all its worlds: the smallest
             # that holds the busiest world's senders. The pmax over
@@ -930,7 +1057,7 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
             # ``rung_lanes``, _vstep) can never drift from it
             self._t_rung = self._fleet_rung = \
                 jnp.asarray(rungs, jnp.int32)[idx]
-        return jax.lax.switch(idx, [tail(A) for A in rungs])
+        return filled(jax.lax.switch(idx, [tail(A) for A in rungs]))
 
     def _superstep(self, st: EngineState, with_trace: bool
                    ) -> Tuple[EngineState, Optional[_StepOut]]:
@@ -1148,9 +1275,9 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         #    - commutative inbox: slot order is unobservable, so freed
         #      slots become *holes* (elementwise — no [K, N] compaction
         #      sort, and no sort of the free rows either: a node's
-        #      holes are ceil(K/32) uint32 words, and insertion finds
-        #      the r-th hole by bit select on the routing rung's
-        #      lanes). Overflow semantics are bit-identical:
+        #      holes are ceil(K/32) uint32 words, and insertion lets
+        #      the node fill them from its arrivals staged by rank,
+        #      `_fill_staged`). Overflow semantics are bit-identical:
         #      rank >= #free ⇔ counts + rank >= K.
         #    - ordered inbox: the variadic compaction sort keeps arrival
         #      order materialized in slot order (contract #2's tiebreak).
@@ -1161,9 +1288,11 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
             mb_rel = jnp.where(keep, st.mb_rel - shift32, _I32MAX)
             mb_src = st.mb_src          # stale in holes; validity is the
             mb_payload = st.mb_payload  # rel sentinel, never these
-            #: holes[k // 32, i] bit k % 32 = row k of node i is free;
-            #: 4 bytes a node (K <= 32) as a routing-switch operand
-            #: (TPU conditionals move their operands)
+            #: holes[k // 32, i] bit k % 32 = row k of node i is free:
+            #: 4 bytes a node (K <= 32), read on the node's own lanes
+            #: after the routing switch (`_route_adaptive` `filled`;
+            #: a fleet's: a routing-switch operand, and TPU
+            #: conditionals move their operands)
             holes = free_bits(keep)
             counts = None
         else:

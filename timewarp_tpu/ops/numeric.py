@@ -14,8 +14,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["I32MAX", "group_rank", "free_bits", "nth_set_bit", "u32sum",
-           "tlo", "thi"]
+__all__ = ["I32MAX", "group_rank", "free_bits", "nth_set_bit",
+           "fill_holes", "u32sum", "tlo", "thi"]
 
 I32MAX = np.int32(2**31 - 1)
 
@@ -63,7 +63,9 @@ def nth_set_bit(words, rank: jax.Array, none: int) -> jax.Array:
     shape. A rank over a mask needs no sort: the word is found by
     running popcounts, the bit by five popcount halvings — elementwise
     throughout (tests/test_free_bits.py holds it to the sorted table it
-    replaced)."""
+    replaced). The engine decides the same a node at a time since
+    PR 32 (:func:`fill_holes`); this form, a message at a time, is
+    what tests/test_insert_law.py holds that one's slots to."""
     rank = rank.astype(jnp.int32)
     word = jnp.zeros_like(words[0])
     pos = jnp.full(rank.shape, none, jnp.int32)
@@ -85,6 +87,95 @@ def nth_set_bit(words, rank: jax.Array, none: int) -> jax.Array:
         rank = jnp.where(up, rank - c, rank)
         pos = jnp.where(up, pos + jnp.int32(width), pos)
     return pos
+
+
+def _shift_up(words, s: int):
+    """The bit set ``words`` (a list of uint32 lanes, word ``j`` holds
+    positions ``32 j … 32 j + 31``) with every position raised by the
+    static ``s``; what passes the last word is lost."""
+    q, r = divmod(s, 32)
+    out = []
+    for j in range(len(words)):
+        v = jnp.zeros_like(words[0])
+        if j - q >= 0:
+            v = words[j - q] << jnp.uint32(r)
+        if r and j - q - 1 >= 0:
+            v = v | (words[j - q - 1] >> jnp.uint32(32 - r))
+        out.append(v)
+    return out
+
+
+def _shift_down(words, s: int):
+    """:func:`_shift_up`'s inverse: every position lowered by ``s``."""
+    q, r = divmod(s, 32)
+    nw = len(words)
+    out = []
+    for j in range(nw):
+        v = jnp.zeros_like(words[0])
+        if j + q < nw:
+            v = words[j + q] >> jnp.uint32(r)
+        if r and j + q + 1 < nw:
+            v = v | (words[j + q + 1] << jnp.uint32(32 - r))
+        out.append(v)
+    return out
+
+
+def _bit(words, k: int) -> jax.Array:
+    """Whether position ``k`` of the bit set ``words`` is set."""
+    return ((words[k // 32] >> jnp.uint32(k % 32)) & jnp.uint32(1)) != 0
+
+
+def fill_holes(words, staged, old, nothing):
+    """Move each lane's staged rows 0, 1, 2, … into its holes in
+    ascending order. ``staged`` and ``old`` are sequences of planes, a
+    plane a sequence of K rows (arrays of the words' shape). For every
+    plane ``p``, row ``k`` of the result is ``staged[p][h]`` where row
+    ``k`` is a hole (bit ``k % 32`` of ``words[k // 32]``, as
+    ``free_bits`` writes them), ``h`` holes lie below it and row ``h``
+    was staged (``staged[0][h] != nothing``: plane 0 is the key);
+    ``old[p][k]`` everywhere else. Returns the planes as lists of rows.
+
+    This is what ``nth_set_bit`` decides a message at a time, decided
+    a node at a time and with no index: the *expand* network of
+    Hacker's Delight 7-5 with rows in the place of bits. The move
+    masks come from the hole words by parallel-suffix steps on uint32
+    lanes (``ceil(K/32)`` words as one long bit set); stage ``j`` then
+    raises by ``2^j`` the rows its mask names, the largest ``j``
+    first, so a row travels the distance "occupied slots below my
+    hole" one binary digit at a time and no two rows ever meet:
+    ``bit_length(K - 1)`` selects a slot, not K. A row is an array of
+    its own, so raising one is naming another: nothing moves along an
+    array's axis (tests/test_free_bits.py holds it to a loop)."""
+    K = len(staged[0])
+    words = list(words)
+    # the masks, least shift first (compress order): `mk` marks the
+    # positions with an odd count of occupied slots below, of those
+    # still to be halved
+    m = words
+    mk = _shift_up([~w for w in words], 1)
+    moves = []
+    for i in range((K - 1).bit_length()):
+        mp = mk
+        s = 1
+        while s < K:
+            mp = [a ^ b for a, b in zip(mp, _shift_up(mp, s))]
+            s *= 2
+        mv = [a & b for a, b in zip(mp, m)]
+        moves.append(mv)
+        m = [(a ^ b) | c for a, b, c in
+             zip(m, mv, _shift_down(mv, 1 << i))]
+        mk = [a & ~b for a, b in zip(mk, mp)]
+    planes = [list(rows) for rows in staged]
+    for i in reversed(range(len(moves))):
+        s = 1 << i
+        # no mask names a row under its own shift: rows < s keep
+        up = [_bit(moves[i], k) for k in range(s, K)]
+        planes = [rows[:s] + [jnp.where(u, rows[k - s], rows[k])
+                              for k, u in zip(range(s, K), up)]
+                  for rows in planes]
+    got = [_bit(words, k) & (planes[0][k] != nothing) for k in range(K)]
+    return [[jnp.where(g, x, o) for g, x, o in zip(got, rows, olds)]
+            for rows, olds in zip(planes, old)]
 
 
 def u32sum(x: jax.Array) -> jax.Array:
