@@ -975,6 +975,8 @@ def bench_praos_1m_b4(n, steps):
         sc, spec.world_link(link, b), seed=spec.seeds[b],
         window=engine.window))
     delivered, dt, fin = _measure(engine, steps or 256, warm_steps=16)
+    assert int(np.asarray(jax.device_get(fin.overflow)).sum()) == 0, \
+        "a mailbox overflowed: tips lost"
     assert int(np.asarray(jax.device_get(fin.short_delay)).sum()) == 0, \
         "windowed run left the exact regime"
     assert int(np.asarray(jax.device_get(fin.route_drop)).sum()) == 0, \
@@ -1023,12 +1025,18 @@ def _praos_consensus(n):
     window — adoption instants spread by lognormal delays batch 8
     grid instants per superstep (exact — engine.py JaxEngine.window).
     The 150 ms delay cap bounds the straggler tail (a 60 s praos
-    relay is not a network, it is an outage)."""
+    relay is not a network, it is an outage).
+
+    ``mailbox_cap`` 24, not the 16 this row had until PR 33: at 2^20
+    some 20 tips are in flight to one node at the height of a slot's
+    flood, and 16 slots dropped the rest, silently (PERF.md, Findings
+    PR 33). The benchmark's cell ``praos_1m.slots`` is this row, two
+    slots a job, with the plain reference beside it."""
     from timewarp_tpu.models.praos import praos
     from timewarp_tpu.net.delays import LogNormalDelay, Quantize
     sc = praos(n, slot_us=1_000_000, n_slots=1 << 30,
                leader_prob=4.0 / n, fanout=8, burst=True,
-               mailbox_cap=16)
+               mailbox_cap=24)
     link = Quantize(LogNormalDelay(20_000, 0.6, cap_us=150_000,
                                    floor_us=8_000), 1_000)
     return sc, link
@@ -1043,6 +1051,7 @@ def bench_praos_1m(n, steps):
     # hand-measured capacity constants
     engine = JaxEngine(sc, link, window="auto")
     delivered, dt, fin = _measure(engine, steps or 256, warm_steps=16)
+    assert int(fin.overflow) == 0, "a mailbox overflowed: tips lost"
     assert int(fin.short_delay) == 0, "windowed run left the exact regime"
     # invariant, not a tuning-knob guard (see bench_gossip_100k)
     assert int(fin.route_drop) == 0, "adaptive routing dropped messages"

@@ -30,7 +30,11 @@ __all__ = ["LocalComm", "StepOut", "I32MAX", "group_rank", "u32sum",
 #: Nested in ``tw.route`` (``engine.py``): ``sample`` (the no-drop
 #: paths' link draw), ``exchange``, ``sort`` (the eager and the lazy
 #: regime's one variadic sort by destination; the adaptive ladder's
-#: sorts are the stage's own) and ``insert``
+#: sorts are the stage's own) and ``insert``; nested in ``tw.fire``:
+#: ``entropy`` (``fire_bits`` over every lane, in the programs of
+#: scenarios with ``needs_key`` and in no other; the v5e's compiler
+#: fuses it into the step's fusion, so it is in the lowered text and
+#: in no profile)
 STAGES = ("tw.next_event", "tw.deliver", "tw.fire", "tw.rebase",
           "tw.route", "tw.finish")
 

@@ -1238,8 +1238,15 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         # (core/rng.py), keyed by the node's own firing instant — the
         # same bits a window=1 run derives for that (node, time) firing.
         # Batch axis is the *minor* dim for inbox and outbox leaves.
-        bits = fire_bits(self.s0, self.s1, node_ids, now_vec) \
-            if sc.needs_key else None
+        # The derivation has a scope of its own (`tw.fire/entropy`): a
+        # Threefry over all N lanes on every superstep of a scenario
+        # that asks for it, and of no other. A name and nothing else:
+        # the v5e's compiler fuses all of it into the step's fusion, so
+        # a profile shows its time under the step's name (PERF.md, PR 33)
+        bits = None
+        if sc.needs_key:
+            with jax.named_scope("entropy"):
+                bits = fire_bits(self.s0, self.s1, node_ids, now_vec)
         stepf = sc.step
         if self._faulted and self._has_skew:
             # the node's VIEW of time shifts; entropy keys, digests
