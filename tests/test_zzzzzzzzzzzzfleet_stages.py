@@ -86,7 +86,13 @@ def test_a_fleet_carries_every_stage_the_solo_driver_does(fleet):
     # with the wrapper off a fleet reads stage for stage like the solo
     unwrapped = {fleet_reduce.unwrap(n) for n in names}
     assert {span_reduce.stage_of(n) for n in unwrapped} == solo
-    assert any("/cond/tw.next_event" in n for n in unwrapped)
+    # the next event is found once before the loop and at the end of
+    # each iteration, never in the condition (ISSUE 34)
+    assert any(n.startswith("jit(_run_while)/tw.next_event")
+               for n in unwrapped)
+    assert any("/body/tw.next_event" in n for n in unwrapped)
+    assert not [n for n in unwrapped
+                if "/cond/" in n and "tw.next_event" in n]
     nested = {span_reduce.stage_of(n, 2) for n in unwrapped}
     assert {"tw.route/insert", "tw.route/sample"} <= nested
 

@@ -133,17 +133,17 @@ def test_the_adaptive_drivers_have_no_sort_scope(kw):
 
 
 #: sha256 of the quiet driver's lowering (``as_text()``: no names, no
-#: locations) at 2^11 nodes. The fleet's is as commit 3408884 (PR 30)
-#: lowers it: the scope of PR 31 is metadata and sits in the eager
-#: branch alone, and PR 32 left a fleet PR 30's insertion
-#: (``_stages_by_rank``), so the fleet's program is still that
-#: parent's. The solo wave's changed in PR 32 (insertion staged by
-#: rank, the holes filled after the ladder's switch; until then
-#: b5fdea788dbb…): the constant is that PR's. A PR that changes what
-#: these drivers compute changes the constants, and says so.
+#: locations) at 2^11 nodes, as PR 34 lowers it: the ``while`` carries
+#: the state's event horizon beside it, its condition reads scalars,
+#: a solo body selects nothing by liveness and a fleet's selects each
+#: world's state once (``tests/test_loop_edge.py`` holds the shape and
+#: the exactness). Until then the solo wave's was PR 32's
+#: (820775e61c25…) and the fleet's PR 30's (5553f5c6c1b5…). A PR that
+#: changes what these drivers compute changes the constants, and says
+#: so.
 _PARENT_LOWERING = {
-    "solo": "820775e61c255bea26abb3b5a7588d5fe6316f6e1802e3a39be70880c714a7c2",
-    "fleet": "5553f5c6c1b55cbc0a5ba51422f5376265fcd84b7dfcbce819b33996026bb0ca",
+    "solo": "e0e9875a2751e65ba6f060e8a8072744ee3ae9251f304fa272c272d890d99f48",
+    "fleet": "8d44f07ef6ed793ad11e7557cb80acbe9d2d47dd3277b90bc43d349378ec8d24",
 }
 
 

@@ -208,15 +208,17 @@ def test_a_scenario_without_a_key_has_no_entropy_scope(make):
 
 
 #: sha256 of the quiet driver's lowering (``as_text()``: no names, no
-#: locations) at 2^11 nodes, as commit 5f05997 (PR 32) lowers it: the
-#: scope of PR 33 is metadata, so the praos program's text is the
-#: parent's too. The wave's and the fleet's are pinned in
-#: ``test_zzzzzzzzzzzzzsteady_mongering.py``; steady mongering's and
-#: praos' are pinned here first. A PR that changes what these drivers
-#: compute changes the constants, and says so.
+#: locations) at 2^11 nodes, as PR 34 lowers it (the loop carries its
+#: successor's event horizon and a solo body selects nothing by
+#: liveness: ``tests/test_loop_edge.py``). Until then they were
+#: commit 5f05997's (PR 32; steady ac6fac01cfaa…, praos df24e3874d17…:
+#: the scope of PR 33 was metadata). The wave's and the fleet's are
+#: pinned in ``test_zzzzzzzzzzzzzsteady_mongering.py``. A PR that
+#: changes what these drivers compute changes the constants, and says
+#: so.
 _PARENT_LOWERING = {
-    "steady": "ac6fac01cfaa7c31b64e35946b240daec27978ac883d9d8bc6ec4c05d10f3f8d",
-    "praos": "df24e3874d17b4d6352481837ebcaf2ff9fcaf69f33f3573bb6048ea4ed23a4f",
+    "steady": "80aa2481fbb3e3d5d62277da62caa49275e7b72afe2eaa0cf553d2be30098d9d",
+    "praos": "07f9f26529613c3856e3bb30907e7cda1f35eae3f7ab1b569ebd47d70aa6cbd6",
 }
 
 

@@ -33,7 +33,7 @@ and trace digests exist only in the traced driver (``run``) — the
 
 from __future__ import annotations
 
-from functools import partial
+from functools import partial, reduce
 from typing import Any, NamedTuple, Optional, Tuple
 
 from ...utils import jaxconfig  # noqa: F401  (must precede jax use)
@@ -59,7 +59,7 @@ from ...integrity.runner import VerifiedRunMixin
 from ...obs.flight import FlightRecorderMixin
 from ...speculate.runner import SpeculativeRunMixin
 
-__all__ = ["JaxEngine", "EngineState", "BatchSpec"]
+__all__ = ["JaxEngine", "EngineState", "Horizon", "BatchSpec"]
 
 #: the name of the fleet's ``vmap`` axis (``_vstep``): what a world
 #: reduces over when all worlds must agree (``_route_adaptive``'s
@@ -122,6 +122,23 @@ class EngineState(NamedTuple):
     #: consumed (faults/apply.py module docstring: the one piece of
     #: state fault masks need)
     restart_done: jax.Array
+
+
+class Horizon(NamedTuple):
+    """A state's event horizon: when its next superstep fires, and
+    which nodes may. A function of the state alone
+    (``JaxEngine._horizon``), so it is no field of it: the quiet
+    driver's loop carries it beside the state, each superstep
+    producing its successor's from what it has just written, so that
+    the loop's condition reads ``t`` and the body starts from
+    ``node_next`` with nothing of the mailbox's rank reduced at the
+    loop's edge (docs/engines.md "The quiet loop")."""
+    t: jax.Array          # int64[] — global next event time; NEVER = quiet
+    #: int64[N] — each node's next event (its wake or its earliest
+    #: message; under a fault schedule slid past its down window, and
+    #: a pending restart's ``t_up``); ``t`` is its minimum over all
+    #: devices
+    node_next: jax.Array
 
 
 class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
@@ -746,6 +763,10 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
                    for w in holes)
         overflow = over + jnp.sum(jnp.maximum(arrived - free, 0),
                                   dtype=jnp.int32)
+        # the new deliver times row by row, for the successor's
+        # horizon (`_superstep_carried`): never inside a conditional,
+        # the fill runs after the ladder's switch
+        self._rel_rows = new[0]
         return (jnp.concatenate(new[0]).reshape(K, n), mb_src,
                 mb_payload, overflow)
 
@@ -1059,15 +1080,84 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
                 jnp.asarray(rungs, jnp.int32)[idx]
         return filled(jax.lax.switch(idx, [tail(A) for A in rungs]))
 
+    def _node_next(self, st: EngineState, nnr=None) -> jax.Array:
+        """Each node's next event time as the state alone has it
+        (int64[N]; the batched "pop min", TimedT.hs:241-245, before
+        the minimum): its wake, or its earliest message. ``nnr`` is
+        the per-node minimum of ``st.mb_rel`` where the caller holds
+        it already (the staged insertion's rows), else one pass over
+        the mailbox finds it."""
+        if nnr is None:
+            nnr = st.mb_rel.min(axis=0)
+        return jnp.minimum(
+            st.wake,
+            jnp.where(nnr == _I32MAX, jnp.int64(NEVER),
+                      st.time + nnr.astype(jnp.int64)))
+
+    def _horizon(self, st: EngineState, nnr=None) -> Horizon:
+        """The state's :class:`Horizon`. Under a fault schedule
+        events inside a down window slide to its ``t_up`` and
+        unconsumed reset rows inject the restart firing
+        (faults/apply.py ``defer_next``), so the deferral is part of
+        the horizon wherever it is computed. Integers and ``min``:
+        the same values from a state whoever asks, so a superstep
+        that takes the horizon it was handed is the superstep that
+        finds it again, bit for bit."""
+        node_next = self._node_next(st, nnr)
+        if self._faulted:
+            from ...faults.apply import defer_next
+            node_next = defer_next(self._ft, self.comm.node_ids(),
+                                   node_next, st.restart_done)
+        return Horizon(self.comm.all_min(node_next.min()), node_next)
+
     def _superstep(self, st: EngineState, with_trace: bool
                    ) -> Tuple[EngineState, Optional[_StepOut]]:
+        """One superstep of a state on its own: finds the state's
+        horizon, and returns the state unchanged once nothing is
+        pending (``live``). What the scan driver, the chunked drivers
+        and every caller outside the quiet loop step with."""
         with Stages() as stage:
-            return self._staged_superstep(st, with_trace, stage)
+            stage("tw.next_event")
+            hz = self._horizon(st)
+            return self._staged_superstep(st, hz, hz.t < NEVER,
+                                          with_trace, stage)
 
-    def _staged_superstep(self, st, with_trace, stage):
-        """One superstep, each numbered part under the scope ``stage``
-        names for it (common.py ``STAGES``)."""
-        stage("tw.next_event")
+    def _superstep_carried(self, st: EngineState, hz: Horizon,
+                           in_budget=None
+                           ) -> Tuple[EngineState, Horizon]:
+        """The quiet loop's superstep: ``(state, horizon) -> (state',
+        horizon')``. ``hz`` is ``st``'s horizon, found by the
+        superstep before (``_quiet_loop``: or by the one scan before
+        the loop). A solo loop's condition has decided on ``hz.t``
+        itself that this superstep runs, so nothing is selected by
+        liveness here (``in_budget`` None). A fleet's world steps
+        while it is live and ``in_budget`` (its own budget, a traced
+        bool): one select of its state by both.
+
+        ``horizon'`` comes from what insertion has just written. In
+        the staged form the new ``mb_rel`` is K rows before they are
+        concatenated (``_fill_staged``), and the per-node minimum is
+        an elementwise ``min`` of them; in the other forms it is one
+        pass over the new ``mb_rel`` (a frozen world's: over the old,
+        which gives its old horizon again)."""
+        with Stages() as stage:
+            stage("tw.next_event")
+            act = None if in_budget is None else \
+                (hz.t < NEVER) & in_budget
+            new, _ = self._staged_superstep(st, hz, act, False, stage)
+            stage("tw.next_event")
+            nnr = None
+            if act is None and self._rel_rows is not None:
+                nnr = reduce(jnp.minimum, self._rel_rows)
+            return new, self._horizon(new, nnr)
+
+    def _staged_superstep(self, st, hz, live, with_trace, stage):
+        """One superstep of ``st`` from its horizon ``hz``, each
+        numbered part under the scope ``stage`` names for it
+        (common.py ``STAGES``); the caller has opened
+        ``tw.next_event``. ``live`` is whether the superstep applies
+        (a traced bool: the result is ``st`` where it is false), or
+        None where the caller's loop has decided that already."""
         sc, comm = self.scenario, self.comm
         K, M, P = sc.mailbox_cap, sc.max_out, sc.payload_width
         n = comm.n_local            # array width on this device
@@ -1081,41 +1171,33 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         #: quiet driver (with_trace=False) emits no rows, so nothing
         #: is captured there (run_quiet is record-free by contract).
         self._rec_extra = []
+        #: the new ``mb_rel`` as K rows, where insertion made it so
+        #: (``_fill_staged``): what ``_superstep_carried`` takes the
+        #: successor's horizon from; reset per trace the same way
+        self._rel_rows = None
         rec_full = with_trace and self.record == "full"
 
         # validity is the rel sentinel (I32MAX = empty slot)
         mb_live = st.mb_rel < _I32MAX                           # [K, N]
         W = self.window
 
-        # 1. global next event time (the batched "pop min", TimedT.hs:241-245)
-        nnr = st.mb_rel.min(axis=0)
-        node_next = jnp.minimum(
-            st.wake,
-            jnp.where(nnr == _I32MAX, jnp.int64(NEVER),
-                      base + nnr.astype(jnp.int64)))
-        if self._faulted:
-            # crash suppression: events inside a down window slide to
-            # its t_up, and unconsumed reset rows inject the restart
-            # firing (faults/apply.py)
-            from ...faults.apply import defer_next
-            node_next_pre = node_next
-            node_next = defer_next(self._ft, node_ids, node_next,
-                                   st.restart_done)
-            if rec_full:
-                # fault action: a crash window slid the node's pending
-                # event later (re-recorded every superstep the node
-                # stays down — the query layer dedups host-side).
-                # send_t carries the ORIGINAL pending instant, t the
-                # deferred-to instant (obs/flight.py docstring)
-                from ...obs import flight
-                dm = (node_next > node_next_pre) \
-                    & (node_next_pre < NEVER)
-                self._rec_extra.append(flight.compact(
-                    self.record_cap, flight.EV_FAULT, dm, node_ids,
-                    node_ids, node_next_pre, node_next,
-                    flight.TAG_DEFER))
-        t = comm.all_min(node_next.min())
-        live = t < NEVER
+        # 1. global next event time: the state's horizon (`_horizon`),
+        # found by whoever made the state
+        t, node_next = hz
+        if self._faulted and rec_full:
+            # fault action: a crash window slid the node's pending
+            # event later (re-recorded every superstep the node
+            # stays down — the query layer dedups host-side).
+            # send_t carries the ORIGINAL pending instant, t the
+            # deferred-to instant (obs/flight.py docstring)
+            from ...obs import flight
+            node_next_pre = self._node_next(st)
+            dm = (node_next > node_next_pre) \
+                & (node_next_pre < NEVER)
+            self._rec_extra.append(flight.compact(
+                self.record_cap, flight.EV_FAULT, dm, node_ids,
+                node_ids, node_next_pre, node_next,
+                flight.TAG_DEFER))
         # dynamic dispatch (controlled.py): the controller's requested
         # window arrives as a traced scalar, clamped to [1, bound] and
         # — under a fault schedule — to the per-superstep degraded
@@ -1136,7 +1218,9 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         # the global min). In-window firings are causally independent
         # because link delays are >= W (validated in __init__; counted
         # in short_delay below when violated).
-        fire = (node_next < NEVER) & (node_next - t < Wv) & live
+        fire = (node_next < NEVER) & (node_next - t < Wv)
+        if live is not None:
+            fire = fire & live
         #: per-node firing instant; t for non-fired (their results are
         #: masked, but the step function must see a sane `now`)
         now_vec = jnp.where(fire, node_next, t)                 # int64[N]
@@ -1637,8 +1721,12 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
             restart_done=st.restart_done if restart_done is None
             else restart_done,
         )
-        # freeze everything once quiesced
-        final = jax.tree.map(lambda a, b: jnp.where(live, b, a), st, new_st)
+        # freeze everything once quiesced (or, a fleet's world in the
+        # quiet loop, out of budget). `live` None: the caller's loop
+        # has decided on this state's horizon that the superstep
+        # runs, and selects nothing
+        final = new_st if live is None else jax.tree.map(
+            lambda a, b: jnp.where(live, b, a), st, new_st)
         if not with_trace:
             return final, None
 
@@ -1777,41 +1865,52 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
 
     # -- the world axis (batch=BatchSpec) --------------------------------
 
-    def _vstep(self, st, s0v, s1v, lpv, ftv, with_trace: bool):
-        """One superstep of every world: ``vmap`` of ``_superstep``
-        over the leading world axis of ``st`` and the world context
-        (per-world seed words + link parameters + fault tables). The
-        per-world seed, link, and fault schedule are bound onto
-        ``self`` for the single trace vmap performs — the traced
-        values ARE the per-world tracers, so the compiled program maps
-        them; ``_superstep`` itself is unchanged (the whole point: one
-        superstep implementation, solo or fleet). A stage's scope
-        entered under ``vmap`` reads ``vmap(tw.route)`` in an
-        operation's ``op_name`` (docs/observability.md). The ``vmap``
-        names its axis so that ``_route_adaptive`` can take one rung
-        for all the worlds; the rung every world ran at is left on
-        ``self._rung_all`` (int32[B], one value B times) for the
-        drivers' ``rung_lanes`` counter (``_step_counted``)."""
-        n = self.comm.n_local
+    def _each_world(self, f, ctx, *args):
+        """``vmap`` of ``f`` over the leading world axis of ``args``,
+        with each world's context ``ctx`` (``_world_context``: seed
+        words, link parameters, fault tables) bound onto ``self`` for
+        the single trace vmap performs — the traced values ARE the
+        per-world tracers, so the compiled program maps them, and
+        ``f`` reads ``self.s0``, ``self.link``, ``self._ft`` as a
+        solo engine does. The ``vmap`` names its axis so that
+        ``_route_adaptive`` can take one rung for all the worlds."""
+        s0v, s1v, lpv, ftv = ctx
 
-        def world(st_w, s0, s1, lp, ft):
+        def world(s0, s1, lp, ft, *a):
             prev = (self.s0, self.s1, self.link, self._ft)
             self.s0, self.s1 = s0, s1
             if lp:
                 self.link = rebind_link(self.link, lp)
             if ft is not None:
                 self._ft = ft
-            # routing without the ladder (the eager and lazy regimes,
-            # the kernel routes) has no rung to choose: it counts n
-            self._fleet_rung = jnp.int32(n)
             try:
-                out = self._superstep(st_w, with_trace)
-                return out, self._fleet_rung
+                return f(*a)
             finally:
                 self.s0, self.s1, self.link, self._ft = prev
-        out, self._rung_all = jax.vmap(
-            world, in_axes=(0, 0, 0, 0, None if ftv is None else 0),
-            axis_name=_FLEET_AXIS)(st, s0v, s1v, lpv, ftv)
+        return jax.vmap(
+            world, in_axes=(0, 0, 0, None if ftv is None else 0)
+            + (0,) * len(args), axis_name=_FLEET_AXIS)(
+                s0v, s1v, lpv, ftv, *args)
+
+    def _vstep(self, step, ctx, *args):
+        """One superstep of every world: ``step`` (``_superstep`` with
+        its ``with_trace`` bound, or the quiet loop's
+        ``_superstep_carried``) under ``_each_world`` — ``_superstep``
+        itself is unchanged (the whole point: one superstep
+        implementation, solo or fleet). A stage's scope entered under
+        ``vmap`` reads ``vmap(tw.route)`` in an operation's
+        ``op_name`` (docs/observability.md). The rung every world ran
+        at is left on ``self._rung_all`` (int32[B], one value B
+        times) for the drivers' ``rung_lanes`` counter."""
+        n = self.comm.n_local
+
+        def world(*a):
+            # routing without the ladder (the eager and lazy regimes)
+            # has no rung to choose: it counts n
+            self._fleet_rung = jnp.int32(n)
+            out = step(*a)
+            return out, self._fleet_rung
+        out, self._rung_all = self._each_world(world, ctx, *args)
         return out
 
     def _identity(self) -> Optional[WorldIdentity]:
@@ -1824,19 +1923,24 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         return WorldIdentity(self._s0v, self._s1v, dict(self._lpv),
                              self._ftv)
 
-    def _step_all(self, st, with_trace: bool):
-        """One driver step: the solo superstep, or the vmapped fleet.
-        The fleet's world context comes from the driver-bound operand
-        (``self._ident_in``), falling back to the constructor's host
-        values when stepped outside a driver (trace-equivalent: the
-        fallback holds the same arrays the operand carries)."""
-        if self.batch is None:
-            return self._superstep(st, with_trace)
+    def _world_context(self):
+        """The fleet's ``(s0v, s1v, lpv, ftv)`` for ``_each_world``,
+        from the driver-bound operand (``self._ident_in``), falling
+        back to the constructor's host values outside a driver
+        (trace-equivalent: the fallback holds the same arrays the
+        operand carries). The world-sharded engine slices its
+        device's worlds out (sharded.py)."""
         ident = self._ident_in
         if ident is None:
             ident = self._identity()
-        return self._vstep(st, ident.s0v, ident.s1v, ident.lpv,
-                           ident.ftv, with_trace)
+        return ident.s0v, ident.s1v, ident.lpv, ident.ftv
+
+    def _step_all(self, st, with_trace: bool):
+        """One driver step: the solo superstep, or the vmapped fleet."""
+        if self.batch is None:
+            return self._superstep(st, with_trace)
+        return self._vstep(partial(self._superstep, with_trace=with_trace),
+                           self._world_context(), st)
 
     def rebind_identity(self, batch: BatchSpec, faults=None) -> bool:
         """Swap this fleet's per-world identity IN PLACE — new seeds,
@@ -1956,45 +2060,77 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         world-sharded engine overrides with a mesh psum."""
         return x
 
-    def _while_cond_fn(self, start_steps, max_steps):
-        """The run_quiet loop condition. Batched: a world is active
-        while it has events pending AND is inside its own step budget
-        — both per world, so a finished world never runs past where
-        its solo run would stop (the exactness law's driver half)."""
+    def _horizon_all(self, st) -> Horizon:
+        """The horizon of a driver's state: the solo state's, or each
+        world's (``t`` int64[B], ``node_next`` int64[B, N]). The one
+        scan of the mailbox a quiet run makes for its next event;
+        every later horizon is its superstep's product."""
+        @jax.named_scope("tw.next_event")
+        def horizon(st):
+            return self._horizon(st)
         if self.batch is None:
-            def cond(carry):
-                nxt = self.comm.all_min(self._next_event(carry))
-                return (nxt < NEVER) & \
-                    (carry.steps - start_steps < max_steps)
-        else:
-            def cond(carry):
-                st = carry[0]
-                nxt = jax.vmap(self._next_event)(st)
-                active = (nxt < NEVER) & \
-                    (st.steps - start_steps < max_steps)
-                return self._any_world(jnp.any(active))
+            return horizon(st)
+        # the scope is entered under the vmap, like a superstep's
+        # stages: a fleet's reads ``vmap(tw.next_event)``
+        return self._each_world(horizon, self._world_context(), st)
+
+    def _while_cond_fn(self, start_steps, max_steps):
+        """The run_quiet loop condition, on the carry ``(state, ...,
+        horizon)``: the carried next event time against NEVER, and
+        the step budget. Scalars (a fleet: ``[B]`` vectors): nothing
+        of the mailbox's rank is read at the loop's edge. Batched: a
+        world is active while it has events pending AND is inside its
+        own step budget — both per world, so a finished world never
+        runs past where its solo run would stop (the exactness law's
+        driver half)."""
+        def cond(carry):
+            st, hz = carry[0], carry[-1]
+            active = (hz.t < NEVER) & \
+                (st.steps - start_steps < max_steps)
+            if self.batch is None:
+                return active
+            return self._any_world(jnp.any(active))
         return cond
 
     def _while_body_fn(self, start_steps, max_steps):
-        """The run_quiet loop body. Batched: budget-exhausted worlds
-        are frozen leaf-wise (quiesced worlds are already frozen
-        inside ``_superstep`` by the ``live`` mask); the carry is
-        ``_fleet_carry``'s, and every iteration counts its rung (the
-        frozen worlds ran at it too)."""
+        """The run_quiet loop body: ``_superstep_carried`` on the
+        carry. Solo, ``(state, horizon)``: the condition has just
+        found this very ``horizon.t`` pending and the budget open, so
+        the superstep runs unconditionally and no leaf is selected.
+        Batched, ``(state, rung_lanes, horizon)``: the loop runs while
+        ANY world is active, so each world's superstep selects its
+        state once, by live and in budget together; every iteration
+        counts its rung (the frozen worlds ran at it too)."""
         if self.batch is None:
             def body(carry):
-                return self._step_all(carry, False)[0]
+                return self._superstep_carried(*carry)
         else:
             def body(carry):
-                (new, lanes), _ = self._step_counted(carry, False)
-                st = carry[0]
-                act = st.steps - start_steps < max_steps  # [B]
-                return jax.tree.map(
-                    lambda a, b: jnp.where(
-                        act.reshape(act.shape + (1,) * (b.ndim - 1)),
-                        b, a),
-                    st, new), lanes
+                st, lanes, hz = carry
+                in_budget = st.steps - start_steps < max_steps  # [B]
+                new, hz = self._vstep(self._superstep_carried,
+                                      self._world_context(), st, hz,
+                                      in_budget)
+                return new, lanes + self._rung_all.astype(lanes.dtype), hz
         return body
+
+    def _quiet_loop(self, st, max_steps):
+        """The quiet driver's ``while``, shared by the local and the
+        sharded ``_run_while``: one scan for the state's horizon
+        (under ``tw.next_event``, inside the same program), then the
+        loop on ``(state, horizon)`` — a fleet's ``(state,
+        rung_lanes, horizon)``. Returns the state, a fleet's with its
+        ``rung_lanes`` (``_uncarry``); the last horizon is dropped
+        (it is a function of the state: ``EngineState`` has no field
+        for it, and the next call scans once again)."""
+        start_steps = st.steps  # max_steps is per-call, same as run()
+        hz = self._horizon_all(st)
+        carry = (st, hz) if self.batch is None \
+            else self._fleet_carry(st) + (hz,)
+        out = jax.lax.while_loop(
+            self._while_cond_fn(start_steps, max_steps),
+            self._while_body_fn(start_steps, max_steps), carry)
+        return out[0] if self.batch is None else out[:2]
 
     # -- drivers ---------------------------------------------------------
 
@@ -2111,8 +2247,12 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
 
     @jax.named_scope("tw.next_event")
     def _next_event(self, carry: EngineState) -> jax.Array:
-        """This device's next event time (NEVER = quiesced) — the
-        while-loop condition shared by the local and sharded drivers."""
+        """This device's next event time as the state alone has it
+        (NEVER = quiesced): the probe of a state at rest
+        (``world_active``, the benchmarks' quiescence gates). No
+        driver's loop asks it: the quiet loop carries each state's
+        :class:`Horizon`, whose ``t`` is this value wherever no fault
+        schedule defers an event."""
         mmin = carry.mb_rel.min()
         return jnp.minimum(
             carry.wake.min(),
@@ -2121,18 +2261,16 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
 
     @partial(jax.jit, static_argnums=(0,))
     def _run_while(self, st: EngineState, max_steps, ident=None):
-        # max_steps is traced (a device scalar), so benchmarking with
-        # different budgets reuses one compiled executable; `ident`
-        # is the fleet identity operand, bound like _run_scan's.
-        # Returns the loop's carry (a fleet's: _fleet_carry)
-        start_steps = st.steps  # max_steps is per-call, same as run()
-        max_steps = jnp.asarray(max_steps, jnp.int64)
+        """The quiet driver's one program: ``_quiet_loop`` (the scan
+        for the first horizon is inside it, no launch of its own).
+        ``max_steps`` is traced (a device scalar), so benchmarking
+        with different budgets reuses one compiled executable;
+        ``ident`` is the fleet identity operand, bound like
+        ``_run_scan``'s. Returns the state, a fleet's with its
+        ``rung_lanes`` beside it (``_uncarry``)."""
         self._ident_in = ident
         try:
-            return jax.lax.while_loop(
-                self._while_cond_fn(start_steps, max_steps),
-                self._while_body_fn(start_steps, max_steps),
-                st if self.batch is None else self._fleet_carry(st))
+            return self._quiet_loop(st, jnp.asarray(max_steps, jnp.int64))
         finally:
             self._ident_in = None
 

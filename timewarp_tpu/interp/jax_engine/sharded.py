@@ -272,26 +272,21 @@ class ShardedBatchedEngine(ShardedDriver, JaxEngine):
         # world axis, not the (nonexistent) replication
         return P(None, self.axis)
 
-    def _step_all(self, st, with_trace: bool):
+    def _world_context(self):
         # this device's slice of the world context (seed words + link
         # parameter vectors + fault tables): the identity arrives as
         # the driver-bound replicated operand (engine.py WorldIdentity
         # — traced, never a closure constant, so an identity swap is
         # zero-recompile here too), sliced by mesh position — the
         # same pattern as MeshComm.local_rows
-        ident = self._ident_in
-        if ident is None:
-            ident = self._identity()
+        s0v, s1v, lpv, ftv = super()._world_context()
         Bl = self.worlds_local
         off = jax.lax.axis_index(self.axis).astype(jnp.int32) \
             * jnp.int32(Bl)
         def sl(v):
             return jax.lax.dynamic_slice_in_dim(v, off, Bl, axis=0)
-        ftv = None if ident.ftv is None else \
-            jax.tree.map(sl, ident.ftv)
-        return self._vstep(st, sl(ident.s0v), sl(ident.s1v),
-                           {k: sl(v) for k, v in ident.lpv.items()},
-                           ftv, with_trace)
+        return (sl(s0v), sl(s1v), {k: sl(v) for k, v in lpv.items()},
+                None if ftv is None else jax.tree.map(sl, ftv))
 
     def _any_world(self, x):
         # liveness must be mesh-wide: one device's worlds finishing

@@ -240,21 +240,31 @@ class ShardedDriver:
                      (self._carry_specs(specs), self._trace_spec()))(
             st, max_steps, dyn, ident)
 
+    def _quiet_loop(self, st, max_steps):
+        """The quiet driver's ``while`` on this device's shard. The
+        general engines bring their own (``JaxEngine._quiet_loop``:
+        the loop carries the state's event horizon, whose ``t`` is
+        reduced over the mesh where it is produced); the edge engine
+        loops on its state alone, its condition asking for the next
+        event."""
+        loop = getattr(super(), "_quiet_loop", None)
+        if loop is not None:
+            return loop(st, max_steps)
+        start_steps = st.steps
+        return jax.lax.while_loop(
+            self._while_cond_fn(start_steps, max_steps),
+            self._while_body_fn(start_steps, max_steps), st)
+
     @partial(jax.jit, static_argnums=(0,))
     def _run_while(self, st, max_steps, ident=None):
         specs = self._state_specs(st)
-        Bl = getattr(self, "worlds_local", None)
         max_steps = jnp.asarray(max_steps, jnp.int64)
         ident_specs = jax.tree.map(lambda _: P(), ident)
 
         def body_fn(s, ms, idn):
             self._ident_in = idn
             try:
-                start_steps = s.steps
-                return jax.lax.while_loop(
-                    self._while_cond_fn(start_steps, ms),
-                    self._while_body_fn(start_steps, ms),
-                    s if Bl is None else self._fleet_carry(s))
+                return self._quiet_loop(s, ms)
             finally:
                 self._ident_in = None
 
