@@ -148,6 +148,24 @@ def test_chunked_drivers_sum_the_counts():
          "dispatches": 1, "readbacks": 2}])
     assert (merged["dispatches"], merged["readbacks"]) == (2, 3)
     assert eng._stats_merge([])["dispatches"] == 0
+    # and what routing and a fleet's worlds counted (ISSUE 35, ROADMAP
+    # D3), elementwise; a key that a chunk lacks is left out
+    assert "rung_lanes" not in merged
+    fleet = [{"supersteps": 5, "wall_seconds": .1, "compiles": 0,
+              "dispatches": 1, "readbacks": 1, "rung_lanes": 3072,
+              "sender_lanes": 1500, "rung_steps": [3, 0],
+              "fleet_iterations": 3, "world_supersteps": [3, 2]},
+             {"supersteps": 4, "wall_seconds": .1, "compiles": 0,
+              "dispatches": 1, "readbacks": 1, "rung_lanes": 4096,
+              "sender_lanes": 2500, "rung_steps": [0, 2],
+              "fleet_iterations": 2, "world_supersteps": [2, 2]}]
+    merged = eng._stats_merge(fleet)
+    assert (merged["rung_lanes"], merged["sender_lanes"]) == (7168, 4000)
+    assert merged["rung_steps"] == [3, 2]
+    assert (merged["fleet_iterations"], merged["world_supersteps"]) == \
+        (5, [5, 4])
+    assert "rung_steps" not in eng._stats_merge(
+        fleet + [{"supersteps": 1, "wall_seconds": .1, "compiles": 0}])
 
 
 def _host_events(logdir):

@@ -151,13 +151,20 @@ def test_a_fleet_without_the_ladder_counts_its_full_width():
     assert eng.last_run_stats["rung_lanes"] == 10 * 512
 
 
-def test_a_solo_engine_counts_no_rung_lanes():
+def test_a_solo_engine_counts_its_rung_lanes_too():
+    """Solo and fleet differ by ``batch`` alone (ISSUE 35): a solo
+    loop carries the same counts, in both drivers (tests/
+    test_zzzzzzzzzzzzzzzrecord.py holds them to the telemetry)."""
     sc, link = _steady()
     eng = JaxEngine(sc, link, window="auto")
     eng.run_quiet(5)
-    assert "rung_lanes" not in eng.last_run_stats
+    quiet = eng.last_run_stats
+    assert quiet["rung_lanes"] == 5 * RUNGS[0]   # the ramp's first rounds
+    assert quiet["rung_steps"] == [5] + [0] * (len(RUNGS) - 1)
     eng.run(5)
-    assert "rung_lanes" not in eng.last_run_stats
+    assert {k: eng.last_run_stats[k] for k in
+            ("rung_lanes", "sender_lanes", "rung_steps")} == \
+        {k: quiet[k] for k in ("rung_lanes", "sender_lanes", "rung_steps")}
 
 
 # -- (c) the exactness law where the worlds differ --------------------------

@@ -133,17 +133,18 @@ def test_the_adaptive_drivers_have_no_sort_scope(kw):
 
 
 #: sha256 of the quiet driver's lowering (``as_text()``: no names, no
-#: locations) at 2^11 nodes, as PR 34 lowers it: the ``while`` carries
-#: the state's event horizon beside it, its condition reads scalars,
-#: a solo body selects nothing by liveness and a fleet's selects each
-#: world's state once (``tests/test_loop_edge.py`` holds the shape and
-#: the exactness). Until then the solo wave's was PR 32's
-#: (820775e61c25…) and the fleet's PR 30's (5553f5c6c1b5…). A PR that
-#: changes what these drivers compute changes the constants, and says
-#: so.
+#: locations) at 2^11 nodes, as PR 35 lowers it: the ``while`` of
+#: PR 34 (the state's event horizon carried beside it, a condition
+#: on scalars, a solo body that selects nothing by liveness:
+#: ``tests/test_loop_edge.py``) with the routing stage's three counts
+#: in its carry, solo and fleet alike (``engine.py`` ``RouteCounts``;
+#: ``tests/test_zzzzzzzzzzzzzzzrecord.py`` holds the final states to
+#: PR 34's, bit for bit). Until then they were PR 34's (solo
+#: e0e9875a2751…, fleet 8d44f07ef6ed…). A PR that changes what these
+#: drivers compute changes the constants, and says so.
 _PARENT_LOWERING = {
-    "solo": "e0e9875a2751e65ba6f060e8a8072744ee3ae9251f304fa272c272d890d99f48",
-    "fleet": "8d44f07ef6ed793ad11e7557cb80acbe9d2d47dd3277b90bc43d349378ec8d24",
+    "solo": "a591ccb6659a77599a40bd5d64e33157181712a9cf6db6c51b813cdc4ce95085",
+    "fleet": "9e610618e0c257f8513091796270360e8d265f6dbeeef72106c88aa8fabb119b",
 }
 
 

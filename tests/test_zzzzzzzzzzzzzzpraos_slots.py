@@ -208,17 +208,17 @@ def test_a_scenario_without_a_key_has_no_entropy_scope(make):
 
 
 #: sha256 of the quiet driver's lowering (``as_text()``: no names, no
-#: locations) at 2^11 nodes, as PR 34 lowers it (the loop carries its
-#: successor's event horizon and a solo body selects nothing by
-#: liveness: ``tests/test_loop_edge.py``). Until then they were
-#: commit 5f05997's (PR 32; steady ac6fac01cfaa…, praos df24e3874d17…:
-#: the scope of PR 33 was metadata). The wave's and the fleet's are
-#: pinned in ``test_zzzzzzzzzzzzzsteady_mongering.py``. A PR that
-#: changes what these drivers compute changes the constants, and says
-#: so.
+#: locations) at 2^11 nodes, as PR 35 lowers it (PR 34's loop, which
+#: carries its successor's event horizon, ``tests/test_loop_edge.py``,
+#: with the routing stage's three counts in its carry:
+#: ``tests/test_zzzzzzzzzzzzzzzrecord.py``). Until then they were
+#: PR 34's (steady 80aa2481fbb3…, praos 07f9f2652961…). The wave's
+#: and the fleet's are pinned in
+#: ``test_zzzzzzzzzzzzzsteady_mongering.py``. A PR that changes what
+#: these drivers compute changes the constants, and says so.
 _PARENT_LOWERING = {
-    "steady": "80aa2481fbb3e3d5d62277da62caa49275e7b72afe2eaa0cf553d2be30098d9d",
-    "praos": "07f9f26529613c3856e3bb30907e7cda1f35eae3f7ab1b569ebd47d70aa6cbd6",
+    "steady": "019784a0569257a2db50f411b7b9bdc4e3b6fa6f0673d1c0a59d0bf4b95d4cec",
+    "praos": "23c5c22aee0179bfd41ae5b712b2bd341bbe93ff0ec4aeddb6b1dae63817e69f",
 }
 
 

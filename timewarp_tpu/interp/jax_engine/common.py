@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...obs.profiler import span
+from ...obs.profiler import call, span
 from ...ops.numeric import I32MAX, group_rank, thi, tlo, u32sum
 
 __all__ = ["LocalComm", "StepOut", "I32MAX", "group_rank", "u32sum",
@@ -218,10 +218,12 @@ class LocalComm:
 class _DriverCall:
     """One driver call's boundary with the chip: what it launched, what
     it read back, and the host spans of both (``obs.profiler.span``,
-    all carrying the call's ``run`` number)."""
+    all carrying the call's ``run`` number). ``record`` is the call's
+    record (``obs.profiler.call``): the spans note themselves there,
+    :meth:`wait` puts the call's ``last_run_stats`` beside them."""
 
-    def __init__(self, eng, run: int):
-        self.eng, self.run = eng, run
+    def __init__(self, eng, record: dict):
+        self.eng, self.record, self.run = eng, record, record["run"]
         self.t0, self.c0 = time.perf_counter(), eng._driver_compiles()
         self.dispatches = self.readbacks = 0
 
@@ -232,18 +234,21 @@ class _DriverCall:
         with span("tw.dispatch", run=self.run):
             return fn(*args)
 
-    def wait(self, steps_before, steps_after, *more, lanes=None):
+    def wait(self, steps_before, steps_after, *more, counts=None):
         """The blocking read that ends the device's work, under
-        ``tw.wait``: the step counters, a fleet's ``rung_lanes``
-        counter (``lanes``) and whatever else the driver reads back
+        ``tw.wait``: the step counters, the routing stage's counts the
+        driver's loop carried beside the state (``counts``: a
+        ``(rung_lanes, sender_lanes, rung_steps)`` of device arrays,
+        ``engine.py`` ``RouteCounts``; None from an engine with no
+        ladder to count) and whatever else the driver reads back
         (``more``, returned on the host) in one transfer. Sets
-        ``last_run_stats``."""
+        ``last_run_stats``, the record's ``counts``."""
         self.readbacks += 1
         with span("tw.wait", run=self.run):
-            before, after, lanes, *more = jax.device_get(
-                (steps_before, steps_after, lanes) + more)
+            before, after, counts, *more = jax.device_get(
+                (steps_before, steps_after, counts) + more)
         d = np.asarray(after, np.int64) - np.asarray(before, np.int64)
-        self.eng.last_run_stats = {
+        stats = self.record["counts"] = self.eng.last_run_stats = {
             "supersteps": int(d.sum()),
             "wall_seconds": time.perf_counter() - self.t0,
             "compiles": self.eng._driver_compiles() - self.c0,
@@ -253,13 +258,18 @@ class _DriverCall:
             # a fleet: the same transfer holds every world's count. The
             # loop steps every world until the last is quiet or out of
             # budget, so its iterations are the largest of them
-            self.eng.last_run_stats.update(
-                world_supersteps=d.tolist(), fleet_iterations=int(d.max()))
-            if lanes is not None:
+            stats.update(world_supersteps=d.tolist(),
+                         fleet_iterations=int(d.max()))
+        if counts is not None:
+            lanes, senders, by_rung = counts
+            if d.ndim:
                 # one rung for all the worlds of a superstep: every
                 # world counted the same (a world-sharded fleet: the
-                # widest of its devices' sums)
-                self.eng.last_run_stats["rung_lanes"] = int(lanes.max())
+                # counts of the device whose rungs sum widest)
+                b = int(np.argmax(lanes))
+                lanes, senders, by_rung = lanes[b], senders[b], by_rung[b]
+            stats.update(rung_lanes=int(lanes), sender_lanes=int(senders),
+                         rung_steps=by_rung.tolist())
         return more
 
     def guard(self):
@@ -280,41 +290,50 @@ class RunStatsMixin:
          "dispatches": int,    # executables launched by the call
          "readbacks": int}     # blocking host reads by the call
 
+    for a general engine (``JaxEngine`` and its sharded twins), solo
+    or fleet, the routing stage's counts, summed over the iterations
+    of the driver's loop::
+
+        {"rung_lanes": int,    # the rung taken, in senders
+         "sender_lanes": int,  # the active senders the rung was chosen
+                               # for (a fleet: its busiest world's)
+         "rung_steps": [int] * R}  # iterations by rung index
+
     and, for a fleet (``batch=BatchSpec``) only::
 
         {"world_supersteps": [int] * B,  # executed by each world
-         "fleet_iterations": int,        # the largest of them: what the
+         "fleet_iterations": int}        # the largest of them: what the
                                          # driver's loop ran, each at the
                                          # cost of all B worlds
-         "rung_lanes": int}              # the routing rung (in senders)
-                                         # the fleet took, summed over
-                                         # those iterations
 
     so ``supersteps / (B * fleet_iterations)`` is the share of the
-    fleet's work spent on worlds that were still running, and
-    ``rung_lanes / (fleet_iterations * n_nodes)`` the share of the
-    routing ladder's full width it paid (engine.py
-    ``_route_adaptive``: one rung for all the worlds of a superstep;
-    1 where routing runs without the ladder). The count is carried
+    fleet's work spent on worlds that were still running,
+    ``rung_lanes / (iterations * n_nodes)`` the share of the routing
+    ladder's full width the call paid (engine.py ``_route_adaptive``;
+    a fleet takes one rung for all the worlds of a superstep) and
+    ``sender_lanes / rung_lanes`` how full the rungs ran. Where routing
+    runs without the ladder both lane counts are ``n_nodes`` an
+    iteration and ``rung_steps`` has one bin. The counts are carried
     beside the state in the driver's loop and read in the call's one
     transfer. The chunked drivers' merged record (``_stats_merge``)
-    keeps none of the three.
+    sums them.
+
+    The same dict is the ``counts`` of the call's record
+    (``obs.profiler.calls()``), beside the call's spans: every driver
+    goes through :meth:`_driver_call`, so the spans and the record of
+    docs/observability.md have one implementation.
 
     Compile counting reads the jitted drivers' ``_cache_size`` (the
     same probe tests/test_world_batch.py pins the pow2 bucketing
     with), so a run that silently retraced is visible in its stats.
-    Host-side only — nothing here is compiled in, so the telemetry
-    zero-overhead law is untouched and the stats exist in every
-    telemetry mode including "off". Every driver goes through
-    :meth:`_driver_call`, so the spans of docs/observability.md have
-    one implementation.
+    The host half compiles nothing in, and the stats exist in every
+    telemetry mode including "off".
     """
 
     #: the jitted driver attributes whose compile caches count
     _DRIVER_FNS = ("_run_scan", "_run_while")
 
     last_run_stats = None
-    _calls = 0                  # driver calls so far: the spans' `run`
 
     def _driver_compiles(self) -> int:
         n = 0
@@ -327,11 +346,13 @@ class RunStatsMixin:
 
     @contextmanager
     def _driver_call(self, driver: str):
-        """The ``tw.<driver>`` span around one driver call; yields its
-        :class:`_DriverCall`."""
-        self._calls += 1
-        with span("tw." + driver, run=self._calls):
-            yield _DriverCall(self, self._calls)
+        """One driver call: its record (``obs.profiler.call``, with the
+        ``tw.<driver>`` span around the call) holding the engine's
+        class and node count; yields the call's :class:`_DriverCall`."""
+        with call("tw." + driver) as record:
+            record.update(engine=type(self).__name__,
+                          n_nodes=self.scenario.n_nodes)
+            yield _DriverCall(self, record)
 
     def _stats_merge(self, chunks) -> dict:
         """Fold per-chunk ``last_run_stats`` dicts into one run-level
@@ -343,7 +364,10 @@ class RunStatsMixin:
         scan pad) was silently lost. ``per_chunk_compiles`` keeps the
         attribution: entry i is the number of driver executables chunk
         i compiled, so "zero recompiles across controller adaptations"
-        is testable per chunk, not just in aggregate."""
+        is testable per chunk, not just in aggregate. The routing
+        counts and a fleet's per-world counts are summed where every
+        chunk has them (elementwise: a chunked fleet's
+        ``fleet_iterations`` is the sum of its chunks' loops)."""
         self.last_run_stats = {
             "supersteps": sum(c["supersteps"] for c in chunks),
             "wall_seconds": sum(c["wall_seconds"] for c in chunks),
@@ -353,4 +377,11 @@ class RunStatsMixin:
             "chunks": len(chunks),
             "per_chunk_compiles": [c["compiles"] for c in chunks],
         }
+        for key in ("rung_lanes", "sender_lanes", "fleet_iterations",
+                    "rung_steps", "world_supersteps"):
+            if chunks and all(key in c for c in chunks):
+                cols = [c[key] for c in chunks]
+                self.last_run_stats[key] = sum(cols) \
+                    if not isinstance(cols[0], list) \
+                    else [sum(col) for col in zip(*cols)]
         return self.last_run_stats

@@ -59,7 +59,8 @@ from ...integrity.runner import VerifiedRunMixin
 from ...obs.flight import FlightRecorderMixin
 from ...speculate.runner import SpeculativeRunMixin
 
-__all__ = ["JaxEngine", "EngineState", "Horizon", "BatchSpec"]
+__all__ = ["JaxEngine", "EngineState", "Horizon", "RouteCounts",
+           "BatchSpec"]
 
 #: the name of the fleet's ``vmap`` axis (``_vstep``): what a world
 #: reduces over when all worlds must agree (``_route_adaptive``'s
@@ -139,6 +140,24 @@ class Horizon(NamedTuple):
     #: a pending restart's ``t_up``); ``t`` is its minimum over all
     #: devices
     node_next: jax.Array
+
+
+class RouteCounts(NamedTuple):
+    """What the routing stage did, summed over the iterations of a
+    driver's loop: every driver loop carries it beside the state and
+    the call reads it in its one transfer (``last_run_stats``, the
+    call's record). All three come from the two scalars
+    ``_route_adaptive`` holds when it picks its branch (the active
+    senders and the rung's index): no pass over node- or mailbox-sized
+    data is made for them. Where routing runs without the ladder every
+    iteration counts the full width, in one bin. A fleet's leaves lead
+    with the world axis like every state leaf (one rung for all the
+    worlds of a superstep: every world of a device counts the same)."""
+    rung_lanes: jax.Array     # int64[] — the rung taken, in senders
+    #: int64[] — the active senders the rung was chosen for (a fleet:
+    #: its busiest world's)
+    sender_lanes: jax.Array
+    rung_steps: jax.Array     # int32[R] — iterations by rung index
 
 
 class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
@@ -240,9 +259,9 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
     One batched op serves B worlds, at ONE rung of the routing ladder
     for all of them: the smallest that holds the busiest world's
     senders (``_route_adaptive``; ``last_run_stats["rung_lanes"]``
-    sums the rungs taken). On a v5e eight gossip worlds deliver 0.95
-    of one solo wave's rate together (docs/engines.md "Multi-world
-    batching"; PERF.md, Findings PR 28). ``record_events`` is
+    sums the rungs taken, solo and fleet alike). On a v5e eight gossip
+    worlds deliver 0.95 of one solo wave's rate together
+    (docs/engines.md "Multi-world batching"; PERF.md, Findings PR 28). ``record_events`` is
     solo-only (the ring decoder is
     a single-run debug artifact — record world b's events by running
     it solo, which is bit-identical by the law above).
@@ -1072,12 +1091,11 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
             pin = jnp.clip(self._dyn.rung_pin, jnp.int32(-1),
                            jnp.int32(len(rungs) - 1))
             idx = jnp.maximum(idx, pin.astype(idx.dtype))
-        if self.telemetry != "off" or self.batch is not None:
-            # the rung the switch actually takes — recorded where the
-            # decision is made, so telemetry (and a fleet's
-            # ``rung_lanes``, _vstep) can never drift from it
-            self._t_rung = self._fleet_rung = \
-                jnp.asarray(rungs, jnp.int32)[idx]
+        # the rung the switch actually takes — recorded where the
+        # decision is made, so telemetry and the drivers' counts
+        # (``RouteCounts``, ``_count_route``) can never drift from it
+        self._t_rung = jnp.asarray(rungs, jnp.int32)[idx]
+        self._routed = (self._t_rung, n_active, idx.astype(jnp.int32))
         return filled(jax.lax.switch(idx, [tail(A) for A in rungs]))
 
     def _node_next(self, st: EngineState, nnr=None) -> jax.Array:
@@ -1408,6 +1426,12 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         #    outbox). Each message is stamped with its sender's firing
         #    instant (== t for W == 1), which keys the link entropy.
         adaptive = self._adaptive_regime()
+        #: what this superstep's routing adds to the drivers' counts
+        #: (``_count_route``): the rung in senders, the senders it was
+        #: chosen for, the rung's index. Routing without a choice of
+        #: rung counts its full width; ``_route_adaptive`` puts its
+        #: own where it takes one of several
+        self._routed = (jnp.int32(n_glob), jnp.int32(n_glob), jnp.int32(0))
         if adaptive:
             res = self._route_adaptive(
                 out, out_valid, now_vec, t, mb_rel, mb_src,
@@ -1899,18 +1923,13 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         itself is unchanged (the whole point: one superstep
         implementation, solo or fleet). A stage's scope entered under
         ``vmap`` reads ``vmap(tw.route)`` in an operation's
-        ``op_name`` (docs/observability.md). The rung every world ran
-        at is left on ``self._rung_all`` (int32[B], one value B
-        times) for the drivers' ``rung_lanes`` counter."""
-        n = self.comm.n_local
-
+        ``op_name`` (docs/observability.md). What routing did in
+        every world is left on ``self._routed`` (three int32[B], each
+        one value B times) for the drivers' counts, as a solo
+        superstep leaves its scalars there."""
         def world(*a):
-            # routing without the ladder (the eager and lazy regimes)
-            # has no rung to choose: it counts n
-            self._fleet_rung = jnp.int32(n)
-            out = step(*a)
-            return out, self._fleet_rung
-        out, self._rung_all = self._each_world(world, ctx, *args)
+            return step(*a), self._routed
+        out, self._routed = self._each_world(world, ctx, *args)
         return out
 
     def _identity(self) -> Optional[WorldIdentity]:
@@ -2029,30 +2048,44 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
                                        for x in tables))
         return True
 
-    def _fleet_carry(self, st):
-        """What a fleet's driver loops carry: the state and, beside
-        it, ``rung_lanes`` (int64[B]: for each world the sum, over the
-        loop's iterations so far, of the rung its superstep ran at;
-        ``last_run_stats``). World-leading like every state leaf, so
-        the world-sharded drivers lay it out the same way."""
-        return st, jnp.zeros_like(st.steps)
+    def _counted(self, st):
+        """What a driver's loop carries: the state and, beside it, the
+        routing stage's counts from zero (:class:`RouteCounts`). A
+        fleet's are world-leading like every state leaf, so the
+        world-sharded drivers lay them out the same way; solo and
+        fleet differ by that alone."""
+        lanes = jnp.zeros_like(st.steps)
+        n = self.comm.n_local
+        bins = len(self._sender_rungs(n)) if self._adaptive_regime() else 1
+        return st, RouteCounts(
+            lanes, lanes, jnp.zeros(lanes.shape + (bins,), jnp.int32))
 
-    def _uncarry(self, carry):
-        """``(state, rung_lanes)`` of a driver loop's carry; a solo
-        engine's carry is its state alone."""
-        return carry if self.batch is not None else (carry, None)
+    def _count_route(self, counts: RouteCounts, stepped=True
+                     ) -> RouteCounts:
+        """``counts`` and what the superstep just traced did in
+        routing (``self._routed``: the scalars ``_route_adaptive``
+        chose its branch by; a fleet's ``_vstep`` returns them a
+        world). ``stepped`` is whether the iteration counts at all (a
+        traced bool where the caller's loop runs on past the last
+        event)."""
+        rung, senders, idx = self._routed
+        bins = counts.rung_steps.shape[-1]
+        one = (idx[..., None] == jnp.arange(bins, dtype=jnp.int32)
+               ) & stepped
+        return RouteCounts(
+            counts.rung_lanes + jnp.where(stepped, rung, 0),
+            counts.sender_lanes + jnp.where(stepped, senders, 0),
+            counts.rung_steps + one.astype(jnp.int32))
 
     def _step_counted(self, carry, with_trace: bool):
-        """``_step_all`` on a fleet's ``(state, rung_lanes)`` carry. An
-        iteration counts where some world stepped, as in
-        ``fleet_iterations``: a traced scan runs on to its padded
-        length after the last world is quiet."""
-        st, lanes = carry
+        """``_step_all`` on a driver loop's ``(state, counts)`` carry.
+        An iteration counts where a superstep fired (a fleet: where
+        some world's did, as in ``fleet_iterations``): a traced scan
+        runs on to its padded length after the last event."""
+        st, counts = carry
         new, y = self._step_all(st, with_trace)
-        rung = self._rung_all.astype(lanes.dtype)
-        if y is not None:
-            rung = jnp.where(jnp.any(y.valid), rung, 0)
-        return (new, lanes + rung), y
+        stepped = True if y is None else jnp.any(y.valid)
+        return (new, self._count_route(counts, stepped)), y
 
     def _any_world(self, x):
         """Whether any world (on any device) is still active — the
@@ -2084,7 +2117,7 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         runs past where its solo run would stop (the exactness law's
         driver half)."""
         def cond(carry):
-            st, hz = carry[0], carry[-1]
+            st, _, hz = carry
             active = (hz.t < NEVER) & \
                 (st.steps - start_steps < max_steps)
             if self.batch is None:
@@ -2094,43 +2127,40 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
 
     def _while_body_fn(self, start_steps, max_steps):
         """The run_quiet loop body: ``_superstep_carried`` on the
-        carry. Solo, ``(state, horizon)``: the condition has just
-        found this very ``horizon.t`` pending and the budget open, so
-        the superstep runs unconditionally and no leaf is selected.
-        Batched, ``(state, rung_lanes, horizon)``: the loop runs while
-        ANY world is active, so each world's superstep selects its
-        state once, by live and in budget together; every iteration
-        counts its rung (the frozen worlds ran at it too)."""
-        if self.batch is None:
-            def body(carry):
-                return self._superstep_carried(*carry)
-        else:
-            def body(carry):
-                st, lanes, hz = carry
+        carry ``(state, counts, horizon)``. Solo: the condition has
+        just found this very ``horizon.t`` pending and the budget
+        open, so the superstep runs unconditionally and no leaf is
+        selected. Batched: the loop runs while ANY world is active, so
+        each world's superstep selects its state once, by live and in
+        budget together. Every iteration counts what its routing did
+        (a fleet's frozen worlds ran at the rung too)."""
+        def body(carry):
+            st, counts, hz = carry
+            if self.batch is None:
+                new, hz = self._superstep_carried(st, hz)
+            else:
                 in_budget = st.steps - start_steps < max_steps  # [B]
                 new, hz = self._vstep(self._superstep_carried,
                                       self._world_context(), st, hz,
                                       in_budget)
-                return new, lanes + self._rung_all.astype(lanes.dtype), hz
+            return new, self._count_route(counts), hz
         return body
 
     def _quiet_loop(self, st, max_steps):
         """The quiet driver's ``while``, shared by the local and the
         sharded ``_run_while``: one scan for the state's horizon
         (under ``tw.next_event``, inside the same program), then the
-        loop on ``(state, horizon)`` — a fleet's ``(state,
-        rung_lanes, horizon)``. Returns the state, a fleet's with its
-        ``rung_lanes`` (``_uncarry``); the last horizon is dropped
-        (it is a function of the state: ``EngineState`` has no field
-        for it, and the next call scans once again)."""
+        loop on ``(state, counts, horizon)``. Returns ``(state,
+        counts)``; the last horizon is dropped (it is a function of
+        the state: ``EngineState`` has no field for it, and the next
+        call scans once again)."""
         start_steps = st.steps  # max_steps is per-call, same as run()
         hz = self._horizon_all(st)
-        carry = (st, hz) if self.batch is None \
-            else self._fleet_carry(st) + (hz,)
         out = jax.lax.while_loop(
             self._while_cond_fn(start_steps, max_steps),
-            self._while_body_fn(start_steps, max_steps), carry)
-        return out[0] if self.batch is None else out[:2]
+            self._while_body_fn(start_steps, max_steps),
+            self._counted(st) + (hz,))
+        return out[:2]
 
     # -- drivers ---------------------------------------------------------
 
@@ -2150,14 +2180,11 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         per-world identity operand, bound the same way — admissions
         swap seeds/link values/fault tables without a retrace (the
         serving layer's zero-recompile contract, docs/serving.md).
-        Returns ``(carry, rows)``; a fleet's carry is
-        ``_fleet_carry``'s."""
+        Returns ``((state, counts), rows)``."""
         self._dyn = dyn
         self._ident_in = ident
         try:
-            if self.batch is None:
-                return padded_scan(self._step_all, st, n_pad, max_steps)
-            return padded_scan(self._step_counted, self._fleet_carry(st),
+            return padded_scan(self._step_counted, self._counted(st),
                                n_pad, max_steps)
         finally:
             self._dyn = None
@@ -2226,11 +2253,10 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         with self._driver_call("run") as call:
             st = state if state is not None else self.init_state()
             budget, top = self._coerce_budget(max_steps)
-            carry, ys = call.dispatch(
+            (final, counts), ys = call.dispatch(
                 self._run_scan, st, _scan_pad(top) * self._pad_mult,
                 budget, _dyn, self._identity())
-            final, lanes = self._uncarry(carry)
-            ys, = call.wait(st.steps, final.steps, ys, lanes=lanes)
+            ys, = call.wait(st.steps, final.steps, ys, counts=counts)
         self._capture_telemetry(ys)
         self._capture_flight(ys, st)
         self._capture_integrity(ys)
@@ -2266,8 +2292,7 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         ``max_steps`` is traced (a device scalar), so benchmarking
         with different budgets reuses one compiled executable;
         ``ident`` is the fleet identity operand, bound like
-        ``_run_scan``'s. Returns the state, a fleet's with its
-        ``rung_lanes`` beside it (``_uncarry``)."""
+        ``_run_scan``'s. Returns ``(state, counts)``."""
         self._ident_in = ident
         try:
             return self._quiet_loop(st, jnp.asarray(max_steps, jnp.int64))
@@ -2284,9 +2309,9 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         with self._driver_call("run_quiet") as call:
             st = state if state is not None else self.init_state()
             budget, _ = self._coerce_budget(max_steps)
-            final, lanes = self._uncarry(call.dispatch(
-                self._run_while, st, budget, self._identity()))
-            call.wait(st.steps, final.steps, lanes=lanes)
+            final, counts = call.dispatch(
+                self._run_while, st, budget, self._identity())
+            call.wait(st.steps, final.steps, counts=counts)
             if self.verify != "off":
                 # never silently unverified: the quiet driver has no
                 # per-superstep rows, so the guard degrades to a

@@ -11,9 +11,15 @@ Kinds:
   time span, load-signal min/mean/max, drop-counter sums, minimum
   quiescence slack. Batched engines flush one line per world.
 - ``span`` — a wall-clock span (name + ``wall_s``): sweep bucket
-  attempts, retry backoffs, checkpoint writes, journal fsyncs.
+  attempts, retry backoffs, checkpoint writes, journal fsyncs. Timed
+  by ``obs.profiler.span``, the one span primitive, so each is also a
+  ``TraceAnnotation`` of an open profile and a tuple of the program's
+  record.
 - ``run_summary`` — one line per driver run: the engine's uniform
-  ``last_run_stats`` (supersteps, wall seconds, driver compiles).
+  ``last_run_stats`` (supersteps, wall seconds, driver compiles; what
+  the call launched and read back; the routing stage's counts where
+  the engine keeps them: ``rung_lanes``, ``sender_lanes``,
+  ``rung_steps``, a fleet's ``fleet_iterations``).
 - ``utilization`` — per-bucket sweep utilization (sweep/runner.py):
   worlds-active occupancy, budget-mask efficiency, pow2 scan-pad
   waste.
@@ -44,9 +50,10 @@ from __future__ import annotations
 
 import json
 import threading
-import time
 from contextlib import contextmanager
 from typing import Any, Dict, List, Optional
+
+from .profiler import span as _span
 
 __all__ = ["METRICS_SCHEMA", "MetricsRegistry", "validate_line",
            "validate_metrics_file"]
@@ -61,6 +68,10 @@ __all__ = ["METRICS_SCHEMA", "MetricsRegistry", "validate_line",
 METRICS_SCHEMA = 5
 
 _NUM = (int, float)
+#: the fields of ``last_run_stats`` a ``run_summary`` line carries
+#: where the driver call counted them (common.py ``RunStatsMixin``)
+_RUN_COUNTS = ("dispatches", "readbacks", "rung_lanes", "sender_lanes",
+               "fleet_iterations")
 #: kind -> {required field: type tuple}; extra fields are allowed
 #: (forward-compatible), missing/badly-typed required ones are not
 _KINDS: Dict[str, Dict[str, tuple]] = {
@@ -104,6 +115,10 @@ _FLIGHT_FIELDS: Dict[str, tuple] = {
 }
 
 
+def _is_int(v) -> bool:
+    return isinstance(v, int) and not isinstance(v, bool)
+
+
 def validate_line(rec: Any) -> None:
     """Validate one metrics record against the schema; raises
     ``ValueError`` naming the offense (never a KeyError/TypeError)."""
@@ -130,6 +145,20 @@ def validate_line(rec: Any) -> None:
             raise ValueError(
                 f"metrics kind {kind!r}: field {field!r} must be "
                 f"{'/'.join(t.__name__ for t in types)}, got {v!r}")
+    if kind == "run_summary":
+        # the driver call's counts are there or not (an engine with
+        # no ladder has no rung), but never of another type: a reader
+        # divides by them
+        for field in _RUN_COUNTS:
+            if field in rec and not _is_int(rec[field]):
+                raise ValueError(
+                    f"metrics kind 'run_summary': field {field!r} must "
+                    f"be int, got {rec[field]!r}")
+        steps = rec.get("rung_steps", [])
+        if not isinstance(steps, list) or not all(map(_is_int, steps)):
+            raise ValueError(
+                "metrics kind 'run_summary': field 'rung_steps' must be "
+                f"a list of int (iterations by rung), got {steps!r}")
     if kind == "event" and rec.get("name") == "flight":
         # the flight-recorder event form (v4): name="flight" promises
         # the per-message provenance tuple — a half-written event is
@@ -228,11 +257,15 @@ class MetricsRegistry:
 
     def run_summary(self, label: str, stats: dict, **fields) -> None:
         """One line per driver run from the engine's uniform
-        ``last_run_stats``."""
+        ``last_run_stats``: the three counts every engine has, and of
+        the call's boundary with the chip and its routing counts those
+        the stats hold."""
+        counts = {k: stats[k] for k in _RUN_COUNTS + ("rung_steps",)
+                  if k in stats}
         self.emit("run_summary", label=label,
                   supersteps=int(stats["supersteps"]),
                   wall_seconds=float(stats["wall_seconds"]),
-                  compiles=int(stats["compiles"]), **fields)
+                  compiles=int(stats["compiles"]), **counts, **fields)
 
     def event(self, name: str, **fields) -> None:
         self.emit("event", name=name, **fields)
@@ -242,17 +275,21 @@ class MetricsRegistry:
     @contextmanager
     def span(self, name: str, **fields):
         """Wall-clock span, mirrored onto the Perfetto timeline when a
-        tracer is attached."""
-        t0 = time.perf_counter()
+        tracer is attached. The span is ``obs.profiler.span``'s and
+        the times are the ones it noted: one primitive, one timing."""
         ts = None if self.tracer is None else self.tracer.now_us()
+        rec = None
         try:
-            yield
+            with _span(name, **fields) as rec:
+                yield
         finally:
-            dt = time.perf_counter() - t0
-            self.emit("span", name=name, wall_s=round(dt, 6), **fields)
-            if self.tracer is not None:
-                self.tracer.complete(name, dur_us=dt * 1e6, ts_us=ts,
-                                     args=fields or None)
+            if rec is not None:
+                _, t0, t1, _, _ = rec["spans"][-1]
+                dt = (t1 - t0) / 1e9
+                self.emit("span", name=name, wall_s=round(dt, 6), **fields)
+                if self.tracer is not None:
+                    self.tracer.complete(name, dur_us=dt * 1e6, ts_us=ts,
+                                         args=fields or None)
 
     def close(self) -> None:
         with self._lock:

@@ -25,7 +25,6 @@ from __future__ import annotations
 
 import json
 import time
-from contextlib import contextmanager
 from typing import Optional
 
 __all__ = ["TraceBuilder"]
@@ -73,17 +72,6 @@ class TraceBuilder:
         if args:
             ev["args"] = args
         self.events.append(ev)
-
-    @contextmanager
-    def span(self, name: str, cat: str = "host",
-             args: Optional[dict] = None, tid: int = 1):
-        t0 = time.perf_counter()
-        ts = self.now_us()
-        try:
-            yield
-        finally:
-            self.complete(name, (time.perf_counter() - t0) * 1e6,
-                          ts_us=ts, cat=cat, args=args, tid=tid)
 
     # -- virtual-time counter track ----------------------------------------
 

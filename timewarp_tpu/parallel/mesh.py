@@ -177,13 +177,20 @@ class ShardedDriver:
         on the world's device)."""
         return P()
 
-    def _carry_specs(self, specs):
-        """PartitionSpecs of a driver loop's carry: the state's, and
-        for the world-sharded fleet its ``rung_lanes`` counter beside
-        it (``JaxEngine._fleet_carry``), a world to an entry."""
-        if getattr(self, "worlds_local", None) is None:
+    def _carry_specs(self, st, specs):
+        """PartitionSpecs of what a driver returns of its loop's
+        carry: the state's, and for the general engines the routing
+        counts beside it (``JaxEngine._counted``): a world to a row
+        in the world-sharded fleet, replicated scalars in a
+        node-sharded world (whose routing counts its full width, the
+        same on every device)."""
+        counted = getattr(self, "_counted", None)
+        if counted is None:
             return specs
-        return specs, P(self.axis)
+        world = getattr(self, "worlds_local", None) is not None
+        return specs, jax.tree.map(
+            lambda x: P(self.axis, *[None] * (x.ndim - 1)) if world
+            else P(), jax.eval_shape(counted, st)[1])
 
     @partial(jax.jit, static_argnums=(0, 2))
     def _run_scan(self, st, n_pad: int, max_steps, dyn=None,
@@ -223,13 +230,13 @@ class ShardedDriver:
             self._dyn = dy
             self._ident_in = idn
             try:
-                if Bl is None:
+                if not hasattr(self, "_counted"):
                     return padded_scan(self._step_all, s, n_pad,
                                        local_ms(ms))
-                # a fleet carries its rung_lanes counter beside the
-                # state (JaxEngine._fleet_carry), one entry a world
+                # the general engines carry their routing counts
+                # beside the state (JaxEngine._counted)
                 return padded_scan(self._step_counted,
-                                   self._fleet_carry(s), n_pad,
+                                   self._counted(s), n_pad,
                                    local_ms(ms))
             finally:
                 self._dyn = None
@@ -237,7 +244,7 @@ class ShardedDriver:
 
         return _smap(body, self.mesh,
                      (specs, P(), dyn_specs, ident_specs),
-                     (self._carry_specs(specs), self._trace_spec()))(
+                     (self._carry_specs(st, specs), self._trace_spec()))(
             st, max_steps, dyn, ident)
 
     def _quiet_loop(self, st, max_steps):
@@ -269,4 +276,4 @@ class ShardedDriver:
                 self._ident_in = None
 
         return _smap(body_fn, self.mesh, (specs, P(), ident_specs),
-                     self._carry_specs(specs))(st, max_steps, ident)
+                     self._carry_specs(st, specs))(st, max_steps, ident)
