@@ -12,6 +12,10 @@ words and the bit select must give its entry for every column and
 every rank, the ranks past the free count (→ K) included. The bit
 select has no caller in the program since PR 32; it is the reference
 tests/test_insert_law.py holds the program's slots to.
+
+``expand_lanes`` (PR 36) is ``fill_holes``' expand run along the lane
+axis: a compacted ascending prefix spread over the lanes it names. Its
+plain reference is the scatter it replaces.
 """
 
 import numpy as np
@@ -20,8 +24,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from timewarp_tpu.ops.numeric import (I32MAX, fill_holes, free_bits,
-                                      nth_set_bit)
+from timewarp_tpu.ops.numeric import (I32MAX, expand_lanes, fill_holes,
+                                      free_bits, nth_set_bit)
 
 #: one word, its edges (31, 32, 33), two words, four (127), five (130)
 KS = (1, 8, 24, 31, 32, 33, 64, 127, 130)
@@ -157,3 +161,61 @@ def test_fill_holes_lowers_without_an_index():
         for op in ("gather", "scatter", "sort", "dynamic_slice"):
             assert f"stablehlo.{op}" not in text, (K, op)
         assert "stablehlo.select" in text
+
+
+# -- the lane-axis expansion -------------------------------------------------
+
+def _targets(fill, n, seed):
+    """The ascending targets of a compacted prefix, by case."""
+    rng = np.random.default_rng(seed)
+    if fill == "empty":
+        return np.zeros(0, np.int32)
+    if fill == "full":
+        return np.arange(n, dtype=np.int32)
+    if fill == "one-node":
+        # every arrival at one node: one lane of rank 0
+        return np.array([rng.integers(0, n)], np.int32)
+    if fill == "last-lanes":
+        return np.arange(n - n // 3, n, dtype=np.int32)
+    if fill == "first-lanes":
+        return np.arange(n // 3, dtype=np.int32)
+    # one arrival a node on average: 1 - 1/e of the lanes named
+    return np.unique(rng.integers(0, n, n)).astype(np.int32)
+
+
+@pytest.mark.parametrize("n", [64, 100, 257, 1024, 4096], ids="n{}".format)
+@pytest.mark.parametrize("fill", ["empty", "full", "one-node", "last-lanes",
+                                  "first-lanes", "random"])
+def test_expand_lanes_equals_a_scatter(fill, n):
+    """Lane ``j`` of the prefix lands at ``target[j]`` on every field,
+    each field's own "nothing" everywhere else; what the arrays hold
+    past the prefix is never read."""
+    t = _targets(fill, n, seed=n)
+    rng = np.random.default_rng(5000 + n)
+    target = rng.integers(0, n, n).astype(np.int32)     # junk past count
+    target[:t.size] = t
+    fields = [rng.integers(-2**31, 2**31, n).astype(np.int32)
+              for _ in range(3)]
+    nothing = (I32MAX, 0, -7)
+    got = jax.jit(lambda tg, c, f: expand_lanes(tg, c, f, nothing))(
+        target, np.int32(t.size), fields)
+    for g, x, e in zip(got, fields, nothing):
+        want = np.full(n, e, np.int32)
+        want[t] = x[:t.size]
+        assert g.dtype == np.int32 and np.array_equal(g, want), (
+            f"{fill} n={n}: {np.argwhere(np.asarray(g) != want)[:5].tolist()}")
+
+
+def test_expand_lanes_lowers_without_an_index():
+    """``bit_length(n - 1)`` stages of shifts and selects: no gather,
+    no scatter, no sort."""
+    n = 1000
+    lanes = jax.ShapeDtypeStruct((n,), np.int32)
+    text = jax.jit(lambda tg, c, a, b: expand_lanes(
+        tg, c, [a, b], (I32MAX, 0))).lower(
+            lanes, jax.ShapeDtypeStruct((), np.int32), lanes,
+            lanes).as_text()
+    for op in ("gather", "scatter", "sort", "dynamic_slice",
+               "dynamic_update_slice"):
+        assert f"stablehlo.{op}" not in text, op
+    assert "stablehlo.select" in text

@@ -39,6 +39,19 @@ holes included; and one call of the insertion on lanes built to hold
 every case of the overflow (ranks past K at one node, fewer holes
 than arrivals, none) against the same copy.
 
+Since PR 36 the staging itself has two forms, chosen by the call's
+shapes (``_stages_dense``: the lanes at least ``_DENSE_STAGE_RATIO`` of the
+nodes): a scatter a
+field, and one sort by staged index with rank 0 expanded on the node
+lanes and the tail scattered declared sorted. At these widths nearly
+every case above takes the second; ``parent_stage_by_rank`` is the
+first, kept here as the reference, and one call of ``_stage_by_rank``
+on built lanes is held to it word for word in both forms (skewed
+destinations, more than K at one node, invalid lanes, a tail over half
+the lanes, a slice clamped at the lanes' end, lanes under the
+threshold), as is a ladder whose first rung is under the threshold
+and whose others are over it.
+
 Then praos (``needs_key``, payload width 2, a lognormal link), the
 socket-state hub (1023 clients into one mailbox), a two-world faulted
 fleet (world b's slice against the solo oracle under
@@ -57,6 +70,7 @@ never the oracle at these widths).
 """
 
 import functools
+import math
 import os
 from typing import Any, NamedTuple
 
@@ -71,7 +85,8 @@ from timewarp_tpu.faults import (FaultFleet, FaultSchedule, NodeCrash,
                                  Partition)
 from timewarp_tpu.interp.jax_engine.batched import BatchSpec, world_slice
 from timewarp_tpu.interp.jax_engine.common import I32MAX, group_rank
-from timewarp_tpu.interp.jax_engine.engine import JaxEngine
+from timewarp_tpu.interp.jax_engine.engine import (_DENSE_STAGE_RATIO,
+                                                   JaxEngine)
 from timewarp_tpu.interp.ref.superstep import SuperstepOracle
 from timewarp_tpu.models.gossip import gossip
 from timewarp_tpu.models.praos import praos
@@ -313,7 +328,7 @@ class ParentInsert(JaxEngine):
 
     def _stage_by_rank(self, *lanes):
         return self._insert_sorted(*self._mailbox[:3], *lanes,
-                                   *self._mailbox[3:])
+                                   *self._mailbox[3:]) + (jnp.int32(0),)
 
     def _fill_staged(self, mb_rel, mb_src, mb_payload, holes, *inserted):
         return inserted
@@ -345,6 +360,17 @@ SLOT_CASES = {
                        (10, 6), False),
     "eager": (lambda: _steady(1024, 24), _STEADY_LINK, {}, (24, 24),
               False),
+    # 20 000 nodes, fanout 4: the ladder's first rung (1024 senders,
+    # 4096 lanes, under a quarter of the nodes) stages by scatters,
+    # the rung of 8192 senders in the dense form; delays inside one
+    # window, a generation every other superstep: those of 1, 4, ...
+    # 964 senders take the first rung, the next two (3284, 7347) the
+    # rungs of 4096 and 8192
+    "ladder-of-both-stagings": (
+        lambda: gossip(20_000, fanout=4, think_us=2_000, burst=True,
+                       end_us=1_000_000, mailbox_cap=24),
+        Quantize(UniformDelay(8_000, 9_000), 1_000),
+        {"window": "auto"}, (16, 4), False),
     "eager-overflows": (lambda: _steady(1000, 3), _STEADY_LINK, {},
                         (24, 24), True),
 }
@@ -381,6 +407,15 @@ def test_every_slot_is_the_one_the_parent_gave(case):
     assert ParentInsert.traced > before, "the parent's form never ran"
     assert (np.asarray(want.mb_rel) != I32MAX).sum() > sc.n_nodes
     assert (int(np.max(want.overflow)) > 0) == overflows
+    if case == "ladder-of-both-stagings":
+        eng.run_quiet(sum(horizons))
+        stats = eng.last_run_stats
+        dense = [eng._stages_dense(a * sc.max_out)
+                 for a in eng._sender_rungs(sc.n_nodes)]
+        assert not dense[0] and dense[3] and stats["rung_steps"][0] > 0
+        assert 0 < stats["dense_stage_steps"] == sum(
+            k for k, d in zip(stats["rung_steps"], dense) if d) \
+            < stats["supersteps"]
 
 
 # -- one call of the insertion, on lanes built for it ----------------------
@@ -442,6 +477,168 @@ def test_one_insertion_equals_the_parents(K, n, P, inbox_src):
         assert np.array_equal(x, y), name
     assert int(got[3]) == lost.sum() > 0
     assert (np.asarray(got[1]) == lanes[1]).all() == (not inbox_src)
+
+
+# -- the two forms of staging by rank ---------------------------------------
+
+def parent_stage_by_rank(self, sd, ok_s, drel_s, src_s, pay_s):
+    """``_stage_by_rank`` as it stood at 1ac92c9 (PR 32's form, the
+    one the program keeps where the lanes are few for the nodes): a
+    flat 1D scatter a field into fresh buffers, the indices as they
+    come."""
+    sc = self.scenario
+    K, P = sc.mailbox_cap, sc.payload_width
+    n = self.comm.n_local
+    rank = group_rank(sd)
+    fits = ok_s & (rank < K)
+    flat = jnp.where(fits, rank * jnp.int32(n) + sd,
+                     jnp.int32(K * n))
+
+    def stage(x, nothing):
+        return jnp.full((K * n,), nothing, x.dtype).at[flat].set(
+            x, mode="drop")
+    rel = stage(drel_s, I32MAX)
+    src = stage(src_s, 0) if sc.inbox_src else None
+    pay = tuple(stage(pay_s[p], 0) for p in range(P))
+    over = jnp.sum(ok_s & (rank >= K), dtype=jnp.int32)
+    return rel, src, pay, over
+
+
+def _destinations(case, n, K, rng):
+    """The valid lanes' destinations (unsorted) and the lane count."""
+    uniform = lambda m: rng.integers(0, n, m)
+    least = math.ceil(_DENSE_STAGE_RATIO * n)   # the dense form's lanes
+    if case == "uniform":
+        return uniform(n), n
+    if case == "skewed":
+        # the fourth power of a uniform draw: a few low nodes take
+        # most, node 0 a sixth of the lanes (far past K)
+        return (n * rng.random(n) ** 4).astype(np.int64), n
+    if case == "more-than-K-at-one-node":
+        return np.concatenate([np.full(K + 5, 7), uniform(n - K - 5)]), n
+    if case == "invalid-lanes":
+        return uniform(n // 2), 2 * n
+    if case == "wide-tail":
+        # three arrivals at every third node: two thirds are the tail
+        return np.repeat(np.arange(0, n, 3)[:n // 3], 3), n
+    if case == "rung-of-four-lanes-a-node":
+        return uniform(4 * n - 11), 4 * n
+    if case == "clamped-slice":
+        # one arrival at every node: no tail, and a slice at the
+        # rank-0 count would start at the lanes' end
+        return rng.permutation(n), n
+    if case == "nothing-valid":
+        return uniform(0), n
+    if case == "under-the-threshold":
+        return uniform(least // 2 - 3), least // 2
+    if case == "just-under-the-threshold":
+        return uniform(least - 1), least - 1
+    if case == "at-the-threshold":
+        return uniform(least), least
+    raise KeyError(case)
+
+
+#: case -> whether the dense form takes it, and its tail at full width
+STAGINGS = {
+    "uniform": (True, False), "skewed": (True, False),
+    "more-than-K-at-one-node": (True, False),
+    "invalid-lanes": (True, False), "wide-tail": (True, True),
+    "rung-of-four-lanes-a-node": (True, True),
+    "clamped-slice": (True, False), "nothing-valid": (True, False),
+    "under-the-threshold": (False, False),
+    "just-under-the-threshold": (False, False),
+    "at-the-threshold": (True, False),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _staging_engine(n, K, P, inbox_src):
+    import dataclasses
+    sc = dataclasses.replace(_burst(n, K), payload_width=P,
+                             inbox_src=inbox_src)
+    return JaxEngine(sc, _WAVE_LINK, window="auto", lint="off")
+
+
+def _staging_lanes(case, n, K, P):
+    rng = np.random.default_rng(len(case) * 1000 + n + P)
+    dst, L = _destinations(case, n, K, rng)
+    sd = np.concatenate([np.sort(dst), np.full(L - len(dst), n)]
+                        ).astype(np.int32)
+    i32 = lambda: rng.integers(-2**31, 2**31, L).astype(np.int32)
+    return (sd, sd < n, rng.integers(0, 10**6, L).astype(np.int32),
+            i32(), tuple(i32() for _ in range(P)))
+
+
+@pytest.mark.parametrize("inbox_src", [False, True], ids=["nosrc", "src"])
+@pytest.mark.parametrize("P", [1, 2], ids="P{}".format)
+@pytest.mark.parametrize("n", [1024, 1000], ids="n{}".format)
+@pytest.mark.parametrize("case", list(STAGINGS))
+def test_one_staging_equals_the_scatters(case, n, P, inbox_src):
+    """``_stage_by_rank`` on built lanes, in whichever form the lane
+    count selects, against a scatter a field: every staged buffer word
+    for word, ``over`` the same number, and ``wide`` what the lanes
+    say (the arrivals of rank 1 and over against half the lanes)."""
+    K = 24
+    eng = _staging_engine(n, K, P, inbox_src)
+    lanes = _staging_lanes(case, n, K, P)
+    sd, ok = lanes[0], lanes[1]
+    dense, wide = STAGINGS[case]
+    assert eng._stages_dense(len(sd)) == dense
+    rank = np.asarray(group_rank(jnp.asarray(sd)))
+    fits = ok & (rank < K)
+    tail = int((fits & (rank > 0)).sum())
+    assert (tail > len(sd) // 2) == wide
+    *got, got_wide = jax.jit(eng._stage_by_rank)(*lanes)
+    want = jax.jit(functools.partial(parent_stage_by_rank, eng))(*lanes)
+    assert int(got_wide) == (dense and wide)
+    assert (got[1] is None) == (not inbox_src) and len(got[2]) == P
+    for name, x, y in zip(("rel", "src", "pay", "over"), got, want):
+        for a, b in zip(jax.tree.leaves(x), jax.tree.leaves(y)):
+            assert np.array_equal(a, b), (case, name)
+    assert int(got[3]) == int((ok & (rank >= K)).sum())
+    assert (int(got[3]) > 0) == (
+        case in ("more-than-K-at-one-node", "skewed"))
+    assert int((np.asarray(got[0]) != I32MAX).sum()) == int(fits.sum())
+
+
+def _staging_text(fn, n, L, P):
+    lane = jax.ShapeDtypeStruct((L,), np.int32)
+    return jax.jit(fn).lower(
+        lane, jax.ShapeDtypeStruct((L,), bool), lane, lane,
+        (lane,) * P).as_text()
+
+
+def test_the_dense_form_declares_every_scatter_sorted():
+    """One sort, the program's own, and every scatter after it with
+    ``indices_are_sorted`` and ``unique_indices``: the flags are what
+    keep the compiler from sorting ``(indices, updates)`` again in
+    front of each scatter (docs/engines.md "Random delivery")."""
+    import re
+    n, P = 1024, 2
+    eng = _staging_engine(n, 24, P, True)
+    text = _staging_text(eng._stage_by_rank, n, 2 * n, P)
+    assert len(re.findall(r"stablehlo\.sort", text)) == 1
+    scatters = re.findall(r'"stablehlo\.scatter".*?<\{(.*?)\}>', text,
+                          flags=re.S)
+    # a field a branch of the tail's conditional
+    assert len(scatters) == 2 * (2 + P)
+    for attrs in scatters:
+        assert "indices_are_sorted = true" in attrs, attrs
+        assert "unique_indices = true" in attrs, attrs
+    assert "stablehlo.gather" not in text
+
+
+def test_under_the_threshold_staging_lowers_to_the_parents_text():
+    """Few lanes for the nodes: the scatters, as the parent lowered
+    them, operation for operation."""
+    n, P = 1024, 2
+    eng = _staging_engine(n, 24, P, True)
+    few = math.ceil(_DENSE_STAGE_RATIO * n) // 2
+    assert not eng._stages_dense(few)
+    text = _staging_text(lambda *a: eng._stage_by_rank(*a)[:4], n, few, P)
+    assert text == _staging_text(
+        lambda *a: parent_stage_by_rank(eng, *a), n, few, P)
+    assert "stablehlo.sort" not in text
 
 
 # ---------------------------------------------------------------------------

@@ -18,6 +18,14 @@ fills its holes from them elementwise (ops/numeric.py ``fill_holes``).
 The parent's one gather a hole word under that scope is gone, on the
 ladder and on the eager path; a fleet keeps it (``JaxEngine.
 _stages_by_rank`` says why); the sorts are what they were.
+
+Since PR 36 a solo engine's staging sorts once itself where its lanes
+are at least its nodes (``_stages_dense``: here both rungs, 8192 and
+16 384 lanes for 2048 nodes, and the eager path at full width): one
+variadic sort by staged index under ``tw.route/insert`` a rung, in the
+place of the sort the compiler put in front of every scatter (which no
+lowered text shows), and the scatters after it declared sorted, two
+branches of them (half the lanes, or all).
 """
 
 import re
@@ -75,7 +83,12 @@ def test_sorts_of_one_superstep(inbox, fleet):
     text = jax.jit(lambda st: eng._step_all(st, False)).lower(
         eng.init_state()).as_text()
     sorts = _sort_operands(text)
-    assert len(sorts) == parent - gone, sorts
+    # a solo commutative inbox stages densely in both rungs: one sort
+    # by staged index each (PR 36)
+    dense = 0 if fleet or inbox == "ordered" else sum(
+        eng._stages_dense(a * sc.max_out) for a in eng._sender_rungs(N))
+    assert dense == (2 if inbox == "commutative" and not fleet else 0)
+    assert len(sorts) == parent - gone + dense, sorts
     # a [K, N] operand (a fleet's: [B, K, N]) is a sort along the
     # mailbox's slots: none for a commutative inbox, the ordered
     # inbox's two as they were
@@ -135,12 +148,18 @@ def test_a_solo_insert_gathers_nothing(path, fleet):
     # the mailbox, behind the parent's one gather a hole word
     rungs = len(eng._sender_rungs(N)) if path == "ladder" else 1
     assert eng._stages_by_rank() == (not fleet)
-    assert len(under_insert("scatter")) == rungs * (1 + sc.payload_width)
+    # a solo engine's lanes are at least its nodes in every rung here
+    # and on the eager path: the dense staging, whose scatters come
+    # in two branches (half the lanes, or all) behind one sort a rung
+    dense = 0 if fleet else rungs
+    assert len(under_insert("scatter")) == \
+        (rungs + dense) * (1 + sc.payload_width)
     assert len(under_insert("gather")) == (rungs if fleet else 0)
-    assert under_insert("sort") == []
+    assert len(under_insert("sort")) == dense
     assert len(under_insert("popcnt")) >= 1     # the holes are counted
     # the ladder's own gathers (the rung's senders) are still found
     # by the same reading, so "none" above is no blind spot
     if path == "ladder":
         assert any("tw.route" in s for s in _scopes_of(text, "gather"))
-    assert len(_sort_operands(text)) == (3 if path == "ladder" else 1)
+    assert len(_sort_operands(text)) == \
+        (3 if path == "ladder" else 1) + dense

@@ -258,7 +258,8 @@ def test_last_run_stats_uniform_across_engines():
         st = eng.last_run_stats
         # the general engine counts its routing stage beside them
         # (ISSUE 35): one bin and the full width, the ring has no ladder
-        routed = {"rung_lanes", "sender_lanes", "rung_steps"} \
+        routed = {"rung_lanes", "sender_lanes", "rung_steps",
+                  "dense_stage_steps", "wide_tail_steps"} \
             if isinstance(eng, JaxEngine) else set()
         assert set(st) == _STATS_KEYS | routed
         if routed:

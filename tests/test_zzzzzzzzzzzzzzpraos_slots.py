@@ -208,17 +208,19 @@ def test_a_scenario_without_a_key_has_no_entropy_scope(make):
 
 
 #: sha256 of the quiet driver's lowering (``as_text()``: no names, no
-#: locations) at 2^11 nodes, as PR 35 lowers it (PR 34's loop, which
+#: locations) at 2^11 nodes, as PR 36 lowers it (PR 34's loop, which
 #: carries its successor's event horizon, ``tests/test_loop_edge.py``,
-#: with the routing stage's three counts in its carry:
-#: ``tests/test_zzzzzzzzzzzzzzzrecord.py``). Until then they were
-#: PR 34's (steady 80aa2481fbb3…, praos 07f9f2652961…). The wave's
-#: and the fleet's are pinned in
-#: ``test_zzzzzzzzzzzzzsteady_mongering.py``. A PR that changes what
-#: these drivers compute changes the constants, and says so.
+#: with the routing stage's five counts in its carry:
+#: ``tests/test_zzzzzzzzzzzzzzzrecord.py``; the arrivals staged in
+#: the dense form, steady's on every superstep, praos' in both rungs:
+#: ``tests/test_insert_law.py``). Until then they were PR 35's
+#: (steady 019784a05692…, praos 23c5c22aee01…). The wave's and the
+#: fleet's are pinned in ``test_zzzzzzzzzzzzzsteady_mongering.py``. A
+#: PR that changes what these drivers compute changes the constants,
+#: and says so.
 _PARENT_LOWERING = {
-    "steady": "019784a0569257a2db50f411b7b9bdc4e3b6fa6f0673d1c0a59d0bf4b95d4cec",
-    "praos": "23c5c22aee0179bfd41ae5b712b2bd341bbe93ff0ec4aeddb6b1dae63817e69f",
+    "steady": "56417b93abea2f6557bc4e61c016e268bd4f06eb38c10cf1cdf17b6840912764",
+    "praos": "02f9e0df7c2262e4962b04ff70ecff4066536d798255a552a3629b8c44282db3",
 }
 
 

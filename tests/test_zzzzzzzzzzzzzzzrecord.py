@@ -280,6 +280,52 @@ def test_routing_without_the_ladder_counts_its_full_width_in_one_bin():
         (10 * 512, 10 * 512, [10])
 
 
+@pytest.mark.parametrize("which", ["ladder", "eager", "burst", "fleet"])
+def test_the_staging_counts_say_which_form_ran(which):
+    """``dense_stage_steps``: the supersteps whose arrivals were staged
+    in the dense form (``engine.py`` ``_stage_by_rank``: the call's
+    lanes at least ``_DENSE_STAGE_RATIO`` of its nodes),
+    ``wide_tail_steps``: of those, the ones
+    whose ranks past 0 were over half the lanes. Carried and read like
+    the rungs' counts, by the scan and the quiet driver alike."""
+    if which == "burst":
+        # 64 nodes, fanout 16 in one firing, delays inside one window:
+        # the third generation sends some 750 messages on 1024 lanes,
+        # and at most 64 of a superstep's arrivals are a node's first
+        sc = gossip(64, fanout=16, think_us=2_000, burst=True,
+                    end_us=150_000, mailbox_cap=64)
+        link = Quantize(UniformDelay(8_000, 9_000), 1_000)
+        kw, steps = {"window": "auto"}, 12
+    else:
+        sc, link = _steady(8192 if which == "ladder" else N)
+        kw = {"ladder": {"window": "auto"}, "eager": {},
+              "fleet": {"window": "auto", "batch": FLEET}}[which]
+        steps = 40
+    eng = JaxEngine(sc, link, lint="off", **kw)
+    eng.run_quiet(steps)
+    st = dict(eng.last_run_stats)
+    dense, wide = st["dense_stage_steps"], st["wide_tail_steps"]
+    if which == "ladder":
+        # one slot a node: the rung of 1024 senders is 1024 lanes for
+        # 8192 nodes and keeps the scatters, the top rung is dense
+        rungs = eng._sender_rungs(8192)
+        assert rungs == [1024, 2048, 4096, 8192]
+        by_form = [eng._stages_dense(a) for a in rungs]
+        assert not by_form[0] and by_form[-1] and min(st["rung_steps"]) > 0
+        assert (dense, wide) == (sum(
+            k for k, d in zip(st["rung_steps"], by_form) if d), 0)
+    elif which == "eager":
+        assert (dense, wide) == (steps, 0)
+    elif which == "burst":
+        assert dense == st["supersteps"] and 0 < wide < dense
+    else:
+        assert not eng._stages_by_rank() and (dense, wide) == (0, 0)
+    assert profiler.calls()[-1]["counts"]["dense_stage_steps"] == dense
+    eng.run(steps)
+    assert (eng.last_run_stats["dense_stage_steps"],
+            eng.last_run_stats["wide_tail_steps"]) == (dense, wide)
+
+
 def test_sharded_engines_follow_their_local_twins():
     from timewarp_tpu.interp.jax_engine.sharded import (
         ShardedBatchedEngine, ShardedEngine)
@@ -302,6 +348,9 @@ def test_sharded_engines_follow_their_local_twins():
         drive(12)
         st = nodes.last_run_stats
         assert (st["rung_lanes"], st["rung_steps"]) == (12 * N, [12])
+        # a device stages what it was handed, on its own nodes: 2048
+        # lanes for 1024, the dense form
+        assert (st["dense_stage_steps"], st["wide_tail_steps"]) == (12, 0)
 
 
 def test_chunked_fleet_says_how_wide_it_routed():
@@ -317,7 +366,8 @@ def test_chunked_fleet_says_how_wide_it_routed():
         chunks.append(eng.last_run_stats)
     merged = eng._stats_merge(chunks)
     for key in ("rung_lanes", "sender_lanes", "rung_steps",
-                "fleet_iterations", "world_supersteps", "supersteps"):
+                "fleet_iterations", "world_supersteps", "supersteps",
+                "dense_stage_steps", "wide_tail_steps"):
         assert merged[key] == whole.last_run_stats[key], key
 
 
@@ -331,7 +381,8 @@ def test_run_summary_writes_the_calls_counts():
     reg.run_summary("fleet", eng.last_run_stats)
     line = reg.lines[-1]
     for key in ("dispatches", "readbacks", "rung_lanes", "sender_lanes",
-                "rung_steps", "fleet_iterations"):
+                "rung_steps", "fleet_iterations", "dense_stage_steps",
+                "wide_tail_steps"):
         assert line[key] == eng.last_run_stats[key]
     ring = EdgeEngine(*_ring(), lint="off")
     ring.run(4)
@@ -339,7 +390,8 @@ def test_run_summary_writes_the_calls_counts():
     assert "rung_lanes" not in reg.lines[-1]
     assert reg.lines[-1]["readbacks"] == 1
     for bad in ({"rung_lanes": 1.5}, {"rung_steps": [1, "2"]},
-                {"rung_steps": 3}, {"readbacks": True}):
+                {"rung_steps": 3}, {"readbacks": True},
+                {"wide_tail_steps": 0.5}):
         with pytest.raises(ValueError, match="run_summary"):
             validate_line({**line, **bad})
 

@@ -238,9 +238,9 @@ class _DriverCall:
         """The blocking read that ends the device's work, under
         ``tw.wait``: the step counters, the routing stage's counts the
         driver's loop carried beside the state (``counts``: a
-        ``(rung_lanes, sender_lanes, rung_steps)`` of device arrays,
-        ``engine.py`` ``RouteCounts``; None from an engine with no
-        ladder to count) and whatever else the driver reads back
+        ``(rung_lanes, sender_lanes, rung_steps, dense_stage_steps,
+        wide_tail_steps)`` of device arrays, ``engine.py``
+        ``RouteCounts``; None from an engine with no ladder to count) and whatever else the driver reads back
         (``more``, returned on the host) in one transfer. Sets
         ``last_run_stats``, the record's ``counts``."""
         self.readbacks += 1
@@ -261,15 +261,17 @@ class _DriverCall:
             stats.update(world_supersteps=d.tolist(),
                          fleet_iterations=int(d.max()))
         if counts is not None:
-            lanes, senders, by_rung = counts
             if d.ndim:
                 # one rung for all the worlds of a superstep: every
                 # world counted the same (a world-sharded fleet: the
                 # counts of the device whose rungs sum widest)
-                b = int(np.argmax(lanes))
-                lanes, senders, by_rung = lanes[b], senders[b], by_rung[b]
+                b = int(np.argmax(counts[0]))
+                counts = [c[b] for c in counts]
+            lanes, senders, by_rung, dense, wide = counts
             stats.update(rung_lanes=int(lanes), sender_lanes=int(senders),
-                         rung_steps=by_rung.tolist())
+                         rung_steps=by_rung.tolist(),
+                         dense_stage_steps=int(dense),
+                         wide_tail_steps=int(wide))
         return more
 
     def guard(self):
@@ -297,7 +299,10 @@ class RunStatsMixin:
         {"rung_lanes": int,    # the rung taken, in senders
          "sender_lanes": int,  # the active senders the rung was chosen
                                # for (a fleet: its busiest world's)
-         "rung_steps": [int] * R}  # iterations by rung index
+         "rung_steps": [int] * R,  # iterations by rung index
+         "dense_stage_steps": int,  # iterations that staged their
+                                    # arrivals in the dense form
+         "wide_tail_steps": int}    # of those, with a full-width tail
 
     and, for a fleet (``batch=BatchSpec``) only::
 
@@ -378,7 +383,8 @@ class RunStatsMixin:
             "per_chunk_compiles": [c["compiles"] for c in chunks],
         }
         for key in ("rung_lanes", "sender_lanes", "fleet_iterations",
-                    "rung_steps", "world_supersteps"):
+                    "rung_steps", "world_supersteps",
+                    "dense_stage_steps", "wide_tail_steps"):
             if chunks and all(key in c for c in chunks):
                 cols = [c[key] for c in chunks]
                 self.last_run_stats[key] = sum(cols) \

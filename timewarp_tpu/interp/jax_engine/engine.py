@@ -45,7 +45,7 @@ import numpy as np
 from ...core.rng import fire_bits, msg_bits, seed_words
 from ...core.scenario import NEVER, Inbox, Outbox, Scenario
 from ...net.delays import LinkModel
-from ...ops.numeric import fill_holes, free_bits, nth_set_bit
+from ...ops.numeric import expand_lanes, fill_holes, free_bits, nth_set_bit
 from ...trace.events import SuperstepTrace
 from ...trace.hashing import FIRED, RECV, SENT, mix32_jnp
 from .batched import BatchSpec, WorldIdentity, rebind_link
@@ -67,6 +67,24 @@ __all__ = ["JaxEngine", "EngineState", "Horizon", "RouteCounts",
 #: rung). Not ``ShardedBatchedEngine``'s mesh axis: a world-sharded
 #: fleet reduces over a device's own worlds only, never over the mesh
 _FLEET_AXIS = "tw_fleet"
+
+#: ``_stage_by_rank`` takes its dense form (one sort by staged index,
+#: rank 0 expanded on the node lanes, the tail scattered declared
+#: sorted) where its lanes are at least this share of its nodes: the
+#: network costs by the nodes, the scatters it replaces by the lanes.
+#: Measured on one v5e (profiling/stage_micro_r06.py; PR 36; the table
+#: is in docs/engines.md "Staging by rank, piece by piece"), us a call
+#: of two fields, a scatter a field against the dense form:
+#:
+#:     lanes / nodes        1        1/2       1/4       1/8
+#:     nodes 2^20     12 046/7 924 6 049/4 091 3 089/2 266 1 617/1 390
+#:     nodes 2^17      1 329/945    717/553    418/350    251/249
+#:
+#: The dense form wins by 16-34 % down to a quarter and is a wash at
+#: an eighth of 2^17 nodes. A dense rung of a ladder compiles one
+#: variadic sort more (20-30 s) and one compiler-made sort a field
+#: less (5-9 s each): the cells' cold compiles rose by 9-14 s.
+_DENSE_STAGE_RATIO = 0.25
 
 
 class EngineState(NamedTuple):
@@ -146,18 +164,26 @@ class RouteCounts(NamedTuple):
     """What the routing stage did, summed over the iterations of a
     driver's loop: every driver loop carries it beside the state and
     the call reads it in its one transfer (``last_run_stats``, the
-    call's record). All three come from the two scalars
+    call's record). The first three come from the two scalars
     ``_route_adaptive`` holds when it picks its branch (the active
-    senders and the rung's index): no pass over node- or mailbox-sized
-    data is made for them. Where routing runs without the ladder every
-    iteration counts the full width, in one bin. A fleet's leaves lead
-    with the world axis like every state leaf (one rung for all the
-    worlds of a superstep: every world of a device counts the same)."""
+    senders and the rung's index), the last two from the one scalar
+    ``_stage_by_rank``'s dense form picks its tail's width by: no
+    pass over node- or mailbox-sized data is made for them. Where
+    routing runs without the ladder every iteration counts the full
+    width, in one bin. A fleet's leaves lead with the world axis like
+    every state leaf (one rung for all the worlds of a superstep:
+    every world of a device counts the same)."""
     rung_lanes: jax.Array     # int64[] — the rung taken, in senders
     #: int64[] — the active senders the rung was chosen for (a fleet:
     #: its busiest world's)
     sender_lanes: jax.Array
     rung_steps: jax.Array     # int32[R] — iterations by rung index
+    #: int64[] — iterations whose arrivals were staged in the dense
+    #: form (``_stage_by_rank``; a fleet's: none)
+    dense_stage_steps: jax.Array
+    #: int64[] — of those, the ones whose tail (ranks past 0) was over
+    #: half the lanes and went through the full-width scatter
+    wide_tail_steps: jax.Array
 
 
 class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
@@ -360,6 +386,11 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         if scenario.n_nodes * scenario.max_out >= 2**31:
             raise ValueError(
                 "n_nodes * max_out must fit int32 (sender-major rank)")
+        if scenario.n_nodes * (scenario.mailbox_cap
+                               + scenario.max_out) >= 2**31:
+            raise ValueError(
+                "n_nodes * (mailbox_cap + max_out) must fit int32 (a "
+                "lane's staged index, `_stage_dense`)")
         if record_events < 0:
             raise ValueError("record_events must be >= 0")
         self.batch = batch
@@ -704,25 +735,43 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
                            jnp.int64(_I32MAX - 1)).astype(jnp.int32)
         return flight, drel, bad, short, strag
 
+    def _stages_dense(self, lanes: int) -> bool:
+        """Whether ``_stage_by_rank`` takes its dense form for a call
+        of ``lanes`` message lanes: a fact of the call's shapes
+        (``_DENSE_STAGE_RATIO``), so a ladder's wide rungs and the
+        eager path take it and the narrow rungs keep the scatters."""
+        return lanes >= _DENSE_STAGE_RATIO * self.comm.n_local
+
     def _stage_by_rank(self, sd, ok_s, drel_s, src_s, pay_s):
         """A commutative inbox's insertion, the message lanes' half:
         the r-th arrival at node d this superstep (``group_rank`` of
         the sorted destinations) goes to ``r * n + d`` of a fresh flat
-        buffer of ``K * n`` words a field, by one 1D scatter each (the
-        2D [col, row] form costs ~7x on this chip, docs/engines.md
-        per-op cost table). Nothing of the node side is read here:
-        which slot the r-th arrival takes only the node needs to know
-        (``_fill_staged``). A deliver time's "nothing" is the hole's
-        own ``_I32MAX`` (a sampled one is clamped under it). Returns
-        ``(rel, src or None, payload words, over)``; ``over`` counts
-        the arrivals past the K-th at one node, which no mailbox can
-        hold. Lanes that do not fit get an out-of-range index and are
-        dropped."""
+        buffer of ``K * n`` words a field. Nothing of the node side is
+        read here: which slot the r-th arrival takes only the node
+        needs to know (``_fill_staged``). A deliver time's "nothing"
+        is the hole's own ``_I32MAX`` (a sampled one is clamped under
+        it). Returns ``(rel, src or None, payload words, over,
+        wide)``; ``over`` counts the arrivals past the K-th at one
+        node, which no mailbox can hold; ``wide`` is 1 where the dense
+        form's tail went at full width.
+
+        Two forms, one result, chosen by the call's shapes
+        (``_stages_dense``). Few lanes for the nodes: one 1D scatter
+        a field (the 2D [col, row] form costs ~7x on this chip,
+        docs/engines.md per-op cost table); lanes that do not fit get
+        an out-of-range index and are dropped. The compiler sorts
+        ``(indices, updates)`` in front of every such scatter, the
+        same indices once a field. So where the lanes are many
+        (``_stage_dense``) the program sorts once, by the staged
+        index, and that order buys the rest."""
         sc = self.scenario
         K, P = sc.mailbox_cap, sc.payload_width
         n = self.comm.n_local
         rank = group_rank(sd)
         fits = ok_s & (rank < K)
+        if self._stages_dense(sd.shape[0]):
+            return self._stage_dense(sd, ok_s, rank, fits, drel_s,
+                                     src_s, pay_s)
         flat = jnp.where(fits, rank * jnp.int32(n) + sd,
                          jnp.int32(K * n))
 
@@ -737,7 +786,69 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         src = stage(src_s, 0) if sc.inbox_src else None
         pay = tuple(stage(pay_s[p], 0) for p in range(P))
         over = jnp.sum(ok_s & (rank >= K), dtype=jnp.int32)
-        return rel, src, pay, over
+        return rel, src, pay, over, jnp.int32(0)
+
+    def _stage_dense(self, sd, ok_s, rank, fits, drel_s, src_s, pay_s):
+        """``_stage_by_rank`` where the lanes are many for the nodes.
+        One variadic sort by the staged index ``rank * n + d`` (lanes
+        that do not fit: distinct indices past ``K * n``) carries
+        every field, and after it:
+
+        - the rank-0 arrivals (in a steady superstep 1 - 1/e of the
+          nodes get one) stand first, compacted, ascending in
+          destination: row 0 is their monotone expansion over the
+          node lanes (ops/numeric.py ``expand_lanes``), with no index
+          at all;
+        - the ranks past 0 follow from lane ``c0`` on, already in
+          scatter order: where they are at most half the lanes (one
+          scalar, one ``lax.cond``) a ``dynamic_slice`` of half the
+          lanes is scattered, else all of them, declared sorted and
+          unique, both true by the sort, so the compiler puts no sort
+          of its own in front. A slice clamped at the lanes' end
+          takes rank-0 lanes in with it; row 0 is written over them.
+
+        Word for word the buffers of the other form
+        (tests/test_insert_law.py)."""
+        sc = self.scenario
+        K, P = sc.mailbox_cap, sc.payload_width
+        n = self.comm.n_local
+        L = sd.shape[0]
+        half = L // 2
+        fields = (drel_s,) + ((src_s,) if sc.inbox_src else ()) \
+            + tuple(pay_s[:P])
+        nothing = (_I32MAX,) + (0,) * (len(fields) - 1)
+        flat = jnp.where(
+            fits, rank * jnp.int32(n) + sd,
+            jnp.int32(K * n) + jnp.arange(L, dtype=jnp.int32))
+        c0 = jnp.sum(fits & (rank == 0), dtype=jnp.int32)
+        wide = jnp.sum(fits, dtype=jnp.int32) - c0 > half
+        flat, *fields = jax.lax.sort((flat,) + fields, num_keys=1)
+
+        def head(x):
+            # the first n lanes: the rank-0 prefix is among them
+            if L >= n:
+                return x[:n]
+            return jnp.concatenate([x, jnp.zeros((n - L,), x.dtype)])
+        row0 = expand_lanes(head(flat), c0, [head(x) for x in fields],
+                            nothing)
+
+        def tail(width):
+            def scatter():
+                at = jax.lax.dynamic_slice_in_dim(flat, c0, width)
+                return tuple(
+                    jnp.full((K * n,), e, x.dtype).at[at].set(
+                        jax.lax.dynamic_slice_in_dim(x, c0, width),
+                        mode="drop", indices_are_sorted=True,
+                        unique_indices=True)
+                    for x, e in zip(fields, nothing))
+            return scatter
+        bufs = jax.lax.cond(wide, tail(L), tail(half))
+        bufs = [jax.lax.dynamic_update_slice_in_dim(b, r, 0, 0)
+                for b, r in zip(bufs, row0)]
+        over = jnp.sum(ok_s & (rank >= K), dtype=jnp.int32)
+        return (bufs[0], bufs[1] if sc.inbox_src else None,
+                tuple(bufs[len(bufs) - P:]), over,
+                wide.astype(jnp.int32))
 
     def _fill_staged(self, mb_rel, mb_src, mb_payload, holes, rel, src,
                      pay, over):
@@ -821,9 +932,12 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         K, P = sc.mailbox_cap, sc.payload_width
         n = self.comm.n_local
         if self._stages_by_rank():
-            return self._fill_staged(
-                mb_rel, mb_src, mb_payload, holes,
-                *self._stage_by_rank(sd, ok_s, drel_s, src_s, pay_s))
+            *staged, wide = self._stage_by_rank(sd, ok_s, drel_s, src_s,
+                                                pay_s)
+            self._staged = (jnp.int32(self._stages_dense(sd.shape[0])),
+                            self.comm.all_max(wide))
+            return self._fill_staged(mb_rel, mb_src, mb_payload, holes,
+                                     *staged)
         rank = group_rank(sd)
         if sc.commutative_inbox:
             # r-th incoming message takes the destination's r-th hole:
@@ -920,12 +1034,17 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
                 return self._stage_by_rank(sd, ok_s, drel_s, src_s,
                                            pay_s)
 
-        def filled(ret):
+        def filled(ret, dense):
+            # `ret` is the taken rung's return, `dense` whether that
+            # rung staged in the dense form: static a rung, so it
+            # needs no place in the switch's return, as `wide`
+            # (ret[4]) does
             if not staged:
                 return ret
+            self._staged = (jnp.asarray(dense, jnp.int32), ret[4])
             with jax.named_scope("insert"):
                 return self._fill_staged(mb_rel, mb_src, mb_payload,
-                                         holes, *ret[:4]) + ret[4:]
+                                         holes, *ret[:4]) + ret[5:]
 
         def tail(A):
             def gather(A):
@@ -993,11 +1112,10 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
                     pay_s = ops[3:]
                 ok_s = sd < n
                 src_s = smrank_s // jnp.int32(M)
-                mrel, msrc, mpay, overflow_step = insert(
-                    sd, ok_s, drel_s, src_s, pay_s)
-                ret = (mrel, msrc, mpay, overflow_step, bad_dst_step,
-                       bad_delay_step, short_step, jnp.int32(0),
-                       sent_count, sent_hash, fault_cut + fault_down)
+                ret = insert(sd, ok_s, drel_s, src_s, pay_s) + (
+                    bad_dst_step, bad_delay_step, short_step,
+                    jnp.int32(0), sent_count, sent_hash,
+                    fault_cut + fault_down)
                 if strag is not None:
                     # the causality plane's straggler min rides the
                     # switch return like the send capture below (the
@@ -1040,8 +1158,7 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
                     self._sample_nodrop(src_s, sd, tmsg_s,
                                         smrank_s % jnp.int32(M),
                                         woff_s, ok_s)
-                mrel, msrc, mpay, overflow_step = insert(
-                    sd, ok_s, drel_s, src_s, pay_s)
+                inserted = insert(sd, ok_s, drel_s, src_s, pay_s)
                 sent_count = jnp.sum(ok, dtype=jnp.int32)
                 if with_trace:
                     dt_abs = tmsg_s + flight_s
@@ -1054,9 +1171,9 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
                 # slot keeps this return the shape of the legacy
                 # paths' (where route_cap can drop), for the one
                 # unpacking in _staged_superstep
-                ret = (mrel, msrc, mpay, overflow_step, bad_dst_step,
-                       bad_delay_step, short_step, jnp.int32(0),
-                       sent_count, sent_hash)
+                ret = inserted + (bad_dst_step, bad_delay_step,
+                                  short_step, jnp.int32(0), sent_count,
+                                  sent_hash)
                 if strag is not None:
                     ret += (strag,)
                 if rec_full:
@@ -1070,7 +1187,8 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         if len(rungs) == 1:
             if self.telemetry != "off":
                 self._t_rung = jnp.int32(rungs[-1])
-            return filled(tail(rungs[-1])())
+            return filled(tail(rungs[-1])(),
+                          self._stages_dense(rungs[-1] * M))
         if self.batch is not None:
             # a fleet takes ONE rung for all its worlds: the smallest
             # that holds the busiest world's senders. The pmax over
@@ -1096,7 +1214,9 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         # (``RouteCounts``, ``_count_route``) can never drift from it
         self._t_rung = jnp.asarray(rungs, jnp.int32)[idx]
         self._routed = (self._t_rung, n_active, idx.astype(jnp.int32))
-        return filled(jax.lax.switch(idx, [tail(A) for A in rungs]))
+        return filled(
+            jax.lax.switch(idx, [tail(A) for A in rungs]),
+            jnp.asarray([self._stages_dense(A * M) for A in rungs])[idx])
 
     def _node_next(self, st: EngineState, nnr=None) -> jax.Array:
         """Each node's next event time as the state alone has it
@@ -1432,6 +1552,10 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         #: rung counts its full width; ``_route_adaptive`` puts its
         #: own where it takes one of several
         self._routed = (jnp.int32(n_glob), jnp.int32(n_glob), jnp.int32(0))
+        #: and whether the arrivals were staged in the dense form, and
+        #: its tail went wide: the staged insertion puts its own
+        #: (``_insert_sorted``; on the ladder ``_route_adaptive``)
+        self._staged = (jnp.int32(0), jnp.int32(0))
         if adaptive:
             res = self._route_adaptive(
                 out, out_valid, now_vec, t, mb_rel, mb_src,
@@ -1924,12 +2048,13 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         implementation, solo or fleet). A stage's scope entered under
         ``vmap`` reads ``vmap(tw.route)`` in an operation's
         ``op_name`` (docs/observability.md). What routing did in
-        every world is left on ``self._routed`` (three int32[B], each
-        one value B times) for the drivers' counts, as a solo
-        superstep leaves its scalars there."""
+        every world is left on ``self._routed`` and ``self._staged``
+        (int32[B] each, one value B times) for the drivers' counts, as
+        a solo superstep leaves its scalars there."""
         def world(*a):
-            return step(*a), self._routed
-        out, self._routed = self._each_world(world, ctx, *args)
+            return step(*a), (self._routed, self._staged)
+        out, (self._routed, self._staged) = self._each_world(
+            world, ctx, *args)
         return out
 
     def _identity(self) -> Optional[WorldIdentity]:
@@ -2058,7 +2183,8 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         n = self.comm.n_local
         bins = len(self._sender_rungs(n)) if self._adaptive_regime() else 1
         return st, RouteCounts(
-            lanes, lanes, jnp.zeros(lanes.shape + (bins,), jnp.int32))
+            lanes, lanes, jnp.zeros(lanes.shape + (bins,), jnp.int32),
+            lanes, lanes)
 
     def _count_route(self, counts: RouteCounts, stepped=True
                      ) -> RouteCounts:
@@ -2069,13 +2195,16 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         traced bool where the caller's loop runs on past the last
         event)."""
         rung, senders, idx = self._routed
+        dense, wide = self._staged
         bins = counts.rung_steps.shape[-1]
         one = (idx[..., None] == jnp.arange(bins, dtype=jnp.int32)
                ) & stepped
         return RouteCounts(
             counts.rung_lanes + jnp.where(stepped, rung, 0),
             counts.sender_lanes + jnp.where(stepped, senders, 0),
-            counts.rung_steps + one.astype(jnp.int32))
+            counts.rung_steps + one.astype(jnp.int32),
+            counts.dense_stage_steps + jnp.where(stepped, dense, 0),
+            counts.wide_tail_steps + jnp.where(stepped, wide, 0))
 
     def _step_counted(self, carry, with_trace: bool):
         """``_step_all`` on a driver loop's ``(state, counts)`` carry.

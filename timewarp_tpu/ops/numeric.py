@@ -15,7 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 
 __all__ = ["I32MAX", "group_rank", "free_bits", "nth_set_bit",
-           "fill_holes", "u32sum", "tlo", "thi"]
+           "fill_holes", "expand_lanes", "u32sum", "tlo", "thi"]
 
 I32MAX = np.int32(2**31 - 1)
 
@@ -176,6 +176,52 @@ def fill_holes(words, staged, old, nothing):
     got = [_bit(words, k) & (planes[0][k] != nothing) for k in range(K)]
     return [[jnp.where(g, x, o) for g, x, o in zip(got, rows, olds)]
             for rows, olds in zip(planes, old)]
+
+
+#: :func:`expand_lanes`' mark of an empty lane: the sign bit alone, so
+#: no stage's shift bit is set in it and an empty lane never moves
+_NO_LANE = np.int32(-2**31)
+
+
+def expand_lanes(target, count, fields, nothing):
+    """Spread a compacted, ascending prefix over the lanes it names:
+    lane ``j < count`` of every field goes to lane ``target[j]`` of
+    the result, a dense array of ``n = target.shape[0]`` lanes a
+    field, ``nothing[f]`` wherever no lane went. ``target`` must
+    ascend strictly over the prefix, inside ``[0, n)`` (so
+    ``target[j] >= j``); what it holds past ``count`` is not read.
+    ``fields`` is a sequence of ``[n]`` arrays, ``nothing`` their fill
+    values. Returns the fields as a list.
+
+    :func:`fill_holes`' expand (Hacker's Delight 7-5) along the lane
+    axis: a monotone expansion needs no index. Every lane carries its
+    remaining displacement ``target[j] - j``; stage ``i`` raises by
+    ``2^i`` the lanes whose displacement has bit ``i`` set, the
+    largest ``i`` first, so after the stages down to ``i`` a lane
+    stands at ``target - (displacement mod 2^i)``: these are the
+    states of the compress network run backwards, in which no two
+    lanes ever meet. A stage is one shift of each array by a static
+    distance and two selects: ``bit_length(n - 1)`` elementwise
+    passes, no gather, no scatter, no sort (tests/test_free_bits.py
+    holds it to a scatter)."""
+    n = target.shape[0]
+    lane = jnp.arange(n, dtype=jnp.int32)
+    disp = jnp.where(lane < count, target.astype(jnp.int32) - lane,
+                     _NO_LANE)
+    fields = list(fields)
+
+    def raised(x, s, fill):
+        return jnp.concatenate(
+            [jnp.full((s,), fill, x.dtype), x[:n - s]])
+    for i in reversed(range((n - 1).bit_length())):
+        s = 1 << i
+        below = raised(disp, s, _NO_LANE)
+        comes = (below & jnp.int32(s)) != 0
+        stays = (disp & jnp.int32(s)) == 0
+        fields = [jnp.where(comes, raised(x, s, 0), x) for x in fields]
+        disp = jnp.where(comes, below, jnp.where(stays, disp, _NO_LANE))
+    return [jnp.where(disp == _NO_LANE, jnp.asarray(e, x.dtype), x)
+            for x, e in zip(fields, nothing)]
 
 
 def u32sum(x: jax.Array) -> jax.Array:
