@@ -234,19 +234,23 @@ class _DriverCall:
         with span("tw.dispatch", run=self.run):
             return fn(*args)
 
-    def wait(self, steps_before, steps_after, *more, counts=None):
+    def wait(self, steps_before, steps_after, *more, counts=None,
+             crossed=None):
         """The blocking read that ends the device's work, under
         ``tw.wait``: the step counters, the routing stage's counts the
         driver's loop carried beside the state (``counts``: a
         ``(rung_lanes, sender_lanes, rung_steps, dense_stage_steps,
         wide_tail_steps)`` of device arrays, ``engine.py``
-        ``RouteCounts``; None from an engine with no ladder to count) and whatever else the driver reads back
-        (``more``, returned on the host) in one transfer. Sets
+        ``RouteCounts``; None from an engine with no ladder to count),
+        a node-sharded edge engine's boundary messages (``crossed``:
+        one count a shard, ``sharded.py`` ``ShardedEdgeEngine``; None
+        from every other engine) and whatever else the driver reads
+        back (``more``, returned on the host) in one transfer. Sets
         ``last_run_stats``, the record's ``counts``."""
         self.readbacks += 1
         with span("tw.wait", run=self.run):
-            before, after, counts, *more = jax.device_get(
-                (steps_before, steps_after, counts) + more)
+            before, after, counts, crossed, *more = jax.device_get(
+                (steps_before, steps_after, counts, crossed) + more)
         d = np.asarray(after, np.int64) - np.asarray(before, np.int64)
         stats = self.record["counts"] = self.eng.last_run_stats = {
             "supersteps": int(d.sum()),
@@ -272,6 +276,10 @@ class _DriverCall:
                          rung_steps=by_rung.tolist(),
                          dense_stage_steps=int(dense),
                          wide_tail_steps=int(wide))
+        if crossed is not None:
+            # counted on each shard beside its state, summed here
+            stats.update(shards=len(crossed),
+                         boundary_msgs=int(crossed.sum()))
         return more
 
     def guard(self):
@@ -303,6 +311,13 @@ class RunStatsMixin:
          "dense_stage_steps": int,  # iterations that staged their
                                     # arrivals in the dense form
          "wide_tail_steps": int}    # of those, with a full-width tail
+
+    for the node-sharded edge engine (``ShardedEdgeEngine``)::
+
+        {"shards": int,         # the mesh axis' size
+         "boundary_msgs": int}  # messages delivered by the call whose
+                                # sender lives on another shard (the
+                                # dense ring: one a shard a superstep)
 
     and, for a fleet (``batch=BatchSpec``) only::
 
@@ -382,9 +397,12 @@ class RunStatsMixin:
             "chunks": len(chunks),
             "per_chunk_compiles": [c["compiles"] for c in chunks],
         }
+        if chunks and all("shards" in c for c in chunks):
+            self.last_run_stats["shards"] = chunks[0]["shards"]
         for key in ("rung_lanes", "sender_lanes", "fleet_iterations",
                     "rung_steps", "world_supersteps",
-                    "dense_stage_steps", "wide_tail_steps"):
+                    "dense_stage_steps", "wide_tail_steps",
+                    "boundary_msgs"):
             if chunks and all(key in c for c in chunks):
                 cols = [c[key] for c in chunks]
                 self.last_run_stats[key] = sum(cols) \

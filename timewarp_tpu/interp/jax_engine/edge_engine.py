@@ -58,7 +58,7 @@ from ...net.delays import LinkModel
 from ...trace.events import SuperstepTrace
 from ...trace.hashing import FIRED, RECV, SENT, mix32_jnp
 from .common import I32MAX as _I32MAX
-from .common import LocalComm, RunStatsMixin, StepOut as _StepOut
+from .common import LocalComm, RunStatsMixin, Stages, StepOut as _StepOut
 from .common import padded_scan, scan_pad
 from .controlled import ControlledRunMixin
 from .common import thi as _thi, tlo as _tlo, u32sum as _u32sum
@@ -308,6 +308,17 @@ class EdgeEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
 
     def _superstep(self, st: EdgeState, with_trace: bool
                    ) -> Tuple[EdgeState, Optional[_StepOut]]:
+        with Stages() as stage:
+            return self._staged_superstep(st, with_trace, stage)
+
+    def _staged_superstep(self, st, with_trace, stage):
+        """One superstep of ``st``, each numbered part under the scope
+        ``stage`` names for it (common.py ``STAGES``, as
+        ``JaxEngine._staged_superstep`` has them); the delivery's
+        ``comm.roll`` calls sit under ``tw.route/exchange``, so that a
+        profile of a sharded run tells the boundary hop from the rest
+        of the stage."""
+        stage("tw.next_event")
         sc, topo, comm = self.scenario, self.topo, self.comm
         E, C, P = topo.n_edges, self.cap, sc.payload_width
         n = comm.n_local            # array width on this device
@@ -384,6 +395,7 @@ class EdgeEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
                     node_ids, node_ids, jnp.int64(-1), now_vec,
                     _flight.TAG_RESTART))
 
+        stage("tw.deliver")
         # 2. deliverable messages (all per-edge slots due at fired nodes)
         shift32 = jnp.minimum(t - base,
                               jnp.int64(_I32MAX - 1)).astype(jnp.int32)
@@ -407,6 +419,8 @@ class EdgeEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         isrc = jnp.broadcast_to(
             src_rows[:, None, :], (E, C, n)).reshape(W, n)
         ipay = st.q_pay.reshape(W, P, n)
+        # what a sharded driver counts beside the state (None here)
+        self._crossed = self._remote_deliveries(deliver, src_rows)
         if rec_full and purge is not None:
             # purged queue entries (reboot memory loss), now that the
             # per-edge sender ids exist — src/deliver-time identify
@@ -453,6 +467,7 @@ class EdgeEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
             payload=jnp.where(iv[:, None, :], ipay, 0),
         )
 
+        stage("tw.fire")
         # 4. fire every node; batch axis is the *minor* dim for inbox and
         #    outbox leaves (no [N, small] padding anywhere)
         bits = fire_bits(self.s0, self.s1, node_ids, t) \
@@ -488,6 +503,7 @@ class EdgeEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         misrouted_step = jnp.sum(
             out_valid & declared & (out.dst != sd_local), dtype=jnp.int32)
 
+        stage("tw.rebase")
         # 5. rebase surviving queue entries to the new epoch t
         keep = q_live & ~deliver
         if purge is not None:
@@ -496,6 +512,7 @@ class EdgeEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         q_step = st.q_step
         q_pay = st.q_pay
 
+        stage("tw.route")
         # 6-7. route + enqueue, one static in-edge at a time — gathers
         # only on non-shift edges, never a scatter
         step32 = st.steps.astype(jnp.int32)
@@ -507,8 +524,9 @@ class EdgeEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
             sh = topo.shift[e]
             if sh is not None:
                 s, slot = sh
-                arr_v = comm.roll(out_valid[slot], s)
-                arr_p = comm.roll(out_pay[slot], s)          # [P, N]
+                with jax.named_scope("exchange"):
+                    arr_v = comm.roll(out_valid[slot], s)
+                    arr_p = comm.roll(out_pay[slot], s)      # [P, N]
                 slot_e = jnp.int32(slot)
             else:
                 flat_idx = jnp.asarray(topo.in_flat[e])
@@ -578,6 +596,7 @@ class EdgeEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
             overflow_step = overflow_step + jnp.sum(
                 ok & (ff == C), dtype=jnp.int32)
 
+        stage("tw.finish")
         recv_count = comm.all_sum(jnp.sum(deliver, dtype=jnp.int32))
         overflow_step = comm.all_sum(overflow_step)
         new_st = EdgeState(
@@ -723,6 +742,19 @@ class EdgeEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         (controlled.py) test it between chunks."""
         return self._next_event(state) < NEVER
 
+    def _remote_deliveries(self, deliver, src_rows):
+        """Messages delivered this superstep whose sender lives on
+        another shard (``deliver`` bool [E, C, n]; ``src_rows`` int32
+        [E, n], the sender on each in-edge). One device has no other
+        shard and counts nothing: ``ShardedEdgeEngine`` overrides."""
+        return None
+
+    def _settled(self, carry):
+        """``(state, crossed)`` of what a driver returned of its
+        loop's carry: here the state alone. A sharded driver's carry
+        holds each shard's count of boundary messages beside it."""
+        return carry, None
+
     def _step_all(self, st, with_trace: bool):
         """One driver step (the ShardedDriver/scan hook — the edge
         engine has no world axis, so this is always the solo step)."""
@@ -768,10 +800,11 @@ class EdgeEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         # (integrity/runner.py) — a distinct executable, same results
         with self._driver_call("run") as call:
             st = state if state is not None else self.init_state()
-            final, ys = call.dispatch(
+            carry, ys = call.dispatch(
                 self._run_scan, st, scan_pad(max_steps) * self._pad_mult,
                 jnp.asarray(max_steps, jnp.int64))
-            ys, = call.wait(st.steps, final.steps, ys)
+            final, crossed = self._settled(carry)
+            ys, = call.wait(st.steps, final.steps, ys, crossed=crossed)
         self._capture_flight(ys, st)
         self._capture_integrity(ys)
         self.last_run_telemetry = None
@@ -805,8 +838,9 @@ class EdgeEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         telemetry planes not even compiled in."""
         with self._driver_call("run_quiet") as call:
             st = state if state is not None else self.init_state()
-            final = call.dispatch(self._run_while, st, max_steps)
-            call.wait(st.steps, final.steps)
+            final, crossed = self._settled(
+                call.dispatch(self._run_while, st, max_steps))
+            call.wait(st.steps, final.steps, crossed=crossed)
             if self.verify != "off":
                 # never silently unverified (JaxEngine.run_quiet twin)
                 from ...integrity.checks import final_state_guard

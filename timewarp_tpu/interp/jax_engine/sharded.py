@@ -82,7 +82,43 @@ class ShardedEdgeEngine(ShardedDriver, EdgeEngine):
         D = axis_size(mesh, axis)
         self.comm = MeshComm(axis, scenario.n_nodes, D)
 
+    # -- the boundary count, beside the state ----------------------------
+
+    def _remote_deliveries(self, deliver, src_rows):
+        # a sum on this shard alone: no collective joins the superstep
+        here = jax.lax.axis_index(self.axis).astype(jnp.int32)
+        remote = src_rows // jnp.int32(self.comm.n_local) != here
+        return jnp.sum(deliver & remote[:, None, :], dtype=jnp.int32)
+
+    def _counted(self, st):
+        """What a driver's loop carries: this shard's state and,
+        beside it, its count of boundary messages from zero, one row
+        of the ``[shards]`` the call reads back and sums
+        (``last_run_stats`` ``boundary_msgs``)."""
+        return st, jnp.zeros((1,), jnp.int64)
+
+    def _step_counted(self, carry, with_trace: bool):
+        st, crossed = carry
+        new, y = self._step_all(st, with_trace)
+        return (new, crossed + self._crossed.astype(jnp.int64)), y
+
+    def _settled(self, carry):
+        return carry
+
+    def _quiet_loop(self, st, max_steps):
+        """The quiet driver's ``while`` on this device's shard: the
+        local engine's condition (the next event, agreed over the
+        mesh) on the counted carry."""
+        cond = self._while_cond_fn(st.steps, max_steps)
+        return jax.lax.while_loop(
+            lambda carry: cond(carry[0]),
+            lambda carry: self._step_counted(carry, False)[0],
+            self._counted(st))
+
     # -- sharding specs --------------------------------------------------
+
+    def _carry_specs(self, st, specs):
+        return specs, P(self.axis)
 
     def _state_specs(self, st: EdgeState) -> EdgeState:
         leaf = self._leaf_spec

@@ -147,9 +147,13 @@ class MeshComm(LocalComm):
 class ShardedDriver:
     """Shared ``shard_map`` driver for the sharded engines. The
     concrete engine supplies ``_state_specs`` (its state's
-    PartitionSpecs, built from :meth:`_leaf_spec`), ``_superstep``, and
+    PartitionSpecs, built from :meth:`_leaf_spec`), ``_superstep``,
     ``_next_event`` (the quiescence expression, inherited from its
-    local base class)."""
+    local base class), the quiet driver's ``while`` on one device's
+    shard (``_quiet_loop``: ``JaxEngine``'s, whose horizon is reduced
+    over the mesh where it is produced, or ``ShardedEdgeEngine``'s
+    own) and what the loops carry beside the state (``_counted``,
+    ``_step_counted``)."""
 
     def _leaf_spec(self, x, last_axis: bool) -> P:
         """PartitionSpec for one state leaf: the node axis (leading or
@@ -164,10 +168,18 @@ class ShardedDriver:
         return P(ax, *([None] * (nd - 1)))
 
     def init_state(self):
+        """The local engine's initial state, each leaf placed by its
+        spec: where a driver returns it, so that a run streamed in
+        calls (each on the state the last returned) is one compiled
+        program from the first call on. A leaf with no entries (the
+        edge engine's ``q_step`` under a commutative inbox) comes back
+        from a driver replicated whatever its spec says, and a state
+        that went in otherwise would compile the second call anew."""
         st = super().init_state()
         specs = self._state_specs(st)
         return jax.tree.map(
-            lambda x, s: jax.device_put(x, NamedSharding(self.mesh, s)),
+            lambda x, s: jax.device_put(
+                x, NamedSharding(self.mesh, s if x.size else P())),
             st, specs)
 
     def _trace_spec(self) -> P:
@@ -179,18 +191,17 @@ class ShardedDriver:
 
     def _carry_specs(self, st, specs):
         """PartitionSpecs of what a driver returns of its loop's
-        carry: the state's, and for the general engines the routing
-        counts beside it (``JaxEngine._counted``): a world to a row
-        in the world-sharded fleet, replicated scalars in a
-        node-sharded world (whose routing counts its full width, the
-        same on every device)."""
-        counted = getattr(self, "_counted", None)
-        if counted is None:
-            return specs
+        carry: the state's and, beside it, what the engine counts
+        there (``_counted``). The general engines' routing counts
+        (``JaxEngine._counted``): a world to a row in the
+        world-sharded fleet, replicated scalars in a node-sharded
+        world (whose routing counts its full width, the same on every
+        device). The edge engine's boundary messages are a row a
+        shard (``ShardedEdgeEngine`` overrides)."""
         world = getattr(self, "worlds_local", None) is not None
         return specs, jax.tree.map(
             lambda x: P(self.axis, *[None] * (x.ndim - 1)) if world
-            else P(), jax.eval_shape(counted, st)[1])
+            else P(), jax.eval_shape(self._counted, st)[1])
 
     @partial(jax.jit, static_argnums=(0, 2))
     def _run_scan(self, st, n_pad: int, max_steps, dyn=None,
@@ -230,11 +241,9 @@ class ShardedDriver:
             self._dyn = dy
             self._ident_in = idn
             try:
-                if not hasattr(self, "_counted"):
-                    return padded_scan(self._step_all, s, n_pad,
-                                       local_ms(ms))
-                # the general engines carry their routing counts
-                # beside the state (JaxEngine._counted)
+                # every sharded engine carries its counts beside the
+                # state (``_counted``: the general engines' routing
+                # counts, the edge engine's boundary messages)
                 return padded_scan(self._step_counted,
                                    self._counted(s), n_pad,
                                    local_ms(ms))
@@ -246,21 +255,6 @@ class ShardedDriver:
                      (specs, P(), dyn_specs, ident_specs),
                      (self._carry_specs(st, specs), self._trace_spec()))(
             st, max_steps, dyn, ident)
-
-    def _quiet_loop(self, st, max_steps):
-        """The quiet driver's ``while`` on this device's shard. The
-        general engines bring their own (``JaxEngine._quiet_loop``:
-        the loop carries the state's event horizon, whose ``t`` is
-        reduced over the mesh where it is produced); the edge engine
-        loops on its state alone, its condition asking for the next
-        event."""
-        loop = getattr(super(), "_quiet_loop", None)
-        if loop is not None:
-            return loop(st, max_steps)
-        start_steps = st.steps
-        return jax.lax.while_loop(
-            self._while_cond_fn(start_steps, max_steps),
-            self._while_body_fn(start_steps, max_steps), st)
 
     @partial(jax.jit, static_argnums=(0,))
     def _run_while(self, st, max_steps, ident=None):
