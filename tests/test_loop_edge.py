@@ -1,4 +1,4 @@
-"""The edge of the quiet loop (ISSUE 34): the general engine's
+"""The edge of the quiet loop (ISSUE 34; the edge engines: ISSUE 38):
 ``run_quiet`` decides a superstep's liveness once.
 
 The ``while`` carries, beside the state, the state's event horizon
@@ -19,6 +19,11 @@ the carried superstep is the plain ``_superstep``, and ``run_quiet``
 lands on the state that many plain supersteps reach, for budgets 0,
 1, mid-run and past quiescence, from a fresh state and from one an
 earlier call returned, under a scalar budget and one per world.
+
+The edge engines (``EdgeEngine`` and, on the virtual mesh of eight,
+``ShardedEdgeEngine``) are cases of the same tests since ISSUE 38:
+their loop carries the same ``Horizon``, their planes are the per-edge
+queues ``q_rel`` and ``q_pay``.
 """
 
 import functools
@@ -33,13 +38,19 @@ from jax.extend.core import Literal
 from timewarp_tpu.core.scenario import NEVER
 from timewarp_tpu.faults import (FaultFleet, FaultSchedule, LinkWindow,
                                  NodeCrash, Partition)
+from jax.sharding import PartitionSpec as P
+
 from timewarp_tpu.interp.jax_engine.batched import BatchSpec
-from timewarp_tpu.interp.jax_engine.engine import EngineState, JaxEngine
+from timewarp_tpu.interp.jax_engine.edge_engine import EdgeEngine, EdgeState
+from timewarp_tpu.interp.jax_engine.engine import (EngineState, Horizon,
+                                                   JaxEngine)
 from timewarp_tpu.interp.jax_engine.sharded import (ShardedBatchedEngine,
+                                                    ShardedEdgeEngine,
                                                     ShardedEngine, make_mesh)
 from timewarp_tpu.models.gossip import gossip
 from timewarp_tpu.models.token_ring import token_ring, token_ring_links
 from timewarp_tpu.net.delays import Quantize, UniformDelay
+from timewarp_tpu.parallel.mesh import _smap
 from timewarp_tpu.trace.events import assert_states_equal
 
 N = 48          # gossip nodes
@@ -56,6 +67,33 @@ def _ring():
     # the observer hub: an ordered inbox, two outbox slots
     return token_ring(16, n_tokens=5, think_us=4_000, bootstrap_us=1_000,
                       end_us=120_000, mailbox_cap=8)
+
+
+EDGE_N = 24     # the lean ring's nodes: three a shard on the mesh of eight
+EDGE_END_US = 90_000
+EDGE_LINK = UniformDelay(1000, 5000)
+
+
+def _edge_ring():
+    # no observer: a static topology (the edge engines' scenario),
+    # a commutative inbox
+    return token_ring(EDGE_N, n_tokens=8, think_us=4_000,
+                      bootstrap_us=1_000, end_us=EDGE_END_US,
+                      with_observer=False, mailbox_cap=8)
+
+
+def _edge_faults():
+    """``_gossip_faults``' shape on the lean ring: a restart in
+    mid-run, a crash, a partition, and a restart past the ring's own
+    end."""
+    return FaultSchedule((
+        NodeCrash(3, 20_000, 50_000, reset_state=True),
+        NodeCrash(10, 10_000, 30_000),
+        Partition((tuple(range(12)), tuple(range(12, EDGE_N))),
+                  40_000, 60_000),
+        NodeCrash(9, EDGE_END_US + 20_000, EDGE_END_US + 50_000,
+                  reset_state=True),
+    ))
 
 
 def _gossip_faults(end_us=90_000):
@@ -141,8 +179,12 @@ def _selects(jaxpr, origin=None):
 def _the_while(eng):
     """``(cond jaxpr, body jaxpr)`` of the one ``while`` of the
     engine's quiet driver."""
-    traced = type(eng)._run_while.trace(
-        eng, eng.init_state(), eng._coerce_budget(8)[0], eng._identity())
+    if isinstance(eng, EdgeEngine):
+        traced = type(eng)._run_while.trace(eng, eng.init_state(), 8)
+    else:
+        traced = type(eng)._run_while.trace(
+            eng, eng.init_state(), eng._coerce_budget(8)[0],
+            eng._identity())
     whiles = [e for e in _eqns(traced.jaxpr.jaxpr)
               if e.primitive.name == "while"]
     # the loop over supersteps carries the whole state: the widest one
@@ -166,6 +208,11 @@ ENGINES = {
     "sharded-fleet": lambda: ShardedBatchedEngine(
         _gossip(True), UNI, make_mesh(2, "worlds"), window="auto",
         lint="off", batch=BatchSpec(seeds=(0, 1, 2, 3))),
+    "edge": lambda: EdgeEngine(_edge_ring(), EDGE_LINK, cap=4, lint="off"),
+    "edge-faulted": lambda: EdgeEngine(_edge_ring(), EDGE_LINK, cap=4,
+                                       faults=_edge_faults(), lint="off"),
+    "sharded-edge": lambda: ShardedEdgeEngine(
+        _edge_ring(), EDGE_LINK, make_mesh(8), cap=4, lint="off"),
 }
 
 
@@ -194,10 +241,11 @@ def test_the_condition_reads_scalars_and_reduces_nothing_of_the_state(name):
         assert shapes <= small, (eqn.primitive.name, shapes)
         if B is None:
             # a solo condition: two compares and an `and`, no
-            # reduction at all (the mesh's minimum was taken where the
-            # horizon was produced)
+            # reduction and no collective at all (the mesh's minimum
+            # was taken where the horizon was produced)
             assert not eqn.primitive.name.startswith(
-                ("reduce", "arg", "pmin", "psum")), eqn.primitive.name
+                ("reduce", "arg", "pmin", "psum", "all_", "ppermute")
+            ), eqn.primitive.name
 
 
 @pytest.mark.parametrize("name", [n for n in ENGINES if "fleet" not in n])
@@ -206,8 +254,11 @@ def test_a_solo_body_selects_no_mailbox_plane_by_a_scalar(name):
     _, body = _the_while(eng)
     st = eng.init_state()
     nl = eng.comm.n_local
-    planes = {(st.mb_rel.shape[0], nl),
-              (st.mb_payload.shape[0], st.mb_payload.shape[1], nl)}
+    if isinstance(eng, EdgeEngine):
+        planes = {st.q_rel.shape[:-1] + (nl,), st.q_pay.shape[:-1] + (nl,)}
+    else:
+        planes = {(st.mb_rel.shape[0], nl),
+                  (st.mb_payload.shape[0], st.mb_payload.shape[1], nl)}
     by_scalar = [(p, s) for p, s in _selects(body)
                  if p == () and s in planes]
     assert not by_scalar, by_scalar
@@ -228,11 +279,16 @@ def test_a_fleets_body_selects_each_mailbox_plane_once_by_world(name):
     assert by_world.count(pay) == 1, by_world
 
 
-def test_the_scan_for_the_horizon_is_inside_the_one_program():
-    eng = _engine("solo-adaptive")
+@pytest.mark.parametrize("name", ["solo-adaptive", "edge", "sharded-edge"])
+def test_the_scan_for_the_horizon_is_inside_the_one_program(name):
+    eng = _engine(name)
     eng.run_quiet(5)
     assert eng.last_run_stats["dispatches"] == 1
     assert eng.last_run_stats["readbacks"] == 1
+    assert EdgeState._fields == (
+        "states", "wake", "q_rel", "q_step", "q_pay", "overflow",
+        "unrouted", "misrouted", "bad_delay", "delivered", "steps", "time",
+        "fault_dropped", "restart_done")
     assert EngineState._fields == (
         "states", "wake", "mb_rel", "mb_src", "mb_payload", "overflow",
         "bad_dst", "bad_delay", "short_delay", "route_drop", "delivered",
@@ -260,12 +316,22 @@ CASES = {
                             {"route_cap": 4 * N}),
     "lazy-ordered-auto": (_ring, UniformDelay(1000, 5000),
                           {"window": "auto", "route_cap": 64}),
+    # the edge engines (per-edge queues, no ladder, window 1): on one
+    # device, and node-sharded over the mesh of eight, which takes no
+    # fault schedule
+    "edge-commutative-w1": (_edge_ring, EDGE_LINK, {"cap": 4}),
+    "edge-commutative-w1-mesh8": (_edge_ring, EDGE_LINK, {"cap": 4}),
 }
 
 
 def _case(name, faulted):
     make, link, kw = CASES[name]
     sc = make()
+    if name.endswith("mesh8"):
+        return ShardedEdgeEngine(sc, link, make_mesh(8), lint="off", **kw)
+    if name.startswith("edge"):
+        return EdgeEngine(sc, link, lint="off",
+                          faults=_edge_faults() if faulted else None, **kw)
     faults = None
     if faulted:
         faults = _ring_faults() if sc.n_nodes == 17 else _gossip_faults()
@@ -273,6 +339,8 @@ def _case(name, faulted):
 
 
 def _regime(eng):
+    if isinstance(eng, EdgeEngine):
+        return "edge"
     if eng._adaptive_regime():
         return "adaptive"
     return "lazy" if eng.route_cap is not None else "eager"
@@ -291,18 +359,34 @@ def _restarts(st):
     return int(np.asarray(st.restart_done).sum())
 
 
+def _jit(eng, st, f, ins, outs):
+    """``jax.jit(f)``; for an engine over a mesh, ``f`` under the
+    engine's own ``shard_map`` (its superstep's collectives need the
+    mesh's axis bound), ``ins`` and ``outs`` naming each argument and
+    result: ``s`` a state like ``st``, ``h`` a horizon, ``.`` a scalar
+    every device holds alike."""
+    if not hasattr(eng, "mesh"):
+        return jax.jit(f)
+    spec = {"s": eng._state_specs(st), "h": Horizon(P(), P(eng.axis)),
+            ".": P()}
+    out_specs = tuple(spec[c] for c in outs)
+    return jax.jit(_smap(
+        f, eng.mesh, tuple(spec[c] for c in ins),
+        out_specs if len(outs) > 1 else out_specs[0]))
+
+
 def _walk(eng, limit=400):
     """The run superstep by superstep through ``_superstep_carried``,
     held at every one to the plain ``_superstep`` and to the horizon
     found again from the new state. Returns the states, the fresh one
     first, the quiet one last."""
-    @jax.jit
     def step(st, hz):
         new, hz2 = eng._superstep_carried(st, hz)
         return (new, hz2, eng._superstep(st, False)[0],
-                eng._horizon(new), eng._next_event(new))
+                eng._horizon(new), eng.comm.all_min(eng._next_event(new)))
     st = eng.init_state()
-    hz = jax.jit(eng._horizon)(st)
+    step = _jit(eng, st, step, "sh", "shsh.")
+    hz = _jit(eng, st, eng._horizon, "s", "h")(st)
     assert int(hz.t) == int(eng._next_event(st))    # nothing deferred yet
     states = [st]
     while int(hz.t) < NEVER:
@@ -319,7 +403,7 @@ def _walk(eng, limit=400):
 
 @pytest.mark.parametrize("name, faulted", [
     (name, faulted) for name in sorted(CASES) for faulted in (False, True)
-    if not (faulted and "lazy" in name)],
+    if not (faulted and ("lazy" in name or "mesh8" in name))],
     ids=lambda v: v if isinstance(v, str) else ("unfaulted", "faulted")[v])
 def test_the_carried_horizon_is_the_states_and_the_drivers_agree(
         name, faulted):
@@ -349,15 +433,23 @@ def test_the_carried_horizon_is_the_states_and_the_drivers_agree(
                         "run past quiescence")
 
 
-def test_the_pending_restart_keeps_the_quiet_loop_running():
+@pytest.mark.parametrize("name", ["adaptive-commutative-auto",
+                                  "edge-commutative-w1"])
+def test_the_pending_restart_keeps_the_quiet_loop_running(name):
     """What only the horizon sees: every wake time NEVER, every
-    mailbox empty, one reboot still to come. The scan driver always
-    fired it; the quiet loop's condition asked the bare minimum."""
-    eng = _case("adaptive-commutative-auto", True)
+    mailbox (every edge's queue) empty, one reboot still to come. The
+    scan driver always fired it; the quiet loop's condition asked the
+    bare minimum. The edge engine's probe of a state at rest
+    (``world_active``, between a controlled driver's chunks) asks the
+    horizon under a fault schedule, and reads such a state as active."""
+    eng = _case(name, True)
     states = _walk(eng)
     lull = [i for i, st in enumerate(states[:-1])
-            if int(eng._next_event(st)) >= NEVER]
+            if int(eng._node_next(st).min()) >= NEVER]
     assert lull, "no state is quiet but for the restart"
+    if isinstance(eng, EdgeEngine):
+        assert bool(eng.world_active(states[lull[0]]))
+        assert not bool(eng.world_active(states[-1]))
     past = eng.run_quiet(len(states) + 40)
     assert int(past.steps) == len(states) - 1 > lull[0]
     assert _restarts(past) == _resets(eng) == 2

@@ -62,6 +62,7 @@ from .common import LocalComm, RunStatsMixin, Stages, StepOut as _StepOut
 from .common import padded_scan, scan_pad
 from .controlled import ControlledRunMixin
 from .common import thi as _thi, tlo as _tlo, u32sum as _u32sum
+from .engine import Horizon
 from ...integrity.runner import VerifiedRunMixin
 from ...obs.flight import FlightRecorderMixin
 
@@ -306,18 +307,70 @@ class EdgeEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
 
     # -- one superstep ---------------------------------------------------
 
+    def _node_next(self, st: EdgeState) -> jax.Array:
+        """Each node's next event time as the state alone has it
+        (int64[n]; the batched "pop min" before the minimum): its
+        wake, or its earliest queued message."""
+        nnr = st.q_rel.min(axis=(0, 1))                          # int32[N]
+        return jnp.minimum(
+            st.wake,
+            jnp.where(nnr == _I32MAX, jnp.int64(NEVER),
+                      st.time + nnr.astype(jnp.int64)))
+
+    def _horizon(self, st: EdgeState) -> Horizon:
+        """The state's :class:`~.engine.Horizon` (``JaxEngine._horizon``'s
+        twin): when its next superstep fires, agreed over the mesh,
+        and each node's next event. Under a fault schedule events
+        inside a down window slide to its ``t_up`` and unconsumed
+        reset rows inject the restart firing (faults/apply.py
+        ``defer_next``), so the deferral is part of the horizon
+        wherever it is computed. Integers and ``min``: the same bits
+        from a state whoever asks, so a superstep that takes the
+        horizon it was handed is the superstep that finds it again."""
+        node_next = self._node_next(st)
+        if self._faulted:
+            # crash suppression + injected restarts (faults/apply.py;
+            # same masks as JaxEngine)
+            from ...faults.apply import defer_next
+            node_next = defer_next(self._ft, self.comm.node_ids(),
+                                   node_next, st.restart_done)
+        return Horizon(self.comm.all_min(node_next.min()), node_next)
+
     def _superstep(self, st: EdgeState, with_trace: bool
                    ) -> Tuple[EdgeState, Optional[_StepOut]]:
+        """One superstep of a state on its own: finds the state's
+        horizon, and returns the state unchanged once nothing is
+        pending. What the scan driver, the chunked and controlled
+        drivers and every caller outside the quiet loop step with."""
         with Stages() as stage:
-            return self._staged_superstep(st, with_trace, stage)
+            return self._staged_superstep(st, None, with_trace, stage)
 
-    def _staged_superstep(self, st, with_trace, stage):
+    def _superstep_carried(self, st: EdgeState, hz: Horizon
+                           ) -> Tuple[EdgeState, Horizon]:
+        """The quiet loop's superstep: ``(state, horizon) -> (state',
+        horizon')``. ``hz`` is ``st``'s horizon, found by the
+        superstep before (or by ``_quiet_loop``'s one scan before the
+        loop), and the loop's condition has decided on ``hz.t`` that
+        this superstep runs: nothing is selected by liveness.
+        ``horizon'`` is one pass over the ``q_rel`` and ``wake`` the
+        superstep has just written, and the one agreement over the
+        mesh an iteration makes on its next event."""
+        with Stages() as stage:
+            new, _ = self._staged_superstep(st, hz, False, stage)
+            stage("tw.next_event")
+            return new, self._horizon(new)
+
+    def _staged_superstep(self, st, hz, with_trace, stage):
         """One superstep of ``st``, each numbered part under the scope
         ``stage`` names for it (common.py ``STAGES``, as
         ``JaxEngine._staged_superstep`` has them); the delivery's
         ``comm.roll`` calls sit under ``tw.route/exchange``, so that a
         profile of a sharded run tells the boundary hop from the rest
-        of the stage."""
+        of the stage. ``hz`` is ``st``'s horizon where the caller's
+        loop carries it and has decided on it that the superstep
+        applies (``_superstep_carried``); None where nobody has: the
+        superstep then finds the horizon itself and the result is
+        ``st`` wherever nothing is pending."""
         stage("tw.next_event")
         sc, topo, comm = self.scenario, self.topo, self.comm
         E, C, P = topo.n_edges, self.cap, sc.payload_width
@@ -335,32 +388,27 @@ class EdgeEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         # validity is the rel sentinel (I32MAX = empty slot)
         q_live = st.q_rel < _I32MAX                          # [E,C,N]
 
-        # 1. global next event time (the batched "pop min")
-        nnr = st.q_rel.min(axis=(0, 1))                          # int32[N]
-        node_next = jnp.minimum(
-            st.wake,
-            jnp.where(nnr == _I32MAX, jnp.int64(NEVER),
-                      base + nnr.astype(jnp.int64)))
-        if self._faulted:
-            # crash suppression + injected restarts (faults/apply.py;
-            # same masks as JaxEngine)
-            from ...faults.apply import defer_next
-            node_next_pre = node_next
-            node_next = defer_next(self._ft, node_ids, node_next,
-                                   st.restart_done)
-            if rec_full:
-                # fault action: crash window slid a pending event
-                # later (engine.py's defer capture, identically)
-                from ...obs import flight as _flight
-                dm = (node_next > node_next_pre) \
-                    & (node_next_pre < NEVER)
-                self._rec_extra.append(_flight.compact(
-                    self.record_cap, _flight.EV_FAULT, dm, node_ids,
-                    node_ids, node_next_pre, node_next,
-                    _flight.TAG_DEFER))
-        t = comm.all_min(node_next.min())
-        live = t < NEVER
-        fire = (node_next == t) & live
+        # 1. global next event time (the batched "pop min"): the
+        # state's horizon, found here or by whoever made the state
+        live = None
+        if hz is None:
+            hz = self._horizon(st)
+            live = hz.t < NEVER
+        t, node_next = hz
+        if self._faulted and rec_full:
+            # fault action: crash window slid a pending event
+            # later (engine.py's defer capture, identically)
+            from ...obs import flight as _flight
+            node_next_pre = self._node_next(st)
+            dm = (node_next > node_next_pre) \
+                & (node_next_pre < NEVER)
+            self._rec_extra.append(_flight.compact(
+                self.record_cap, _flight.EV_FAULT, dm, node_ids,
+                node_ids, node_next_pre, node_next,
+                _flight.TAG_DEFER))
+        fire = node_next == t
+        if live is not None:
+            fire = fire & live
 
         # 1.5. restart bookkeeping (engine.py twin): consume restart
         # rows firing now; reset their nodes' state; purge pre-crash
@@ -612,6 +660,9 @@ class EdgeEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
             fault_dropped=st.fault_dropped + fault_step,
             restart_done=restart_done,
         )
+        if live is None:
+            # the caller's loop has decided that this superstep runs
+            return new_st, None
         final = jax.tree.map(lambda a, b: jnp.where(live, b, a), st, new_st)
         if not with_trace:
             return final, None
@@ -723,14 +774,15 @@ class EdgeEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
     # -- drivers ---------------------------------------------------------
 
     def _next_event(self, carry: EdgeState) -> jax.Array:
-        """This device's next event time (NEVER = quiesced) — the
-        while-loop condition shared by the local and sharded drivers."""
-        qmin = carry.q_rel.min()
-        return jnp.minimum(
-            carry.wake.min(),
-            jnp.where(qmin < _I32MAX,
-                      carry.time + qmin.astype(jnp.int64),
-                      jnp.int64(NEVER)))
+        """This device's next event time (NEVER = quiesced): the probe
+        of a state at rest (``world_active``, the controlled drivers
+        between chunks). No driver's loop asks it: the quiet loop
+        carries each state's horizon. Under a fault schedule it is
+        the horizon's ``t``, so that a state quiet but for a restart
+        still to come reads as active, as the supersteps see it."""
+        if self._faulted:
+            return self._horizon(carry).t
+        return self._node_next(carry).min()
 
     #: the edge engine carries no world axis (batch=BatchSpec is the
     #: general engine's lever); the shared drivers key off this
@@ -749,10 +801,15 @@ class EdgeEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         shard and counts nothing: ``ShardedEdgeEngine`` overrides."""
         return None
 
+    def _counted(self, st):
+        """What the quiet loop carries of ``st``: here the state
+        alone. A sharded driver carries each shard's count of
+        boundary messages beside it."""
+        return st
+
     def _settled(self, carry):
-        """``(state, crossed)`` of what a driver returned of its
-        loop's carry: here the state alone. A sharded driver's carry
-        holds each shard's count of boundary messages beside it."""
+        """``(state, crossed)`` of what a driver carried or returned:
+        here the state alone (``_counted``'s inverse)."""
         return carry, None
 
     def _step_all(self, st, with_trace: bool):
@@ -760,17 +817,36 @@ class EdgeEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         engine has no world axis, so this is always the solo step)."""
         return self._superstep(st, with_trace)
 
-    def _while_cond_fn(self, start_steps, max_steps):
-        def cond(carry):
-            nxt = self.comm.all_min(self._next_event(carry))
-            return (nxt < NEVER) & \
-                (carry.steps - start_steps < max_steps)
-        return cond
+    def _step_carried(self, carry, hz):
+        """The quiet loop's body on ``(_counted(state), horizon)``."""
+        return self._superstep_carried(carry, hz)
 
-    def _while_body_fn(self, start_steps, max_steps):
-        def body(carry):
-            return self._step_all(carry, False)[0]
-        return body
+    def _quiet_loop(self, st, max_steps):
+        """The quiet driver's ``while``, the local ``_run_while``'s and
+        (on one device's shard) ``ShardedDriver._run_while``'s, in the
+        shape of ``JaxEngine._quiet_loop``: one scan for the state's
+        horizon (under ``tw.next_event``, inside the same program),
+        then the loop on ``(_counted(state), horizon)``. The
+        condition is the carried next event time against NEVER and
+        the step budget: two compares of scalars every device holds
+        alike (``hz.t`` comes out of ``all_min``), no reduction and
+        no collective at the loop's edge; the body runs only where
+        the condition has just found ``hz.t`` pending, and selects
+        nothing by it. The last horizon is dropped (a function of the
+        state: ``EdgeState`` has no field for it, and the next call
+        scans once again)."""
+        start_steps = st.steps  # max_steps is per-call, same as run()
+        with jax.named_scope("tw.next_event"):
+            hz = self._horizon(st)
+
+        def cond(carry):
+            counted, hz = carry
+            steps = self._settled(counted)[0].steps
+            return (hz.t < NEVER) & (steps - start_steps < max_steps)
+
+        return jax.lax.while_loop(
+            cond, lambda carry: self._step_carried(*carry),
+            (self._counted(st), hz))[0]
 
     @partial(jax.jit, static_argnums=(0, 2))
     def _run_scan(self, st: EdgeState, n_pad: int, max_steps):
@@ -826,11 +902,7 @@ class EdgeEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
 
     @partial(jax.jit, static_argnums=(0,))
     def _run_while(self, st: EdgeState, max_steps) -> EdgeState:
-        start_steps = st.steps
-        max_steps = jnp.asarray(max_steps, jnp.int64)
-        return jax.lax.while_loop(
-            self._while_cond_fn(start_steps, max_steps),
-            self._while_body_fn(start_steps, max_steps), st)
+        return self._quiet_loop(st, jnp.asarray(max_steps, jnp.int64))
 
     def run_quiet(self, max_steps: int,
                   state: Optional[EdgeState] = None) -> EdgeState:

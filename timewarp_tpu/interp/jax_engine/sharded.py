@@ -102,18 +102,18 @@ class ShardedEdgeEngine(ShardedDriver, EdgeEngine):
         new, y = self._step_all(st, with_trace)
         return (new, crossed + self._crossed.astype(jnp.int64)), y
 
+    def _step_carried(self, carry, hz):
+        """``EdgeEngine._quiet_loop``'s body on this device's shard:
+        the carried superstep on the counted carry. The horizon it
+        returns holds the next event time all devices agree on
+        (``all_min`` where it is produced), which is all the loop's
+        condition reads."""
+        st, crossed = carry
+        new, hz = self._superstep_carried(st, hz)
+        return (new, crossed + self._crossed.astype(jnp.int64)), hz
+
     def _settled(self, carry):
         return carry
-
-    def _quiet_loop(self, st, max_steps):
-        """The quiet driver's ``while`` on this device's shard: the
-        local engine's condition (the next event, agreed over the
-        mesh) on the counted carry."""
-        cond = self._while_cond_fn(st.steps, max_steps)
-        return jax.lax.while_loop(
-            lambda carry: cond(carry[0]),
-            lambda carry: self._step_counted(carry, False)[0],
-            self._counted(st))
 
     # -- sharding specs --------------------------------------------------
 

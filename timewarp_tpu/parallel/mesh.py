@@ -148,12 +148,14 @@ class ShardedDriver:
     """Shared ``shard_map`` driver for the sharded engines. The
     concrete engine supplies ``_state_specs`` (its state's
     PartitionSpecs, built from :meth:`_leaf_spec`), ``_superstep``,
-    ``_next_event`` (the quiescence expression, inherited from its
-    local base class), the quiet driver's ``while`` on one device's
-    shard (``_quiet_loop``: ``JaxEngine``'s, whose horizon is reduced
-    over the mesh where it is produced, or ``ShardedEdgeEngine``'s
-    own) and what the loops carry beside the state (``_counted``,
-    ``_step_counted``)."""
+    the quiet driver's ``while`` on one device's shard
+    (``_quiet_loop``, inherited from its local base class,
+    ``JaxEngine`` or ``EdgeEngine``: both carry the state's horizon,
+    reduced over the mesh where a superstep produces it, so the
+    loop's condition holds no collective) and what the loops carry
+    beside the state (``_counted``, ``_step_counted``; the edge
+    engine's quiet body: ``_step_carried``). ``_next_event``, a local
+    base class's probe of a state at rest, is asked by no loop."""
 
     def _leaf_spec(self, x, last_axis: bool) -> P:
         """PartitionSpec for one state leaf: the node axis (leading or
