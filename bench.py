@@ -268,7 +268,10 @@ def bench_token_ring_observer(n, steps):
     """The reference example's real shape (examples/token-ring/Main.hs:
     104-208): every token hop also notifies an observer hub —
     dynamic destinations, general engine. Dense-token regime with
-    think quantized so rings fire co-temporally."""
+    think quantized so rings fire co-temporally. The hub's inbox is
+    the scenario's 8 slots: of the n notes an instant it keeps 8 and
+    the engine counts the rest in ``overflow`` (the benchmark's cell
+    ``ring_64k.observer`` gates every job on that count)."""
     from timewarp_tpu.interp.jax_engine.engine import JaxEngine
     from timewarp_tpu.models.token_ring import token_ring
     from timewarp_tpu.net.delays import FixedDelay
@@ -279,8 +282,24 @@ def bench_token_ring_observer(n, steps):
         end_us=(1 << 50), with_observer=True,
         mailbox_cap=8)
     engine = JaxEngine(sc, FixedDelay(500))
-    delivered, dt, _ = _measure(engine, steps or 512)
-    return (f"token-ring observer (general engine) "
+    steps = steps or 512
+    # one whole ring cycle to warm up (timers, tokens, the hub), so the
+    # measured run starts where a cycle does
+    delivered, dt, fin = _measure(engine, steps, warm_steps=3)
+    # in-bench proof of what the run drops: all n notes of a cycle
+    # reach the hub at one instant, its inbox has 8 slots, and the
+    # engine keeps the first 8 in arrival order and counts the rest.
+    # A cycle's notes are sent on its second superstep, so the warm-up
+    # and the measured run hold (steps + 4) // 3 such supersteps
+    cycles = (steps + 4) // 3
+    assert int(fin.overflow) == cycles * (n - 8), (
+        f"the hub dropped {int(fin.overflow)} notes, not the "
+        f"{cycles} x {n - 8} a bounded hub of 8 slots drops")
+    for counter in ("bad_dst", "bad_delay", "short_delay", "route_drop"):
+        v = int(getattr(fin, counter))
+        assert v == 0, f"measured run lost messages elsewhere: {counter}={v}"
+    return (f"token-ring observer (general engine; bounded hub, notes "
+            f"past 8 an instant dropped and counted) "
             f"delivered-messages/sec/chip @{n} nodes", delivered / dt)
 
 

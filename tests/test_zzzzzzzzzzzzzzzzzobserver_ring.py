@@ -1,0 +1,342 @@
+"""The token ring with its observer hub as a deployment (ISSUE 39): the
+general engine's default contract (the ordered inbox with sender ids,
+two outbox slots on the routing ladder) against the benchmark's plain
+reference, entry for entry, at ring sizes that are no multiple of 128
+lanes; a hub of bounded inbox whose drops are counted exactly; the two
+scopes of the ordered inbox's sorts (``tw.deliver/sort``,
+``tw.rebase/compact``) and the counter ``fan_in_peak``, carried by an
+engine whose inbox is ordered and by no other.
+
+(Named test_zz* to sort after the whole existing suite.)
+"""
+
+import json
+import os
+import re
+import sys
+
+import numpy as np
+import pytest
+
+import jax.numpy as jnp
+
+from timewarp_tpu.interp.jax_engine.batched import BatchSpec
+from timewarp_tpu.interp.jax_engine.engine import JaxEngine
+from timewarp_tpu.interp.ref.superstep import SuperstepOracle
+from timewarp_tpu.models.token_ring import token_ring
+from timewarp_tpu.net.delays import FixedDelay
+from timewarp_tpu.obs.metrics import MetricsRegistry, validate_line
+from timewarp_tpu.trace.events import assert_states_equal
+
+BENCHMARK = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmark")
+if BENCHMARK not in sys.path:
+    sys.path.insert(0, BENCHMARK)
+
+import fleet_reduce  # noqa: E402
+import hub_costs  # noqa: E402
+import span_reduce  # noqa: E402
+from builders import gossip_wave, observer_ring  # noqa: E402
+from reference import observer_ring_ref  # noqa: E402
+
+SIZES = (1000, 4096, 8191)           # ring nodes; + 1: the hub
+FIELDS = ("cnt", "val", "send_at", "wake", "mailbox_due", "mailbox_src",
+          "mailbox_word", "mailbox_kind", "hub_prev", "hub_errs",
+          "delivered", "overflow", "steps", "time")
+ORDER_SCOPES = {"tw.deliver/sort", "tw.rebase/compact"}
+
+
+def _load(kind, name):
+    with open(os.path.join(BENCHMARK, kind, name + ".json")) as f:
+        return json.load(f)
+
+
+def _cell(n):
+    traffic = _load("workloads", "ring_64k.observer")
+    config = _load("configs", traffic["config"])
+    config["params"].update(n_ring=n, n_nodes=n + 1, n_tokens=n)
+    return observer_ring.Cell(config, traffic)
+
+
+@pytest.fixture(scope="module")
+def cells():
+    made = {}
+    return lambda n: made.get(n) or made.setdefault(n, _cell(n))
+
+
+def _mismatches(got, want, fields=FIELDS):
+    return {f: int(np.sum(np.asarray(got[f]) != np.asarray(want[f])))
+            for f in fields}
+
+
+# -- the program against the plain reference ----------------------------------
+
+@pytest.mark.parametrize("steps", [1, 2, 3, 96])
+@pytest.mark.parametrize("n", SIZES)
+def test_the_engine_equals_the_reference_entry_for_entry(cells, n, steps):
+    c = cells(n)
+    c.set_up(3_000_000_019 + n)          # compiles; its job is not used
+    start = c.engine.init_state()
+    val = start.states["val"].at[:n].set(jnp.asarray(c.val0))
+    st = c.engine.run_quiet(
+        steps, start._replace(states={**start.states, "val": val}))
+    want = observer_ring_ref.ObserverRing(c.p, c.val0).run_to(steps)
+    assert want["steps"] == steps
+    assert _mismatches(c._facts(st), want) == dict.fromkeys(FIELDS, 0)
+    # two supersteps in: the hub's slots hold the notes of senders 0-7
+    if steps == 2:
+        assert want["mailbox_src"][:, n].tolist() == list(range(8))
+        assert (want["mailbox_due"][:, :n] == -1).all()
+    if steps >= 2:
+        assert c.engine.last_run_stats["fan_in_peak"] \
+            == want["fan_in_peak"] == n
+
+
+@pytest.mark.parametrize("n", SIZES)
+def test_a_cycle_delivers_n_plus_8_and_counts_n_minus_8_dropped(cells, n):
+    c = cells(n)
+    first = c.set_up(7)
+    jobs = [first, c.job(1), c.job(2)]
+    assert [j["failed"] for j in jobs] == ["", "", ""]
+    for j in jobs:
+        assert j["supersteps"] == 96 and j["msgs"] == 32 * (n + 8)
+        assert j["fan_in_peak"] == n
+    assert int(c.state.overflow) == 3 * 32 * (n - 8)
+    for lost in ("bad_dst", "bad_delay", "short_delay", "route_drop"):
+        assert int(getattr(c.state, lost)) == 0
+    stats = c.engine.last_run_stats
+    assert (stats["dispatches"], stats["readbacks"]) == (1, 1)
+    # two supersteps in three at the least rung that holds the ring's
+    # n senders (the top one, of n + 1, only where no other does), the
+    # third (the hub alone, no sender) at the ladder's lowest
+    rungs = c.engine._sender_rungs(n + 1)
+    by_rung = [0] * len(rungs)
+    by_rung[0] += 32
+    by_rung[min(i for i, r in enumerate(rungs) if r >= n)] += 64
+    assert stats["rung_steps"] == by_rung
+    assert all(v == 0 for _, v, _ in c.compare(observer_ring_ref))
+
+
+@pytest.mark.parametrize("n", SIZES[:2])
+def test_three_jobs_of_96_equal_one_run_of_288(cells, n):
+    c = cells(n)
+    c.set_up(5)
+    start = c.state
+    for i in range(3):
+        assert not c.job(i + 1)["failed"]
+    assert_states_equal(c.engine.run_quiet(288, start), c.state,
+                        "one run of 288 against three jobs of 96")
+
+
+def test_a_job_that_loses_a_count_fails_its_gate(cells, monkeypatch):
+    c = cells(1000)
+    c.set_up(5)
+    monkeypatch.setattr(c, "dropped", c.dropped - 1)
+    assert "the hub dropped and counted 31744 notes" in c.job(1)["failed"]
+    monkeypatch.setattr(c, "n", 999)
+    assert "fan_in_peak 1000, due 999" in c.job(2)["failed"]
+
+
+# -- the controls ----------------------------------------------------------------
+
+def test_both_controls_fail_the_comparison(cells):
+    n = 1000
+    c = cells(n)
+    c.set_up(4_100_000_007)
+    assert not c.job(1)["failed"] and not c.job(2)["failed"]
+    assert all(v == 0 for _, v, _ in c.compare(observer_ring_ref))
+    rows = {name: v for name, v, _ in c.control(observer_ring_ref)}
+    assert {r.partition(".")[0] for r in rows} == {"int16_values",
+                                                   "hub_descending"}
+    for tag in ("first_job", "window_end", "tokens_in_flight", "hub_inbox"):
+        # seeded values reach 2^20: every one wraps in int16
+        assert rows[f"int16_values.{tag}.val.mismatches"] == n
+        assert rows[f"int16_values.{tag}.steps.mismatches"] == 0
+        for f in ("hub_prev", "hub_errs"):
+            assert rows[f"hub_descending.{tag}.{f}.mismatches"] == 1
+        # the ring itself does not see the hub's order
+        assert rows[f"hub_descending.{tag}.val.mismatches"] == 0
+    assert rows["int16_values.tokens_in_flight.mailbox_word.mismatches"] == n
+    # the hub kept senders n-1 .. n-8 instead of 0 .. 7
+    assert rows["hub_descending.hub_inbox.mailbox_src.mismatches"] == 8
+    assert rows["hub_descending.window_end.mailbox_src.mismatches"] == 0
+
+
+def test_a_control_that_passes_is_returned_alone(cells, monkeypatch):
+    c = cells(1000)
+    c.set_up(5)
+    c.job(1)
+    # a "control" that is the configuration's own precision passes,
+    # and must not hide behind the other
+    monkeypatch.setitem(c.control_of, "value_dtype", "int32")
+    rows = c.control(observer_ring_ref)
+    assert all(name.startswith(("first_job.", "window_end.",
+                                "tokens_in_flight.", "hub_inbox."))
+               for name, _, _ in rows)
+    assert all(v <= limit for _, v, limit in rows)
+
+
+# -- the reference against the host oracle ------------------------------------------
+
+@pytest.mark.parametrize("steps", [2, 3, 11, 24])
+def test_the_reference_equals_the_host_oracle_at_64_plus_1(steps):
+    n = 64
+    p = {**_load("configs", "token_ring_64k_observer")["params"],
+         "n_ring": n, "n_nodes": n + 1, "n_tokens": n}
+    val0 = np.random.default_rng(steps).integers(0, 1 << 20, n,
+                                                 dtype=np.int32)
+    sc = token_ring(n, n_tokens=n, think_us=p["think_us"],
+                    bootstrap_us=p["bootstrap_us"], end_us=p["end_us"],
+                    with_observer=True, mailbox_cap=p["mailbox_cap"])
+    oracle = SuperstepOracle(sc, FixedDelay(p["link"]["delay_us"]),
+                             lint="off")
+    oracle.states["val"][:n] = val0
+    trace = oracle.run(steps)
+    want = observer_ring_ref.ObserverRing(p, val0).run_to(steps)
+    assert len(trace) == want["steps"] == steps
+    assert oracle.time == want["time"]
+    assert oracle.overflow_total == want["overflow"]
+    assert trace.total_delivered() == want["delivered"]
+    for f in ("cnt", "val"):
+        assert (oracle.states[f] == want[f]).all(), f
+    assert int(oracle.states["prev"][n]) == want["hub_prev"]
+    assert int(oracle.states["errs"][n]) == want["hub_errs"]
+    never = 1 << 61
+    assert [-1 if w > never else w for w in oracle.wake] \
+        == want["wake"].tolist()
+    # every mailbox, slot by slot in arrival order
+    for i, box in enumerate(oracle.mailbox):
+        held = [(int(t), int(src), int(pay[0]), int(pay[1]))
+                for t, src, pay in box]
+        k = len(held)
+        assert (want["mailbox_due"][k:, i] == -1).all()
+        assert held == list(zip(*(want[f][:k, i].tolist() for f in (
+            "mailbox_due", "mailbox_src", "mailbox_word",
+            "mailbox_kind")))), i
+
+
+def test_the_reference_says_what_it_cannot_run():
+    p = _load("configs", "token_ring_64k_observer")["params"]
+    small = {**p, "n_ring": 16, "n_nodes": 17, "n_tokens": 16}
+    val0 = np.zeros(16, np.int32)
+    ring = observer_ring_ref.ObserverRing(small, val0)
+    ring.run_to(6)
+    with pytest.raises(ValueError, match="only runs forwards"):
+        ring.run_to(5)
+    with pytest.raises(ValueError, match="n_nodes = n_ring"):
+        observer_ring_ref.ObserverRing({**small, "n_nodes": 16}, val0)
+    with pytest.raises(ValueError, match="every ring node holds"):
+        observer_ring_ref.ObserverRing({**small, "n_tokens": 1}, val0)
+    # 16 notes an instant and 8 slots: two cycles drop 8 each
+    assert ring.facts()["overflow"] == 16 and ring.fan_in_peak == 16
+
+
+# -- the scopes, and who carries the counter -------------------------------------------
+
+def _wave_engine(n, **kw):
+    p = _load("configs", "gossip_100k")["params"]
+    sc, link = gossip_wave.scenario_and_link({**p, "n_nodes": n})
+    return JaxEngine(sc, link, window="auto", insert="xla", lint="off",
+                     **kw)
+
+
+def _ring_engine(n, **kw):
+    sc = token_ring(n, n_tokens=n, think_us=1000, bootstrap_us=1000,
+                    end_us=1 << 50, with_observer=True, mailbox_cap=8)
+    return JaxEngine(sc, FixedDelay(500), lint="off", **kw)
+
+
+def _nested_scopes(eng) -> set:
+    text = type(eng)._run_while.lower(
+        eng, eng.init_state(), eng._coerce_budget(8)[0],
+        eng._identity()).as_text(debug_info=True)
+    names = re.findall(r'loc\("(jit\(_run_while\)[^"]*)"', text)
+    return {span_reduce.stage_of(fleet_reduce.unwrap(n), 2) for n in names}
+
+
+@pytest.mark.parametrize("make, ordered", [
+    (lambda: _ring_engine(1000), True),
+    (lambda: _ring_engine(256, batch=BatchSpec(seeds=(0, 1))), True),
+    (lambda: _wave_engine(1024, seed=0), False),
+    (lambda: _wave_engine(1024, batch=BatchSpec(seeds=(0, 1))), False)],
+    ids=["ordered", "ordered-fleet", "commutative", "commutative-fleet"])
+def test_the_order_scopes_are_in_an_ordered_engines_text_alone(make, ordered):
+    nested = _nested_scopes(make())
+    assert "tw.route/insert" in nested
+    assert (ORDER_SCOPES <= nested) if ordered \
+        else not (ORDER_SCOPES & nested)
+
+
+@pytest.mark.parametrize("kw", [
+    {"seed": 0}, {"batch": BatchSpec(seeds=(0, 1))}], ids=["solo", "fleet"])
+def test_a_commutative_inbox_carries_no_fan_in_peak(kw):
+    eng = _wave_engine(1024, **kw)
+    assert not eng._ranks_fan_in()
+    eng.run_quiet(6)
+    assert "fan_in_peak" not in eng.last_run_stats
+    assert eng._counted(eng.init_state())[1].fan_in_peak is None
+
+
+def test_an_ordered_fleet_reads_its_worlds_largest_fan_in():
+    n = 256
+    eng = _ring_engine(n, batch=BatchSpec(seeds=(0, 1)))
+    eng.run_quiet(3)
+    assert eng.last_run_stats["fan_in_peak"] == n
+    eng.run_quiet(1)                     # the timers alone: one a node
+    assert eng.last_run_stats["fan_in_peak"] == 1
+    eng.run(4)                           # the scan driver counts it too
+    assert eng.last_run_stats["fan_in_peak"] == n
+
+
+def test_the_counter_reaches_the_summary_line_and_merges_as_a_maximum():
+    eng = _ring_engine(256)
+    eng.run_quiet(3)
+    stats = eng.last_run_stats
+    assert stats["fan_in_peak"] == 256
+    reg = MetricsRegistry()
+    reg.run_summary("observer", stats)
+    line, = reg.lines
+    assert line["fan_in_peak"] == 256
+    validate_line(line)
+    with pytest.raises(ValueError, match="fan_in_peak"):
+        validate_line({**line, "fan_in_peak": 1.5})
+    merged = eng._stats_merge([{**stats, "fan_in_peak": 3},
+                               {**stats, "fan_in_peak": 7}])
+    assert merged["fan_in_peak"] == 7
+    assert "fan_in_peak" not in eng._stats_merge(
+        [stats, {k: v for k, v in stats.items() if k != "fan_in_peak"}])
+
+
+# -- the committed cell --------------------------------------------------------------
+
+def test_the_committed_cell_is_bench_pys_row_with_nothing_cut():
+    traffic = _load("workloads", "ring_64k.observer")
+    config = _load("configs", traffic["config"])
+    p = config["params"]
+    assert (p["n_ring"], p["n_nodes"], p["n_tokens"]) == (65536, 65537, 65536)
+    assert (p["think_us"], p["bootstrap_us"], p["mailbox_cap"]) == (
+        1000, 1000, 8)
+    assert p["link"] == {"model": "fixed", "delay_us": 500}
+    assert p["window"] == 1 and p["with_observer"] and config["reduced"] == []
+    assert traffic["chips"] == 1 and traffic["supersteps_per_job"] == 96
+    assert "bounded_hub" in config["guarantees"]
+    with open(os.path.join(os.path.dirname(BENCHMARK),
+                           "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    mine = [m for m in bench["per_layer"]
+            if m.get("workloads") == ["ring_64k.observer"]]
+    assert [m["name"] for m in mine] == [
+        "hub_superstep_us", "hub_order_us", "hub_route_us", "hub_insert_us",
+        "hub_fire_us", "hub_fan_in_peak", "hub_superstep_roofline"]
+    assert {m["moves"] for m in mine} == {"msgs_per_s"}
+    assert bench["workloads"][-1]["name"] == "ring_64k.observer"
+    assert bench["configs"][-1]["reduced"] == []
+
+
+def test_the_bytes_of_a_superstep_that_touches_its_state_once():
+    # per node: 32 bytes of leaves and 8 slots of four int32 words, read
+    # and written; a message that takes a slot writes its four words
+    assert hub_costs.hub_superstep_bytes(1, 8, 2, 0) == 2 * (32 + 128)
+    assert hub_costs.hub_superstep_bytes(1, 8, 2, 3) == 2 * (32 + 128) + 48
+    assert hub_costs.hub_superstep_bytes(65537, 8, 2, 65544 / 3) \
+        == 21_321_408

@@ -30,7 +30,13 @@ __all__ = ["LocalComm", "StepOut", "I32MAX", "group_rank", "u32sum",
 #: Nested in ``tw.route`` (``engine.py``): ``sample`` (the no-drop
 #: paths' link draw), ``exchange``, ``sort`` (the eager and the lazy
 #: regime's one variadic sort by destination; the adaptive ladder's
-#: sorts are the stage's own) and ``insert``; nested in ``tw.fire``:
+#: sorts are the stage's own) and ``insert``; nested in ``tw.deliver``:
+#: ``sort`` (an ordered inbox's variadic sort along the mailbox's
+#: slots, due time then arrival slot), and in ``tw.rebase``:
+#: ``compact`` (an ordered inbox's second one, which closes the gaps
+#: of what was delivered and keeps arrival order in slot order, and
+#: the kept messages a node): in the programs of scenarios that keep
+#: the default ordered inbox and in no other; nested in ``tw.fire``:
 #: ``entropy`` (``fire_bits`` over every lane, in the programs of
 #: scenarios with ``needs_key`` and in no other; the v5e's compiler
 #: fuses it into the step's fusion, so it is in the lowered text and
@@ -240,7 +246,8 @@ class _DriverCall:
         ``tw.wait``: the step counters, the routing stage's counts the
         driver's loop carried beside the state (``counts``: a
         ``(rung_lanes, sender_lanes, rung_steps, dense_stage_steps,
-        wide_tail_steps)`` of device arrays, ``engine.py``
+        wide_tail_steps, fan_in_peak)`` of device arrays, the last
+        None but from an ordered inbox, ``engine.py``
         ``RouteCounts``; None from an engine with no ladder to count),
         a node-sharded edge engine's boundary messages (``crossed``:
         one count a shard, ``sharded.py`` ``ShardedEdgeEngine``; None
@@ -265,6 +272,7 @@ class _DriverCall:
             stats.update(world_supersteps=d.tolist(),
                          fleet_iterations=int(d.max()))
         if counts is not None:
+            *counts, fan_in = counts
             if d.ndim:
                 # one rung for all the worlds of a superstep: every
                 # world counted the same (a world-sharded fleet: the
@@ -276,6 +284,9 @@ class _DriverCall:
                          rung_steps=by_rung.tolist(),
                          dense_stage_steps=int(dense),
                          wide_tail_steps=int(wide))
+            if fan_in is not None:
+                # a fleet's: its worlds' largest
+                stats.update(fan_in_peak=int(np.max(fan_in)))
         if crossed is not None:
             # counted on each shard beside its state, summed here
             stats.update(shards=len(crossed),
@@ -311,6 +322,15 @@ class RunStatsMixin:
          "dense_stage_steps": int,  # iterations that staged their
                                     # arrivals in the dense form
          "wide_tail_steps": int}    # of those, with a full-width tail
+
+    for a general engine whose scenario keeps the default ordered
+    inbox (``commutative_inbox=False``: insertion ranks the arrivals
+    of every destination to append them in arrival order)::
+
+        {"fan_in_peak": int}   # the most arrivals to one destination
+                               # in one superstep of the call, kept
+                               # and dropped alike (a fleet: of any
+                               # world)
 
     for the node-sharded edge engine (``ShardedEdgeEngine``)::
 
@@ -408,4 +428,8 @@ class RunStatsMixin:
                 self.last_run_stats[key] = sum(cols) \
                     if not isinstance(cols[0], list) \
                     else [sum(col) for col in zip(*cols)]
+        if chunks and all("fan_in_peak" in c for c in chunks):
+            # a largest value, not a sum
+            self.last_run_stats["fan_in_peak"] = max(
+                c["fan_in_peak"] for c in chunks)
         return self.last_run_stats

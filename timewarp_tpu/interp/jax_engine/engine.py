@@ -168,7 +168,9 @@ class RouteCounts(NamedTuple):
     ``_route_adaptive`` holds when it picks its branch (the active
     senders and the rung's index), the last two from the one scalar
     ``_stage_by_rank``'s dense form picks its tail's width by: no
-    pass over node- or mailbox-sized data is made for them. Where
+    pass over node- or mailbox-sized data is made for them
+    (``fan_in_peak`` is one reduction over the message lanes that an
+    ordered inbox's insertion has ranked already). Where
     routing runs without the ladder every iteration counts the full
     width, in one bin. A fleet's leaves lead with the world axis like
     every state leaf (one rung for all the worlds of a superstep:
@@ -184,6 +186,13 @@ class RouteCounts(NamedTuple):
     #: int64[] — of those, the ones whose tail (ranks past 0) was over
     #: half the lanes and went through the full-width scatter
     wide_tail_steps: jax.Array
+    #: int32[] — the most arrivals to one destination in one superstep
+    #: (``_insert_sorted``'s largest rank + 1 over its valid lanes): a
+    #: maximum over the loop's iterations, not a sum. Carried by an
+    #: engine whose inbox is ordered and by no other (``_ranks_fan_in``;
+    #: None is an empty pytree node, so the loops of every other
+    #: engine carry what they carried)
+    fan_in_peak: Any = None
 
 
 class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
@@ -911,6 +920,24 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         PERF.md, Findings PR 32)."""
         return self.scenario.commutative_inbox and self.batch is None
 
+    def _ranks_fan_in(self) -> bool:
+        """Whether insertion appends after the kept messages by rank
+        (an ordered inbox, solo or fleet) and so counts the call's
+        ``fan_in_peak``. Static, like ``_stages_by_rank``: the drivers
+        of a commutative inbox lower to the text they lowered to
+        before the counter."""
+        return not self.scenario.commutative_inbox
+
+    def _take_fan_in(self, ret):
+        """``ret`` as ``_insert_sorted`` returns it (and whatever a
+        rung put after it) without the largest fan-in, which is left
+        on ``self._fan_in`` for the drivers' counts: taken outside the
+        routing switch, where a rung's value may be kept."""
+        if not self._ranks_fan_in():
+            return ret
+        self._fan_in = self.comm.all_max(ret[4])
+        return ret[:4] + ret[5:]
+
     @jax.named_scope("insert")
     def _insert_sorted(self, mb_rel, mb_src, mb_payload, sd, ok_s,
                        drel_s, src_s, pay_s, holes, counts):
@@ -925,7 +952,9 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         inbox: append-after-kept) -> flat 1D scatters into the
         mailbox; non-fitting lanes get an out-of-range index and are
         dropped. Returns the updated arrays plus the local overflow
-        count. Both commutative forms put every message in the same
+        count and, from an ordered inbox, the largest number of
+        arrivals to one destination (``_take_fan_in`` takes it off
+        again). Both commutative forms put every message in the same
         slot; held to the oracle, and to each other, by
         tests/test_insert_law.py."""
         sc = self.scenario
@@ -948,10 +977,12 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
             fits = ok_s & (prow < K)
             col = jnp.clip(prow, 0, K - 1)
             pos = jnp.where(fits, jnp.int32(0), jnp.int32(K))
+            fan_in = ()
         else:
             pos = counts[jnp.clip(sd, 0, n - 1)] + rank
             fits = ok_s & (pos < K)
             col = jnp.clip(pos, 0, K - 1)
+            fan_in = (jnp.max(jnp.where(ok_s, rank + 1, 0)),)
         flat = jnp.where(fits, col * jnp.int32(n) + sd,
                          jnp.int32(K * n))
         mb_rel = mb_rel.reshape(-1).at[flat].set(
@@ -968,7 +999,7 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
                                                    mode="drop")
         mb_payload = mb_payload.reshape(K, P, n)
         overflow = jnp.sum(ok_s & (pos >= K), dtype=jnp.int32)
-        return mb_rel, mb_src, mb_payload, overflow
+        return (mb_rel, mb_src, mb_payload, overflow) + fan_in
 
     def _route_adaptive(self, out, out_valid, now_vec, t, mb_rel,
                         mb_src, mb_payload, holes, counts,
@@ -1040,7 +1071,7 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
             # needs no place in the switch's return, as `wide`
             # (ret[4]) does
             if not staged:
-                return ret
+                return self._take_fan_in(ret)
             self._staged = (jnp.asarray(dense, jnp.int32), ret[4])
             with jax.named_scope("insert"):
                 return self._fill_staged(mb_rel, mb_src, mb_payload,
@@ -1435,11 +1466,12 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
                 payload=jnp.where(deliver[:, None, :], st.mb_payload, 0),
             )
         else:
-            rel_key = jnp.where(deliver, st.mb_rel, _I32MAX)
-            ops = jax.lax.sort(
-                (~deliver, rel_key, slots, st.mb_src) + tuple(
-                    st.mb_payload[:, p, :] for p in range(P)),
-                dimension=0, num_keys=3)
+            with jax.named_scope("sort"):
+                rel_key = jnp.where(deliver, st.mb_rel, _I32MAX)
+                ops = jax.lax.sort(
+                    (~deliver, rel_key, slots, st.mb_src) + tuple(
+                        st.mb_payload[:, p, :] for p in range(P)),
+                    dimension=0, num_keys=3)
             ib_valid, ib_rel, ib_src = ~ops[0], ops[1], ops[3]
             ib_pay = jnp.stack(ops[4:4 + P], axis=1)            # [K, P, N]
             # pad invalid slots exactly like the oracle (src=0,
@@ -1525,16 +1557,17 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
             holes = free_bits(keep)
             counts = None
         else:
-            ops2 = jax.lax.sort(
-                (~keep, slots, st.mb_rel, st.mb_src) + tuple(
-                    st.mb_payload[:, p, :] for p in range(P)),
-                dimension=0, num_keys=2)
-            kept = ~ops2[0]
+            with jax.named_scope("compact"):
+                ops2 = jax.lax.sort(
+                    (~keep, slots, st.mb_rel, st.mb_src) + tuple(
+                        st.mb_payload[:, p, :] for p in range(P)),
+                    dimension=0, num_keys=2)
+                kept = ~ops2[0]
+                counts = kept.sum(axis=0, dtype=jnp.int32)      # [N]
             mb_rel = jnp.where(kept, ops2[2] - shift32, _I32MAX)
             mb_src = ops2[3]
             mb_payload = jnp.stack(ops2[4:4 + P], axis=1)
             holes = None
-            counts = kept.sum(axis=0, dtype=jnp.int32)          # [N]
 
         stage("tw.route")
         # 6. route outboxes — three regimes. Adaptive sender-compacted
@@ -1556,6 +1589,9 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         #: its tail went wide: the staged insertion puts its own
         #: (``_insert_sorted``; on the ladder ``_route_adaptive``)
         self._staged = (jnp.int32(0), jnp.int32(0))
+        #: and the most arrivals to one destination, where insertion
+        #: ranks them for an ordered inbox (``_take_fan_in``)
+        self._fan_in = None
         if adaptive:
             res = self._route_adaptive(
                 out, out_valid, now_vec, t, mb_rel, mb_src,
@@ -1762,9 +1798,10 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
             ok_s = sd < n
             src_s = ops3[1] // jnp.int32(M)   # smrank = src * M + slot
             pay_s = ops3[3:]
-        mb_rel, mb_src, mb_payload, overflow_local = self._insert_sorted(
-            mb_rel, mb_src, mb_payload, sd, ok_s, drel_s, src_s, pay_s,
-            holes, counts)
+        mb_rel, mb_src, mb_payload, overflow_local = self._take_fan_in(
+            self._insert_sorted(
+                mb_rel, mb_src, mb_payload, sd, ok_s, drel_s, src_s,
+                pay_s, holes, counts))
         overflow_step = comm.all_sum(overflow_local) + bucket_ovf
 
         sent_count = sent_hash = None
@@ -2049,12 +2086,13 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         ``vmap`` reads ``vmap(tw.route)`` in an operation's
         ``op_name`` (docs/observability.md). What routing did in
         every world is left on ``self._routed`` and ``self._staged``
-        (int32[B] each, one value B times) for the drivers' counts, as
-        a solo superstep leaves its scalars there."""
+        (int32[B] each, one value B times) and ``self._fan_in`` (a
+        world's own, or None) for the drivers' counts, as a solo
+        superstep leaves its scalars there."""
         def world(*a):
-            return step(*a), (self._routed, self._staged)
-        out, (self._routed, self._staged) = self._each_world(
-            world, ctx, *args)
+            return step(*a), (self._routed, self._staged, self._fan_in)
+        out, (self._routed, self._staged, self._fan_in) = \
+            self._each_world(world, ctx, *args)
         return out
 
     def _identity(self) -> Optional[WorldIdentity]:
@@ -2184,7 +2222,9 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         bins = len(self._sender_rungs(n)) if self._adaptive_regime() else 1
         return st, RouteCounts(
             lanes, lanes, jnp.zeros(lanes.shape + (bins,), jnp.int32),
-            lanes, lanes)
+            lanes, lanes,
+            jnp.zeros(lanes.shape, jnp.int32) if self._ranks_fan_in()
+            else None)
 
     def _count_route(self, counts: RouteCounts, stepped=True
                      ) -> RouteCounts:
@@ -2204,7 +2244,9 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
             counts.sender_lanes + jnp.where(stepped, senders, 0),
             counts.rung_steps + one.astype(jnp.int32),
             counts.dense_stage_steps + jnp.where(stepped, dense, 0),
-            counts.wide_tail_steps + jnp.where(stepped, wide, 0))
+            counts.wide_tail_steps + jnp.where(stepped, wide, 0),
+            None if counts.fan_in_peak is None else jnp.maximum(
+                counts.fan_in_peak, jnp.where(stepped, self._fan_in, 0)))
 
     def _step_counted(self, carry, with_trace: bool):
         """``_step_all`` on a driver loop's ``(state, counts)`` carry.

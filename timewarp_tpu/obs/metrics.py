@@ -20,7 +20,7 @@ Kinds:
   the call launched and read back; the routing stage's counts where
   the engine keeps them: ``rung_lanes``, ``sender_lanes``,
   ``rung_steps``, ``dense_stage_steps``, ``wide_tail_steps``, a
-  fleet's ``fleet_iterations``).
+  fleet's ``fleet_iterations``, an ordered inbox's ``fan_in_peak``).
 - ``utilization`` — per-bucket sweep utilization (sweep/runner.py):
   worlds-active occupancy, budget-mask efficiency, pow2 scan-pad
   waste.
@@ -72,7 +72,8 @@ _NUM = (int, float)
 #: the fields of ``last_run_stats`` a ``run_summary`` line carries
 #: where the driver call counted them (common.py ``RunStatsMixin``)
 _RUN_COUNTS = ("dispatches", "readbacks", "rung_lanes", "sender_lanes",
-               "fleet_iterations", "dense_stage_steps", "wide_tail_steps")
+               "fleet_iterations", "dense_stage_steps", "wide_tail_steps",
+               "fan_in_peak")
 #: kind -> {required field: type tuple}; extra fields are allowed
 #: (forward-compatible), missing/badly-typed required ones are not
 _KINDS: Dict[str, Dict[str, tuple]] = {
