@@ -5,7 +5,10 @@ reference, entry for entry, at ring sizes that are no multiple of 128
 lanes; a hub of bounded inbox whose drops are counted exactly; the two
 scopes of the ordered inbox's sorts (``tw.deliver/sort``,
 ``tw.rebase/compact``) and the counter ``fan_in_peak``, carried by an
-engine whose inbox is ordered and by no other.
+engine whose inbox is ordered and by no other. Since PR 43 the ranked
+insertion of a solo engine cuts its scatters to the prefix that can
+land: streamed jobs equal the one-scatter engine's leaf for leaf, and
+``scatter_lanes`` reads the cycle's three widths.
 
 (Named test_zz* to sort after the whole existing suite.)
 """
@@ -20,6 +23,7 @@ import pytest
 
 import jax.numpy as jnp
 
+from timewarp_tpu.interp.jax_engine import engine as engine_module
 from timewarp_tpu.interp.jax_engine.batched import BatchSpec
 from timewarp_tpu.interp.jax_engine.engine import JaxEngine
 from timewarp_tpu.interp.ref.superstep import SuperstepOracle
@@ -126,6 +130,61 @@ def test_three_jobs_of_96_equal_one_run_of_288(cells, n):
         assert not c.job(i + 1)["failed"]
     assert_states_equal(c.engine.run_quiet(288, start), c.state,
                         "one run of 288 against three jobs of 96")
+
+
+def _cycle_lanes(eng, n, cut):
+    """What a ring cycle's three insertions hand to each scatter: the
+    tokens' (every sender uses one slot of two: half the rung's lanes
+    hold the prefix), the notes' (the hub keeps 8: an eighth) and the
+    hub's own superstep (no arrival at all, at the lowest rung), each
+    at full width where ``cut`` says the rung has no widths."""
+    rungs = eng._sender_rungs(n + 1)
+    wide, low = 2 * min(r for r in rungs if r >= n), 2 * rungs[0]
+    return sum(-(-L // d) if cut(L) else L
+               for L, d in ((wide, 2), (wide, 8), (low, 8)))
+
+
+@pytest.mark.parametrize("n", SIZES[:2])
+def test_streamed_jobs_with_the_scatters_cut_equal_the_one_scatter_engines(
+        cells, n, monkeypatch):
+    """With ``_PREFIX_SCATTER_LANES`` patched down every rung scatters
+    a prefix: three streamed jobs of 96 supersteps, from the state the
+    unpatched engine starts on, end on its states leaf for leaf, and
+    ``scatter_lanes`` reads the cycle's three widths 32 times a job."""
+    c = cells(n)
+    c.set_up(11)
+    start, want, plain = c.state, [], []
+    for i in range(3):
+        assert not c.job(i + 1)["failed"]
+        want.append(c.state)
+        plain.append(c.engine.last_run_stats["scatter_lanes"])
+    assert plain == [32 * _cycle_lanes(c.engine, n, lambda L: False)] * 3
+    monkeypatch.setattr(engine_module, "_PREFIX_SCATTER_LANES", 64)
+    eng = observer_ring.engine_of(c.p)
+    st = start
+    for ref in want:
+        st = eng.run_quiet(96, st)
+        assert eng.last_run_stats["scatter_lanes"] \
+            == 32 * _cycle_lanes(eng, n, lambda L: True)
+        assert eng.last_run_stats["fan_in_peak"] == n
+        assert_states_equal(st, ref, "the scatters cut against one scatter")
+    assert int(st.overflow) - int(start.overflow) == 3 * 32 * (n - 8)
+
+
+def test_the_widest_ring_here_cuts_its_scatters_as_it_is_built(cells):
+    """8191 ring nodes take the rung of 8192 senders, 16 384 lanes:
+    ``_PREFIX_SCATTER_LANES`` as the program has it, so the jobs the
+    tests above hold to the reference ran the ladder of widths there
+    and on no other rung."""
+    n = SIZES[2]
+    c = cells(n)
+    cuts = [len(c.engine._scatter_widths(2 * r)) > 1
+            for r in c.engine._sender_rungs(n + 1)]
+    assert cuts == [False, False, False, True]
+    assert not c.set_up(13)["failed"]
+    assert c.engine.last_run_stats["scatter_lanes"] == 32 * _cycle_lanes(
+        c.engine, n, lambda L: L >= engine_module._PREFIX_SCATTER_LANES) \
+        == 32 * (8192 + 2048 + 2048)
 
 
 def test_a_job_that_loses_a_count_fails_its_gate(cells, monkeypatch):
@@ -275,6 +334,29 @@ def test_a_commutative_inbox_carries_no_fan_in_peak(kw):
     eng.run_quiet(6)
     assert "fan_in_peak" not in eng.last_run_stats
     assert eng._counted(eng.init_state())[1].fan_in_peak is None
+    # nor the lanes of scatters it does not cut (PR 43)
+    assert not eng._cuts_scatters()
+    assert eng._scatter_widths(1 << 20) == (1 << 20,)
+    assert "scatter_lanes" not in eng.last_run_stats
+    assert eng._counted(eng.init_state())[1].scatter_lanes is None
+
+
+def test_scatter_lanes_reach_the_summary_line_and_merge_as_a_sum():
+    eng = _ring_engine(256)
+    assert eng._cuts_scatters()
+    eng.run_quiet(3)
+    stats = eng.last_run_stats
+    # under the constant: one scatter at the one rung's 2 x 257 lanes
+    assert stats["scatter_lanes"] == 3 * 2 * 257
+    eng.run(3)                           # the scan driver counts it too
+    assert eng.last_run_stats["scatter_lanes"] == 3 * 2 * 257
+    reg = MetricsRegistry()
+    reg.run_summary("observer", stats)
+    line, = reg.lines
+    assert line["scatter_lanes"] == stats["scatter_lanes"]
+    validate_line(line)
+    assert eng._stats_merge([stats, stats])["scatter_lanes"] \
+        == 2 * stats["scatter_lanes"]
 
 
 def test_an_ordered_fleet_reads_its_worlds_largest_fan_in():
@@ -286,6 +368,11 @@ def test_an_ordered_fleet_reads_its_worlds_largest_fan_in():
     assert eng.last_run_stats["fan_in_peak"] == 1
     eng.run(4)                           # the scan driver counts it too
     assert eng.last_run_stats["fan_in_peak"] == n
+    # a fleet ranks and keeps the one scatter: no width to count
+    assert not eng._cuts_scatters()
+    assert eng._scatter_widths(1 << 20) == (1 << 20,)
+    assert "scatter_lanes" not in eng.last_run_stats
+    assert eng._counted(eng.init_state())[1].scatter_lanes is None
 
 
 def test_the_counter_reaches_the_summary_line_and_merges_as_a_maximum():
@@ -327,7 +414,8 @@ def test_the_committed_cell_is_bench_pys_row_with_nothing_cut():
             if m.get("workloads") == ["ring_64k.observer"]]
     assert [m["name"] for m in mine] == [
         "hub_superstep_us", "hub_order_us", "hub_route_us", "hub_insert_us",
-        "hub_fire_us", "hub_fan_in_peak", "hub_superstep_roofline"]
+        "hub_fire_us", "hub_fan_in_peak", "hub_superstep_roofline",
+        "hub_scatter_lane_share"]
     assert {m["moves"] for m in mine} == {"msgs_per_s"}
     assert bench["workloads"][-1]["name"] == "ring_64k.observer"
     assert bench["configs"][-1]["reduced"] == []

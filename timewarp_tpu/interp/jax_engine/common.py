@@ -246,8 +246,9 @@ class _DriverCall:
         ``tw.wait``: the step counters, the routing stage's counts the
         driver's loop carried beside the state (``counts``: a
         ``(rung_lanes, sender_lanes, rung_steps, dense_stage_steps,
-        wide_tail_steps, fan_in_peak)`` of device arrays, the last
-        None but from an ordered inbox, ``engine.py``
+        wide_tail_steps, fan_in_peak, scatter_lanes)`` of device
+        arrays, the last two None but from an ordered inbox (the last
+        from a solo one on one device), ``engine.py``
         ``RouteCounts``; None from an engine with no ladder to count),
         a node-sharded edge engine's boundary messages (``crossed``:
         one count a shard, ``sharded.py`` ``ShardedEdgeEngine``; None
@@ -272,7 +273,7 @@ class _DriverCall:
             stats.update(world_supersteps=d.tolist(),
                          fleet_iterations=int(d.max()))
         if counts is not None:
-            *counts, fan_in = counts
+            *counts, fan_in, scattered = counts
             if d.ndim:
                 # one rung for all the worlds of a superstep: every
                 # world counted the same (a world-sharded fleet: the
@@ -287,6 +288,8 @@ class _DriverCall:
             if fan_in is not None:
                 # a fleet's: its worlds' largest
                 stats.update(fan_in_peak=int(np.max(fan_in)))
+            if scattered is not None:
+                stats.update(scatter_lanes=int(scattered))
         if crossed is not None:
             # counted on each shard beside its state, summed here
             stats.update(shards=len(crossed),
@@ -331,6 +334,14 @@ class RunStatsMixin:
                                # in one superstep of the call, kept
                                # and dropped alike (a fleet: of any
                                # world)
+
+    for such an engine that is solo and on one device (its insertion
+    may scatter a prefix of its lanes, engine.py ``_scatter_widths``)::
+
+        {"scatter_lanes": int}  # the lanes handed to each of the
+                                # insertion's scatters, summed over
+                                # the iterations: the width taken, the
+                                # call's lanes where one scatter ran
 
     for the node-sharded edge engine (``ShardedEdgeEngine``)::
 
@@ -388,10 +399,13 @@ class RunStatsMixin:
     def _driver_call(self, driver: str):
         """One driver call: its record (``obs.profiler.call``, with the
         ``tw.<driver>`` span around the call) holding the engine's
-        class and node count; yields the call's :class:`_DriverCall`."""
+        class, node count and outbox slots a node (what a rung's
+        senders are multiplied by to give its lanes); yields the
+        call's :class:`_DriverCall`."""
         with call("tw." + driver) as record:
             record.update(engine=type(self).__name__,
-                          n_nodes=self.scenario.n_nodes)
+                          n_nodes=self.scenario.n_nodes,
+                          max_out=self.scenario.max_out)
             yield _DriverCall(self, record)
 
     def _stats_merge(self, chunks) -> dict:
@@ -422,7 +436,7 @@ class RunStatsMixin:
         for key in ("rung_lanes", "sender_lanes", "fleet_iterations",
                     "rung_steps", "world_supersteps",
                     "dense_stage_steps", "wide_tail_steps",
-                    "boundary_msgs"):
+                    "scatter_lanes", "boundary_msgs"):
             if chunks and all(key in c for c in chunks):
                 cols = [c[key] for c in chunks]
                 self.last_run_stats[key] = sum(cols) \

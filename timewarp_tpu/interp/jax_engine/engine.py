@@ -86,6 +86,23 @@ _FLEET_AXIS = "tw_fleet"
 #: less (5-9 s each): the cells' cold compiles rose by 9-14 s.
 _DENSE_STAGE_RATIO = 0.25
 
+#: an ordered inbox's ranked insertion (``_insert_sorted``) cuts its
+#: scatters to the prefix that ends at the last lane that fits, by a
+#: ladder of four static widths (``_scatter_widths``), where the call
+#: has at least this many lanes; under it one scatter a field at the
+#: call's width, as ever. The four scatters of one insertion into
+#: the observer ring's [8, 65 537] planes, both forms, in us on a v5e
+#: (profiling/prefix_scatter_micro_r07.py, PR 43; lanes: one / the
+#: switch where 8 lanes fit (it takes L/8), where half fit (L/2),
+#: where all fit (L)): 2^12: 191-196 / 177, 202, 248; 2^13: 270-276
+#: / 176, 245, 332; 2^14: 436-439 / 198, 323, 495; 2^15: 754-761 /
+#: 242, 482, 808; 2^17: 2 700-2 703 / 501, 1 450, 2 728. A scatter
+#: costs what its lanes cost whatever lands (5 ns a lane over some
+#: 100 us); the switch adds 45-57 us (26 at 2^17). At 2^13 the cut to
+#: half gains 26 us where a full call loses 56; at 2^14 it gains 116
+#: for 57, and the cut to an eighth 238.
+_PREFIX_SCATTER_LANES = 1 << 14
+
 
 class EngineState(NamedTuple):
     """The complete simulation state — one pytree, trivially
@@ -193,6 +210,12 @@ class RouteCounts(NamedTuple):
     #: None is an empty pytree node, so the loops of every other
     #: engine carry what they carried)
     fan_in_peak: Any = None
+    #: int64[] — the lanes the ranked insertion handed to each of its
+    #: scatters (``_insert_sorted``: the width its ladder took, the
+    #: call's lanes where it has none), summed over the iterations.
+    #: Carried by a solo engine on one device whose inbox is ordered
+    #: and by no other (``_cuts_scatters``; None elsewhere, as above)
+    scatter_lanes: Any = None
 
 
 class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
@@ -928,15 +951,45 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         before the counter."""
         return not self.scenario.commutative_inbox
 
+    def _cuts_scatters(self) -> bool:
+        """Whether the ranked insertion may cut its scatters to the
+        lanes that can land (``_scatter_widths``) and so counts the
+        call's ``scatter_lanes``: an ordered inbox of a solo engine on
+        one device. Under a fleet's ``vmap`` the width's index would
+        be a world's own and the switch a select over every branch
+        (``_route_adaptive`` says the same of its rungs), and the
+        sharded engine's lanes are a device's share after the
+        exchange; no cell runs either, so both keep the one scatter.
+        Static, like ``_ranks_fan_in``."""
+        return self._ranks_fan_in() and self.batch is None \
+            and type(self.comm) is LocalComm
+
+    def _scatter_widths(self, L: int) -> Tuple[int, ...]:
+        """The static widths, ascending, of which the ranked insertion
+        of ``L`` lanes scatters the smallest that holds every lane
+        that fits: ``L/8``, ``L/4``, ``L/2``, ``L`` (rounded up)
+        from ``_PREFIX_SCATTER_LANES`` lanes on, else ``L`` alone.
+        Spaced by two, as the routing ladder's rungs are
+        (``_sender_rungs``): the width taken is under twice the lanes
+        that can land; under an eighth, seven eighths of the cost are
+        gone and more widths would only be more to compile."""
+        if not self._cuts_scatters() or L < _PREFIX_SCATTER_LANES:
+            return (L,)
+        return tuple(-(-L // d) for d in (8, 4, 2, 1))
+
     def _take_fan_in(self, ret):
         """``ret`` as ``_insert_sorted`` returns it (and whatever a
-        rung put after it) without the largest fan-in, which is left
-        on ``self._fan_in`` for the drivers' counts: taken outside the
-        routing switch, where a rung's value may be kept."""
+        rung put after it) without the largest fan-in and the width
+        the scatters took, which are left on ``self._fan_in`` and
+        ``self._scattered`` for the drivers' counts: taken outside
+        the routing switch, where a rung's value may be kept."""
         if not self._ranks_fan_in():
             return ret
         self._fan_in = self.comm.all_max(ret[4])
-        return ret[:4] + ret[5:]
+        if not self._cuts_scatters():
+            return ret[:4] + ret[5:]
+        self._scattered = ret[5]
+        return ret[:4] + ret[6:]
 
     @jax.named_scope("insert")
     def _insert_sorted(self, mb_rel, mb_src, mb_payload, sd, ok_s,
@@ -951,9 +1004,15 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         ``holes`` words, ops/numeric.py ``nth_set_bit``; an ordered
         inbox: append-after-kept) -> flat 1D scatters into the
         mailbox; non-fitting lanes get an out-of-range index and are
-        dropped. Returns the updated arrays plus the local overflow
-        count and, from an ordered inbox, the largest number of
-        arrivals to one destination (``_take_fan_in`` takes it off
+        dropped. Where a solo engine on one device ranks
+        (``_cuts_scatters``) and the call has the lanes for it, the
+        scatters take only the prefix that ends at the last lane that
+        fits, at the smallest of four static widths that holds it
+        (``_scatter_widths``): the lanes left out are dropped ones,
+        so no word changes. Returns the updated arrays plus the local
+        overflow count and, from an ordered inbox, the largest number
+        of arrivals to one destination and (where it may cut them)
+        the lanes its scatters took (``_take_fan_in`` takes both off
         again). Both commutative forms put every message in the same
         slot; held to the oracle, and to each other, by
         tests/test_insert_law.py."""
@@ -983,23 +1042,52 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
             fits = ok_s & (pos < K)
             col = jnp.clip(pos, 0, K - 1)
             fan_in = (jnp.max(jnp.where(ok_s, rank + 1, 0)),)
-        flat = jnp.where(fits, col * jnp.int32(n) + sd,
-                         jnp.int32(K * n))
-        mb_rel = mb_rel.reshape(-1).at[flat].set(
-            drel_s, mode="drop").reshape(K, n)
+        L = sd.shape[0]
+
+        def scatter(w):
+            # the first `w` lanes into the mailbox, a flat scatter a
+            # field: static slices from lane 0, none at the full width
+            def cut(x):
+                return x if w == L else x[:w]
+            fits_w, col_w, sd_w = cut(fits), cut(col), cut(sd)
+            flat = jnp.where(fits_w, col_w * jnp.int32(n) + sd_w,
+                             jnp.int32(K * n))
+            rel = mb_rel.reshape(-1).at[flat].set(
+                cut(drel_s), mode="drop").reshape(K, n)
+            src = mb_src.reshape(-1).at[flat].set(
+                cut(src_s), mode="drop").reshape(K, n) \
+                if sc.inbox_src else None
+            pay = mb_payload.reshape(-1)
+            for p in range(P):
+                flat_p = jnp.where(
+                    fits_w,
+                    (col_w * jnp.int32(P) + p) * jnp.int32(n) + sd_w,
+                    jnp.int32(K * P * n))
+                pay = pay.at[flat_p].set(cut(pay_s[p]), mode="drop")
+            return rel, src, pay.reshape(K, P, n)
+        widths = self._scatter_widths(L)
+        if len(widths) == 1:
+            rel, src, pay = scatter(L)
+            width = jnp.int32(L)
+        else:
+            # the routing sort put the valid lanes first, ordered by
+            # destination and arrival, so the lanes that fit end at
+            # `hi` and every lane from there on has the out-of-range
+            # index already: leaving it out changes no word. One
+            # scalar picks the smallest width that holds the prefix,
+            # as the ladder of rungs picks its own (`_route_adaptive`)
+            hi = jnp.max(jnp.where(
+                fits, jnp.arange(1, L + 1, dtype=jnp.int32), 0))
+            steps = jnp.asarray(widths, jnp.int32)
+            idx = jnp.sum(hi > steps)
+            rel, src, pay = jax.lax.switch(
+                idx, [partial(scatter, w) for w in widths])
+            width = steps[idx]
         if sc.inbox_src:
-            mb_src = mb_src.reshape(-1).at[flat].set(
-                src_s, mode="drop").reshape(K, n)
-        mb_payload = mb_payload.reshape(-1)
-        for p in range(P):
-            flat_p = jnp.where(
-                fits, (col * jnp.int32(P) + p) * jnp.int32(n) + sd,
-                jnp.int32(K * P * n))
-            mb_payload = mb_payload.at[flat_p].set(pay_s[p],
-                                                   mode="drop")
-        mb_payload = mb_payload.reshape(K, P, n)
+            mb_src = src
         overflow = jnp.sum(ok_s & (pos >= K), dtype=jnp.int32)
-        return (mb_rel, mb_src, mb_payload, overflow) + fan_in
+        return (rel, mb_src, pay, overflow) + fan_in + (
+            (width,) if self._cuts_scatters() else ())
 
     def _route_adaptive(self, out, out_valid, now_vec, t, mb_rel,
                         mb_src, mb_payload, holes, counts,
@@ -1592,6 +1680,9 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         #: and the most arrivals to one destination, where insertion
         #: ranks them for an ordered inbox (``_take_fan_in``)
         self._fan_in = None
+        #: and the lanes it handed to its scatters, where it may cut
+        #: them (``_cuts_scatters``)
+        self._scattered = None
         if adaptive:
             res = self._route_adaptive(
                 out, out_valid, now_vec, t, mb_rel, mb_src,
@@ -2224,7 +2315,8 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
             lanes, lanes, jnp.zeros(lanes.shape + (bins,), jnp.int32),
             lanes, lanes,
             jnp.zeros(lanes.shape, jnp.int32) if self._ranks_fan_in()
-            else None)
+            else None,
+            lanes if self._cuts_scatters() else None)
 
     def _count_route(self, counts: RouteCounts, stepped=True
                      ) -> RouteCounts:
@@ -2246,7 +2338,9 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
             counts.dense_stage_steps + jnp.where(stepped, dense, 0),
             counts.wide_tail_steps + jnp.where(stepped, wide, 0),
             None if counts.fan_in_peak is None else jnp.maximum(
-                counts.fan_in_peak, jnp.where(stepped, self._fan_in, 0)))
+                counts.fan_in_peak, jnp.where(stepped, self._fan_in, 0)),
+            None if counts.scatter_lanes is None else
+            counts.scatter_lanes + jnp.where(stepped, self._scattered, 0))
 
     def _step_counted(self, carry, with_trace: bool):
         """``_step_all`` on a driver loop's ``(state, counts)`` carry.

@@ -20,7 +20,8 @@ Kinds:
   the call launched and read back; the routing stage's counts where
   the engine keeps them: ``rung_lanes``, ``sender_lanes``,
   ``rung_steps``, ``dense_stage_steps``, ``wide_tail_steps``, a
-  fleet's ``fleet_iterations``, an ordered inbox's ``fan_in_peak``).
+  fleet's ``fleet_iterations``, an ordered inbox's ``fan_in_peak`` and,
+  solo on one device, its ``scatter_lanes``).
 - ``utilization`` — per-bucket sweep utilization (sweep/runner.py):
   worlds-active occupancy, budget-mask efficiency, pow2 scan-pad
   waste.
@@ -73,7 +74,7 @@ _NUM = (int, float)
 #: where the driver call counted them (common.py ``RunStatsMixin``)
 _RUN_COUNTS = ("dispatches", "readbacks", "rung_lanes", "sender_lanes",
                "fleet_iterations", "dense_stage_steps", "wide_tail_steps",
-               "fan_in_peak")
+               "fan_in_peak", "scatter_lanes")
 #: kind -> {required field: type tuple}; extra fields are allowed
 #: (forward-compatible), missing/badly-typed required ones are not
 _KINDS: Dict[str, Dict[str, tuple]] = {

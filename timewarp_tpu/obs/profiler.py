@@ -136,8 +136,8 @@ def call(name: str):
 def calls() -> List[dict]:
     """The finished records, oldest first, at most ``MAX_CALLS``: for
     each driver call ``{"run": int, "engine": str, "n_nodes": int,
-    "spans": ((name, start_ns, end_ns, cause, attrs), ...), "counts":
-    {...}}``. Spans are in the order they closed (the call's own span
+    "max_out": int, "spans": ((name, start_ns, end_ns, cause, attrs),
+    ...), "counts": {...}}``. Spans are in the order they closed (the call's own span
     last), times are ``time.perf_counter_ns()``, ``counts`` is the
     call's ``last_run_stats`` (docs/observability.md). A span opened
     under no driver call is a record with that one span, ``run`` None
