@@ -257,9 +257,12 @@ def test_last_run_stats_uniform_across_engines():
         _, trace = eng.run(STEPS)
         st = eng.last_run_stats
         # the general engine counts its routing stage beside them
-        # (ISSUE 35): one bin and the full width, the ring has no ladder
+        # (ISSUE 35): one bin and the full width, the ring has no ladder;
+        # this ring's inbox is commutative, so its insertion stages by
+        # rank and counts its tail too (ISSUE 44: the list grew by three)
         routed = {"rung_lanes", "sender_lanes", "rung_steps",
-                  "dense_stage_steps", "wide_tail_steps"} \
+                  "dense_stage_steps", "wide_tail_steps",
+                  "dense_lanes", "tail_lanes", "net_rows"} \
             if isinstance(eng, JaxEngine) else set()
         assert set(st) == _STATS_KEYS | routed
         if routed:

@@ -145,10 +145,17 @@ def test_the_adaptive_drivers_have_no_sort_scope(kw):
 #: in the dense form (8192 lanes and more for 2048 nodes:
 #: ``tests/test_insert_law.py`` holds it to the scatters). Until then
 #: they were PR 35's (solo a591ccb6659a…, fleet 9e610618e0c2…), whose
-#: carry held three counts. A PR that changes what these drivers
-#: compute changes the constants, and says so.
+#: carry held three counts. PR 44 changed the solo constant (it was
+#: b341ad0cc880…): a solo engine's commutative inbox stages by rank,
+#: and its carry holds three counts more (``dense_lanes``,
+#: ``tail_lanes``, ``net_rows``); at these widths (16 384 lanes and
+#: fewer, under ``_TAIL_LADDER_LANES``) the staging itself is PR 36's
+#: text (``tests/test_stage_tail_law.py``), and the fleet's driver,
+#: which stages nothing, lowers to what it lowered to. A PR that
+#: changes what these drivers compute changes the constants, and
+#: says so.
 _PARENT_LOWERING = {
-    "solo": "b341ad0cc8800245e6de095ef1ece89a157c64098c84cf7050dc535788150fc8",
+    "solo": "76f791da8f0195730c00917d33fa9580aa84f0a9bb746e0e1209cd415a5f1a09",
     "fleet": "d9883414d933ff760ab83d6e05390be3fdd403566d0a41711b69a358ebc547b5",
 }
 

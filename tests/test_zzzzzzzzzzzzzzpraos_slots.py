@@ -214,13 +214,18 @@ def test_a_scenario_without_a_key_has_no_entropy_scope(make):
 #: ``tests/test_zzzzzzzzzzzzzzzrecord.py``; the arrivals staged in
 #: the dense form, steady's on every superstep, praos' in both rungs:
 #: ``tests/test_insert_law.py``). Until then they were PR 35's
-#: (steady 019784a05692…, praos 23c5c22aee01…). The wave's and the
+#: (steady 019784a05692…, praos 23c5c22aee01…). PR 44 changed both
+#: (they were 56417b93abea… and 02f9e0df7c22…): the carry of a solo
+#: engine that stages by rank holds three counts more
+#: (``dense_lanes``, ``tail_lanes``, ``net_rows``); the staging
+#: itself is PR 36's text at these widths (under
+#: ``_TAIL_LADDER_LANES``: ``tests/test_stage_tail_law.py``). The wave's and the
 #: fleet's are pinned in ``test_zzzzzzzzzzzzzsteady_mongering.py``. A
 #: PR that changes what these drivers compute changes the constants,
 #: and says so.
 _PARENT_LOWERING = {
-    "steady": "56417b93abea2f6557bc4e61c016e268bd4f06eb38c10cf1cdf17b6840912764",
-    "praos": "02f9e0df7c2262e4962b04ff70ecff4066536d798255a552a3629b8c44282db3",
+    "steady": "2cf72c06d42d1eb8125ab0d9dec3910692af50485db9ce4a878fd040f5866712",
+    "praos": "8aa4cb7fa8dca73defc011f7891d702d4dd469eaeb590fc414d1269549c3efb9",
 }
 
 

@@ -287,7 +287,13 @@ def test_the_staging_counts_say_which_form_ran(which):
     lanes at least ``_DENSE_STAGE_RATIO`` of its nodes),
     ``wide_tail_steps``: of those, the ones
     whose ranks past 0 were over half the lanes. Carried and read like
-    the rungs' counts, by the scan and the quiet driver alike."""
+    the rungs' counts, by the scan and the quiet driver alike. Since
+    PR 44 the same carry counts ``dense_lanes``, ``tail_lanes`` and
+    ``net_rows`` where insertion stages by rank: at these widths
+    (under ``_PREFIX_SCATTER_LANES`` lanes) every dense superstep
+    sends one row through the network and scatters its tail at half
+    its lanes, or at all of them (tests/test_stage_tail_law.py holds
+    the form over that lane count)."""
     if which == "burst":
         # 64 nodes, fanout 16 in one firing, delays inside one window:
         # the third generation sends some 750 messages on 1024 lanes,
@@ -314,16 +320,31 @@ def test_the_staging_counts_say_which_form_ran(which):
         assert not by_form[0] and by_form[-1] and min(st["rung_steps"]) > 0
         assert (dense, wide) == (sum(
             k for k, d in zip(st["rung_steps"], by_form) if d), 0)
+        lanes = sum(k * a for k, a, d in zip(st["rung_steps"], rungs,
+                                             by_form) if d)
     elif which == "eager":
         assert (dense, wide) == (steps, 0)
+        lanes = steps * N
     elif which == "burst":
         assert dense == st["supersteps"] and 0 < wide < dense
+        lanes = dense * 1024
     else:
         assert not eng._stages_by_rank() and (dense, wide) == (0, 0)
+        assert not {"dense_lanes", "tail_lanes", "net_rows"} & set(st)
+    if which != "fleet":
+        assert (st["dense_lanes"], st["net_rows"]) == (lanes, dense)
+        if which == "burst":
+            assert st["tail_lanes"] == (dense + wide) * 512
+        else:
+            assert st["tail_lanes"] == lanes // 2
+        assert profiler.calls()[-1]["counts"]["tail_lanes"] \
+            == st["tail_lanes"]
     assert profiler.calls()[-1]["counts"]["dense_stage_steps"] == dense
     eng.run(steps)
     assert (eng.last_run_stats["dense_stage_steps"],
             eng.last_run_stats["wide_tail_steps"]) == (dense, wide)
+    for key in ("dense_lanes", "tail_lanes", "net_rows"):
+        assert eng.last_run_stats.get(key) == st.get(key), key
 
 
 def test_sharded_engines_follow_their_local_twins():
@@ -351,6 +372,8 @@ def test_sharded_engines_follow_their_local_twins():
         # a device stages what it was handed, on its own nodes: 2048
         # lanes for 1024, the dense form
         assert (st["dense_stage_steps"], st["wide_tail_steps"]) == (12, 0)
+        assert (st["dense_lanes"], st["tail_lanes"], st["net_rows"]) \
+            == (12 * 2048, 12 * 1024, 12)
 
 
 def test_chunked_fleet_says_how_wide_it_routed():

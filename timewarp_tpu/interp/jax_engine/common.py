@@ -246,9 +246,11 @@ class _DriverCall:
         ``tw.wait``: the step counters, the routing stage's counts the
         driver's loop carried beside the state (``counts``: a
         ``(rung_lanes, sender_lanes, rung_steps, dense_stage_steps,
-        wide_tail_steps, fan_in_peak, scatter_lanes)`` of device
-        arrays, the last two None but from an ordered inbox (the last
-        from a solo one on one device), ``engine.py``
+        wide_tail_steps, fan_in_peak, scatter_lanes, dense_lanes,
+        tail_lanes, net_rows)`` of device arrays, ``fan_in_peak`` and
+        ``scatter_lanes`` None but from an ordered inbox (the second
+        from a solo one on one device), the last three None but from
+        an insertion staged by rank, ``engine.py``
         ``RouteCounts``; None from an engine with no ladder to count),
         a node-sharded edge engine's boundary messages (``crossed``:
         one count a shard, ``sharded.py`` ``ShardedEdgeEngine``; None
@@ -273,7 +275,8 @@ class _DriverCall:
             stats.update(world_supersteps=d.tolist(),
                          fleet_iterations=int(d.max()))
         if counts is not None:
-            *counts, fan_in, scattered = counts
+            *counts, fan_in, scattered, dense_lanes, tail_lanes, rows = \
+                counts
             if d.ndim:
                 # one rung for all the worlds of a superstep: every
                 # world counted the same (a world-sharded fleet: the
@@ -290,6 +293,10 @@ class _DriverCall:
                 stats.update(fan_in_peak=int(np.max(fan_in)))
             if scattered is not None:
                 stats.update(scatter_lanes=int(scattered))
+            if dense_lanes is not None:
+                stats.update(dense_lanes=int(dense_lanes),
+                             tail_lanes=int(tail_lanes),
+                             net_rows=int(rows))
         if crossed is not None:
             # counted on each shard beside its state, summed here
             stats.update(shards=len(crossed),
@@ -342,6 +349,15 @@ class RunStatsMixin:
                                 # insertion's scatters, summed over
                                 # the iterations: the width taken, the
                                 # call's lanes where one scatter ran
+
+    for a solo general engine with a commutative inbox (its
+    insertion stages the arrivals by rank, engine.py
+    ``_stage_by_rank``)::
+
+        {"dense_lanes": int,  # the message lanes of the iterations
+                              # staged in the dense form
+         "tail_lanes": int,   # the width their tails' scatters took
+         "net_rows": int}     # the rows they sent through the network
 
     for the node-sharded edge engine (``ShardedEdgeEngine``)::
 
@@ -436,7 +452,8 @@ class RunStatsMixin:
         for key in ("rung_lanes", "sender_lanes", "fleet_iterations",
                     "rung_steps", "world_supersteps",
                     "dense_stage_steps", "wide_tail_steps",
-                    "scatter_lanes", "boundary_msgs"):
+                    "scatter_lanes", "dense_lanes", "tail_lanes",
+                    "net_rows", "boundary_msgs"):
             if chunks and all(key in c for c in chunks):
                 cols = [c[key] for c in chunks]
                 self.last_run_stats[key] = sum(cols) \

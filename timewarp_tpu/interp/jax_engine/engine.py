@@ -103,6 +103,44 @@ _DENSE_STAGE_RATIO = 0.25
 #: for 57, and the cut to an eighth 238.
 _PREFIX_SCATTER_LANES = 1 << 14
 
+#: ``_stage_by_rank``'s dense form places its arrivals rank by rank
+#: (``_dense_plan``) where the call has at least this many lanes: the
+#: ranks under R through one ``expand_lanes`` over R rows' lanes, the
+#: rest scattered at the smallest of the static widths ``L /
+#: _TAIL_DIVISORS`` that holds it. Under it PR 36's text (rank 0
+#: through the network, the rest at half the lanes or all). R is 2
+#: where the call's lanes are at least ``_NET_ROW_RATIO`` times its
+#: nodes, else 1. Measured on one v5e (profiling/
+#: stage_tail_micro_r08.py, PR 44; the whole table is in
+#: docs/engines.md "The dense staging's tail, by how it is placed"),
+#: us a call after the sort, uniform destinations, two fields / three:
+#:
+#:     lanes / nodes     PR 36's form    R = 1           R = 2
+#:     2^20 nodes  2    21 144 / 31 646  21 157 / 31 663  11 067 / 16 502
+#:                 1     5 761 /  8 588   5 764 /  8 587   2 076 /  3 068
+#:                 1/2   3 185 /  4 732   1 907 /  2 820   1 402 /  2 049
+#:                 1/4   1 890 /  2 784     949 /  1 350   1 063 /  1 568
+#:     2^17 nodes  1       784 /  1 141     796 /  1 151     364 /    492
+#:                 1/4     320 /    438     235 /    280     233 /    317
+#:
+#: The network takes 219, 333 and 466 us over one, two and three rows
+#: of 2^20 lanes (107, 114, 133 at 2^17), a declared scatter into a
+#: fresh ``[24 n]`` buffer 285, 461, 781 and 1 419 us at n/32 ... n/4
+#: lanes: some 120 us and 5 ns a lane. On a burst whose tail takes the
+#: full width (eight arrivals a node) the second row costs 178-266 us
+#: of 10 877-16 252 and the switch nothing. 2^15 lanes is the least
+#: the micro measured (2^17 nodes, a lane to four nodes) and the least
+#: any cell stages densely. The second row wins down to half a lane
+#: a node (by 17-27 % at both sizes) and is a wash or a loss at a
+#: quarter: the ratio is 1/2. A third row at a lane a node with L/32
+#: for L/4 wins on uniform lanes too (-40 %; a steady job 207.2 ->
+#: 194.0 ms), but three rows of three fields at 2^20 lose 1.0 ms on
+#: the burst (0.5 ms a row) and praos' and the wave's cells were not
+#: measured with it: ROADMAP B1 (b).
+_TAIL_LADDER_LANES = 1 << 15
+_TAIL_DIVISORS = (8, 4, 2, 1)
+_NET_ROW_RATIO = 0.5
+
 
 class EngineState(NamedTuple):
     """The complete simulation state — one pytree, trivially
@@ -183,8 +221,9 @@ class RouteCounts(NamedTuple):
     the call reads it in its one transfer (``last_run_stats``, the
     call's record). The first three come from the two scalars
     ``_route_adaptive`` holds when it picks its branch (the active
-    senders and the rung's index), the last two from the one scalar
-    ``_stage_by_rank``'s dense form picks its tail's width by: no
+    senders and the rung's index), the next two and the last three
+    from the one scalar ``_stage_by_rank``'s dense form picks its
+    tail's width by: no
     pass over node- or mailbox-sized data is made for them
     (``fan_in_peak`` is one reduction over the message lanes that an
     ordered inbox's insertion has ranked already). Where
@@ -200,8 +239,9 @@ class RouteCounts(NamedTuple):
     #: int64[] — iterations whose arrivals were staged in the dense
     #: form (``_stage_by_rank``; a fleet's: none)
     dense_stage_steps: jax.Array
-    #: int64[] — of those, the ones whose tail (ranks past 0) was over
-    #: half the lanes and went through the full-width scatter
+    #: int64[] — of those, the ones whose tail (the ranks that no
+    #: row of the network took) was over half the lanes and went
+    #: through the full-width scatter
     wide_tail_steps: jax.Array
     #: int32[] — the most arrivals to one destination in one superstep
     #: (``_insert_sorted``'s largest rank + 1 over its valid lanes): a
@@ -216,6 +256,14 @@ class RouteCounts(NamedTuple):
     #: Carried by a solo engine on one device whose inbox is ordered
     #: and by no other (``_cuts_scatters``; None elsewhere, as above)
     scatter_lanes: Any = None
+    #: int64[] each — the message lanes of every iteration staged in
+    #: the dense form, the width its tail's scatters took and the rows
+    #: that went through the network (``_dense_plan``), summed over
+    #: the iterations. Carried where insertion stages by rank
+    #: (``_stages_by_rank``; None elsewhere, as above)
+    dense_lanes: Any = None
+    tail_lanes: Any = None
+    net_rows: Any = None
 
 
 class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
@@ -783,9 +831,10 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         needs to know (``_fill_staged``). A deliver time's "nothing"
         is the hole's own ``_I32MAX`` (a sampled one is clamped under
         it). Returns ``(rel, src or None, payload words, over,
-        wide)``; ``over`` counts the arrivals past the K-th at one
-        node, which no mailbox can hold; ``wide`` is 1 where the dense
-        form's tail went at full width.
+        took)``; ``over`` counts the arrivals past the K-th at one
+        node, which no mailbox can hold; ``took`` is the index of the
+        width the dense form's tail was scattered at, of
+        ``_dense_plan``'s (the top one: the full width).
 
         Two forms, one result, chosen by the call's shapes
         (``_stages_dense``). Few lanes for the nodes: one 1D scatter
@@ -820,67 +869,122 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         over = jnp.sum(ok_s & (rank >= K), dtype=jnp.int32)
         return rel, src, pay, over, jnp.int32(0)
 
+    def _dense_plan(self, L: int) -> Tuple[int, Tuple[int, ...]]:
+        """How ``_stage_dense`` places a call of ``L`` lanes: the rows
+        that go through the network, and the static widths,
+        ascending, of which the scatters of the rest take the
+        smallest that holds them. Facts of the call's shapes. From
+        ``_TAIL_LADDER_LANES`` lanes on: ``L/8``, ``L/4``,
+        ``L/2``, ``L`` (``_TAIL_DIVISORS``, rounded up; the top one
+        stays, no message may be lost), and row 1 beside row 0 where
+        the lanes are ``_NET_ROW_RATIO`` times the nodes. Under it:
+        row 0 and the two widths ``L // 2``, ``L`` of PR 36's form,
+        whose text the dense staging then keeps."""
+        if L < _TAIL_LADDER_LANES:
+            return 1, (L // 2, L)
+        rows = 2 if L >= _NET_ROW_RATIO * self.comm.n_local else 1
+        return (min(rows, self.scenario.mailbox_cap),
+                tuple(-(-L // d) for d in _TAIL_DIVISORS))
+
+    def _tail_counts(self, lanes, idx, took):
+        """What a superstep staged by ``_stage_by_rank`` at
+        ``lanes[idx]`` message lanes adds to the drivers' counts
+        (``_count_route``) beside whether that call is dense: whether
+        its tail took the full width, its lanes, the width of its
+        tail and its rows through the network (zeros where the call
+        is not dense). ``lanes`` is static (a ladder: a rung's),
+        ``idx`` and ``took`` (``_stage_by_rank``'s last) are scalars
+        of the program: table reads, outside the routing switch."""
+        plans = [self._dense_plan(L) if self._stages_dense(L)
+                 else (0, (0,)) for L in lanes]
+        most = max(len(w) for _, w in plans)
+        row = jnp.asarray(
+            [(R > 0, len(w) - 1, L * (R > 0), R) + w
+             + w[-1:] * (most - len(w))
+             for L, (R, w) in zip(lanes, plans)], jnp.int32)[idx]
+        dense, top, L, R = row[:4]
+        return (dense * (took == top).astype(jnp.int32), L,
+                row[4:][took], R)
+
     def _stage_dense(self, sd, ok_s, rank, fits, drel_s, src_s, pay_s):
         """``_stage_by_rank`` where the lanes are many for the nodes.
         One variadic sort by the staged index ``rank * n + d`` (lanes
         that do not fit: distinct indices past ``K * n``) carries
-        every field, and after it:
+        every field, and after it the arrivals stand rank by rank,
+        each rank's run ascending in destination
+        (``_dense_plan`` says how many rows ``R`` and which widths):
 
-        - the rank-0 arrivals (in a steady superstep 1 - 1/e of the
-          nodes get one) stand first, compacted, ascending in
-          destination: row 0 is their monotone expansion over the
-          node lanes (ops/numeric.py ``expand_lanes``), with no index
-          at all;
-        - the ranks past 0 follow from lane ``c0`` on, already in
-          scatter order: where they are at most half the lanes (one
-          scalar, one ``lax.cond``) a ``dynamic_slice`` of half the
-          lanes is scattered, else all of them, declared sorted and
-          unique, both true by the sort, so the compiler puts no sort
-          of its own in front. A slice clamped at the lanes' end
-          takes rank-0 lanes in with it; row 0 is written over them.
+        - the arrivals of the ranks under ``R`` (in a steady
+          superstep 1 - 1/e of the nodes get a first, 0.26 of them a
+          second) stand first, compacted, ascending in staged index:
+          rows 0 … R-1 are their monotone expansion over ``R * n``
+          lanes (ops/numeric.py ``expand_lanes``), with no index at
+          all, and one contiguous piece of each flat buffer;
+        - the ranks from ``R`` on follow from lane ``c`` on, already
+          in scatter order: one scalar, their count, picks the
+          smallest of the static widths that holds them
+          (``lax.switch``; under ``_TAIL_LADDER_LANES`` the
+          ``lax.cond`` of two), and a ``dynamic_slice`` of that
+          width is scattered, declared sorted and unique, both true
+          by the sort, so the compiler puts no sort of its own in
+          front. A slice clamped at the lanes' end takes lanes of
+          the rows in with it; the rows are written over them.
 
-        Word for word the buffers of the other form
-        (tests/test_insert_law.py)."""
+        The last of the return is the index of the width taken (the
+        top one: the full width). Word for word the buffers of the
+        other form (tests/test_insert_law.py,
+        tests/test_stage_tail_law.py)."""
         sc = self.scenario
         K, P = sc.mailbox_cap, sc.payload_width
         n = self.comm.n_local
         L = sd.shape[0]
-        half = L // 2
+        R, widths = self._dense_plan(L)
         fields = (drel_s,) + ((src_s,) if sc.inbox_src else ()) \
             + tuple(pay_s[:P])
         nothing = (_I32MAX,) + (0,) * (len(fields) - 1)
         flat = jnp.where(
             fits, rank * jnp.int32(n) + sd,
             jnp.int32(K * n) + jnp.arange(L, dtype=jnp.int32))
-        c0 = jnp.sum(fits & (rank == 0), dtype=jnp.int32)
-        wide = jnp.sum(fits, dtype=jnp.int32) - c0 > half
+        c = jnp.sum(fits & (rank == 0 if R == 1 else rank < R),
+                    dtype=jnp.int32)
+        tail = jnp.sum(fits, dtype=jnp.int32) - c
+        if len(widths) == 2:
+            took = tail > widths[0]
+        else:
+            took = jnp.sum(tail > jnp.asarray(widths[:-1], jnp.int32),
+                           dtype=jnp.int32)
         flat, *fields = jax.lax.sort((flat,) + fields, num_keys=1)
 
         def head(x):
-            # the first n lanes: the rank-0 prefix is among them
-            if L >= n:
-                return x[:n]
-            return jnp.concatenate([x, jnp.zeros((n - L,), x.dtype)])
-        row0 = expand_lanes(head(flat), c0, [head(x) for x in fields],
+            # the first R * n lanes: the rows' prefix is among them
+            if L >= R * n:
+                return x[:R * n]
+            return jnp.concatenate(
+                [x, jnp.zeros((R * n - L,), x.dtype)])
+        rows = expand_lanes(head(flat), c, [head(x) for x in fields],
                             nothing)
 
-        def tail(width):
-            def scatter():
-                at = jax.lax.dynamic_slice_in_dim(flat, c0, width)
+        def scatter(width):
+            def go():
+                at = jax.lax.dynamic_slice_in_dim(flat, c, width)
                 return tuple(
                     jnp.full((K * n,), e, x.dtype).at[at].set(
-                        jax.lax.dynamic_slice_in_dim(x, c0, width),
+                        jax.lax.dynamic_slice_in_dim(x, c, width),
                         mode="drop", indices_are_sorted=True,
                         unique_indices=True)
                     for x, e in zip(fields, nothing))
-            return scatter
-        bufs = jax.lax.cond(wide, tail(L), tail(half))
+            return go
+        if len(widths) == 2:
+            bufs = jax.lax.cond(took, scatter(widths[1]),
+                                scatter(widths[0]))
+        else:
+            bufs = jax.lax.switch(took, [scatter(w) for w in widths])
         bufs = [jax.lax.dynamic_update_slice_in_dim(b, r, 0, 0)
-                for b, r in zip(bufs, row0)]
+                for b, r in zip(bufs, rows)]
         over = jnp.sum(ok_s & (rank >= K), dtype=jnp.int32)
         return (bufs[0], bufs[1] if sc.inbox_src else None,
                 tuple(bufs[len(bufs) - P:]), over,
-                wide.astype(jnp.int32))
+                took.astype(jnp.int32))
 
     def _fill_staged(self, mb_rel, mb_src, mb_payload, holes, rel, src,
                      pay, over):
@@ -1020,10 +1124,12 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         K, P = sc.mailbox_cap, sc.payload_width
         n = self.comm.n_local
         if self._stages_by_rank():
-            *staged, wide = self._stage_by_rank(sd, ok_s, drel_s, src_s,
+            *staged, took = self._stage_by_rank(sd, ok_s, drel_s, src_s,
                                                 pay_s)
-            self._staged = (jnp.int32(self._stages_dense(sd.shape[0])),
-                            self.comm.all_max(wide))
+            self._staged = (
+                jnp.int32(self._stages_dense(sd.shape[0])),
+                *self._tail_counts((sd.shape[0],), 0,
+                                   self.comm.all_max(took)))
             return self._fill_staged(mb_rel, mb_src, mb_payload, holes,
                                      *staged)
         rank = group_rank(sd)
@@ -1153,14 +1259,17 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
                 return self._stage_by_rank(sd, ok_s, drel_s, src_s,
                                            pay_s)
 
-        def filled(ret, dense):
-            # `ret` is the taken rung's return, `dense` whether that
-            # rung staged in the dense form: static a rung, so it
-            # needs no place in the switch's return, as `wide`
-            # (ret[4]) does
+        def filled(ret, dense, idx):
+            # `ret` is the return of the rung taken, `idx` that
+            # rung's index, `dense` whether that rung staged in the
+            # dense form: that, and at which widths, is static a
+            # rung, so only the index of the width taken (ret[4]) has
+            # a place in the switch's return
             if not staged:
                 return self._take_fan_in(ret)
-            self._staged = (jnp.asarray(dense, jnp.int32), ret[4])
+            self._staged = (
+                jnp.asarray(dense, jnp.int32),
+                *self._tail_counts([A * M for A in rungs], idx, ret[4]))
             with jax.named_scope("insert"):
                 return self._fill_staged(mb_rel, mb_src, mb_payload,
                                          holes, *ret[:4]) + ret[5:]
@@ -1307,7 +1416,7 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
             if self.telemetry != "off":
                 self._t_rung = jnp.int32(rungs[-1])
             return filled(tail(rungs[-1])(),
-                          self._stages_dense(rungs[-1] * M))
+                          self._stages_dense(rungs[-1] * M), 0)
         if self.batch is not None:
             # a fleet takes ONE rung for all its worlds: the smallest
             # that holds the busiest world's senders. The pmax over
@@ -1335,7 +1444,8 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         self._routed = (self._t_rung, n_active, idx.astype(jnp.int32))
         return filled(
             jax.lax.switch(idx, [tail(A) for A in rungs]),
-            jnp.asarray([self._stages_dense(A * M) for A in rungs])[idx])
+            jnp.asarray([self._stages_dense(A * M) for A in rungs])[idx],
+            idx)
 
     def _node_next(self, st: EngineState, nnr=None) -> jax.Array:
         """Each node's next event time as the state alone has it
@@ -1674,9 +1784,12 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         #: own where it takes one of several
         self._routed = (jnp.int32(n_glob), jnp.int32(n_glob), jnp.int32(0))
         #: and whether the arrivals were staged in the dense form, and
-        #: its tail went wide: the staged insertion puts its own
-        #: (``_insert_sorted``; on the ladder ``_route_adaptive``)
-        self._staged = (jnp.int32(0), jnp.int32(0))
+        #: its tail went wide (and, from there on, its lanes, its
+        #: tail's width and its rows: ``_tail_counts``): the
+        #: staged insertion puts its own (``_insert_sorted``; on the
+        #: ladder ``_route_adaptive``)
+        self._staged = (jnp.int32(0),) * (
+            5 if self._stages_by_rank() else 2)
         #: and the most arrivals to one destination, where insertion
         #: ranks them for an ordered inbox (``_take_fan_in``)
         self._fan_in = None
@@ -2316,7 +2429,8 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
             lanes, lanes,
             jnp.zeros(lanes.shape, jnp.int32) if self._ranks_fan_in()
             else None,
-            lanes if self._cuts_scatters() else None)
+            lanes if self._cuts_scatters() else None,
+            *((lanes,) * 3 if self._stages_by_rank() else ()))
 
     def _count_route(self, counts: RouteCounts, stepped=True
                      ) -> RouteCounts:
@@ -2327,7 +2441,7 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         traced bool where the caller's loop runs on past the last
         event)."""
         rung, senders, idx = self._routed
-        dense, wide = self._staged
+        dense, wide, *tail = self._staged
         bins = counts.rung_steps.shape[-1]
         one = (idx[..., None] == jnp.arange(bins, dtype=jnp.int32)
                ) & stepped
@@ -2340,7 +2454,10 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
             None if counts.fan_in_peak is None else jnp.maximum(
                 counts.fan_in_peak, jnp.where(stepped, self._fan_in, 0)),
             None if counts.scatter_lanes is None else
-            counts.scatter_lanes + jnp.where(stepped, self._scattered, 0))
+            counts.scatter_lanes + jnp.where(stepped, self._scattered, 0),
+            *(c + jnp.where(stepped, x, 0) for c, x in zip(
+                (counts.dense_lanes, counts.tail_lanes, counts.net_rows),
+                tail)))
 
     def _step_counted(self, carry, with_trace: bool):
         """``_step_all`` on a driver loop's ``(state, counts)`` carry.

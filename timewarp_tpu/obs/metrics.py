@@ -21,7 +21,8 @@ Kinds:
   the engine keeps them: ``rung_lanes``, ``sender_lanes``,
   ``rung_steps``, ``dense_stage_steps``, ``wide_tail_steps``, a
   fleet's ``fleet_iterations``, an ordered inbox's ``fan_in_peak`` and,
-  solo on one device, its ``scatter_lanes``).
+  solo on one device, its ``scatter_lanes``, a staged insertion's
+  ``dense_lanes``, ``tail_lanes``, ``net_rows``).
 - ``utilization`` — per-bucket sweep utilization (sweep/runner.py):
   worlds-active occupancy, budget-mask efficiency, pow2 scan-pad
   waste.
@@ -74,7 +75,8 @@ _NUM = (int, float)
 #: where the driver call counted them (common.py ``RunStatsMixin``)
 _RUN_COUNTS = ("dispatches", "readbacks", "rung_lanes", "sender_lanes",
                "fleet_iterations", "dense_stage_steps", "wide_tail_steps",
-               "fan_in_peak", "scatter_lanes")
+               "fan_in_peak", "scatter_lanes", "dense_lanes", "tail_lanes",
+               "net_rows")
 #: kind -> {required field: type tuple}; extra fields are allowed
 #: (forward-compatible), missing/badly-typed required ones are not
 _KINDS: Dict[str, Dict[str, tuple]] = {
