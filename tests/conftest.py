@@ -35,6 +35,13 @@ os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "0"
 import jax  # noqa: E402
 import pytest  # noqa: E402
 
+# the modules that hold what several test files share (no test lives
+# in them, no ``test_`` leads their names): their asserts read like a
+# test file's own
+pytest.register_assert_rewrite(*(
+    name[:-3] for name in sorted(os.listdir(os.path.dirname(__file__)))
+    if name.endswith(".py") and not name.startswith(("test_", "conftest"))))
+
 jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_enable_x64", True)
 jax.config.update("jax_enable_compilation_cache", False)

@@ -1,0 +1,12 @@
+"""bench.py's configs at tiny scale: the flight recorder's and the
+verifier's rows (tests/bench_rows.py has the run and what each line
+must carry)."""
+
+import pytest
+
+from bench_rows import ROWS, bench_config_runs
+
+
+@pytest.mark.parametrize("cfg", ROWS["_gates"])
+def test_bench_config_runs(cfg, monkeypatch):
+    bench_config_runs(cfg, monkeypatch)

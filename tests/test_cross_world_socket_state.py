@@ -9,7 +9,7 @@ and counts per client — final counters and send counts equal; the
 batched twin itself holds the bit-exact oracle ≡ engine trace law
 like every other scenario (and appears in tools/parity_tpu.py /
 PARITY_TPU.json; its 1023-way hub fan-in is a case of
-tests/test_insert_law.py)."""
+tests/test_insert_scenarios.py)."""
 
 import numpy as np
 import pytest

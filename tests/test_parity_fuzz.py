@@ -1,6 +1,12 @@
 """Property-based parity fuzz: randomized scenario families must match
 the oracle bit-for-bit on every draw — the dual-interpreter law under
-configurations nobody hand-picked."""
+configurations nobody hand-picked.
+
+Every example is a scenario of its own, so every example compiles its
+own engines (7-14 s each on the CPU). Tier-1 walks a few, derandomized:
+the same few in every run. The full random draw is the ``slow`` case of
+the same test, which CI runs (pyproject.toml: its pytest invocations
+apply no marker filter)."""
 
 import jax.numpy as jnp
 import numpy as np
@@ -18,6 +24,17 @@ from timewarp_tpu.net.delays import UniformDelay, WithDrop
 from timewarp_tpu.trace.events import assert_traces_equal
 
 N = 12  # fixed shape: keeps XLA recompiles per example cheap
+
+#: (examples a test, derandomized): tier-1's few, the same in every
+#: run, and the full random draw
+DRAWS = [pytest.param(3, True, id="first3"),
+         pytest.param(15, False, id="full-draw", marks=pytest.mark.slow)]
+
+
+def _hold(law, examples, derandomize):
+    """``law`` over ``examples`` draws of ``st.data()``."""
+    settings(max_examples=examples, deadline=None, derandomize=derandomize)(
+        given(data=st.data())(law))()
 
 
 def _rand_scenario(periods, dsts, end_us, commutative):
@@ -63,9 +80,12 @@ def _rand_scenario(periods, dsts, end_us, commutative):
         commutative_inbox=commutative)
 
 
-@settings(max_examples=15, deadline=None)
-@given(data=st.data())
-def test_randomized_scenario_parity(data):
+@pytest.mark.parametrize("examples, derandomize", DRAWS)
+def test_randomized_scenario_parity(examples, derandomize):
+    _hold(_scenario_parity, examples, derandomize)
+
+
+def _scenario_parity(data):
     rng = np.random.default_rng(data.draw(st.integers(0, 2**31)))
     periods = rng.integers(500, 5_000, N)
     commutative = bool(data.draw(st.booleans()))
@@ -94,12 +114,15 @@ def test_randomized_scenario_parity(data):
     assert_traces_equal(ot2, et, "oracle", "edge", limit=len(et))
 
 
-@settings(max_examples=15, deadline=None)
-@given(data=st.data())
-def test_randomized_windowed_parity(data):
+@pytest.mark.parametrize("examples, derandomize", DRAWS)
+def test_randomized_windowed_parity(examples, derandomize):
     """The windowed path under randomized timers/links: engine ≡
     windowed oracle bit-for-bit for any window ≤ the link's declared
     delay floor, with and without a route_cap."""
+    _hold(_windowed_parity, examples, derandomize)
+
+
+def _windowed_parity(data):
     from timewarp_tpu.net.delays import Quantize
 
     rng = np.random.default_rng(data.draw(st.integers(0, 2**31)))

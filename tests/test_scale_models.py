@@ -1,11 +1,15 @@
 """Gossip (BASELINE config 4) and Praos (config 5) scenarios: trace
 parity at small n across oracle / 1-device general engine / 8-device
 all_to_all sharded engine, plus behavioral sanity (the rumor actually
-spreads; the chain actually grows)."""
+spreads; the chain actually grows). Gossip is here; Praos is
+tests/test_scale_praos.py (tests/scale_laws.py has the three-way
+comparison)."""
 
-import jax
 import numpy as np
 
+import jax
+
+from scale_laws import three_way
 from timewarp_tpu.interp.jax_engine.engine import JaxEngine
 from timewarp_tpu.interp.jax_engine.sharded import ShardedEngine, make_mesh
 from timewarp_tpu.interp.ref.superstep import SuperstepOracle
@@ -13,15 +17,6 @@ from timewarp_tpu.models.gossip import gossip, gossip_links
 from timewarp_tpu.models.praos import praos
 from timewarp_tpu.net.delays import UniformDelay, WithDrop
 from timewarp_tpu.trace.events import assert_traces_equal
-
-
-def three_way(sc, link, steps):
-    ot = SuperstepOracle(sc, link).run(10 * steps)
-    lst, lt = JaxEngine(sc, link).run(steps)
-    sst, st = ShardedEngine(sc, link, make_mesh(8)).run(steps)
-    assert_traces_equal(ot, lt, "oracle", "local", limit=len(lt))
-    assert_traces_equal(ot, st, "oracle", "sharded", limit=len(st))
-    return lst, lt
 
 
 def test_gossip_lognormal_parity_and_spread():
@@ -45,36 +40,6 @@ def test_gossip_with_drop_parity():
     fst, _ = three_way(sc, link, 600)
     hops = np.asarray(jax.device_get(fst.states["hop"]))
     assert (hops >= 0).mean() > 0.9  # drops may strand a few
-
-
-def test_praos_parity_and_chain_growth():
-    sc = praos(64, slot_us=100_000, n_slots=3, leader_prob=0.05,
-               fanout=6, relay_interval=2_000)
-    link = UniformDelay(3_000, 25_000)
-    fst, lt = three_way(sc, link, 4000)
-    best = np.asarray(jax.device_get(fst.states["best"]))
-    slots = np.asarray(jax.device_get(fst.states["slot"]))
-    assert (slots == 3).all()        # every node saw every slot
-    assert best.max() >= 2           # E[leaders/slot]=3.2: chain grew
-    # consensus: most nodes converged on the longest chain
-    assert (best == best.max()).mean() > 0.8
-    assert lt.total_delivered() > 100
-
-
-def test_praos_leadership_is_deterministic():
-    """Same seed -> identical chain; different seed -> (almost surely)
-    different leadership schedule."""
-    sc = praos(32, slot_us=50_000, n_slots=4, leader_prob=0.1,
-               fanout=4, relay_interval=1_000)
-    link = UniformDelay(1_000, 9_000)
-    a, _ = JaxEngine(sc, link, seed=0).run(400)
-    b, _ = JaxEngine(sc, link, seed=0).run(400)
-    c, _ = JaxEngine(sc, link, seed=7).run(400)
-    ba = np.asarray(jax.device_get(a.states["best"]))
-    bb = np.asarray(jax.device_get(b.states["best"]))
-    bc = np.asarray(jax.device_get(c.states["best"]))
-    assert np.array_equal(ba, bb)
-    assert not np.array_equal(ba, bc)
 
 
 def test_sharded_general_run_quiet_matches_traced():
@@ -153,30 +118,3 @@ def test_general_engine_overflow_parity_with_oracle():
     sst, st = ShardedEngine(sc, link, make_mesh(8)).run(300)
     assert_traces_equal(ot, st, "oracle", "sharded", limit=len(st))
     assert int(sst.overflow) == int(fst.overflow)
-
-
-def test_praos_stake_weighted_leadership():
-    """Stake weights scale leadership linearly; zero stake never
-    leads; parity holds across oracle / local / sharded with the
-    per-node thresholds."""
-    n = 64
-    stake = np.zeros(n, np.int64)
-    stake[:8] = 50          # 8 whales hold all the stake
-    sc = praos(n, slot_us=50_000, n_slots=4, leader_prob=0.01,
-               stake=stake, fanout=4, relay_interval=1_000)
-    link = UniformDelay(2_000, 9_000)
-    fst, lt = three_way(sc, link, 3000)
-    best = np.asarray(jax.device_get(fst.states["best"]))
-    slots = np.asarray(jax.device_get(fst.states["slot"]))
-    assert (slots == 4).all()
-    assert best.max() >= 1  # E[leaders/slot] = 8*50*0.01 = 4
-    # determinism across runs: only whales can have minted; a non-whale
-    # node's chain can only come from adoption, so every non-whale best
-    # must be <= the whale max (trivially true) — the sharper check is
-    # that with zero-stake-only there are no blocks at all
-    sc0 = praos(n, slot_us=50_000, n_slots=4, leader_prob=0.01,
-                stake=np.zeros(n, np.int64), fanout=4,
-                relay_interval=1_000)
-    f0, t0 = JaxEngine(sc0, link).run(500)
-    assert int(np.asarray(jax.device_get(f0.states["best"])).max()) == 0
-    assert t0.total_delivered() == 0

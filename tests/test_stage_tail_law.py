@@ -7,12 +7,12 @@ rows through one ``expand_lanes`` over ``R * n`` lanes and scatters
 what is left at the smallest of four static widths that holds it
 (``_dense_plan``); under that lane count it keeps PR 36's text. Of
 tier-1's engines one has the lanes (the ladder of 20 000 nodes in
-tests/test_insert_law.py, whose rungs of 8192 senders and over are
+tests/test_insert_slot_law.py, whose rungs of 8192 senders and over are
 32 768 lanes and more), so every test here patches the constant down
 to 64 *before* the engine's first trace.
 
 - One call of ``_stage_by_rank`` on built lanes against a scatter a
-  field (``tests/test_insert_law.py`` ``parent_stage_by_rank``), word
+  field (``tests/insertion_laws.py`` ``parent_stage_by_rank``), word
   for word, with the width it must take counted by hand: Poisson
   arrivals, a tail of exactly each width and one lane over it, a run
   of the last row that ends at the lanes' last lane (L = n/4, n/2, n,
@@ -40,8 +40,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from test_insert_law import (ParentInsert, _STEADY_LINK, _WAVE_LINK, _burst,
-                             _observer_ring, _steady, parent_stage_by_rank)
+from insertion_laws import (ParentInsert, _STEADY_LINK, _WAVE_LINK, _burst,
+                            _observer_ring, _steady, parent_stage_by_rank)
 from timewarp_tpu.interp.jax_engine import engine as engine_module
 from timewarp_tpu.interp.jax_engine.batched import BatchSpec
 from timewarp_tpu.interp.jax_engine.common import I32MAX, group_rank

@@ -5,21 +5,21 @@ world b out of ANY batched run — traced or quiet, local or sharded,
 seed-swept or link-swept — is bit-identical to the solo run with that
 world's seed and link. Plus the driver-side guarantees that make the
 law hold (per-world quiescence and step-budget masking) and the
-pow2-padded ``_run_scan`` compile-reuse contract.
+pow2-padded ``_run_scan`` compile-reuse contract. The ladder's shared
+rung and the fleet sharded over a mesh are
+tests/test_world_batch_sharded.py.
 """
 
 import numpy as np
 import pytest
 
-from timewarp_tpu.interp.jax_engine.batched import (BatchSpec,
-                                                    rebind_link,
+from timewarp_tpu.interp.jax_engine.batched import (BatchSpec, rebind_link,
                                                     world_slice)
 from timewarp_tpu.interp.jax_engine.engine import JaxEngine, _scan_pad
 from timewarp_tpu.models.gossip import gossip
 from timewarp_tpu.models.token_ring import token_ring, token_ring_links
 from timewarp_tpu.net.delays import Quantize, UniformDelay
-from timewarp_tpu.trace.events import (assert_states_equal,
-                                       assert_traces_equal)
+from timewarp_tpu.trace.events import assert_states_equal, assert_traces_equal
 
 
 def _ring(n=48):
@@ -34,7 +34,7 @@ def _burst_gossip(n=64):
     return sc, Quantize(UniformDelay(3_000, 9_000), 1_000)
 
 
-# -- the exactness law -----------------------------------------------------
+
 
 def test_batched_run_slices_equal_solo():
     sc, link = _ring()
@@ -111,27 +111,6 @@ def test_batched_resume_across_worlds():
             full[b].recv_hash)
 
 
-def test_batched_shares_one_rung_exactly():
-    """At n > 1024 the routing ladder is live in the solo engine and
-    in the fleet alike: a solo world takes the smallest rung that
-    holds its own senders, a fleet one rung for all its worlds, the
-    smallest that holds the busiest's (engine.py ``_route_adaptive``).
-    The law says rung choice is result-invisible, so the slices must
-    still match bit-for-bit."""
-    n = 2048
-    sc = gossip(n, fanout=4, think_us=700, burst=True, end_us=60_000,
-                mailbox_cap=16)
-    link = Quantize(UniformDelay(3_000, 9_000), 1_000)
-    assert len(JaxEngine._sender_rungs(n)) > 1  # ladder actually live
-    eng = JaxEngine(sc, link, window=3_000, batch=BatchSpec(seeds=(0, 4)))
-    fin = eng.run_quiet(8)
-    # the wave's first supersteps fit the narrow rung: the fleet took it
-    assert eng.last_run_stats["rung_lanes"] < 8 * n
-    for b, s in enumerate((0, 4)):
-        solo = JaxEngine(sc, link, seed=s, window=3_000).run_quiet(8)
-        assert_states_equal(solo, world_slice(fin, b), f"world {b}")
-
-
 def test_batched_window_auto_resolves_fleet_floor():
     """window="auto" under a link sweep must use the MIN over every
     world's declared floor — the widest window exact fleet-wide."""
@@ -142,42 +121,6 @@ def test_batched_window_auto_resolves_fleet_floor():
     eng = JaxEngine(sc, link, window="auto", batch=spec)
     assert eng.window == 3000
 
-
-# -- sharded fleet ---------------------------------------------------------
-
-@pytest.mark.parametrize("devices", [8, 4])
-def test_sharded_batched_equals_local_fleet(devices):
-    """ShardedBatchedEngine (worlds sharded over the mesh, nodes
-    device-local): 8 worlds over 8 or 4 virtual CPU devices must
-    reproduce the local batched engine — and hence every solo run —
-    bit-for-bit, traced and quiet."""
-    from timewarp_tpu.interp.jax_engine.sharded import (
-        ShardedBatchedEngine, make_mesh)
-    sc, link = _ring(32)
-    spec = BatchSpec(seeds=tuple(range(8)))
-    sh = ShardedBatchedEngine(sc, link,
-                              make_mesh(devices, axis="worlds"),
-                              batch=spec)
-    local = JaxEngine(sc, link, batch=spec)
-    shf, shtr = sh.run(100)
-    lof, lotr = local.run(100)
-    for b in range(8):
-        assert_traces_equal(lotr[b], shtr[b], "local", f"sharded w{b}")
-    assert_states_equal(lof, shf, "sharded fleet state")
-    assert_states_equal(local.run_quiet(60), sh.run_quiet(60),
-                        "sharded fleet run_quiet")
-
-
-def test_sharded_batched_rejects_indivisible_fleet():
-    from timewarp_tpu.interp.jax_engine.sharded import (
-        ShardedBatchedEngine, make_mesh)
-    sc, link = _ring(32)
-    with pytest.raises(ValueError, match="not divisible"):
-        ShardedBatchedEngine(sc, link, make_mesh(4, axis="worlds"),
-                             batch=BatchSpec(seeds=(0, 1, 2)))
-
-
-# -- spec validation / guards ---------------------------------------------
 
 def test_batchspec_validation_errors():
     with pytest.raises(ValueError, match="at least one world"):
@@ -219,7 +162,7 @@ def test_batched_engine_guards():
                          "inner.hi": [9000, 9000]}))
 
 
-# -- pow2-padded scan driver (compile reuse) -------------------------------
+
 
 def test_scan_pad_buckets():
     assert [_scan_pad(m) for m in (0, 1, 2, 3, 4, 5, 8, 9, 1000)] == \

@@ -22,6 +22,12 @@ The laws under test:
   (never re-made) on resume, and the survival law's solo twin replays
   the bucket's decision chain.
 
+Here: the zero-recompile accounting, the ``window="auto"`` edge cases,
+the controller's refusals and its journal. The replay law and the
+per-chunk equivalence on a solo run are tests/test_dispatch_replay_law.py,
+fleets and the sweep tests/test_dispatch_fleets.py
+(tests/dispatch_laws.py has what the three share).
+
 (Named test_zzz* to sort after the whole suite — the tier-1 time
 window truncates, so new tests must not displace existing dots.)
 """
@@ -31,163 +37,17 @@ import json
 import numpy as np
 import pytest
 
-import jax
-
+from dispatch_laws import (BUDGET, _auto_engine, _ctrl_pack, _shrink_sched,
+                           _wave)
 from timewarp_tpu.core.time import FOREVER
-from timewarp_tpu.dispatch import (Decision, DecisionTrace,
-                                   DispatchController,
+from timewarp_tpu.dispatch import (Decision, DecisionTrace, DispatchController,
                                    DispatchTraceError)
-from timewarp_tpu.faults.schedule import (FaultFleet, FaultSchedule,
-                                          LinkWindow)
-from timewarp_tpu.interp.jax_engine.batched import BatchSpec, world_slice
+from timewarp_tpu.faults.schedule import FaultFleet, FaultSchedule
+from timewarp_tpu.interp.jax_engine.batched import BatchSpec
 from timewarp_tpu.interp.jax_engine.common import DynDispatch
 from timewarp_tpu.interp.jax_engine.engine import JaxEngine
-from timewarp_tpu.models.gossip import gossip, gossip_links
-from timewarp_tpu.net.delays import FixedDelay, Quantize
-from timewarp_tpu.trace.events import (assert_states_equal,
-                                       assert_traces_equal)
-
-BUDGET = 1 << 14
-
-
-def _wave(n=64, end_us=200_000, mailbox_cap=16):
-    sc = gossip(n, fanout=4, think_us=2_000, burst=True,
-                end_us=end_us, mailbox_cap=mailbox_cap)
-    link = Quantize(gossip_links(median_us=20_000, sigma=0.6,
-                                 floor_us=8_000), 1_000)
-    return sc, link
-
-
-def _shrink_sched():
-    """A degradation window that UNDERCUTS the link's declared 8 ms
-    floor (2 ms inside [40 ms, 90 ms))."""
-    return FaultSchedule((LinkWindow(None, None, 40_000, 90_000,
-                                     scale=0.25),))
-
-
-def _auto_engine(sc, link, **kw):
-    return JaxEngine(sc, link, window="auto", telemetry="counters",
-                     lint="off",
-                     controller=DispatchController(chunk=8,
-                                                   chunk_max=32),
-                     **kw)
-
-
-def _replay_engine(sc, link, decisions, **kw):
-    return JaxEngine(sc, link, window="auto", lint="off",
-                     controller=DispatchController(
-                         mode="replay",
-                         replay=DecisionTrace.of(decisions)), **kw)
-
-
-# -- the replay law --------------------------------------------------------
-
-def test_replay_law_solo_bit_identical(tmp_path):
-    sc, link = _wave()
-    eng = _auto_engine(sc, link)
-    final, trace = eng.run_controlled(BUDGET)
-    decs = eng.last_run_decisions
-    assert len(decs) >= 2, "run too short to exercise adaptation"
-    # trace file round-trip: what --decisions-out writes is what
-    # --controller replay: loads
-    path = str(tmp_path / "trace.jsonl")
-    DecisionTrace.of(decs).save(path)
-    rep = _replay_engine(sc, link, DecisionTrace.load(path).decisions)
-    final2, trace2 = rep.run_controlled(BUDGET)
-    assert_traces_equal(trace, trace2, "auto", "replay")
-    assert_states_equal(final, final2, "replay law (solo)")
-    assert [d.chunk for d in rep.last_run_decisions] == \
-        [d.chunk for d in decs]
-
-
-def test_replay_law_checkpoint_identical(tmp_path):
-    """Checkpoints written mid-run by the two sides are bit-equal:
-    drive both engines chunk-by-chunk over the same decisions and
-    compare the state pytree after every chunk."""
-    sc, link = _wave()
-    eng = _auto_engine(sc, link)
-    eng.run_controlled(BUDGET)
-    decs = eng.last_run_decisions
-    rep = _replay_engine(sc, link, decs)
-    rep.controller.begin(rep)
-    st_a, st_b = eng.init_state(), rep.init_state()
-    for d in decs:
-        dyn = eng.dyn_values(d)
-        st_a, _ = eng.run(d.chunk_len, state=st_a, _dyn=dyn)
-        st_b, _ = rep.run(d.chunk_len, state=st_b,
-                          _dyn=rep.dyn_values(d))
-        assert_states_equal(st_a, st_b,
-                            f"checkpoint after chunk {d.chunk}")
-
-
-def test_per_chunk_equals_static_run(tmp_path):
-    """Each chunk of a (degradation-free) controlled run ≡ a STATIC
-    engine constructed with that chunk's window, run for the same
-    budget from the same state."""
-    sc, link = _wave()
-    eng = _auto_engine(sc, link)
-    eng.run_controlled(BUDGET)
-    decs = eng.last_run_decisions
-    ctl = _replay_engine(sc, link, decs)
-    ctl.controller.begin(ctl)
-    st_c = ctl.init_state()
-    st_s = None
-    for d in decs:
-        static = JaxEngine(sc, link, window=d.window_us, lint="off")
-        if st_s is None:
-            st_s = static.init_state()
-        st_c, tr_c = ctl.run(d.chunk_len, state=st_c,
-                             _dyn=ctl.dyn_values(d))
-        st_s, tr_s = static.run(d.chunk_len, state=st_s)
-        assert_traces_equal(tr_s, tr_c, "static", "chunk")
-        assert_states_equal(st_s, st_c,
-                            f"chunk {d.chunk} ≡ static "
-                            f"window={d.window_us}")
-
-
-def test_replay_law_batched_faulted_with_slack_reduction():
-    """The world axis + per-world fault schedules, one of which
-    undercuts the link floor: the fleet decision trace records the
-    slack/load reductions, short_delay stays 0 (the device clamp
-    held), and replay is bit-identical per world."""
-    B = 3
-    sc, link = _wave(n=48, end_us=150_000)
-    fleet = FaultFleet((
-        FaultSchedule(()),
-        _shrink_sched(),
-        FaultSchedule((LinkWindow(None, None, 20_000, 60_000,
-                                  scale=0.5),)),
-    ))
-    spec = BatchSpec(seeds=(0, 1, 2))
-    eng = _auto_engine(sc, link, batch=spec, faults=fleet)
-    assert eng.window == 8_000, \
-        "controller bound must be the UNDEGRADED fleet floor"
-    final, traces = eng.run_controlled(BUDGET)
-    assert int(np.asarray(final.short_delay).sum()) == 0, \
-        "device window clamp failed under the degradation fleet"
-    decs = eng.last_run_decisions
-    agg = [d.obs.get("agg") for d in decs if "agg" in d.obs]
-    assert any("min-over-worlds" in a for a in agg), \
-        "fleet decisions must record the slack reduction"
-    rep = _replay_engine(sc, link, decs, batch=spec, faults=fleet)
-    final2, traces2 = rep.run_controlled(BUDGET)
-    for b in range(B):
-        assert_traces_equal(traces[b], traces2[b], f"auto w{b}",
-                            f"replay w{b}")
-    assert_states_equal(final, final2, "replay law (batched+faults)")
-    # world-b slice ≡ solo replay with that world's schedule (the
-    # batch exactness law composed with the replay law)
-    b = 1
-    solo = JaxEngine(sc, link, window="auto", lint="off",
-                     seed=spec.seeds[b],
-                     faults=fleet.world_schedule(b),
-                     controller=DispatchController(
-                         mode="replay",
-                         replay=DecisionTrace.of(decs)))
-    sfinal, strace = solo.run_controlled(BUDGET)
-    assert_traces_equal(strace, traces[b], "solo replay", f"world {b}")
-    assert_states_equal(sfinal, world_slice(final, b),
-                        f"world {b} slice")
+from timewarp_tpu.net.delays import FixedDelay
+from timewarp_tpu.trace.events import assert_states_equal, assert_traces_equal
 
 
 def test_rung_pin_is_result_identical():
@@ -206,33 +66,6 @@ def test_rung_pin_is_result_identical():
     assert_traces_equal(tr_a, tr_b, "unpinned", "pinned")
     assert_states_equal(a, b, "rung pin result-identity")
 
-
-def test_sharded_batched_controller_matches_local_fleet():
-    """The world-sharded engine under a controller: dyn scalars ride
-    the shard_map as replicated operands, per-world budget vectors
-    slice per device, and the run is bit-identical to the local
-    batched fleet replaying the same decisions."""
-    from timewarp_tpu.interp.jax_engine.sharded import (
-        ShardedBatchedEngine, make_mesh)
-    sc, link = _wave(n=32, end_us=120_000)
-    spec = BatchSpec(seeds=tuple(range(4)))
-    eng = ShardedBatchedEngine(
-        sc, link, make_mesh(4, axis="worlds"), batch=spec,
-        window="auto", telemetry="counters", lint="off",
-        controller=DispatchController(chunk=8, chunk_max=32))
-    final, traces = eng.run_controlled(1 << 12)
-    decs = eng.last_run_decisions
-    loc = _replay_engine(sc, link, decs, batch=spec)
-    lfinal, ltraces = loc.run_controlled(1 << 12)
-    for b in range(4):
-        assert_traces_equal(ltraces[b], traces[b], f"local w{b}",
-                            f"sharded w{b}")
-    assert_states_equal(jax.device_get(lfinal),
-                        jax.device_get(final),
-                        "sharded ≡ local controller fleet")
-
-
-# -- zero recompiles + per-chunk compile accounting ------------------------
 
 def test_zero_recompiles_across_adaptations():
     sc, link = _wave()
@@ -273,7 +106,7 @@ def test_run_stream_per_chunk_compile_accounting():
         "the first chunk's compile must be attributed somewhere"
 
 
-# -- window="auto" edge cases (satellite) ----------------------------------
+
 
 def test_window_auto_forever_delay_link():
     """A FOREVER-delay link declares an astronomical floor; auto must
@@ -320,7 +153,7 @@ def test_window_auto_batched_fleet_floor():
     assert faulted.window == fleet.min_delay_floor(4_000) == 1_000
 
 
-# -- chunk-length-only engines (edge / fused) ------------------------------
+
 
 def test_edge_engine_controller_chunk_only():
     from timewarp_tpu.interp.jax_engine.edge_engine import EdgeEngine
@@ -343,7 +176,7 @@ def test_edge_engine_controller_chunk_only():
                for d in eng.last_run_decisions)
 
 
-# -- the decision trace / controller object --------------------------------
+
 
 def test_decision_trace_validation_is_loud(tmp_path):
     with pytest.raises(DispatchTraceError, match="gapless"):
@@ -389,7 +222,7 @@ def test_controller_requires_telemetry_for_auto():
                                                     8)])))
 
 
-# -- metrics schema (satellite) --------------------------------------------
+
 
 def test_metrics_decision_kind_validates(tmp_path):
     from timewarp_tpu.obs.metrics import (MetricsRegistry,
@@ -423,58 +256,6 @@ def test_controller_decisions_stream_to_metrics(tmp_path):
     kinds = [json.loads(x)["kind"]
              for x in open(path) if x.strip()]
     assert kinds.count("decision") == len(eng.last_run_decisions)
-
-
-# -- sweep integration -----------------------------------------------------
-
-_GOSSIP = {"nodes": 24, "fanout": 3, "burst": True, "end_us": 90_000,
-           "mailbox_cap": 16, "think_us": 700}
-
-
-def _ctrl_pack():
-    from timewarp_tpu.sweep import SweepPack
-    return SweepPack.from_json([
-        {"id": "gc0", "scenario": "gossip", "params": _GOSSIP,
-         "link": "quantize:1000:uniform:3000:9000", "seed": 2,
-         "window": "auto", "budget": 100, "controller": "auto"},
-        {"id": "gc1", "scenario": "gossip", "params": _GOSSIP,
-         "link": "quantize:1000:uniform:3000:9000", "seed": 5,
-         "window": "auto", "budget": 60, "controller": "auto"},
-        {"id": "goff", "scenario": "gossip", "params": _GOSSIP,
-         "link": "quantize:1000:uniform:3000:9000", "seed": 9,
-         "window": "auto", "budget": 100},
-    ])
-
-
-def test_sweep_controller_kill_resume_replays_decisions(tmp_path):
-    from timewarp_tpu.sweep import SweepService, solo_result
-    from timewarp_tpu.sweep.service import SweepKilled
-    pack = _ctrl_pack()
-    d = str(tmp_path / "j")
-    svc = SweepService(pack, d, chunk=16, lint="off", inject="die:2")
-    with pytest.raises(SweepKilled):
-        svc.run()
-    scan = svc.journal.scan()
-    pre = {b: list(v) for b, v in scan.decisions.items()}
-    assert sum(len(v) for v in pre.values()) >= 1, \
-        "no decision was journaled before the kill"
-
-    svc2 = SweepService.resume(d, chunk=16, lint="off")
-    report = svc2.run()
-    assert report.ok, report.to_json()
-    scan2 = svc2.journal.scan()
-    for b, recs in pre.items():
-        post = {r["chunk"]: r for r in scan2.decisions[b]}
-        for r in recs:
-            assert post[r["chunk"]] == r, \
-                f"pre-kill decision re-made differently: {r}"
-    # the survival law, controller form: solo twin replays the chain
-    for rid, res in report.done.items():
-        cfg = pack.by_id(rid)
-        decs = svc2.decisions_for_world(rid) \
-            if cfg.controller == "auto" else None
-        want = solo_result(cfg, lint="off", decisions=decs)
-        assert want == res, f"{rid}:\n solo {want}\n strm {res}"
 
 
 def test_controller_config_solo_twin_requires_decisions():

@@ -8,72 +8,23 @@ sharded over a mesh (each device its own rung, and no collective in
 the superstep); ``last_run_stats["rung_lanes"]`` sums the rungs taken
 and costs no readback.
 
+Under faults and over a mesh: tests/test_fleet_rung_sharded.py.
+
 (Named test_zz* to sort after the whole existing suite.)
 """
 
 import numpy as np
-import pytest
 
 import jax
 
-from timewarp_tpu.analysis.jaxpr_lint import _all_jaxprs
-from timewarp_tpu.faults import (FaultFleet, FaultSchedule, LinkWindow,
-                                 NodeCrash, Partition)
+from fleet_rung_laws import (N, RUNGS, SLOW, _eqns, _ladder_conds, _named_axes,
+                             _shared_rung, _steady)
 from timewarp_tpu.interp.jax_engine.batched import BatchSpec, world_slice
 from timewarp_tpu.interp.jax_engine.engine import JaxEngine
 from timewarp_tpu.models.gossip import gossip
 from timewarp_tpu.net.delays import Quantize, UniformDelay
-from timewarp_tpu.trace.events import (assert_states_equal,
-                                       assert_traces_equal)
+from timewarp_tpu.trace.events import assert_states_equal, assert_traces_equal
 
-N = 2048
-RUNGS = JaxEngine._sender_rungs(N)
-#: per-world link bounds: world 1's links are four times slower, so its
-#: ramp is still under the first rung when world 0's has filled the top
-SLOW = {"inner.lo": [500, 4_000], "inner.hi": [4_500, 16_000]}
-
-
-def _steady(n=N, end_us=60_000):
-    """Steady gossip: the active set doubles a round, so a run crosses
-    the ladder's rungs on its ramp."""
-    sc = gossip(n, fanout=1, think_us=1_000, gossip_interval=1_000,
-                end_us=end_us, steady=True, mailbox_cap=8)
-    return sc, Quantize(UniformDelay(500, 4_500), 1_000)
-
-
-def _eqns(jaxpr):
-    """Every equation of a jaxpr and of the jaxprs in its parameters
-    (loop bodies, branches, ``shard_map`` and ``pjit`` bodies)."""
-    return [e for jx in _all_jaxprs(jaxpr.jaxpr) for e in jx.eqns]
-
-
-def _named_axes(eqn) -> set:
-    """The axis names an equation reduces or exchanges over."""
-    names = set()
-    for key in ("axes", "axis_name"):
-        v = eqn.params.get(key, ())
-        names |= {a for a in (v if isinstance(v, (tuple, list)) else (v,))
-                  if isinstance(a, str)}
-    return names
-
-
-def _ladder_conds(jaxpr, rungs):
-    return [e for e in _eqns(jaxpr) if e.primitive.name == "cond"
-            and len(e.params["branches"]) == len(rungs)]
-
-
-def _shared_rung(frames):
-    """The ``rung`` column of a fleet's telemetry, by iteration. A
-    world steps from the loop's first iteration until it is quiet or
-    out of budget, so row ``i`` of its frames is iteration ``i``, and
-    every world that stepped in an iteration recorded the same."""
-    cols = sorted((fr.data["rung"].tolist() for fr in frames), key=len)
-    for col in cols:
-        assert col == cols[-1][:len(col)]
-    return cols[-1]
-
-
-# -- (a) the program ---------------------------------------------------------
 
 def test_fleet_superstep_holds_the_ladder_as_one_conditional():
     n = 4096
@@ -102,7 +53,7 @@ def test_solo_superstep_reduces_over_nothing():
                 if e.primitive.name in ("pmax", "pmin", "psum")]
 
 
-# -- (b) the counter ---------------------------------------------------------
+
 
 def test_rung_lanes_sums_the_rungs_taken_at_no_readback():
     sc, link = _steady()
@@ -167,7 +118,7 @@ def test_a_solo_engine_counts_its_rung_lanes_too():
         {k: quiet[k] for k in ("rung_lanes", "sender_lanes", "rung_steps")}
 
 
-# -- (c) the exactness law where the worlds differ --------------------------
+
 
 def test_worlds_of_very_different_activity_slice_bit_equal():
     sc, link = _steady()
@@ -190,82 +141,3 @@ def test_worlds_of_very_different_activity_slice_bit_equal():
         solo_fin, solo_trace = solo.run(40)
         assert_traces_equal(solo_trace, traces[b], "solo", f"world{b}")
         assert_states_equal(solo_fin, world_slice(fin, b), f"world {b}")
-
-
-def test_faulted_fleet_on_the_ladder_slices_bit_equal():
-    """``branch_faulted``: the sample-before-sort tail of every rung,
-    under per-world schedules, on a ramp that crosses the rungs."""
-    sc, link = _steady()
-    half = N // 2
-    fleet = FaultFleet(tuple(FaultSchedule((
-        NodeCrash(b + 1, 4_000 + 1_000 * b, 30_000,
-                  reset_state=(b % 2 == 0)),
-        Partition((tuple(range(half)), tuple(range(half, N))),
-                  8_000, 20_000 + 5_000 * b),
-        LinkWindow(tuple(range(16)), None, 25_000, 40_000, scale=2.0,
-                   extra_us=1_000),
-    )) for b in range(2)))
-    spec = BatchSpec(seeds=(0, 5))
-    eng = JaxEngine(sc, link, window="auto", batch=spec, faults=fleet,
-                    telemetry="counters")
-    fin, traces = eng.run(40)
-    assert len(set(_shared_rung(eng.last_run_telemetry))) > 1
-    assert int(np.asarray(fin.fault_dropped).min()) > 0
-    for b in range(spec.B):
-        solo = JaxEngine(sc, link, window=eng.window, seed=spec.seeds[b],
-                         faults=fleet.world_schedule(b))
-        solo_fin, solo_trace = solo.run(40)
-        assert_traces_equal(solo_trace, traces[b], "solo", f"world{b}")
-        assert_states_equal(solo_fin, world_slice(fin, b), f"world {b}")
-
-
-# -- (d) the worlds over a mesh ----------------------------------------------
-
-@pytest.fixture(scope="module")
-def sharded():
-    from timewarp_tpu.interp.jax_engine.sharded import (
-        ShardedBatchedEngine, make_mesh)
-    sc, link = _steady()
-    # two worlds a device: the fast pair on device 0, the slow on 1
-    spec = BatchSpec(seeds=(3, 4, 9, 10), link_params={
-        k: [v[0], v[0], v[1], v[1]] for k, v in SLOW.items()})
-    eng = ShardedBatchedEngine(sc, link, make_mesh(2, axis="worlds"),
-                               window="auto", telemetry="counters",
-                               batch=spec)
-    return eng, sc, link, spec
-
-
-def test_sharded_fleet_takes_a_rung_a_device_and_slices_bit_equal(sharded):
-    eng, sc, link, spec = sharded
-    fin, traces = eng.run(40)
-    frames = eng.last_run_telemetry
-    by_device = [_shared_rung(frames[:2]), _shared_rung(frames[2:])]
-    assert by_device[0] != by_device[1]
-    assert eng.last_run_stats["rung_lanes"] == max(map(sum, by_device))
-    for b in range(spec.B):
-        solo = JaxEngine(sc, spec.world_link(link, b),
-                         seed=spec.seeds[b], window=eng.window)
-        solo_fin, solo_trace = solo.run(40)
-        assert_traces_equal(solo_trace, traces[b], "solo", f"world{b}")
-        assert_states_equal(solo_fin, world_slice(fin, b), f"world {b}")
-    quiet = eng.run_quiet(40)
-    assert_states_equal(fin, quiet, "sharded fleet run_quiet")
-    assert eng.last_run_stats["rung_lanes"] == max(map(sum, by_device))
-
-
-def test_sharded_fleets_superstep_names_no_mesh_collective(sharded):
-    eng = sharded[0]
-    st = eng.init_state()
-    jx = jax.make_jaxpr(lambda s: type(eng)._run_scan(
-        eng, s, 4, 4, None, eng._identity()))(st)
-    assert len(_ladder_conds(jx, RUNGS)) == 1
-    named = {e.primitive.name: _named_axes(e) for e in _eqns(jx)
-             if _named_axes(e)}
-    # the one use of the mesh axis is the slice of the worlds' identity
-    assert set(named) <= {"axis_index"}, named
-    # the quiet driver's only collective is its loop's liveness psum
-    jq = jax.make_jaxpr(lambda s: type(eng)._run_while(
-        eng, s, 4, eng._identity()))(st)
-    named = [e.primitive.name for e in _eqns(jq)
-             if _named_axes(e) and e.primitive.name != "axis_index"]
-    assert named == ["psum"], named

@@ -143,7 +143,7 @@ def test_the_adaptive_drivers_have_no_sort_scope(kw):
 #: ``tests/test_zzzzzzzzzzzzzzzrecord.py`` holds the final states to
 #: PR 34's, bit for bit), and in the solo driver every rung's staging
 #: in the dense form (8192 lanes and more for 2048 nodes:
-#: ``tests/test_insert_law.py`` holds it to the scatters). Until then
+#: ``tests/test_insert_staging_law.py`` holds it to the scatters). Until then
 #: they were PR 35's (solo a591ccb6659a…, fleet 9e610618e0c2…), whose
 #: carry held three counts. PR 44 changed the solo constant (it was
 #: b341ad0cc880…): a solo engine's commutative inbox stages by rank,
