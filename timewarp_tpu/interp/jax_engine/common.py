@@ -282,6 +282,17 @@ class _DriverCall:
                 # world counted the same (a world-sharded fleet: the
                 # counts of the device whose rungs sum widest)
                 b = int(np.argmax(counts[0]))
+                local = getattr(self.eng, "worlds_local", None)
+                if local is not None:
+                    # a rung a device: the devices meet at the loop's
+                    # liveness reduction, so the widest sets the pace
+                    # and the others wait. Every world of a device
+                    # counted the same: a device's own sums are the
+                    # rows of its first world, in the same transfer
+                    stats.update(
+                        shards=len(d) // local, worlds_local=local,
+                        device_rung_lanes=counts[0][::local].tolist(),
+                        device_sender_lanes=counts[1][::local].tolist())
                 counts = [c[b] for c in counts]
             lanes, senders, by_rung, dense, wide = counts
             stats.update(rung_lanes=int(lanes), sender_lanes=int(senders),
@@ -358,6 +369,17 @@ class RunStatsMixin:
                               # staged in the dense form
          "tail_lanes": int,   # the width their tails' scatters took
          "net_rows": int}     # the rows they sent through the network
+
+    for the world-sharded fleet (``ShardedBatchedEngine``: a rung a
+    device, the devices in lockstep at the loop's liveness
+    reduction)::
+
+        {"shards": int,         # the mesh axis' size
+         "worlds_local": int,   # worlds a device
+         "device_rung_lanes": [int] * shards,    # each device's own
+         "device_sender_lanes": [int] * shards}  # sums of the two
+                                # above: ``rung_lanes`` is the widest
+                                # device's, max(device_rung_lanes)
 
     for the node-sharded edge engine (``ShardedEdgeEngine``)::
 
@@ -437,7 +459,10 @@ class RunStatsMixin:
         is testable per chunk, not just in aggregate. The routing
         counts and a fleet's per-world counts are summed where every
         chunk has them (elementwise: a chunked fleet's
-        ``fleet_iterations`` is the sum of its chunks' loops)."""
+        ``fleet_iterations`` is the sum of its chunks' loops; a
+        world-sharded fleet's ``device_rung_lanes`` a device, so the
+        merged ``rung_lanes``, each chunk's widest device, is at least
+        their largest)."""
         self.last_run_stats = {
             "supersteps": sum(c["supersteps"] for c in chunks),
             "wall_seconds": sum(c["wall_seconds"] for c in chunks),
@@ -447,13 +472,15 @@ class RunStatsMixin:
             "chunks": len(chunks),
             "per_chunk_compiles": [c["compiles"] for c in chunks],
         }
-        if chunks and all("shards" in c for c in chunks):
-            self.last_run_stats["shards"] = chunks[0]["shards"]
+        for key in ("shards", "worlds_local"):    # the mesh's, kept
+            if chunks and all(key in c for c in chunks):
+                self.last_run_stats[key] = chunks[0][key]
         for key in ("rung_lanes", "sender_lanes", "fleet_iterations",
                     "rung_steps", "world_supersteps",
                     "dense_stage_steps", "wide_tail_steps",
                     "scatter_lanes", "dense_lanes", "tail_lanes",
-                    "net_rows", "boundary_msgs"):
+                    "net_rows", "boundary_msgs", "device_rung_lanes",
+                    "device_sender_lanes"):
             if chunks and all(key in c for c in chunks):
                 cols = [c[key] for c in chunks]
                 self.last_run_stats[key] = sum(cols) \

@@ -298,10 +298,14 @@ class ShardedBatchedEngine(ShardedDriver, JaxEngine):
     # -- world-axis sharding ---------------------------------------------
 
     def _state_specs(self, st: EngineState) -> EngineState:
-        # uniform rule: every leaf's LEADING axis is the world axis
-        ax = self.axis
-        return jax.tree.map(
-            lambda x: P(ax, *([None] * (x.ndim - 1))), st)
+        # uniform rule: every leaf's LEADING axis is the world axis.
+        # Written without the trailing ``None``s, the form in which
+        # JAX names a sharding it reads back from a compiled program:
+        # a driver returned the mailbox planes as ``P(ax)`` where
+        # ``init_state`` had placed them as ``P(ax, None, None)``, the
+        # same layout under another name, and a fleet streamed in
+        # calls compiled its second call anew (PERF.md, PR 46)
+        return jax.tree.map(lambda x: P(self.axis), st)
 
     def _trace_spec(self) -> P:
         # scan-trace leaves are [T, B_local] per device: gather the
@@ -324,8 +328,14 @@ class ShardedBatchedEngine(ShardedDriver, JaxEngine):
         return (sl(s0v), sl(s1v), {k: sl(v) for k, v in lpv.items()},
                 None if ftv is None else jax.tree.map(sl, ftv))
 
+    @jax.named_scope("tw.liveness")
     def _any_world(self, x):
         # liveness must be mesh-wide: one device's worlds finishing
         # must not stop the others' (int32 psum — bool all-reduce
-        # does not lower on the TPU path, see MeshComm.all_min)
+        # does not lower on the TPU path, see MeshComm.all_min).
+        # It sits in the quiet loop's CONDITION, once an iteration:
+        # the one collective of the world-sharded drivers, and the
+        # point where four devices on four rungs wait for the widest.
+        # The scope is its name in a profile (``op_name``); no other
+        # driver's text holds it
         return jax.lax.psum(x.astype(jnp.int32), self.axis) > 0

@@ -22,7 +22,9 @@ Kinds:
   ``rung_steps``, ``dense_stage_steps``, ``wide_tail_steps``, a
   fleet's ``fleet_iterations``, an ordered inbox's ``fan_in_peak`` and,
   solo on one device, its ``scatter_lanes``, a staged insertion's
-  ``dense_lanes``, ``tail_lanes``, ``net_rows``).
+  ``dense_lanes``, ``tail_lanes``, ``net_rows``, a mesh's ``shards``
+  and a world-sharded fleet's ``worlds_local``, ``device_rung_lanes``
+  and ``device_sender_lanes``).
 - ``utilization`` — per-bucket sweep utilization (sweep/runner.py):
   worlds-active occupancy, budget-mask efficiency, pow2 scan-pad
   waste.
@@ -76,7 +78,10 @@ _NUM = (int, float)
 _RUN_COUNTS = ("dispatches", "readbacks", "rung_lanes", "sender_lanes",
                "fleet_iterations", "dense_stage_steps", "wide_tail_steps",
                "fan_in_peak", "scatter_lanes", "dense_lanes", "tail_lanes",
-               "net_rows")
+               "net_rows", "shards", "worlds_local")
+#: and those that are one int an entry: iterations by rung, and a
+#: world-sharded fleet's lanes by device
+_RUN_LISTS = ("rung_steps", "device_rung_lanes", "device_sender_lanes")
 #: kind -> {required field: type tuple}; extra fields are allowed
 #: (forward-compatible), missing/badly-typed required ones are not
 _KINDS: Dict[str, Dict[str, tuple]] = {
@@ -159,11 +164,12 @@ def validate_line(rec: Any) -> None:
                 raise ValueError(
                     f"metrics kind 'run_summary': field {field!r} must "
                     f"be int, got {rec[field]!r}")
-        steps = rec.get("rung_steps", [])
-        if not isinstance(steps, list) or not all(map(_is_int, steps)):
-            raise ValueError(
-                "metrics kind 'run_summary': field 'rung_steps' must be "
-                f"a list of int (iterations by rung), got {steps!r}")
+        for field in _RUN_LISTS:
+            v = rec.get(field, [])
+            if not isinstance(v, list) or not all(map(_is_int, v)):
+                raise ValueError(
+                    f"metrics kind 'run_summary': field {field!r} must "
+                    f"be a list of int, got {v!r}")
     if kind == "event" and rec.get("name") == "flight":
         # the flight-recorder event form (v4): name="flight" promises
         # the per-message provenance tuple — a half-written event is
@@ -265,7 +271,7 @@ class MetricsRegistry:
         ``last_run_stats``: the three counts every engine has, and of
         the call's boundary with the chip and its routing counts those
         the stats hold."""
-        counts = {k: stats[k] for k in _RUN_COUNTS + ("rung_steps",)
+        counts = {k: stats[k] for k in _RUN_COUNTS + _RUN_LISTS
                   if k in stats}
         self.emit("run_summary", label=label,
                   supersteps=int(stats["supersteps"]),

@@ -152,7 +152,9 @@ class ShardedDriver:
     (``_quiet_loop``, inherited from its local base class,
     ``JaxEngine`` or ``EdgeEngine``: both carry the state's horizon,
     reduced over the mesh where a superstep produces it, so the
-    loop's condition holds no collective) and what the loops carry
+    loop's condition holds no collective; the world-sharded fleet's
+    holds one, its liveness ``psum`` under ``tw.liveness``:
+    ``ShardedBatchedEngine._any_world``) and what the loops carry
     beside the state (``_counted``, ``_step_counted``; the edge
     engine's quiet body: ``_step_carried``). ``_next_event``, a local
     base class's probe of a state at rest, is asked by no loop."""
