@@ -1,7 +1,7 @@
 """One routing rung for the whole fleet
 (tests/test_zzzzzzzzzzzzzfleet_rung.py), under faults and with the
 worlds sharded over a mesh: every slice stays bit-equal to its solo
-run, each device takes its own rung, and the superstep names no mesh
+run, each device takes its own rung, and no driver names a mesh
 collective."""
 
 import numpy as np
@@ -89,9 +89,10 @@ def test_sharded_fleets_superstep_names_no_mesh_collective(sharded):
              if _named_axes(e)}
     # the one use of the mesh axis is the slice of the worlds' identity
     assert set(named) <= {"axis_index"}, named
-    # the quiet driver's only collective is its loop's liveness psum
+    # the quiet driver names none either: its loop's condition reads
+    # this device's worlds, and the devices meet at the readback
     jq = jax.make_jaxpr(lambda s: type(eng)._run_while(
         eng, s, 4, eng._identity()))(st)
     named = [e.primitive.name for e in _eqns(jq)
              if _named_axes(e) and e.primitive.name != "axis_index"]
-    assert named == ["psum"], named
+    assert named == [], named

@@ -63,6 +63,14 @@ def test_sharded_batched_equals_local_fleet(devices):
     assert_states_equal(lof, shf, "sharded fleet state")
     assert_states_equal(local.run_quiet(60), sh.run_quiet(60),
                         "sharded fleet run_quiet")
+    # the eager regime's ``rung_steps`` has one bin, and a device's
+    # row of it still sums to the trips of that device's loop
+    st, each = sh.last_run_stats, 8 // devices
+    assert len(st["rung_steps"]) == 1
+    assert st["device_iterations"] == [
+        max(st["world_supersteps"][d * each:(d + 1) * each])
+        for d in range(devices)]
+    assert st["fleet_iterations"] == max(st["device_iterations"])
 
 
 def test_sharded_batched_rejects_indivisible_fleet():

@@ -5,10 +5,13 @@ every world equals, bit for bit, the same world of the one-device
 fleet and its solo run, whichever device holds it; every job is one
 program, one dispatch and one readback on a state that stays four
 slices on four devices; the call's record counts the mesh, the worlds
-a device and each device's own rung and sender lanes; the liveness
-reduction of the loop's condition has a name, ``tw.liveness``, in the
-world-sharded quiet driver and in no other, and the name is a name and
-nothing else: every driver lowers to the text the parent lowered.
+a device and each device's own rung and sender lanes. Since ISSUE 47
+the quiet loop's condition reads a device's own worlds: no driver's
+text holds a collective or the scope ``tw.liveness``, each device
+leaves its loop when its own last world is quiet
+(``device_iterations``), a device whose worlds are all quiet at entry
+runs nothing, and every other driver lowers to the text the parent
+lowered.
 
 (Named test_zz* to sort after the whole existing suite.)
 """
@@ -32,6 +35,7 @@ from timewarp_tpu.interp.jax_engine.sharded import (ShardedBatchedEngine,
 from timewarp_tpu.models.token_ring import token_ring
 from timewarp_tpu.net.delays import FixedDelay
 from timewarp_tpu.obs import profiler
+from timewarp_tpu.obs.metrics import MetricsRegistry, validate_line
 from timewarp_tpu.parallel.mesh import make_mesh
 from timewarp_tpu.trace.events import assert_states_equal
 
@@ -51,6 +55,12 @@ LOCAL = WORLDS // SHARDS
 #: between devices: a rotation by one device's worlds and a reversal
 ORDERS = {"rotated": (2, 3, 4, 5, 6, 7, 0, 1),
           "reversed": (7, 6, 5, 4, 3, 2, 1, 0)}
+
+
+def _device_trips(by_world):
+    """The trips of each device's own loop in a run to quiescence:
+    its last world's supersteps."""
+    return [max(by_world[d * LOCAL:(d + 1) * LOCAL]) for d in range(SHARDS)]
 
 
 def _toy():
@@ -138,13 +148,14 @@ def twins():
         for eng in (sharded, one):
             assert eng.rebind_identity(BatchSpec(seeds=order))
             fins.append(eng.run_quiet(BUDGET, eng.init_state()))
-        out[key] = fins
+        # and what the four-device call counted
+        out[key] = fins + [dict(sharded.last_run_stats)]
     return sc, link, sharded.window, out
 
 
 @pytest.mark.parametrize("order", sorted(ORDERS))
 def test_every_world_equals_the_one_device_fleets(twins, order):
-    got, want = twins[3][order]
+    got, want, _ = twins[3][order]
     for slot, seed in enumerate(ORDERS[order]):
         assert_states_equal(world_slice(want, slot), world_slice(got, slot),
                             f"world {seed} in slot {slot}")
@@ -161,9 +172,29 @@ def test_every_world_equals_the_one_device_fleets(twins, order):
 def test_a_world_equals_its_solo_run(twins, seed):
     sc, link, window, out = twins
     want = JaxEngine(sc, link, seed=seed, window=window).run_quiet(BUDGET)
-    for order, (got, _) in sorted(out.items()):
+    for order, (got, _, _) in sorted(out.items()):
         assert_states_equal(want, world_slice(
             got, ORDERS[order].index(seed)), f"world {seed}, {order}")
+
+
+@pytest.mark.parametrize("order", sorted(ORDERS))
+def test_the_devices_leave_the_loop_each_at_its_own_last_world(twins,
+                                                               order):
+    """The fleets the three tests above hold to the one-device fleet
+    and to the solo runs bit for bit ran their devices out of step:
+    each left its ``while`` when its own last world was quiet."""
+    got, want, stats = twins[3][order]
+    assert_states_equal(want, got, f"the whole fleet, {order}")
+    trips, by_world = stats["device_iterations"], stats["world_supersteps"]
+    assert len(set(trips)) > 1, trips
+    assert trips == _device_trips(by_world)
+    assert stats["fleet_iterations"] == max(trips)
+    assert by_world == np.asarray(want.steps).tolist()
+    # a device counts the iterations it ran, at the rung it took
+    # (this size has one rung, the full width)
+    assert stats["device_rung_lanes"] == [N * t for t in trips]
+    assert stats["rung_lanes"] == N * max(trips) \
+        and stats["rung_steps"] == [max(trips)]
 
 
 # -- (c) the gates -------------------------------------------------------------
@@ -223,8 +254,12 @@ def uneven():
     """A fleet whose devices take different rungs in one iteration:
     steady gossip on a ramp, the worlds of devices 2 and 3 on links
     four times slower, the ladder's floor patched down to this file's
-    size before the engine's first trace. Two calls streamed on one
-    state, then the scan driver."""
+    size before the engine's first trace. Calls of one scalar budget
+    streamed on one state until a call finds every world quiet (the
+    fast pair of devices is quiet two calls before the slow pair),
+    one call from the fresh state to quiescence, then the scan driver
+    over the first two calls' iterations and over the whole run (under
+    one budget a world, all alike and with one world cut short)."""
     patch = pytest.MonkeyPatch()
     patch.setattr(JaxEngine, "_sender_rungs", staticmethod(
         lambda n: [r for r in RUNGS if r < n] + [n]))
@@ -235,20 +270,30 @@ def uneven():
             for k, v in SLOW.items()})
         eng = ShardedBatchedEngine(sc, link, make_mesh(SHARDS, "worlds"),
                                    batch=spec, window="auto")
-        calls, st = [], eng.init_state()
-        for _ in range(2):
-            st = eng.run_quiet(12, st)
+        calls, states = [], [eng.init_state()]
+        while not calls or calls[-1]["supersteps"]:
+            states.append(eng.run_quiet(12, states[-1]))
             calls.append(dict(eng.last_run_stats))
+        fin = eng.run_quiet(BUDGET)
+        whole = dict(eng.last_run_stats)
         eng.run(24)
-        return eng, calls, dict(eng.last_run_stats)
+        scan = dict(eng.last_run_stats)
+        # one budget a world (one program for both): all the same,
+        # then the first world of a slow device cut short
+        eng.run(np.full(WORLDS, 128))
+        whole_scan = dict(eng.last_run_stats)
+        eng.run(np.array([128] * (WORLDS // 2) + [66] + [128] * (
+            WORLDS // 2 - 1)))
+        return eng, calls, scan, {
+            "states": states, "fin": fin, "quiet": whole,
+            "scan": whole_scan, "cut": dict(eng.last_run_stats)}
     finally:
         patch.undo()        # every trace of the fixture is made
 
 
 @pytest.mark.parametrize("call", range(2))
 def test_a_call_counts_each_devices_own_lanes(uneven, call):
-    _, calls, _ = uneven
-    stats = calls[call]
+    stats = uneven[1][call]
     assert (stats["shards"], stats["worlds_local"]) == (SHARDS, LOCAL)
     assert len(stats["device_rung_lanes"]) == SHARDS \
         == len(stats["device_sender_lanes"])
@@ -264,7 +309,7 @@ def test_a_call_counts_each_devices_own_lanes(uneven, call):
 
 
 def test_two_devices_took_different_rungs(uneven):
-    _, calls, scan = uneven
+    _, calls, scan, _ = uneven
     lanes = calls[1]["device_rung_lanes"]
     # the fast pair of devices ran ahead of the slow pair's ramp
     assert min(lanes[:2]) > max(lanes[2:])
@@ -275,14 +320,93 @@ def test_two_devices_took_different_rungs(uneven):
     assert (scan["shards"], scan["worlds_local"]) == (SHARDS, LOCAL)
 
 
-def test_the_chunked_drivers_merge_sums_them(uneven):
-    eng, calls, _ = uneven
-    merged = eng._stats_merge(calls)
+@pytest.mark.parametrize("key", ["device_rung_lanes", "device_sender_lanes",
+                                 "device_iterations"])
+def test_the_chunked_drivers_merge_sums_them(uneven, key):
+    eng, calls = uneven[:2]
+    merged = eng._stats_merge(calls[:2])
     assert (merged["shards"], merged["worlds_local"]) == (SHARDS, LOCAL)
-    for key in ("device_rung_lanes", "device_sender_lanes"):
-        assert merged[key] == [a + b for a, b in zip(
-            calls[0][key], calls[1][key])]
+    assert merged[key] == [a + b for a, b in zip(
+        calls[0][key], calls[1][key])]
     assert merged["rung_lanes"] >= max(merged["device_rung_lanes"])
+    assert merged["fleet_iterations"] == max(merged["device_iterations"])
+
+
+def test_a_device_whose_worlds_are_quiet_at_entry_runs_nothing(uneven):
+    _, calls, _, more = uneven
+    # the first streamed call that the fast pair of devices entered
+    # with every world quiet while the slow pair ran on
+    i = next(i for i, c in enumerate(calls)
+             if c["device_iterations"][:2] == [0, 0]
+             and min(c["device_iterations"][2:]) > 0)
+    stats, came, went = calls[i], more["states"][i], more["states"][i + 1]
+    fast = LOCAL * 2                   # the worlds of devices 0 and 1
+    assert stats["world_supersteps"][:fast] == [0] * fast
+    assert stats["device_rung_lanes"][:2] == [0, 0] \
+        == stats["device_sender_lanes"][:2]
+    # the others ran on, in the one program of the first call
+    assert min(stats["world_supersteps"][fast:]) > 0
+    assert stats["fleet_iterations"] == max(stats["device_iterations"])
+    assert (stats["compiles"], stats["dispatches"]) == (0, 1)
+    # every leaf of the quiet devices as it came, and where it came
+    moved = 0
+    for a, b in zip(jax.tree.leaves(came), jax.tree.leaves(went)):
+        assert a.sharding == b.sharding
+        a, b = np.asarray(a), np.asarray(b)
+        assert np.array_equal(a[:fast], b[:fast])
+        moved += not np.array_equal(a[fast:], b[fast:])
+    assert moved > 0
+    # and once every device is quiet a call runs no iteration at all
+    assert calls[-1]["device_iterations"] == [0] * SHARDS
+    assert_states_equal(more["states"][-2], more["states"][-1],
+                        "a call on a quiet fleet")
+
+
+def test_the_quiet_and_the_scan_driver_count_a_devices_own_iterations(
+        uneven):
+    eng, calls, _, more = uneven
+    quiet, scan = more["quiet"], more["scan"]
+    trips = _device_trips(quiet["world_supersteps"])
+    assert len(set(trips)) > 1 and quiet["device_iterations"] == trips
+    for key in ("device_iterations", "device_rung_lanes",
+                "device_sender_lanes", "world_supersteps", "rung_lanes",
+                "sender_lanes", "rung_steps", "fleet_iterations"):
+        assert quiet[key] == scan[key], key
+    # a frozen device no longer counts the smallest rung an iteration:
+    # the widest device's rungs sum to its own trips, not the fleet's
+    widest = int(np.argmax(quiet["device_rung_lanes"]))
+    assert sum(quiet["rung_steps"]) == trips[widest] < max(trips)
+    # a world cut short by a budget of its own stops counting there
+    # (the scan goes on stepping it into the discard, so its device's
+    # iterations count on): a device's counts are its widest world's,
+    # not its first's, and the call's are still the widest device's
+    cut = more["cut"]
+    assert cut["world_supersteps"][WORLDS // 2] == 66 < trips[2] \
+        <= cut["device_iterations"][2]
+    assert cut["rung_lanes"] == max(cut["device_rung_lanes"])
+    for key in ("device_iterations", "device_rung_lanes",
+                "device_sender_lanes"):
+        assert cut[key][:2] + cut[key][3:] == scan[key][:2] + scan[key][3:]
+    # and streamed in calls the devices count the same, call by call
+    merged = eng._stats_merge(calls)
+    for key in ("device_iterations", "device_rung_lanes",
+                "device_sender_lanes", "world_supersteps"):
+        assert merged[key] == quiet[key], key
+    assert_states_equal(more["states"][-1], more["fin"],
+                        "streamed against one call")
+
+
+def test_the_run_summary_line_carries_a_devices_iterations(uneven):
+    quiet = uneven[3]["quiet"]
+    reg = MetricsRegistry()
+    reg.run_summary("fleet x4", quiet)
+    line = reg.lines[-1]
+    for key in ("shards", "worlds_local", "device_rung_lanes",
+                "device_sender_lanes", "device_iterations"):
+        assert line[key] == quiet[key], key
+    for bad in (3, [1, "2"], [1.5] * SHARDS):
+        with pytest.raises(ValueError, match="device_iterations"):
+            validate_line({**line, "device_iterations": bad})
 
 
 def _solo():
@@ -314,17 +438,19 @@ def _sharded_edge():
 def test_no_other_engine_counts_a_device(build):
     eng = build()
     stats = eng.last_run_stats
-    for key in ("worlds_local", "device_rung_lanes", "device_sender_lanes"):
+    for key in ("worlds_local", "device_rung_lanes", "device_sender_lanes",
+                "device_iterations"):
         assert key not in stats, key
     # the mesh axis' size is the edge engine's too, as it was
     assert ("shards" in stats) == isinstance(eng, ShardedEdgeEngine)
     assert profiler.calls()[-1]["counts"] == stats
     merged = eng._stats_merge([stats, stats])
     assert "device_rung_lanes" not in merged \
-        and "worlds_local" not in merged
+        and "worlds_local" not in merged \
+        and "device_iterations" not in merged
 
 
-# -- (e) the name, and that it is nothing else -------------------------------
+# -- (e) no driver meets another device --------------------------------------
 
 def _fleet_spec():
     return BatchSpec(seeds=tuple(range(WORLDS)))
@@ -369,13 +495,13 @@ def _edge_sharded_quiet():
 
 
 #: sha256 of each driver's lowering (``as_text()``: no names, no
-#: locations) as the parent of PR 46 lowers it. The scope is metadata:
-#: the world-sharded quiet driver's text is the parent's too. A PR that
-#: changes what these drivers compute changes the constants, and says
-#: so.
+#: locations) as the parent of PR 46 lowers it, but for the
+#: world-sharded quiet driver's: PR 47 took the all-reduce out of its
+#: loop's condition and re-pinned that one. A PR that changes what
+#: these drivers compute changes the constants, and says so.
 _PARENT_LOWERING = {
     "world_sharded_quiet":
-        "1dda21194f883fad0bd6cc6fc1134298e3579da3ebe343fa63abdfbe70c6fec4",
+        "6708eebb04851fff5939c3dbd4e14ea2248d3a8bc1ade190d4053ec789ea1bec",
     "world_sharded_scan":
         "9d5a701b241d6804b25af34ab509808c7029a86dc5113a2e98dfb415c950040b",
     "fleet_quiet":
@@ -409,15 +535,20 @@ def test_every_driver_lowers_to_the_parents_text(lowered, key):
 @pytest.mark.parametrize("key", sorted(_LOWER))
 def test_the_liveness_scope_is_the_world_sharded_quiet_drivers(lowered,
                                                                key):
+    """It was, until PR 47: now no driver's lowered text holds a
+    ``tw.liveness`` name, no loop's condition a collective, and the
+    fleets' and the solo engine's text no ``all_reduce`` at all (a
+    node-sharded world's supersteps sum their counters over the mesh,
+    in the loop's body)."""
     text = lowered[key].as_text(debug_info=True)
     names = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
-    scoped = [n for n in names.values() if "tw.liveness" in n.split("/")]
-    if key != "world_sharded_quiet":
-        assert scoped == []
+    assert [n for n in names.values() if "tw.liveness" in n] == []
+    assert [n for n in names.values() if "while/cond" in n and re.search(
+        r"psum|pmax|pmin|all_|ppermute", n)] == []
+    if key in ("node_sharded_quiet", "edge_sharded_quiet"):
         return
-    # the one collective of the program, in the loop's condition
-    assert len(re.findall(r'"stablehlo\.all_reduce"', text)) == 1
-    assert [n for n in scoped if n.endswith("/psum")] == [
-        "while/cond/tw.liveness/psum"]
-    assert {n.partition("tw.liveness/")[0] for n in scoped} == {
-        "while/cond/"}
+    assert re.findall(r"stablehlo\.all_reduce", text) == []
+    if key.startswith("world_sharded"):
+        # the fleet over a mesh: no collective of any kind
+        assert re.findall(r"stablehlo\.(all_\w+|collective_\w+|"
+                          r"reduce_scatter)", text) == []

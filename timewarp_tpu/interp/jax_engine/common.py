@@ -284,15 +284,24 @@ class _DriverCall:
                 b = int(np.argmax(counts[0]))
                 local = getattr(self.eng, "worlds_local", None)
                 if local is not None:
-                    # a rung a device: the devices meet at the loop's
-                    # liveness reduction, so the widest sets the pace
-                    # and the others wait. Every world of a device
-                    # counted the same: a device's own sums are the
-                    # rows of its first world, in the same transfer
+                    # a rung and a loop a device: each counts the
+                    # iterations it ran, until its own last world was
+                    # quiet or out of budget (the devices meet at the
+                    # readback and nowhere before). The worlds of a
+                    # device count the same but under the scan
+                    # driver's per-world budgets, where a world stops
+                    # counting at its own: a device's sums are the
+                    # rows of its widest world, in the same transfer;
+                    # its iterations are that ``rung_steps`` row
+                    # summed (one bin where routing has no ladder)
+                    rows = counts[0].reshape(-1, local).argmax(axis=1) \
+                        + np.arange(0, len(d), local)
                     stats.update(
                         shards=len(d) // local, worlds_local=local,
-                        device_rung_lanes=counts[0][::local].tolist(),
-                        device_sender_lanes=counts[1][::local].tolist())
+                        device_rung_lanes=counts[0][rows].tolist(),
+                        device_sender_lanes=counts[1][rows].tolist(),
+                        device_iterations=counts[2][rows].sum(
+                            axis=-1).tolist())
                 counts = [c[b] for c in counts]
             lanes, senders, by_rung, dense, wide = counts
             stats.update(rung_lanes=int(lanes), sender_lanes=int(senders),
@@ -370,16 +379,21 @@ class RunStatsMixin:
          "tail_lanes": int,   # the width their tails' scatters took
          "net_rows": int}     # the rows they sent through the network
 
-    for the world-sharded fleet (``ShardedBatchedEngine``: a rung a
-    device, the devices in lockstep at the loop's liveness
-    reduction)::
+    for the world-sharded fleet (``ShardedBatchedEngine``: a rung and
+    a loop a device, no collective; a device counts the iterations it
+    ran, and stops counting when its own last world does)::
 
         {"shards": int,         # the mesh axis' size
          "worlds_local": int,   # worlds a device
          "device_rung_lanes": [int] * shards,    # each device's own
-         "device_sender_lanes": [int] * shards}  # sums of the two
+         "device_sender_lanes": [int] * shards,  # sums of the two
                                 # above: ``rung_lanes`` is the widest
                                 # device's, max(device_rung_lanes)
+         "device_iterations": [int] * shards}    # the trips of each
+                                # device's loop (its ``rung_steps``
+                                # summed); all equal where the devices
+                                # ran in step, their largest is
+                                # ``fleet_iterations`` in a quiet run
 
     for the node-sharded edge engine (``ShardedEdgeEngine``)::
 
@@ -460,9 +474,9 @@ class RunStatsMixin:
         counts and a fleet's per-world counts are summed where every
         chunk has them (elementwise: a chunked fleet's
         ``fleet_iterations`` is the sum of its chunks' loops; a
-        world-sharded fleet's ``device_rung_lanes`` a device, so the
-        merged ``rung_lanes``, each chunk's widest device, is at least
-        their largest)."""
+        world-sharded fleet's ``device_rung_lanes`` and
+        ``device_iterations`` a device, so the merged ``rung_lanes``,
+        each chunk's widest device, is at least their largest)."""
         self.last_run_stats = {
             "supersteps": sum(c["supersteps"] for c in chunks),
             "wall_seconds": sum(c["wall_seconds"] for c in chunks),
@@ -480,7 +494,7 @@ class RunStatsMixin:
                     "dense_stage_steps", "wide_tail_steps",
                     "scatter_lanes", "dense_lanes", "tail_lanes",
                     "net_rows", "boundary_msgs", "device_rung_lanes",
-                    "device_sender_lanes"):
+                    "device_sender_lanes", "device_iterations"):
             if chunks and all(key in c for c in chunks):
                 cols = [c[key] for c in chunks]
                 self.last_run_stats[key] = sum(cols) \

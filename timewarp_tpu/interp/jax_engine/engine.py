@@ -2470,9 +2470,9 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         return (new, self._count_route(counts, stepped)), y
 
     def _any_world(self, x):
-        """Whether any world (on any device) is still active — the
-        while-loop liveness reduction. Identity single-chip; the
-        world-sharded engine overrides with a mesh psum."""
+        """Whether any world of this device is still active: the
+        quiet loop's liveness. Identity, and no engine overrides it:
+        worlds on other devices run their own loop."""
         return x
 
     def _horizon_all(self, st) -> Horizon:
@@ -2515,7 +2515,7 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         selected. Batched: the loop runs while ANY world is active, so
         each world's superstep selects its state once, by live and in
         budget together. Every iteration counts what its routing did
-        (a fleet's frozen worlds ran at the rung too)."""
+        (a frozen world ran at its device's rung too)."""
         def body(carry):
             st, counts, hz = carry
             if self.batch is None:

@@ -245,13 +245,13 @@ class ShardedBatchedEngine(ShardedDriver, JaxEngine):
     """The fleet over a mesh: the **world axis** sharded, nodes
     device-local. Each device runs ``B / D`` complete worlds — the
     embarrassingly-parallel layout the replica-sweep workload wants
-    (worlds are independent, so the superstep needs NO collectives;
-    the only mesh-wide reduction is run_quiet's "any world still
-    active" liveness check). Contrast :class:`ShardedEngine`, which
-    shards the *node* axis of one world and pays an ``all_to_all``
-    per superstep — compose them by passing this engine a mesh axis
-    of a multi-axis mesh when single-world capacity AND fleet width
-    are both needed.
+    (worlds are independent, so NO driver holds a collective: the
+    quiet loop runs while a world of THIS device is active, each
+    device its own trip count, and the devices meet once a call, at
+    its readback). Contrast :class:`ShardedEngine`, which shards the
+    *node* axis of one world and pays an ``all_to_all`` a superstep.
+    A world axis needs no agreement whatever mesh axes it is
+    composed with.
 
     The batch exactness law is unchanged: world b sliced out of the
     gathered state is bit-identical to the solo run with that world's
@@ -327,15 +327,3 @@ class ShardedBatchedEngine(ShardedDriver, JaxEngine):
             return jax.lax.dynamic_slice_in_dim(v, off, Bl, axis=0)
         return (sl(s0v), sl(s1v), {k: sl(v) for k, v in lpv.items()},
                 None if ftv is None else jax.tree.map(sl, ftv))
-
-    @jax.named_scope("tw.liveness")
-    def _any_world(self, x):
-        # liveness must be mesh-wide: one device's worlds finishing
-        # must not stop the others' (int32 psum — bool all-reduce
-        # does not lower on the TPU path, see MeshComm.all_min).
-        # It sits in the quiet loop's CONDITION, once an iteration:
-        # the one collective of the world-sharded drivers, and the
-        # point where four devices on four rungs wait for the widest.
-        # The scope is its name in a profile (``op_name``); no other
-        # driver's text holds it
-        return jax.lax.psum(x.astype(jnp.int32), self.axis) > 0

@@ -23,8 +23,8 @@ Kinds:
   fleet's ``fleet_iterations``, an ordered inbox's ``fan_in_peak`` and,
   solo on one device, its ``scatter_lanes``, a staged insertion's
   ``dense_lanes``, ``tail_lanes``, ``net_rows``, a mesh's ``shards``
-  and a world-sharded fleet's ``worlds_local``, ``device_rung_lanes``
-  and ``device_sender_lanes``).
+  and a world-sharded fleet's ``worlds_local``, ``device_rung_lanes``,
+  ``device_sender_lanes`` and ``device_iterations``).
 - ``utilization`` — per-bucket sweep utilization (sweep/runner.py):
   worlds-active occupancy, budget-mask efficiency, pow2 scan-pad
   waste.
@@ -80,8 +80,9 @@ _RUN_COUNTS = ("dispatches", "readbacks", "rung_lanes", "sender_lanes",
                "fan_in_peak", "scatter_lanes", "dense_lanes", "tail_lanes",
                "net_rows", "shards", "worlds_local")
 #: and those that are one int an entry: iterations by rung, and a
-#: world-sharded fleet's lanes by device
-_RUN_LISTS = ("rung_steps", "device_rung_lanes", "device_sender_lanes")
+#: world-sharded fleet's lanes and loop trips by device
+_RUN_LISTS = ("rung_steps", "device_rung_lanes", "device_sender_lanes",
+              "device_iterations")
 #: kind -> {required field: type tuple}; extra fields are allowed
 #: (forward-compatible), missing/badly-typed required ones are not
 _KINDS: Dict[str, Dict[str, tuple]] = {

@@ -151,10 +151,10 @@ class ShardedDriver:
     the quiet driver's ``while`` on one device's shard
     (``_quiet_loop``, inherited from its local base class,
     ``JaxEngine`` or ``EdgeEngine``: both carry the state's horizon,
-    reduced over the mesh where a superstep produces it, so the
-    loop's condition holds no collective; the world-sharded fleet's
-    holds one, its liveness ``psum`` under ``tw.liveness``:
-    ``ShardedBatchedEngine._any_world``) and what the loops carry
+    reduced over the mesh where a superstep produces it, so no
+    loop's condition holds a collective; the world-sharded fleet's
+    reads its own device's worlds, each device its own trip count:
+    ``last_run_stats`` ``device_iterations``) and what the loops carry
     beside the state (``_counted``, ``_step_counted``; the edge
     engine's quiet body: ``_step_carried``). ``_next_event``, a local
     base class's probe of a state at rest, is asked by no loop."""
