@@ -15,7 +15,10 @@ tests/test_insert_slot_law.py holds the program's slots to.
 
 ``expand_lanes`` (PR 36) is ``fill_holes``' expand run along the lane
 axis: a compacted ascending prefix spread over the lanes it names. Its
-plain reference is the scatter it replaces.
+plain reference is the scatter it replaces. ``compress_lanes`` (PR 48)
+is its mirror, the compress network run forwards: the lanes a mask
+names put in front, in their order. Its plain reference is the sort it
+replaces in ``_route_adaptive``, ``lax.sort(where(mask, ids, n))``.
 """
 
 import functools
@@ -26,8 +29,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from timewarp_tpu.ops.numeric import (I32MAX, expand_lanes, fill_holes,
-                                      free_bits, nth_set_bit)
+from timewarp_tpu.ops.numeric import (I32MAX, compress_lanes, expand_lanes,
+                                      fill_holes, free_bits, nth_set_bit)
 
 from free_bits_laws import (N, fill_eager, fill_holes_law, fill_jitted,
                             keep_mask)
@@ -178,6 +181,105 @@ def test_expand_lanes_lowers_without_an_index():
         tg, c, [a, b], (I32MAX, 0))).lower(
             lanes, jax.ShapeDtypeStruct((), np.int32), lanes,
             lanes).as_text()
+    for op in ("gather", "scatter", "sort", "dynamic_slice",
+               "dynamic_update_slice"):
+        assert f"stablehlo.{op}" not in text, op
+    assert "stablehlo.select" in text
+
+
+# -- the lane-axis compression -------------------------------------------------
+
+#: one lane, the network's first stages, a row of 128 less one, a rung
+#: and one over it, two rungs and one, the observer ring's 2^16 + 1
+COMPRESS_NS = (1, 2, 3, 127, 1024, 1025, 2049, 65537)
+SHARES = ("none", "one-lane", "1%", "30%", "97%", "all")
+
+
+def _live(share, shape, seed):
+    """The mask of the live lanes, by case."""
+    rng = np.random.default_rng(seed)
+    if share == "none":
+        return np.zeros(shape, bool)
+    if share == "all":
+        return np.ones(shape, bool)
+    if share == "one-lane":
+        m = np.zeros(shape, bool)
+        m[..., rng.integers(0, shape[-1])] = True
+        return m
+    return rng.random(shape) < float(share[:-1]) / 100
+
+
+@functools.cache
+def _compress_jitted(n):
+    """One program a width, whatever the share: the node ids and a
+    field of words, each with its own "nothing"."""
+    ids = jnp.arange(n, dtype=jnp.int32)
+    return jax.jit(lambda m, v: compress_lanes(m, [ids, v], [n, -7]))
+
+
+def _sender_sort(mask):
+    """What ``_route_adaptive`` ran until PR 48, on the last axis."""
+    n = mask.shape[-1]
+    return np.asarray(jax.lax.sort(jnp.where(
+        mask, jnp.arange(n, dtype=jnp.int32), jnp.int32(n))))
+
+
+@pytest.mark.parametrize("n", COMPRESS_NS, ids="n{}".format)
+@pytest.mark.parametrize("share", SHARES)
+def test_compress_lanes_equals_the_sort(share, n):
+    """The live ids ascending, then n: the sort's output word for
+    word; and a second field rides with them, its own "nothing"
+    behind the live count."""
+    mask = _live(share, (n,), seed=n)
+    v = np.random.default_rng(7000 + n).integers(
+        -2**31, 2**31, n).astype(np.int32)
+    ids, words = _compress_jitted(n)(mask, v)
+    want = _sender_sort(mask)
+    assert ids.dtype == np.int32 and words.dtype == np.int32
+    assert np.array_equal(ids, want), (
+        f"{share} n={n}: {np.argwhere(np.asarray(ids) != want)[:5].tolist()}")
+    k = int(mask.sum())
+    assert np.array_equal(words[:k], v[mask]) and (words[k:] == -7).all()
+
+
+@pytest.mark.parametrize("n", COMPRESS_NS, ids="n{}".format)
+def test_compress_lanes_under_vmap_over_eight_rows(n):
+    """A fleet's worlds: every row its own mask, ``vmap`` of the call
+    and the call on the batch itself alike."""
+    mask = np.stack([_live(share, (n,), seed=100 * n + r)
+                     for r, share in enumerate(SHARES + ("30%", "1%"))])
+    ids = jnp.arange(n, dtype=jnp.int32)
+    want = _sender_sort(mask)
+    by_vmap = jax.jit(jax.vmap(
+        lambda m: compress_lanes(m, [ids], [n])[0]))(mask)
+    whole = jax.jit(lambda m: compress_lanes(
+        m, [jnp.broadcast_to(ids, m.shape)], [n])[0])(mask)
+    assert np.array_equal(by_vmap, want) and np.array_equal(whole, want)
+
+
+@pytest.mark.parametrize("n", COMPRESS_NS, ids="n{}".format)
+@pytest.mark.parametrize("share", ["one-lane", "30%", "all"])
+def test_expand_lanes_undoes_compress_lanes(share, n):
+    """The compacted prefix names its own targets: expanded over them
+    every live lane holds its word again, every other "nothing"."""
+    mask = _live(share, (n,), seed=n + 1)
+    v = np.random.default_rng(9000 + n).integers(
+        -2**31, 2**31, n).astype(np.int32)
+    ids, words = _compress_jitted(n)(mask, v)
+    back, = jax.jit(lambda t, c, x: expand_lanes(t, c, [x], [I32MAX]))(
+        ids, np.int32(mask.sum()), words)
+    assert np.array_equal(back, np.where(mask, v, I32MAX))
+
+
+def test_compress_lanes_lowers_without_an_index():
+    """One prefix sum and ``bit_length(n - 1)`` stages of shifts and
+    selects: no gather, no scatter, no sort."""
+    n = 1000
+    text = jax.jit(lambda m, a, b: compress_lanes(
+        m, [a, b], (n, 0))).lower(
+            jax.ShapeDtypeStruct((n,), bool),
+            jax.ShapeDtypeStruct((n,), np.int32),
+            jax.ShapeDtypeStruct((n,), np.int32)).as_text()
     for op in ("gather", "scatter", "sort", "dynamic_slice",
                "dynamic_update_slice"):
         assert f"stablehlo.{op}" not in text, op

@@ -5,9 +5,14 @@ active counts, the fleet's one shared rung, and — end-to-end — that the
 recorded ``rung`` telemetry column equals the rung the ``lax.switch``
 actually took for the superstep's recorded active-sender count (the
 rung is recorded where the decision is made, engine.py
-``_route_adaptive``; this pins that they can never drift)."""
+``_route_adaptive``; this pins that they can never drift). Since PR 48
+also what puts the active senders in front of the rungs: no sort of
+the node lanes, a compaction under the scope ``tw.route/senders``."""
+
+import re
 
 import numpy as np
+import pytest
 
 from timewarp_tpu.interp.jax_engine.engine import BatchSpec, JaxEngine
 from timewarp_tpu.models.gossip import gossip
@@ -118,3 +123,47 @@ def test_batched_shares_one_rung():
     # supersteps at it
     assert set(rung[0].tolist()) == set(rungs)
     assert active.min() < 1024 < active.max()
+
+
+# -- the sender compaction (PR 48) ---------------------------------------------
+
+def _one_operand_sorts(text):
+    """The operand type of every single-operand ``sort`` of a lowered
+    text."""
+    return re.findall(
+        r'"stablehlo\.sort"\(%[^,)]*\).*?\}\) : \((tensor<[^>]*>)\)',
+        text, re.S)
+
+
+def test_the_reader_finds_a_one_operand_sort():
+    """What the ladder ran until PR 48, lowered alone: the reader
+    below must see it."""
+    import jax
+    import jax.numpy as jnp
+    text = jax.jit(lambda m: jax.lax.sort(jnp.where(
+        m, jnp.arange(2048, dtype=jnp.int32), 2048))).lower(
+            jax.ShapeDtypeStruct((2048,), bool)).as_text()
+    assert _one_operand_sorts(text) == ["tensor<2048xi32>"]
+
+
+@pytest.mark.parametrize("kw, lanes", [
+    ({}, "2048xi32"), ({"batch": BatchSpec(seeds=(0, 1))}, "2x2048xi32")],
+    ids=["wave", "fleet"])
+def test_no_sort_of_the_node_lanes_compacts_the_senders(kw, lanes):
+    """The quiet driver of a ladder engine, solo and fleet: no
+    one-operand sort over the n node lanes (the rungs' variadic sorts
+    of their own lanes stay), and the scope ``tw.route/senders``
+    around the network that took its place (``compress_lanes``)."""
+    n = 2048
+    eng = JaxEngine(*_steady(n), window="auto", **kw)
+    assert eng._adaptive_regime() and len(eng._sender_rungs(n)) > 1
+    args = (eng.init_state(), eng._coerce_budget(8)[0])
+    if eng.batch is not None:
+        args += (eng._identity(),)
+    text = type(eng)._run_while.lower(eng, *args).as_text(debug_info=True)
+    sorts = _one_operand_sorts(text)
+    assert f"tensor<{lanes}>" not in sorts, sorts
+    assert "stablehlo.sort" in text          # the rungs' own
+    scopes = set(re.findall(r'loc\("(jit\(_run_while\)[^"]*)"', text))
+    assert any("tw.route" in name and "/senders/" in name + "/"
+               for name in scopes), "no tw.route/senders scope"

@@ -26,6 +26,13 @@ variadic sort by staged index under ``tw.route/insert`` a rung, in the
 place of the sort the compiler put in front of every scatter (which no
 lowered text shows), and the scatters after it declared sorted, two
 branches of them (half the lanes, or all).
+
+Since PR 48 the sender compaction is no sort: the active sender ids
+come in front by a prefix count and a log N shift network
+(ops/numeric.py ``compress_lanes``; tests/test_free_bits.py holds it
+to the sort it replaced, tests/test_sender_rungs.py to its scope), so
+every ladder superstep counts one sort fewer: the routing sort a rung
+and, for an ordered inbox, the two along the mailbox's slots.
 """
 
 import re
@@ -56,8 +63,10 @@ def _ordered():
 
 
 #: inbox -> (scenario and link, ``stablehlo.sort`` operations in the
-#: parent's superstep (28d821d), how many of them went in PR 30)
-INBOX = {"commutative": (_commutative, 4, 1), "ordered": (_ordered, 5, 0)}
+#: superstep at 28d821d, how many of them have gone since: the
+#: free-rows sort of a commutative inbox in PR 30, the sender
+#: compaction's N-sort of both in PR 48)
+INBOX = {"commutative": (_commutative, 4, 2), "ordered": (_ordered, 5, 1)}
 
 
 def _sort_operands(text: str):
@@ -161,5 +170,7 @@ def test_a_solo_insert_gathers_nothing(path, fleet):
     # by the same reading, so "none" above is no blind spot
     if path == "ladder":
         assert any("tw.route" in s for s in _scopes_of(text, "gather"))
+    # the routing sort a rung (no N-sort in front of them since
+    # PR 48), the eager path's one
     assert len(_sort_operands(text)) == \
-        (3 if path == "ladder" else 1) + dense
+        (2 if path == "ladder" else 1) + dense

@@ -151,12 +151,18 @@ def test_the_adaptive_drivers_have_no_sort_scope(kw):
 #: ``tail_lanes``, ``net_rows``); at these widths (16 384 lanes and
 #: fewer, under ``_TAIL_LADDER_LANES``) the staging itself is PR 36's
 #: text (``tests/test_stage_tail_law.py``), and the fleet's driver,
-#: which stages nothing, lowers to what it lowered to. A PR that
-#: changes what these drivers compute changes the constants, and
-#: says so.
+#: which stages nothing, lowers to what it lowered to. PR 48 changed
+#: both (they were 76f791da8f01… and d9883414d933…): both drivers
+#: take the ladder, and the ladder's sender compaction is no longer
+#: a one-operand sort of the node lanes but ``compress_lanes``, a
+#: prefix count and a log N shift network, whose output is the
+#: sort's word for word (``tests/test_free_bits.py``; the final
+#: states of ``tests/test_zzzzzzzzzzzzzzzrecord.py`` are unmoved). A
+#: PR that changes what these drivers compute changes the
+#: constants, and says so.
 _PARENT_LOWERING = {
-    "solo": "76f791da8f0195730c00917d33fa9580aa84f0a9bb746e0e1209cd415a5f1a09",
-    "fleet": "d9883414d933ff760ab83d6e05390be3fdd403566d0a41711b69a358ebc547b5",
+    "solo": "f738f2c7dadc59269a121ac8fd9543d788fdae22c1b5e25225eefd12a89bd798",
+    "fleet": "2948d0bc4acc8b940a393729758942de76130264fd692895c954a54916649479",
 }
 
 

@@ -497,17 +497,26 @@ def _edge_sharded_quiet():
 #: sha256 of each driver's lowering (``as_text()``: no names, no
 #: locations) as the parent of PR 46 lowers it, but for the
 #: world-sharded quiet driver's: PR 47 took the all-reduce out of its
-#: loop's condition and re-pinned that one. A PR that changes what
-#: these drivers compute changes the constants, and says so.
+#: loop's condition and re-pinned that one. PR 48 re-pinned the four
+#: drivers that take the ladder (``window="auto"`` on ``LocalComm``:
+#: ``world_sharded_quiet`` was 6708eebb0485…, ``world_sharded_scan``
+#: 9d5a701b241d…, ``fleet_quiet`` 59e965122ffd…, ``solo_quiet``
+#: 4cd9e69f8ce0…): the ladder's sender compaction went from a
+#: one-operand sort of the node lanes to ``compress_lanes``, the
+#: same array word for word (``tests/test_free_bits.py``). The
+#: node-sharded and the edge-sharded driver never took the ladder
+#: (a ``MeshComm``; no routing at all) and keep their constants. A
+#: PR that changes what these drivers compute changes the
+#: constants, and says so.
 _PARENT_LOWERING = {
     "world_sharded_quiet":
-        "6708eebb04851fff5939c3dbd4e14ea2248d3a8bc1ade190d4053ec789ea1bec",
+        "984d8edf41b944c58b127ed4b3ad98df6658e652cd42f234577c5c8fcadd630f",
     "world_sharded_scan":
-        "9d5a701b241d6804b25af34ab509808c7029a86dc5113a2e98dfb415c950040b",
+        "27b7df94d44faa2b8b3587bb8def97175a6a622dab6e3b624d53a3a9062f4071",
     "fleet_quiet":
-        "59e965122ffd4ce9bd90de2763ecbd0678839f7ac4129b6a2d07a68779297cc8",
+        "db301bff740d6fe57f6cec804850ddacb82ba2afb440aff351e07f56fcc3e161",
     "solo_quiet":
-        "4cd9e69f8ce0e5ba1103f651ab09b5cbd08ea9ee2f5b31ba1e964d868dd3a3e8",
+        "d68a4763b0010823b89082a3e929a77ec8d598a69404acc41cb3cacafe4bc6b5",
     "node_sharded_quiet":
         "24939e824a423d6d39313d9a07fbb7550c039bc9970d0d92e4b00b1f275594a8",
     "edge_sharded_quiet":

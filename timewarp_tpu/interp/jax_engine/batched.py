@@ -4,7 +4,7 @@ The production use of a cheap emulator is *fleets* of runs — seed
 sweeps, link-model sweeps, Monte-Carlo fault studies (ROADMAP north
 star; the replica-sweep workload of Revati-style time-warp emulation,
 PAPERS.md). Per-superstep the general engine pays fixed N-width costs
-(sender-compaction sort, rung gathers, the [K, N] mailbox base —
+(the sender compaction, rung gathers, the [K, N] mailbox base —
 docs/engines.md "Measured on a v5e") that do not shrink with the instantaneous event count;
 a leading **world axis B** amortizes one compile, one dispatch and one
 readback over B independent worlds. Measured on a v5e (PERF.md,

@@ -27,8 +27,11 @@ pathological and random scatters are the dominant real cost, so
 mailbox deliver-times are stored as **int32 relative** to the rebased
 epoch (``EngineState.time``), inbox ordering and mailbox compaction are
 single variadic ``lax.sort`` calls instead of lexsort+gather chains,
-and trace digests exist only in the traced driver (``run``) — the
-``run_quiet`` benchmark path compiles them out.
+the ladder's sender compaction is a prefix count and a log N shift
+network on the node lanes and no sort at all (PR 48;
+ops/numeric.py ``compress_lanes``), and trace digests exist only in
+the traced driver (``run``) — the ``run_quiet`` benchmark path
+compiles them out.
 """
 
 from __future__ import annotations
@@ -45,7 +48,8 @@ import numpy as np
 from ...core.rng import fire_bits, msg_bits, seed_words
 from ...core.scenario import NEVER, Inbox, Outbox, Scenario
 from ...net.delays import LinkModel
-from ...ops.numeric import expand_lanes, fill_holes, free_bits, nth_set_bit
+from ...ops.numeric import (compress_lanes, expand_lanes, fill_holes,
+                            free_bits, nth_set_bit)
 from ...trace.events import SuperstepTrace
 from ...trace.hashing import FIRED, RECV, SENT, mix32_jnp
 from .batched import BatchSpec, WorldIdentity, rebind_link
@@ -321,8 +325,12 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
     (``window > 1 or max_out > 1``), routing never touches the
     S = N·max_out flattened arrays. All ``max_out`` lanes of a sender
     share ``(src, send instant)``, so the engine compacts *senders*
-    (one single-operand sort of N node ids — the only N-sized routing
-    cost), then gathers outbox lanes, sorts by ``(dst, window offset,
+    (the active node ids put in front in ascending order by a prefix
+    count and a log N shift network on the node lanes, ops/numeric.py
+    ``compress_lanes`` under the scope ``tw.route/senders`` — the
+    only N-sized routing cost; until PR 48 one single-operand sort
+    of N node ids, whose output it equals word for word), then
+    gathers outbox lanes, sorts by ``(dst, window offset,
     sender-major rank)``, samples link delays, ranks and scatters at a
     **ladder-selected static width**: a `lax.switch` over geometric
     sender-count rungs (…, n/16, n/4, n) picks the smallest compiled
@@ -1199,8 +1207,11 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
                         mb_src, mb_payload, holes, counts,
                         node_ids, with_trace):
         """Sender-compacted adaptive-width routing + insertion (class
-        docstring): compact active sender ids with ONE single-operand
-        N-sort, then gather/sort/sample/rank/scatter at the smallest
+        docstring): put the active sender ids in front with ONE
+        order-preserving compaction of the N node lanes
+        (``compress_lanes``: a prefix count and ``bit_length(N - 1)``
+        shifts, scope ``tw.route/senders``; no N-sort since PR 48),
+        then gather/sort/sample/rank/scatter at the smallest
         ladder rung that fits this superstep's active-sender count
         (``lax.switch`` — every branch is static-shape, so this is
         XLA-legal). All ``max_out`` lanes of a sender share its firing
@@ -1237,8 +1248,12 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
             pdst = jnp.where(cutm, jnp.int32(-1), pdst)
         sender_live = jnp.any(pdst >= 0, axis=0)                # [N]
         n_active = jnp.sum(sender_live, dtype=jnp.int32)
-        sid_sorted = jax.lax.sort(
-            jnp.where(sender_live, node_ids, jnp.int32(n)))
+        with jax.named_scope("senders"):
+            # the live ids ascending, then n: what a sort of
+            # where(sender_live, node_ids, n) gives, by a prefix count
+            # and log N shifts on the node lanes (PR 48)
+            sid_sorted = compress_lanes(sender_live, [node_ids],
+                                        [jnp.int32(n)])[0]
         # precomputed int32 in-window offsets: the branches gather one
         # int32 word per sender instead of an int64
         woff_n = (now_vec - t).astype(jnp.int32)                # [N]

@@ -2,7 +2,9 @@
 
 These are the "hot ops" of the TPU build in their XLA-native form —
 profiled and shaped for the VPU (docs/engines.md "Measured on a v5e"):
-pure elementwise/scan/sort building blocks, no gathers or scatters.
+pure elementwise/scan/sort building blocks, no gathers or scatters
+(and one small product on the matrix unit: ``compress_lanes``' prefix
+count).
 SURVEY.md §2 records the design stance: XLA-compiled JAX *is* this
 framework's native layer; Pallas would only enter if a fused op beat
 the compiler, and at 10x the performance target none currently does.
@@ -10,12 +12,15 @@ the compiler, and at 10x the performance target none currently does.
 
 from __future__ import annotations
 
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 __all__ = ["I32MAX", "group_rank", "free_bits", "nth_set_bit",
-           "fill_holes", "expand_lanes", "u32sum", "tlo", "thi"]
+           "fill_holes", "expand_lanes", "compress_lanes", "u32sum", "tlo",
+           "thi"]
 
 I32MAX = np.int32(2**31 - 1)
 
@@ -222,6 +227,99 @@ def expand_lanes(target, count, fields, nothing):
         disp = jnp.where(comes, below, jnp.where(stays, disp, _NO_LANE))
     return [jnp.where(disp == _NO_LANE, jnp.asarray(e, x.dtype), x)
             for x, e in zip(fields, nothing)]
+
+
+#: :func:`_live_below`'s row: the lanes of one vector register
+_ROW = 128
+
+
+def _live_below(mask):
+    """Each lane's count of set lanes below it along the last axis
+    (int32, the exclusive prefix sum of ``mask``), in two levels:
+    inside a row of 128 lanes one product with the strict triangle of
+    ones (int8 operands, int32 sums: exact, and the matrix unit's
+    work where seven shifted adds would be the vector unit's), and
+    over the rows a prefix of their sums, ``n / 128`` entries. On a
+    v5e 35 us at 2^20 lanes where ``lax.cumsum``'s ``reduce-window``
+    takes 216 (and 20-35 s of the compiler's time for 0.5), the seven
+    adds 56 (profiling/sender_compact_micro_r09.py less its floor,
+    PR 48; docs/engines.md "The sender compaction, by its form")."""
+    n = mask.shape[-1]
+    rows = -(-n // _ROW)
+    x = mask.astype(jnp.int8)
+    if rows * _ROW != n:
+        x = jnp.concatenate(
+            [x, jnp.zeros(x.shape[:-1] + (rows * _ROW - n,), x.dtype)],
+            axis=-1)
+    x = x.reshape(x.shape[:-1] + (rows, _ROW))
+    k = jnp.arange(_ROW, dtype=jnp.int32)
+    in_row = jnp.matmul(x, (k[:, None] < k[None, :]).astype(jnp.int8),
+                        preferred_element_type=jnp.int32)
+    last = partial(jax.lax.index_in_dim, index=_ROW - 1, axis=-1,
+                   keepdims=False)
+    row = last(in_row) + last(x)
+    below = in_row + (jnp.cumsum(row, axis=-1) - row)[..., None]
+    return below.reshape(below.shape[:-2] + (rows * _ROW,))[..., :n]
+
+
+def _compress(disp, fields, nothing):
+    """:func:`compress_lanes`' network on the lanes' displacements
+    ``disp`` (int32 ``[..., n]``: how far down each live lane goes,
+    ``_NO_LANE`` on a dead one)."""
+    n = disp.shape[-1]
+    fields = list(fields)
+
+    def lowered(x, s, fill):
+        return jnp.concatenate(
+            [x[..., s:], jnp.full(x.shape[:-1] + (s,), fill, x.dtype)],
+            axis=-1)
+    for i in range((n - 1).bit_length()):
+        s = 1 << i
+        above = lowered(disp, s, _NO_LANE)
+        comes = (above & jnp.int32(s)) != 0
+        stays = (disp & jnp.int32(s)) == 0
+        fields = [jnp.where(comes, lowered(x, s, 0), x) for x in fields]
+        disp = jnp.where(comes, above, jnp.where(stays, disp, _NO_LANE))
+    return [jnp.where(disp == _NO_LANE, jnp.asarray(e, x.dtype), x)
+            for x, e in zip(fields, nothing)]
+
+
+@jax.jit
+def compress_lanes(mask, fields, nothing):
+    """Put the lanes ``mask`` names in front, in their order: lane
+    ``j`` of every field of the result is the field's ``j``-th live
+    lane, ``nothing[f]`` from the live count on. ``mask`` is bool
+    ``[..., n]``, ``fields`` a sequence of arrays of its shape,
+    ``nothing`` their fill values; the lanes run along the last axis
+    and every leading axis is a batch. Returns the fields as a list.
+    ``compress_lanes(m, [ids], [n])[0]`` is
+    ``lax.sort(where(m, ids, n))`` for ascending ``ids < n``, word for
+    word.
+
+    :func:`expand_lanes`' mirror, the *compress* network of Hacker's
+    Delight 7-5 along the lane axis: every live lane carries its
+    displacement ``lane - (live lanes below it)``, the dead lanes
+    below it, and stage ``i`` lowers by ``2^i`` the lanes whose
+    displacement has bit ``i`` set, the smallest ``i`` first (the
+    order in which :func:`fill_holes` builds its masks), so after the
+    stages up to ``i`` a lane stands at ``lane - (displacement mod
+    2^(i+1))``: two live lanes ``a < b`` part by at least
+    ``1 + (d_b - d_a)`` and ``d mod 2^k`` grows no faster than ``d``,
+    so no two lanes ever meet. A stage is one shift of each array by
+    a static distance and two selects: one prefix count
+    (:func:`_live_below`) and ``bit_length(n - 1)`` elementwise
+    passes, no gather, no scatter, no sort (tests/test_free_bits.py
+    holds it to the sort).
+
+    Jitted, so that a program's tracing finds it traced: a ladder
+    driver built under ``vmap`` and ``shard_map`` spends more of
+    XLA:CPU's time tracing these stages than compiling them (tier-1's
+    clock, PR 48); the compiler inlines the call, the chip's program
+    is the same."""
+    n = mask.shape[-1]
+    lane = jnp.arange(n, dtype=jnp.int32)
+    return _compress(jnp.where(mask, lane - _live_below(mask), _NO_LANE),
+                     fields, nothing)
 
 
 def u32sum(x: jax.Array) -> jax.Array:
