@@ -505,7 +505,15 @@ def _edge_sharded_quiet():
 #: one-operand sort of the node lanes to ``compress_lanes``, the
 #: same array word for word (``tests/test_free_bits.py``). The
 #: node-sharded and the edge-sharded driver never took the ladder
-#: (a ``MeshComm``; no routing at all) and keep their constants. A
+#: (a ``MeshComm``; no routing at all) and kept their constants
+#: there. PR 49 re-pinned ``node_sharded_quiet`` (it was
+#: 24939e824a42…): each shard of ``ShardedEngine`` now carries
+#: its own ``remote_msgs`` and ``bucket_fill_peak`` beside the state
+#: (two reductions over the lanes its exchange has sorted by shard,
+#: no collective), and the shard's index is read once at the top of
+#: ``_exchange``; the state it computes is the parent's
+#: (``tests/test_zzzzzzzzzzzzzzzzzzzsteady_x4.py``), and the other
+#: five drivers lower to the parent's text. A
 #: PR that changes what these drivers compute changes the
 #: constants, and says so.
 _PARENT_LOWERING = {
@@ -518,7 +526,7 @@ _PARENT_LOWERING = {
     "solo_quiet":
         "d68a4763b0010823b89082a3e929a77ec8d598a69404acc41cb3cacafe4bc6b5",
     "node_sharded_quiet":
-        "24939e824a423d6d39313d9a07fbb7550c039bc9970d0d92e4b00b1f275594a8",
+        "7d1edba5936ba102cbec5b7fd870a789f0ab1e4cdec7fe82133dcefaa7fda111",
     "edge_sharded_quiet":
         "fb22bbbdb766457edff1d5a6f0107e58663db37f44d10757fec769575fdf4def",
 }

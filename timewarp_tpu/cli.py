@@ -236,6 +236,11 @@ def build_engine(args, sc, link):
         raise SystemExit(
             f"--route-cap applies to the XLA general engines only; "
             f"{args.engine} has no XLA insertion stage to bound")
+    if args.engine != "sharded" and args.bucket_cap is not None:
+        raise SystemExit(
+            f"--bucket-cap applies to the node-sharded general engine "
+            f"only (--engine sharded); {args.engine} has no "
+            "all_to_all exchange to bound")
     if args.engine == "oracle":
         from .interp.ref.superstep import SuperstepOracle
         return SuperstepOracle(sc, link, seed=args.seed,
@@ -297,6 +302,7 @@ def build_engine(args, sc, link):
                                      telemetry=telemetry,
                                      verify=verify)
         return ShardedEngine(sc, link, mesh, seed=args.seed,
+                             bucket_cap=args.bucket_cap,
                              window=args.window,
                              route_cap=args.route_cap,
                              lint=args.lint, telemetry=telemetry,
@@ -684,6 +690,13 @@ def main(argv=None) -> int:
     p.add_argument("--route-cap", type=int, default=None,
                    help="static active-message budget for the insertion "
                         "stage (clipped messages are counted)")
+    p.add_argument("--bucket-cap", type=int, default=None,
+                   help="--engine sharded: lanes of one (source shard, "
+                        "destination shard) bucket of the all_to_all "
+                        "exchange (default: a device's whole outbox "
+                        "width, which cannot overflow and ships the "
+                        "world's width to every device; what does not "
+                        "fit is counted in overflow)")
     p.add_argument("--fanout", type=int, default=8)
     p.add_argument("--slots", type=int, default=10)
     p.add_argument("--leader-prob", type=float, default=0.05)

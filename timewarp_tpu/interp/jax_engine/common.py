@@ -28,7 +28,10 @@ __all__ = ["LocalComm", "StepOut", "I32MAX", "group_rank", "u32sum",
 #: A scope is metadata: it adds no equation, and a profile shows it in
 #: each operation's ``op_name`` (benchmark/span_reduce.py ``stage_ns``).
 #: Nested in ``tw.route`` (``engine.py``): ``sample`` (the no-drop
-#: paths' link draw), ``exchange``, ``sort`` (the eager and the lazy
+#: paths' link draw), ``exchange`` (in the node-sharded general
+#: engine's, sharded.py, ``bucket``: the sort by destination shard,
+#: the ranks, the scatters into a bucket a shard; and ``swap``: the
+#: ``all_to_all``s), ``sort`` (the eager and the lazy
 #: regime's one variadic sort by destination; the adaptive ladder's
 #: sorts are the stage's own) and ``insert``; nested in ``tw.deliver``:
 #: ``sort`` (an ordered inbox's variadic sort along the mailbox's
@@ -247,10 +250,13 @@ class _DriverCall:
         driver's loop carried beside the state (``counts``: a
         ``(rung_lanes, sender_lanes, rung_steps, dense_stage_steps,
         wide_tail_steps, fan_in_peak, scatter_lanes, dense_lanes,
-        tail_lanes, net_rows)`` of device arrays, ``fan_in_peak`` and
+        tail_lanes, net_rows, remote_msgs, bucket_fill_peak)`` of
+        device arrays, ``fan_in_peak`` and
         ``scatter_lanes`` None but from an ordered inbox (the second
-        from a solo one on one device), the last three None but from
-        an insertion staged by rank, ``engine.py``
+        from a solo one on one device), the three of the staging None
+        but from an insertion staged by rank, the last two None but
+        from the node-sharded general engine (one row a shard),
+        ``engine.py``
         ``RouteCounts``; None from an engine with no ladder to count),
         a node-sharded edge engine's boundary messages (``crossed``:
         one count a shard, ``sharded.py`` ``ShardedEdgeEngine``; None
@@ -275,8 +281,8 @@ class _DriverCall:
             stats.update(world_supersteps=d.tolist(),
                          fleet_iterations=int(d.max()))
         if counts is not None:
-            *counts, fan_in, scattered, dense_lanes, tail_lanes, rows = \
-                counts
+            (*counts, fan_in, scattered, dense_lanes, tail_lanes, rows,
+             remote, fill) = counts
             if d.ndim:
                 # one rung for all the worlds of a superstep: every
                 # world counted the same (a world-sharded fleet: the
@@ -317,6 +323,15 @@ class _DriverCall:
                 stats.update(dense_lanes=int(dense_lanes),
                              tail_lanes=int(tail_lanes),
                              net_rows=int(rows))
+            if remote is not None:
+                # counted on each shard beside its state: summed, and
+                # the fullest bucket of any shard, here
+                cap = self.eng.bucket_cap
+                stats.update(shards=len(remote),
+                             remote_msgs=int(remote.sum()),
+                             bucket_fill_peak=int(fill.max()),
+                             bucket_cap=cap,
+                             exchange_lanes=len(remote) * cap)
         if crossed is not None:
             # counted on each shard beside its state, summed here
             stats.update(shards=len(crossed),
@@ -402,6 +417,25 @@ class RunStatsMixin:
                                 # sender lives on another shard (the
                                 # dense ring: one a shard a superstep)
 
+    for the node-sharded general engine (``ShardedEngine``: every
+    message handed to its destination's shard by ``all_to_all``; a
+    device counts what it hands over, no collective)::
+
+        {"shards": int,            # the mesh axis' size
+         "remote_msgs": int,       # valid messages of the call whose
+                                   # destination's shard is not the
+                                   # sender's
+         "bucket_fill_peak": int,  # the most messages one (source
+                                   # shard, destination shard) bucket
+                                   # was asked to hold in one superstep
+                                   # of the call, before the cut at
+                                   # ``bucket_cap``: over it, by how
+                                   # much the capacity was short
+         "bucket_cap": int,        # the engine's, lanes a bucket
+         "exchange_lanes": int}    # shards * bucket_cap: the lanes a
+                                   # device receives, sorts and
+                                   # inserts a superstep
+
     and, for a fleet (``batch=BatchSpec``) only::
 
         {"world_supersteps": [int] * B,  # executed by each world
@@ -476,7 +510,9 @@ class RunStatsMixin:
         ``fleet_iterations`` is the sum of its chunks' loops; a
         world-sharded fleet's ``device_rung_lanes`` and
         ``device_iterations`` a device, so the merged ``rung_lanes``,
-        each chunk's widest device, is at least their largest)."""
+        each chunk's widest device, is at least their largest); the
+        two peaks (``fan_in_peak``, ``bucket_fill_peak``) take their
+        chunks' largest."""
         self.last_run_stats = {
             "supersteps": sum(c["supersteps"] for c in chunks),
             "wall_seconds": sum(c["wall_seconds"] for c in chunks),
@@ -486,22 +522,24 @@ class RunStatsMixin:
             "chunks": len(chunks),
             "per_chunk_compiles": [c["compiles"] for c in chunks],
         }
-        for key in ("shards", "worlds_local"):    # the mesh's, kept
+        for key in ("shards", "worlds_local", "bucket_cap",
+                    "exchange_lanes"):            # the mesh's, kept
             if chunks and all(key in c for c in chunks):
                 self.last_run_stats[key] = chunks[0][key]
         for key in ("rung_lanes", "sender_lanes", "fleet_iterations",
                     "rung_steps", "world_supersteps",
                     "dense_stage_steps", "wide_tail_steps",
                     "scatter_lanes", "dense_lanes", "tail_lanes",
-                    "net_rows", "boundary_msgs", "device_rung_lanes",
+                    "net_rows", "boundary_msgs", "remote_msgs",
+                    "device_rung_lanes",
                     "device_sender_lanes", "device_iterations"):
             if chunks and all(key in c for c in chunks):
                 cols = [c[key] for c in chunks]
                 self.last_run_stats[key] = sum(cols) \
                     if not isinstance(cols[0], list) \
                     else [sum(col) for col in zip(*cols)]
-        if chunks and all("fan_in_peak" in c for c in chunks):
+        for key in ("fan_in_peak", "bucket_fill_peak"):
             # a largest value, not a sum
-            self.last_run_stats["fan_in_peak"] = max(
-                c["fan_in_peak"] for c in chunks)
+            if chunks and all(key in c for c in chunks):
+                self.last_run_stats[key] = max(c[key] for c in chunks)
         return self.last_run_stats

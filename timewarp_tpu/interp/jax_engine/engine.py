@@ -225,12 +225,13 @@ class RouteCounts(NamedTuple):
     the call reads it in its one transfer (``last_run_stats``, the
     call's record). The first three come from the two scalars
     ``_route_adaptive`` holds when it picks its branch (the active
-    senders and the rung's index), the next two and the last three
-    from the one scalar ``_stage_by_rank``'s dense form picks its
-    tail's width by: no
+    senders and the rung's index), the next two and the three of the
+    staging from the one scalar ``_stage_by_rank``'s dense form picks
+    its tail's width by: no
     pass over node- or mailbox-sized data is made for them
     (``fan_in_peak`` is one reduction over the message lanes that an
-    ordered inbox's insertion has ranked already). Where
+    ordered inbox's insertion has ranked already; the last two are
+    two over the lanes the sharded exchange has sorted by shard). Where
     routing runs without the ladder every iteration counts the full
     width, in one bin. A fleet's leaves lead with the world axis like
     every state leaf (one rung for all the worlds of a superstep:
@@ -268,6 +269,16 @@ class RouteCounts(NamedTuple):
     dense_lanes: Any = None
     tail_lanes: Any = None
     net_rows: Any = None
+    #: int64[1] and int32[1], a device's own — the valid messages the
+    #: exchange sent to another shard, summed over the iterations, and
+    #: the most that one destination shard's bucket was asked to hold
+    #: in one of them, counted before the cut at ``bucket_cap`` (a
+    #: maximum, not a sum). Carried by the node-sharded general engine
+    #: (sharded.py ``ShardedEngine``: one row a shard of what the call
+    #: reads back, no collective) and by no other (None elsewhere, as
+    #: above)
+    remote_msgs: Any = None
+    bucket_fill_peak: Any = None
 
 
 class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,

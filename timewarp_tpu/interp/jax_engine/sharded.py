@@ -151,7 +151,18 @@ class ShardedEngine(ShardedDriver, JaxEngine):
     width (``n_local * max_out``), which cannot overflow — bit-for-bit
     parity by construction; tune it down to shrink the exchange volume
     (≤ the true per-shard fan-in) and any overflow is counted in
-    ``EngineState.overflow``, never silent.
+    ``EngineState.overflow``, never silent. At the default every
+    device receives ``D * n_local * max_out`` lanes a superstep, the
+    whole world's outbox width, and sorts and inserts them all.
+
+    Under ``tw.route/exchange`` the sort by shard, the ranks and the
+    scatters into the ``[D, bucket_cap]`` buffers carry the scope
+    ``bucket``, the ``all_to_all``s ``swap``. ``last_run_stats`` of
+    every call holds ``shards``, ``remote_msgs``, ``bucket_fill_peak``
+    (counted before the cut, so a value over ``bucket_cap`` says by
+    how much it was short), ``bucket_cap`` and ``exchange_lanes``
+    (``D * bucket_cap``): a device counts its own beside the state and
+    the call's one readback brings them (common.py ``RunStatsMixin``).
     """
 
     def __init__(self, scenario: Scenario, link: LinkModel,
@@ -179,45 +190,81 @@ class ShardedEngine(ShardedDriver, JaxEngine):
     def _exchange(self, ok, drel, src_f, dst_f, smrank, woff, pay_cols):
         comm = self.comm
         D, nl, B = comm.n_shards, comm.n_local, self.bucket_cap
-        # destination shard of each message; invalid -> sentinel D.
-        # One variadic sort groups messages by shard with all values
-        # riding along (no argsort + gather chain); in-bucket order is
-        # irrelevant — insertion downstream sorts on (woff, smrank).
-        dshard = jnp.where(ok, dst_f // jnp.int32(nl), jnp.int32(D))
-        ops = jax.lax.sort(
-            (dshard, drel, src_f, dst_f, smrank, woff) + pay_cols,
-            dimension=0, num_keys=1)
-        sk = ops[0]
-        rank = group_rank(sk)
-        fits = (sk < D) & (rank < B)
-        brow = jnp.where(fits, sk, D)             # -> dropped scatter
-        bcol = jnp.clip(rank, 0, B - 1)
+        here = jax.lax.axis_index(self.axis).astype(jnp.int32)
+        with jax.named_scope("bucket"):
+            # destination shard of each message; invalid -> sentinel D.
+            # One variadic sort groups messages by shard with all
+            # values riding along (no argsort + gather chain);
+            # in-bucket order is irrelevant — insertion downstream
+            # sorts on (woff, smrank).
+            dshard = jnp.where(ok, dst_f // jnp.int32(nl), jnp.int32(D))
+            ops = jax.lax.sort(
+                (dshard, drel, src_f, dst_f, smrank, woff) + pay_cols,
+                dimension=0, num_keys=1)
+            sk = ops[0]
+            rank = group_rank(sk)
+            sent = sk < D
+            fits = sent & (rank < B)
+            brow = jnp.where(fits, sk, D)         # -> dropped scatter
+            bcol = jnp.clip(rank, 0, B - 1)
+
+            def scat(x):
+                buf = jnp.zeros((D, B), x.dtype)
+                return buf.at[brow, bcol].set(x, mode="drop")
+
+            # only fitting entries scatter (brow==D drops the rest), so
+            # the occupancy mask is just "slot was written"
+            b_ok = jnp.zeros((D, B), jnp.int8).at[brow, bcol].set(
+                jnp.int8(1), mode="drop")
+            bufs = [b_ok] + [scat(x) for x in ops[1:]]
+            # what this shard hands the exchange, for the drivers'
+            # counts (``_count_route``): the messages that leave it
+            # and its fullest bucket before the cut at ``B``. Its own
+            # lanes only: no collective joins the superstep for them
+            self._exchanged = (
+                jnp.sum(sent & (sk != here), dtype=jnp.int32),
+                jnp.max(jnp.where(sent, rank + 1, 0)))
         bucket_ovf = comm.all_sum(
-            jnp.sum((sk < D) & (rank >= B), dtype=jnp.int32))
+            jnp.sum(sent & (rank >= B), dtype=jnp.int32))
 
-        def scat(x):
-            buf = jnp.zeros((D, B), x.dtype)
-            return buf.at[brow, bcol].set(x, mode="drop")
+        with jax.named_scope("swap"):
+            def a2a(x):
+                return jax.lax.all_to_all(
+                    x, self.axis, split_axis=0,
+                    concat_axis=0).reshape(D * B)
 
-        # only fitting entries scatter (brow==D drops the rest), so the
-        # occupancy mask is just "slot was written"
-        b_ok = jnp.zeros((D, B), jnp.int8).at[brow, bcol].set(
-            jnp.int8(1), mode="drop")
-        bufs = [b_ok] + [scat(x) for x in ops[1:]]
-
-        def a2a(x):
-            return jax.lax.all_to_all(
-                x, self.axis, split_axis=0, concat_axis=0).reshape(D * B)
-
-        r_ok = a2a(b_ok).astype(bool)
-        r_drel, r_src, r_dst, r_smrank, r_woff = (
-            a2a(b) for b in bufs[1:6])
-        r_pay = tuple(a2a(b) for b in bufs[6:])
+            r_ok = a2a(b_ok).astype(bool)
+            r_drel, r_src, r_dst, r_smrank, r_woff = (
+                a2a(b) for b in bufs[1:6])
+            r_pay = tuple(a2a(b) for b in bufs[6:])
         # received rows are local: subtract this shard's node offset
-        off = jax.lax.axis_index(self.axis).astype(jnp.int32) \
-            * jnp.int32(nl)
-        return (r_ok, r_drel, r_src, r_dst - off, r_smrank, r_woff,
-                r_pay, bucket_ovf)
+        return (r_ok, r_drel, r_src, r_dst - here * jnp.int32(nl),
+                r_smrank, r_woff, r_pay, bucket_ovf)
+
+    # -- what crossed, beside the state ------------------------------------
+
+    def _counted(self, st):
+        """``JaxEngine._counted`` and, beside the routing counts, this
+        shard's own two of the exchange from zero: one row each of the
+        ``[shards]`` the call reads back (``last_run_stats``
+        ``remote_msgs``, ``bucket_fill_peak``)."""
+        st, counts = super()._counted(st)
+        return st, counts._replace(
+            remote_msgs=jnp.zeros((1,), jnp.int64),
+            bucket_fill_peak=jnp.zeros((1,), jnp.int32))
+
+    def _count_route(self, counts, stepped=True):
+        remote, fill = self._exchanged
+        return super()._count_route(counts, stepped)._replace(
+            remote_msgs=counts.remote_msgs + jnp.where(
+                stepped, remote, 0).astype(jnp.int64),
+            bucket_fill_peak=jnp.maximum(
+                counts.bucket_fill_peak, jnp.where(stepped, fill, 0)))
+
+    def _carry_specs(self, st, specs):
+        specs, counts = super()._carry_specs(st, specs)
+        return specs, counts._replace(remote_msgs=P(self.axis),
+                                      bucket_fill_peak=P(self.axis))
 
     # -- sharding specs --------------------------------------------------
 

@@ -24,7 +24,15 @@ Kinds:
   solo on one device, its ``scatter_lanes``, a staged insertion's
   ``dense_lanes``, ``tail_lanes``, ``net_rows``, a mesh's ``shards``
   and a world-sharded fleet's ``worlds_local``, ``device_rung_lanes``,
-  ``device_sender_lanes`` and ``device_iterations``).
+  ``device_sender_lanes`` and ``device_iterations``, and the
+  node-sharded general engine's five of its ``all_to_all`` exchange:
+  ``shards``, ``remote_msgs`` (valid messages whose destination's
+  shard is not the sender's), ``bucket_fill_peak`` (the most one
+  bucket was asked to hold in one superstep, before the cut at the
+  capacity), ``bucket_cap`` and ``exchange_lanes`` (``shards *
+  bucket_cap``, the lanes a device receives a superstep); their
+  device work lies under ``tw.route/exchange/bucket`` and
+  ``tw.route/exchange/swap``).
 - ``utilization`` — per-bucket sweep utilization (sweep/runner.py):
   worlds-active occupancy, budget-mask efficiency, pow2 scan-pad
   waste.
@@ -78,7 +86,8 @@ _NUM = (int, float)
 _RUN_COUNTS = ("dispatches", "readbacks", "rung_lanes", "sender_lanes",
                "fleet_iterations", "dense_stage_steps", "wide_tail_steps",
                "fan_in_peak", "scatter_lanes", "dense_lanes", "tail_lanes",
-               "net_rows", "shards", "worlds_local")
+               "net_rows", "shards", "worlds_local", "remote_msgs",
+               "bucket_fill_peak", "bucket_cap", "exchange_lanes")
 #: and those that are one int an entry: iterations by rung, and a
 #: world-sharded fleet's lanes and loop trips by device
 _RUN_LISTS = ("rung_steps", "device_rung_lanes", "device_sender_lanes",

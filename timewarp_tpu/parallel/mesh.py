@@ -200,8 +200,10 @@ class ShardedDriver:
         (``JaxEngine._counted``): a world to a row in the
         world-sharded fleet, replicated scalars in a node-sharded
         world (whose routing counts its full width, the same on every
-        device). The edge engine's boundary messages are a row a
-        shard (``ShardedEdgeEngine`` overrides)."""
+        device; beside them its exchange's two counts are a row a
+        shard: ``ShardedEngine`` overrides). The edge engine's
+        boundary messages are a row a shard (``ShardedEdgeEngine``
+        overrides)."""
         world = getattr(self, "worlds_local", None) is not None
         return specs, jax.tree.map(
             lambda x: P(self.axis, *[None] * (x.ndim - 1)) if world
