@@ -513,7 +513,12 @@ def _edge_sharded_quiet():
 #: no collective), and the shard's index is read once at the top of
 #: ``_exchange``; the state it computes is the parent's
 #: (``tests/test_zzzzzzzzzzzzzzzzzzzsteady_x4.py``), and the other
-#: five drivers lower to the parent's text. A
+#: five drivers lower to the parent's text. PR 50 re-pinned
+#: ``node_sharded_quiet`` again (it was 7d1edba5936b…): the
+#: exchange's ``[shards, bucket_cap]`` buffers are slices of the
+#: planes sorted by shard and no longer scatters, the same words
+#: (``tests/test_exchange_bucket_law.py``); the other five drivers
+#: run no sharded exchange and lower to the parent's text. A
 #: PR that changes what these drivers compute changes the
 #: constants, and says so.
 _PARENT_LOWERING = {
@@ -526,7 +531,7 @@ _PARENT_LOWERING = {
     "solo_quiet":
         "d68a4763b0010823b89082a3e929a77ec8d598a69404acc41cb3cacafe4bc6b5",
     "node_sharded_quiet":
-        "7d1edba5936ba102cbec5b7fd870a789f0ab1e4cdec7fe82133dcefaa7fda111",
+        "786fb1206f5e785538452595eb8c4b170035dbfda1224494a04bbae449070c06",
     "edge_sharded_quiet":
         "fb22bbbdb766457edff1d5a6f0107e58663db37f44d10757fec769575fdf4def",
 }

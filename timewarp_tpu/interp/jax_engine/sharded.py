@@ -33,7 +33,6 @@ from ...net.delays import LinkModel
 from ...parallel.mesh import (AxisName, Mesh, MeshComm,
                               ShardedDriver, axis_size, make_mesh)
 from .batched import BatchSpec
-from .common import group_rank
 from .edge_engine import EdgeEngine, EdgeState
 from .engine import EngineState, JaxEngine
 
@@ -155,14 +154,18 @@ class ShardedEngine(ShardedDriver, JaxEngine):
     device receives ``D * n_local * max_out`` lanes a superstep, the
     whole world's outbox width, and sorts and inserts them all.
 
-    Under ``tw.route/exchange`` the sort by shard, the ranks and the
-    scatters into the ``[D, bucket_cap]`` buffers carry the scope
-    ``bucket``, the ``all_to_all``s ``swap``. ``last_run_stats`` of
-    every call holds ``shards``, ``remote_msgs``, ``bucket_fill_peak``
-    (counted before the cut, so a value over ``bucket_cap`` says by
-    how much it was short), ``bucket_cap`` and ``exchange_lanes``
-    (``D * bucket_cap``): a device counts its own beside the state and
-    the call's one readback brings them (common.py ``RunStatsMixin``).
+    After the sort by shard a bucket is a contiguous run of the sorted
+    lanes, so each plane's ``[D, bucket_cap]`` buffer is ``D`` slices
+    of the sorted plane, blank from each run's end: no scatter, no
+    gather, and the first ``bucket_cap`` of a run are the ones that
+    fit. Under ``tw.route/exchange`` the sort, the runs' counts and
+    the slices carry the scope ``bucket``, the ``all_to_all``s
+    ``swap``. ``last_run_stats`` of every call holds ``shards``,
+    ``remote_msgs``, ``bucket_fill_peak`` (counted before the cut, so
+    a value over ``bucket_cap`` says by how much it was short),
+    ``bucket_cap`` and ``exchange_lanes`` (``D * bucket_cap``): a
+    device counts its own beside the state and the call's one readback
+    brings them (common.py ``RunStatsMixin``).
     """
 
     def __init__(self, scenario: Scenario, link: LinkModel,
@@ -201,31 +204,42 @@ class ShardedEngine(ShardedDriver, JaxEngine):
             ops = jax.lax.sort(
                 (dshard, drel, src_f, dst_f, smrank, woff) + pay_cols,
                 dimension=0, num_keys=1)
-            sk = ops[0]
-            rank = group_rank(sk)
-            sent = sk < D
-            fits = sent & (rank < B)
-            brow = jnp.where(fits, sk, D)         # -> dropped scatter
-            bcol = jnp.clip(rank, 0, B - 1)
+            # after the sort a shard's lanes are one contiguous run
+            # (``start`` lanes sort below it) and a lane's offset in
+            # the run is its column in the bucket, so row ``d`` of a
+            # plane's buffer is a slice of the sorted plane from
+            # ``start[d]``, blank from the run's end: the first ``B``
+            # of a run fit (the sort is stable), the rest overflow
+            count = jnp.sum(
+                dshard == jnp.arange(D, dtype=jnp.int32)[:, None],
+                axis=1, dtype=jnp.int32)
+            start = jnp.cumsum(count, dtype=jnp.int32) - count
+            live = (jnp.arange(B, dtype=jnp.int32)
+                    < jnp.minimum(count, B)[:, None])
 
-            def scat(x):
-                buf = jnp.zeros((D, B), x.dtype)
-                return buf.at[brow, bcol].set(x, mode="drop")
+            def rows(x):
+                # ``B`` blank lanes behind the plane: a slice from any
+                # ``start <= len(x)`` lies inside, so dynamic_slice
+                # never clamps its start and shifts a run. One gather
+                # of ``D`` slices, so the program does not grow with
+                # the mesh (on four v5e ``D`` unrolled slices are 65 us
+                # a superstep cheaper: PERF.md, Findings PR 50)
+                x = jnp.pad(x, (0, B))
+                return jnp.where(live, jax.vmap(
+                    lambda s: jax.lax.dynamic_slice(x, (s,), (B,)))(
+                        start), 0)
 
-            # only fitting entries scatter (brow==D drops the rest), so
-            # the occupancy mask is just "slot was written"
-            b_ok = jnp.zeros((D, B), jnp.int8).at[brow, bcol].set(
-                jnp.int8(1), mode="drop")
-            bufs = [b_ok] + [scat(x) for x in ops[1:]]
+            b_ok = live.astype(jnp.int8)
+            bufs = [b_ok] + [rows(x) for x in ops[1:]]
             # what this shard hands the exchange, for the drivers'
             # counts (``_count_route``): the messages that leave it
             # and its fullest bucket before the cut at ``B``. Its own
             # lanes only: no collective joins the superstep for them
             self._exchanged = (
-                jnp.sum(sent & (sk != here), dtype=jnp.int32),
-                jnp.max(jnp.where(sent, rank + 1, 0)))
+                jnp.sum(count, dtype=jnp.int32) - count[here],
+                jnp.max(count))
         bucket_ovf = comm.all_sum(
-            jnp.sum(sent & (rank >= B), dtype=jnp.int32))
+            jnp.sum(jnp.maximum(count - B, 0), dtype=jnp.int32))
 
         with jax.named_scope("swap"):
             def a2a(x):
