@@ -248,8 +248,8 @@ def test_fault_dropped_counter_bites():
 
 
 
-_STATS_KEYS = {"supersteps", "wall_seconds", "compiles", "dispatches",
-               "readbacks"}
+_STATS_KEYS = {"supersteps", "wall_seconds", "compiles", "compile_seconds",
+               "cache_misses", "dispatches", "readbacks"}
 
 
 def test_last_run_stats_uniform_across_engines():

@@ -17,6 +17,7 @@ The three interpreters:
 All interpreters agree on observable event traces (bit-for-bit at small
 node counts — the framework's core law, tested in tests/test_parity*).
 """
+_PACKAGE_START_NS = __import__("time").perf_counter_ns()  # obs/profiler.py
 
 from .core import effects, errors, time
 from .core.effects import (Fork, ForkSlave, GetLogName, GetTime, MyTid,

@@ -8,13 +8,14 @@ from __future__ import annotations
 
 import time
 from contextlib import ExitStack, contextmanager
+from functools import wraps
 from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...obs.profiler import call, span
+from ...obs.profiler import call, compile_account, listen, phase, span
 from ...ops.numeric import I32MAX, group_rank, thi, tlo, u32sum
 
 __all__ = ["LocalComm", "StepOut", "I32MAX", "group_rank", "u32sum",
@@ -46,6 +47,10 @@ __all__ = ["LocalComm", "StepOut", "I32MAX", "group_rank", "u32sum",
 #: in no profile)
 STAGES = ("tw.next_event", "tw.deliver", "tw.fire", "tw.rebase",
           "tw.route", "tw.finish")
+
+# whatever an engine traces, lowers and compiles from here on is in the
+# program's record, by name (obs/profiler.py ``phases()``)
+listen()
 
 
 class Stages(ExitStack):
@@ -268,10 +273,13 @@ class _DriverCall:
             before, after, counts, crossed, *more = jax.device_get(
                 (steps_before, steps_after, counts, crossed) + more)
         d = np.asarray(after, np.int64) - np.asarray(before, np.int64)
+        compile_seconds, cache_misses = compile_account()
         stats = self.record["counts"] = self.eng.last_run_stats = {
             "supersteps": int(d.sum()),
             "wall_seconds": time.perf_counter() - self.t0,
             "compiles": self.eng._driver_compiles() - self.c0,
+            "compile_seconds": compile_seconds,
+            "cache_misses": cache_misses,
             "dispatches": self.dispatches, "readbacks": self.readbacks,
         }
         if d.ndim:
@@ -353,6 +361,16 @@ class RunStatsMixin:
         {"supersteps": int,    # executed this call (fleet total)
          "wall_seconds": float,
          "compiles": int,      # driver executables compiled this call
+         "compile_seconds": float,  # the call's seconds tracing,
+                               # lowering and compiling (or fetching
+                               # from the persistent cache) whatever
+                               # it compiled, the driver's own and
+                               # the small programs around it; 0.0
+                               # in a call that compiled nothing
+         "cache_misses": int,  # of those backend compiles, the ones
+                               # the persistent cache did not have
+                               # and now keeps (obs/profiler.py
+                               # ``phases()`` names them)
          "dispatches": int,    # executables launched by the call
          "readbacks": int}     # blocking host reads by the call
 
@@ -472,6 +490,42 @@ class RunStatsMixin:
 
     last_run_stats = None
 
+    #: the methods that build a run, and the live span around each
+    _PHASES = {"__init__": "tw.engine.init", "init_state": "tw.init_state"}
+
+    def __init_subclass__(cls, **kwargs):
+        """Every engine class's constructor and ``init_state``, as the
+        class resolves them (its own, or a mesh driver's beside it),
+        run under their live span (:meth:`_phased`): one place for
+        every engine there is and will be, and no line added to
+        ``engine.py``, whose source locations some drivers' entries in
+        the persistent compile cache are keyed on (the steady cell's
+        and the observer ring's compile anew when they move: PERF.md
+        section 7)."""
+        super().__init_subclass__(**kwargs)
+        for method, name in cls._PHASES.items():
+            setattr(cls, method, cls._phased(name)(getattr(cls, method)))
+
+    @staticmethod
+    def _phased(name: str):
+        """Decorator of an engine's constructor (``tw.engine.init``)
+        and of its ``init_state`` (``tw.init_state``): the live span
+        ``name`` around the method (``obs.profiler.phase``: the
+        outermost only, so a subclass that calls its base class's, or
+        a fused ring that builds its edge engine, is one span),
+        carrying the engine's class and its scenario's ``n_nodes``."""
+        def wrap(method):
+            @wraps(method)
+            def under(self, *args, **kwargs):
+                # a constructor's scenario is its first argument
+                sc = getattr(self, "scenario", None) or \
+                    kwargs.get("scenario") or args[0]
+                with phase(name, engine=type(self).__name__,
+                           n_nodes=sc.n_nodes):
+                    return method(self, *args, **kwargs)
+            return under
+        return wrap
+
     def _driver_compiles(self) -> int:
         n = 0
         for name in self._DRIVER_FNS:
@@ -517,6 +571,9 @@ class RunStatsMixin:
             "supersteps": sum(c["supersteps"] for c in chunks),
             "wall_seconds": sum(c["wall_seconds"] for c in chunks),
             "compiles": sum(c["compiles"] for c in chunks),
+            "compile_seconds": sum(c.get("compile_seconds", 0.0)
+                                   for c in chunks),
+            "cache_misses": sum(c.get("cache_misses", 0) for c in chunks),
             "dispatches": sum(c.get("dispatches", 0) for c in chunks),
             "readbacks": sum(c.get("readbacks", 0) for c in chunks),
             "chunks": len(chunks),

@@ -429,6 +429,7 @@ class SuperstepOracle:
         self.last_run_stats = {
             "supersteps": len(rows),
             "wall_seconds": _time.perf_counter() - _wall0,
-            "compiles": 0, "dispatches": 0, "readbacks": 0,
+            "compiles": 0, "compile_seconds": 0.0, "cache_misses": 0,
+            "dispatches": 0, "readbacks": 0,
         }
         return SuperstepTrace.from_rows(rows)

@@ -28,11 +28,13 @@ import jax.numpy as jnp
 from ..core.scenario import NEVER, Inbox, Outbox, Scenario
 from ..core.time import Microsecond, ms, sec
 from ..net.delays import LinkModel, LogNormalDelay
+from ..obs.profiler import phased
 from .peers import distinct_mask, lcg_peers
 
 __all__ = ["gossip", "gossip_links"]
 
 
+@phased("tw.scenario", model="gossip")
 def gossip(n: int, *,
            fanout: int = 8,
            think_us: Microsecond = ms(5),

@@ -29,11 +29,13 @@ import jax.numpy as jnp
 
 from ..core.scenario import NEVER, Inbox, Outbox, Scenario
 from ..core.time import Microsecond, ms, sec
+from ..obs.profiler import phased
 from .peers import distinct_mask, lcg_peers
 
 __all__ = ["praos"]
 
 
+@phased("tw.scenario", model="praos")
 def praos(n: int, *,
           slot_us: Microsecond = sec(1),
           n_slots: int = 20,

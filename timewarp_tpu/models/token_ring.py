@@ -41,12 +41,14 @@ import numpy as np
 from ..core.scenario import NEVER, Inbox, Outbox, Scenario
 from ..core.time import Microsecond, ms, sec
 from ..net.delays import FnDelay, LinkModel, UniformDelay
+from ..obs.profiler import phased
 
 __all__ = ["token_ring", "token_ring_links", "TOKEN", "NOTE"]
 
 TOKEN, NOTE = 0, 1
 
 
+@phased("tw.scenario", model="token_ring")
 def token_ring(n_ring: int, *,
                n_tokens: int = 1,
                think_us: Microsecond = sec(3),

@@ -83,11 +83,14 @@ METRICS_SCHEMA = 5
 _NUM = (int, float)
 #: the fields of ``last_run_stats`` a ``run_summary`` line carries
 #: where the driver call counted them (common.py ``RunStatsMixin``)
-_RUN_COUNTS = ("dispatches", "readbacks", "rung_lanes", "sender_lanes",
+_RUN_COUNTS = ("cache_misses", "dispatches", "readbacks", "rung_lanes",
+               "sender_lanes",
                "fleet_iterations", "dense_stage_steps", "wide_tail_steps",
                "fan_in_peak", "scatter_lanes", "dense_lanes", "tail_lanes",
                "net_rows", "shards", "worlds_local", "remote_msgs",
                "bucket_fill_peak", "bucket_cap", "exchange_lanes")
+#: the call's seconds on the compile path, a number like ``wall_seconds``
+_RUN_SECONDS = ("compile_seconds",)
 #: and those that are one int an entry: iterations by rung, and a
 #: world-sharded fleet's lanes and loop trips by device
 _RUN_LISTS = ("rung_steps", "device_rung_lanes", "device_sender_lanes",
@@ -174,6 +177,12 @@ def validate_line(rec: Any) -> None:
                 raise ValueError(
                     f"metrics kind 'run_summary': field {field!r} must "
                     f"be int, got {rec[field]!r}")
+        for field in _RUN_SECONDS:
+            v = rec.get(field, 0.0)
+            if isinstance(v, bool) or not isinstance(v, _NUM):
+                raise ValueError(
+                    f"metrics kind 'run_summary': field {field!r} must "
+                    f"be int/float, got {v!r}")
         for field in _RUN_LISTS:
             v = rec.get(field, [])
             if not isinstance(v, list) or not all(map(_is_int, v)):
@@ -279,9 +288,10 @@ class MetricsRegistry:
     def run_summary(self, label: str, stats: dict, **fields) -> None:
         """One line per driver run from the engine's uniform
         ``last_run_stats``: the three counts every engine has, and of
-        the call's boundary with the chip and its routing counts those
-        the stats hold."""
-        counts = {k: stats[k] for k in _RUN_COUNTS + _RUN_LISTS
+        the call's boundary with the chip, its compile path
+        (``compile_seconds``, ``cache_misses``) and its routing counts
+        those the stats hold."""
+        counts = {k: stats[k] for k in _RUN_COUNTS + _RUN_SECONDS + _RUN_LISTS
                   if k in stats}
         self.emit("run_summary", label=label,
                   supersteps=int(stats["supersteps"]),
