@@ -542,6 +542,19 @@ def _ordered_lanes(case, n, K, P, L, rng):
         # along a prefix that ends near the last valid lane
         per = np.where(np.arange(n) % 7 == 3, K + 3, rng.integers(1, 3, n))
         dst = np.repeat(np.arange(n), per)[:L - 5]
+    elif case == "full-under-arrivals-past-an-edge":
+        # one arrival a node to one lane past L/4, every mailbox full
+        # but the first five: what lands ends at lane 5, what the
+        # ranks allow at L/4 + 1
+        dst = np.arange(L // 4 + 1)
+        counts[5:] = K
+    elif case == "hub-rank-on-an-edge":
+        # one arrival a node, then L/2 to a hub that keeps 3 of its 8
+        # slots: its rank K - 1 is lane L/4 - 1, the edge of a width
+        dst = np.concatenate([np.arange(L // 4 - K),
+                              np.full(L // 2, n - 1)])
+        counts[:] = 0
+        counts[n - 1] = 3
     else:
         raise KeyError(case)
     sd = np.concatenate([dst, np.full(L - len(dst), n)]).astype(np.int32)

@@ -2,14 +2,18 @@
 that can land.
 
 Since PR 43 an ordered inbox's ranked insertion, solo and on one
-device, cuts its scatters to the prefix that ends at the last lane
-that fits, by a ladder of four static widths from
-``_PREFIX_SCATTER_LANES`` lanes on (``_scatter_widths``). With the
+device, cuts its scatters to a prefix of its lanes, by a ladder of
+four static widths from ``_PREFIX_SCATTER_LANES`` lanes on
+(``_scatter_widths``); since PR 52 the prefix ends at the last valid
+lane of rank under K, a function of the ranks alone, and the gather of
+the destinations' kept counts runs inside the width's branch. With the
 constant patched down to 64 lanes, one call on built lanes (no lane
 fits, the prefix on a width's edge and one past it, every lane fits,
 one hub of 8 slots taking every lane, overloaded destinations between
-fitting ones) is held to the one-scatter form and to a plain numpy
-insertion word for word, with the width it must take; the observer
+fitting ones, full mailboxes under arrivals spread past a width's
+edge, a hub whose K-th rank lies on a width's edge) is held to the
+one-scatter form and to a plain numpy insertion word for word, with
+the width it must take; the observer
 ring runs on a rung and on the eager path against the oracle and the
 unpatched engine, leaf for leaf; and under the constant an ordered
 engine's driver lowers to the one-scatter text.
@@ -41,12 +45,16 @@ def plain_ranked_insertion(K, P, mb_rel, mb_src, mb_payload, sd, ok,
     """An ordered inbox's insertion, lane by lane: a valid lane takes
     the slot after its destination's kept messages and earlier
     arrivals, or is counted. Returns the planes, ``overflow``, the
-    largest fan-in and the lane after the last that landed."""
+    largest fan-in, the lane after the last that landed and the lane
+    after the last that can land whatever the mailbox keeps (valid,
+    of rank under ``K`` among its destination's arrivals)."""
     mb_rel, mb_src, mb_payload = (np.array(x) for x in
                                   (mb_rel, mb_src, mb_payload))
-    used, over, hi = counts.astype(np.int64), 0, 0
+    used, over, hi, can = counts.astype(np.int64), 0, 0, 0
     for lane in np.flatnonzero(ok):
         d = sd[lane]
+        if used[d] - counts[d] < K:
+            can = lane + 1
         if used[d] < K:
             mb_rel[used[d], d] = drel[lane]
             mb_src[used[d], d] = src[lane]
@@ -57,7 +65,7 @@ def plain_ranked_insertion(K, P, mb_rel, mb_src, mb_payload, sd, ok,
             over += 1
         used[d] += 1
     fan_in = int((used - counts).max())
-    return mb_rel, mb_src, mb_payload, over, fan_in, hi
+    return mb_rel, mb_src, mb_payload, over, fan_in, hi, can
 
 
 @functools.lru_cache(maxsize=None)
@@ -86,10 +94,12 @@ def _ranked_insertions(n, P):
 
 
 #: case -> which of the four widths (L/8, L/4, L/2, L) it must take
-PREFIXES = {"nothing-fits": 0, "edge-of-a-width": 1,
+PREFIXES = {"nothing-fits": 2, "edge-of-a-width": 1,
             "one-past-the-edge": 2, "every-lane-fits": 3,
             "one-hub-takes-every-lane": 0,
-            "overloaded-between-fitting": 3}
+            "overloaded-between-fitting": 3,
+            "full-under-arrivals-past-an-edge": 2,
+            "hub-rank-on-an-edge": 1}
 
 
 @pytest.mark.parametrize("P", [1, 2], ids="P{}".format)
@@ -99,22 +109,35 @@ def test_the_prefix_scatters_equal_the_one_scatter_word_for_word(case, n, P):
     """One call of the ranked insertion cut to its prefix, against the
     one-scatter form and a plain insertion in numpy: every plane,
     ``overflow`` and the fan-in the same, and the width taken the
-    smallest of the four that holds the last lane that fits."""
+    smallest of the four that holds the last valid lane of rank under
+    ``K``: the ranks alone pick it, whatever the mailboxes keep."""
     K, L = 8, 2 * n
     eng, cut, one = _ranked_insertions(n, P)
     lanes = _ordered_lanes(case, n, K, P, L,
                            np.random.default_rng(len(case) + n + P))
     got, ref = cut(*lanes), one(*lanes)
-    *want, hi = plain_ranked_insertion(K, P, *lanes)
+    *want, hi, can = plain_ranked_insertion(K, P, *lanes)
     widths = [-(-L // d) for d in (8, 4, 2, 1)]
-    assert int(got[5]) == min(w for w in widths if w >= hi) \
+    assert hi <= can
+    assert int(got[5]) == min(w for w in widths if w >= can) \
         == widths[PREFIXES[case]]
     assert int(ref[5]) == L
     for name, x, y, z in zip(("mb_rel", "mb_src", "mb_payload", "overflow",
                               "fan_in"), got, ref, want):
         assert np.array_equal(x, y) and np.array_equal(x, z), (case, name)
     if case == "nothing-fits":
-        assert hi == 0 and int(got[3]) == L // 3
+        # wider than the lanes that fit would ask for (none: L/8)
+        assert hi == 0 and can > L // 4 and int(got[3]) == L // 3
+    if case == "full-under-arrivals-past-an-edge":
+        # five lanes land, at the front; the full mailboxes' arrivals,
+        # one a node, reach one lane past L/4 and every one is counted
+        assert hi == 5 and can == L // 4 + 1
+        assert int(got[3]) == L // 4 + 1 - 5 and int(got[4]) == 1
+    if case == "hub-rank-on-an-edge":
+        # the hub keeps 3: its ranks 0-4 land, 5-7 could and do not,
+        # and rank 7 is the last lane of the width
+        assert (hi, can) == (L // 4 - 3, L // 4)
+        assert int(got[3]) == L // 2 - (K - 3) and int(got[4]) == L // 2
     if case == "one-hub-takes-every-lane":
         assert hi == K and int(got[3]) == L - K and int(got[4]) == L
     if case == "overloaded-between-fitting":

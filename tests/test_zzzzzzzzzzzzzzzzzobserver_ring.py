@@ -27,8 +27,8 @@ from timewarp_tpu.interp.jax_engine import engine as engine_module
 from timewarp_tpu.interp.jax_engine.batched import BatchSpec
 from timewarp_tpu.interp.jax_engine.engine import JaxEngine
 from timewarp_tpu.interp.ref.superstep import SuperstepOracle
-from timewarp_tpu.models.token_ring import token_ring
-from timewarp_tpu.net.delays import FixedDelay
+from timewarp_tpu.models.token_ring import TOKEN, token_ring
+from timewarp_tpu.net.delays import FixedDelay, WithDrop
 from timewarp_tpu.obs.metrics import MetricsRegistry, validate_line
 from timewarp_tpu.trace.events import assert_states_equal
 
@@ -169,6 +169,61 @@ def test_streamed_jobs_with_the_scatters_cut_equal_the_one_scatter_engines(
         assert eng.last_run_stats["fan_in_peak"] == n
         assert_states_equal(st, ref, "the scatters cut against one scatter")
     assert int(st.overflow) - int(start.overflow) == 3 * 32 * (n - 8)
+
+
+@pytest.mark.parametrize("site", ["rung", "eager"])
+def test_full_ring_mailboxes_take_their_arrivals_width_and_count_every_drop(
+        cells, site, monkeypatch):
+    """Every ring node's 8 slots kept by hand (tokens due 10 ms on):
+    the timers' tokens, one a node, find no room. The ranks alone pick
+    the width, so the cut engine takes that of the arrivals (half the
+    rung: where only the lanes that fit counted, PR 43 took an
+    eighth) and counts all ``n``; a job streamed from there, through
+    the kept tokens' delivery, ends on the one-scatter engine's state
+    leaf for leaf, on a rung of the ladder and on the eager path."""
+    n = SIZES[0]
+    c = cells(n)
+    c.set_up(17)
+    sc = c.engine.scenario
+
+    def engine():
+        if site == "rung":
+            return observer_ring.engine_of(c.p)
+        return JaxEngine(sc, WithDrop(FixedDelay(c.p["link"]["delay_us"]),
+                                      0.1), window=c.p["window"])
+    K, ring = sc.mailbox_cap, jnp.arange(n + 1) < n
+    slot = jnp.arange(K, dtype=jnp.int32)[:, None]
+    st = c.state
+    full = st._replace(
+        mb_rel=jnp.where(ring, jnp.int32(10_000), st.mb_rel),
+        mb_src=jnp.where(ring, (jnp.arange(n + 1, dtype=jnp.int32) - 1) % n,
+                         st.mb_src),
+        mb_payload=jnp.where(
+            ring, jnp.stack([jnp.broadcast_to(100 * slot, (K, n + 1)),
+                             jnp.full((K, n + 1), TOKEN, jnp.int32)], axis=1),
+            st.mb_payload))
+    plain = engine()
+    assert plain._adaptive_regime() == (site == "rung")
+    first, job = plain.run_quiet(1, full), plain.run_quiet(96, full)
+    dropped = int(first.overflow) - int(st.overflow)
+    # every token, but those the eager site's link lost on the way
+    assert dropped == n if site == "rung" else n * 4 // 5 < dropped < n
+    monkeypatch.setattr(engine_module, "_PREFIX_SCATTER_LANES", 64)
+    eng = engine()
+    L = 2 * (min(r for r in eng._sender_rungs(n + 1) if r >= n)
+             if site == "rung" else n + 1)
+    assert len(eng._scatter_widths(L)) == 4
+    assert_states_equal(eng.run_quiet(1, full), first,
+                        "full mailboxes, the timers' tokens")
+    # the arrivals end at lane n (fewer where the link dropped some):
+    # over a quarter of the lanes, so half of them are taken
+    assert eng.last_run_stats["scatter_lanes"] == -(-L // 2)
+    assert_states_equal(eng.run_quiet(96, full), job,
+                        "full mailboxes, a job of 96")
+    assert eng.last_run_stats["fan_in_peak"] \
+        == plain.last_run_stats["fan_in_peak"] > K
+    assert int(job.overflow) - int(st.overflow) > dropped
+    assert int(job.delivered) - int(st.delivered) > K * n
 
 
 def test_the_widest_ring_here_cuts_its_scatters_as_it_is_built(cells):

@@ -400,8 +400,12 @@ class RunStatsMixin:
 
         {"scatter_lanes": int}  # the lanes handed to each of the
                                 # insertion's scatters, summed over
-                                # the iterations: the width taken, the
-                                # call's lanes where one scatter ran
+                                # the iterations: the width taken (the
+                                # smallest that holds the last valid
+                                # lane of rank under K; the gather of
+                                # the destinations' kept counts runs
+                                # at it too, PR 52), the call's lanes
+                                # where one scatter ran
 
     for a solo general engine with a commutative inbox (its
     insertion stages the arrivals by rank, engine.py

@@ -91,10 +91,11 @@ _FLEET_AXIS = "tw_fleet"
 _DENSE_STAGE_RATIO = 0.25
 
 #: an ordered inbox's ranked insertion (``_insert_sorted``) cuts its
-#: scatters to the prefix that ends at the last lane that fits, by a
-#: ladder of four static widths (``_scatter_widths``), where the call
-#: has at least this many lanes; under it one scatter a field at the
-#: call's width, as ever. The four scatters of one insertion into
+#: gather of the kept counts and its scatters to the prefix that ends
+#: at the last valid lane of rank under K, by a ladder of four static
+#: widths (``_scatter_widths``), where the call has at least this many
+#: lanes; under it the gather and one scatter a field at the call's
+#: width, as ever. The four scatters of one insertion into
 #: the observer ring's [8, 65 537] planes, both forms, in us on a v5e
 #: (profiling/prefix_scatter_micro_r07.py, PR 43; lanes: one / the
 #: switch where 8 lanes fit (it takes L/8), where half fit (L/2),
@@ -104,7 +105,25 @@ _DENSE_STAGE_RATIO = 0.25
 #: costs what its lanes cost whatever lands (5 ns a lane over some
 #: 100 us); the switch adds 45-57 us (26 at 2^17). At 2^13 the cut to
 #: half gains 26 us where a full call loses 56; at 2^14 it gains 116
-#: for 57, and the cut to an eighth 238.
+#: for 57, and the cut to an eighth 238. The same micro with the
+#: gather (PR 52; ``counts[clip(sd)] + rank`` alone on the first w
+#: lanes, w = L/8, L/4, L/2, L): 2^14: 83, 84, 119, 185; 2^15: 78,
+#: 115, 180, 310; 2^16: 120, 183, 301, 538; 2^17: 183, 304, 544,
+#: 1 009: 7.2 ns a lane over a floor of 65-80 us that is the micro's
+#: loop's, not the gather's (in the observer ring's superstep the same
+#: gather reads 14.9 us at 2 048 lanes, 116.6 at 16 384, 467.5 at
+#: 65 536, 935 at 131 072: 7.1 ns a lane through the origin, and its
+#: scatters 4.9 ns a lane through the origin too). The whole insertion,
+#: the gather on all L lanes and the width from the lanes that fit
+#: (PR 43) / the width from the ranks and the gather in its branch
+#: (PR 52): where 8 lanes fit 2^14: 337 / 229, 2^15: 497 / 276, 2^16:
+#: 811 / 400, 2^17: 1 448 / 614; where half fit 444 / 399, 735 / 613,
+#: 1 283 / 1 057, 2 407 / 1 919; where all fit 613 / 627, 1 057 /
+#: 1 053, 1 941 / 1 932, 3 681 / 3 662 (a wash); and the one case
+#: where the ranks take the wider branch, half the lanes valid, one to
+#: a node, every mailbox full (nothing fits: L/8 / L/2): 344 / 389,
+#: 494 / 617, 811 / 1 049, 1 442 / 1 931: dearer by 45 to 488 us, and
+#: 1 750 under the one-scatter form's 3 681 at 2^17.
 _PREFIX_SCATTER_LANES = 1 << 14
 
 #: ``_stage_by_rank``'s dense form places its arrivals rank by rank
@@ -1089,9 +1108,11 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
 
     def _scatter_widths(self, L: int) -> Tuple[int, ...]:
         """The static widths, ascending, of which the ranked insertion
-        of ``L`` lanes scatters the smallest that holds every lane
-        that fits: ``L/8``, ``L/4``, ``L/2``, ``L`` (rounded up)
-        from ``_PREFIX_SCATTER_LANES`` lanes on, else ``L`` alone.
+        of ``L`` lanes gathers and scatters the smallest that holds
+        every lane that can land (valid, of rank under K in its
+        destination's group): ``L/8``, ``L/4``, ``L/2``, ``L``
+        (rounded up) from ``_PREFIX_SCATTER_LANES`` lanes on, else
+        ``L`` alone.
         Spaced by two, as the routing ladder's rungs are
         (``_sender_rungs``): the width taken is under twice the lanes
         that can land; under an eighth, seven eighths of the cost are
@@ -1129,10 +1150,20 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         mailbox; non-fitting lanes get an out-of-range index and are
         dropped. Where a solo engine on one device ranks
         (``_cuts_scatters``) and the call has the lanes for it, the
-        scatters take only the prefix that ends at the last lane that
-        fits, at the smallest of four static widths that holds it
-        (``_scatter_widths``): the lanes left out are dropped ones,
-        so no word changes. Returns the updated arrays plus the local
+        gather of the destinations' kept counts and the scatters take
+        only the prefix that ends at the last valid lane of rank
+        under K, at the smallest of four static widths that holds it
+        (``_scatter_widths``): the lanes left out cannot land
+        whatever the mailbox keeps, so no word changes, and
+        ``overflow`` is the valid lanes less those that landed. One
+        algorithm whose width follows what the call's own lanes show:
+        the width is that of the lanes that fit wherever the
+        destinations have room; one hot destination costs K lanes;
+        many distinct destinations that stand full under spread
+        arrivals (the worst case) take the width of their arrivals,
+        at most the full one, which is what every call paid before
+        the cut (some 4 x 4.85 ns x 7L/8 over the narrowest width's
+        scatters). Returns the updated arrays plus the local
         overflow count and, from an ordered inbox, the largest number
         of arrivals to one destination and (where it may cut them)
         the lanes its scatters took (``_take_fan_in`` takes both off
@@ -1152,6 +1183,8 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
             return self._fill_staged(mb_rel, mb_src, mb_payload, holes,
                                      *staged)
         rank = group_rank(sd)
+        L = sd.shape[0]
+        widths = self._scatter_widths(L)
         if sc.commutative_inbox:
             # r-th incoming message takes the destination's r-th hole:
             # one 1D gather a word, then a bit select on these lanes
@@ -1163,18 +1196,18 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
             pos = jnp.where(fits, jnp.int32(0), jnp.int32(K))
             fan_in = ()
         else:
-            pos = counts[jnp.clip(sd, 0, n - 1)] + rank
-            fits = ok_s & (pos < K)
-            col = jnp.clip(pos, 0, K - 1)
+            if len(widths) == 1:
+                pos = counts[jnp.clip(sd, 0, n - 1)] + rank
+                fits = ok_s & (pos < K)
+                col = jnp.clip(pos, 0, K - 1)
             fan_in = (jnp.max(jnp.where(ok_s, rank + 1, 0)),)
-        L = sd.shape[0]
 
-        def scatter(w):
+        def scatter(w, fits_w, col_w):
             # the first `w` lanes into the mailbox, a flat scatter a
             # field: static slices from lane 0, none at the full width
             def cut(x):
                 return x if w == L else x[:w]
-            fits_w, col_w, sd_w = cut(fits), cut(col), cut(sd)
+            sd_w = cut(sd)
             flat = jnp.where(fits_w, col_w * jnp.int32(n) + sd_w,
                              jnp.int32(K * n))
             rel = mb_rel.reshape(-1).at[flat].set(
@@ -1190,27 +1223,40 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
                     jnp.int32(K * P * n))
                 pay = pay.at[flat_p].set(cut(pay_s[p]), mode="drop")
             return rel, src, pay.reshape(K, P, n)
-        widths = self._scatter_widths(L)
         if len(widths) == 1:
-            rel, src, pay = scatter(L)
+            rel, src, pay = scatter(L, fits, col)
             width = jnp.int32(L)
+            overflow = jnp.sum(ok_s & (pos >= K), dtype=jnp.int32)
         else:
             # the routing sort put the valid lanes first, ordered by
-            # destination and arrival, so the lanes that fit end at
-            # `hi` and every lane from there on has the out-of-range
-            # index already: leaving it out changes no word. One
-            # scalar picks the smallest width that holds the prefix,
-            # as the ladder of rungs picks its own (`_route_adaptive`)
+            # destination and arrival, and `counts` is never negative:
+            # a lane whose rank in its destination's group is K or
+            # more cannot land whatever the mailbox keeps, nor can an
+            # invalid one. So the lanes that can land end at `hi`, a
+            # function of the ranks alone, and one scalar picks the
+            # smallest width that holds them, as the ladder of rungs
+            # picks its own (`_route_adaptive`), before anything is
+            # gathered. The branch gathers its destinations' kept
+            # counts on its own `w` lanes; a valid lane it leaves out
+            # is a dropped one, so `overflow` is the valid lanes less
+            # those that landed
             hi = jnp.max(jnp.where(
-                fits, jnp.arange(1, L + 1, dtype=jnp.int32), 0))
+                ok_s & (rank < K),
+                jnp.arange(1, L + 1, dtype=jnp.int32), 0))
             steps = jnp.asarray(widths, jnp.int32)
             idx = jnp.sum(hi > steps)
-            rel, src, pay = jax.lax.switch(
-                idx, [partial(scatter, w) for w in widths])
+
+            def ranked(w):
+                pos_w = counts[jnp.clip(sd[:w], 0, n - 1)] + rank[:w]
+                fits_w = ok_s[:w] & (pos_w < K)
+                return scatter(w, fits_w, jnp.clip(pos_w, 0, K - 1)) + (
+                    jnp.sum(fits_w, dtype=jnp.int32),)
+            rel, src, pay, landed = jax.lax.switch(
+                idx, [partial(ranked, w) for w in widths])
             width = steps[idx]
+            overflow = jnp.sum(ok_s, dtype=jnp.int32) - landed
         if sc.inbox_src:
             mb_src = src
-        overflow = jnp.sum(ok_s & (pos >= K), dtype=jnp.int32)
         return (rel, mb_src, pay, overflow) + fan_in + (
             (width,) if self._cuts_scatters() else ())
 
