@@ -118,13 +118,26 @@ FAULT_ENGINES = ("oracle", "general", "edge", "sharded-batched")
 
 
 def build_faults(args):
-    """The fault schedule from --faults, or None. A batched run
-    replicates the one schedule to every world (per-world schedules
-    are the library FaultFleet API)."""
+    """The fault schedule from --faults, or None. Given once, a
+    batched run replicates the one schedule to every world; given
+    once a world (with --batch B / --seeds: B of them), world b runs
+    the b-th: the ``FaultFleet`` a Monte-Carlo chaos study builds
+    (docs/faults.md "Per-world fault fleets"). Any other count is
+    refused."""
     if args.faults is None:
         return None
-    from .faults.schedule import parse_faults
-    return parse_faults(args.faults)
+    from .faults.schedule import FaultFleet, parse_faults
+    if isinstance(args.faults, str):
+        return parse_faults(args.faults)
+    batch = build_batch(args)
+    worlds = 1 if batch is None else batch.B
+    if len(args.faults) != worlds:
+        raise SystemExit(
+            f"--faults was given {len(args.faults)} times and the run "
+            f"has {worlds} world{'s' * (worlds > 1)}: give it once "
+            "(every world runs that schedule) or once a world, in "
+            "world order (--batch B / --seeds)")
+    return FaultFleet(tuple(parse_faults(f) for f in args.faults))
 
 
 #: engines the dispatch controller drives (dispatch/,
@@ -651,8 +664,10 @@ def main(argv=None) -> int:
                         "inner model with i.i.d. loss probability P; "
                         "never severs the link entirely — the old "
                         "NeverConnected)")
-    p.add_argument("--faults", default=None,
-                   help="deterministic fault schedule (faults/): "
+    p.add_argument("--faults", default=None, action="append",
+                   help="deterministic fault schedule (faults/); once "
+                        "(every world of a --batch runs it) or once a "
+                        "world, world b the b-th: "
                         "';'-separated events, e.g. "
                         "\"crash:3:5s:9s:reset; partition:0-3|4-7:2s:4s;"
                         " degrade:all:all:1s:2s:4.0:10ms; skew:2:250\" "
@@ -825,6 +840,10 @@ def main(argv=None) -> int:
                         "against the conservative run's to check the "
                         "speculation equivalence law byte-for-byte")
     args = p.parse_args(argv)
+    if args.faults is not None and len(args.faults) == 1:
+        # given once: the one string every later reader of the flag
+        # has always had (a checkpoint's meta, the repro line)
+        args.faults, = args.faults
     if args.telemetry == "off" and (args.metrics_out or args.trace_out):
         raise SystemExit(
             "--metrics-out/--trace-out need --telemetry counters|full "

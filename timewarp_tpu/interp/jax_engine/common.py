@@ -48,6 +48,12 @@ __all__ = ["LocalComm", "StepOut", "I32MAX", "group_rank", "u32sum",
 STAGES = ("tw.next_event", "tw.deliver", "tw.fire", "tw.rebase",
           "tw.route", "tw.finish")
 
+#: the counts of a call on an engine built with ``faults`` (engine.py
+#: ``FaultCounts``; a fleet's have a ``world_`` list each beside them),
+#: and what its masks compared (``JaxEngine._fault_table_lanes``)
+_FAULT_COUNTS = ("fault_cut", "fault_down", "fault_purged",
+                 "fault_degraded", "fault_restarts", "fault_table_lanes")
+
 # whatever an engine traces, lowers and compiles from here on is in the
 # program's record, by name (obs/profiler.py ``phases()``)
 listen()
@@ -260,8 +266,9 @@ class _DriverCall:
         ``scatter_lanes`` None but from an ordered inbox (the second
         from a solo one on one device), the three of the staging None
         but from an insertion staged by rank, the last two None but
-        from the node-sharded general engine (one row a shard),
-        ``engine.py``
+        from the node-sharded general engine (one row a shard), and
+        last a ``FaultCounts`` from an engine built with ``faults``,
+        None from any other, ``engine.py``
         ``RouteCounts``; None from an engine with no ladder to count),
         a node-sharded edge engine's boundary messages (``crossed``:
         one count a shard, ``sharded.py`` ``ShardedEdgeEngine``; None
@@ -290,7 +297,17 @@ class _DriverCall:
                          fleet_iterations=int(d.max()))
         if counts is not None:
             (*counts, fan_in, scattered, dense_lanes, tail_lanes, rows,
-             remote, fill) = counts
+             remote, fill, faults) = counts
+            if faults is not None:
+                # a world's own counts (a fleet: a list beside each
+                # sum, as `world_supersteps` is beside `supersteps`)
+                for name, x in zip(faults._fields, faults):
+                    stats["fault_" + name] = int(np.sum(x))
+                    if d.ndim:
+                        stats["world_fault_" + name] = x.tolist()
+                stats["fault_table_lanes"] = self.eng._fault_table_lanes(
+                    int(np.max(counts[2].sum(axis=-1))),
+                    int(np.max(counts[0])))
             if d.ndim:
                 # one rung for all the worlds of a superstep: every
                 # world counted the same (a world-sharded fleet: the
@@ -458,6 +475,26 @@ class RunStatsMixin:
                                    # device receives, sorts and
                                    # inserts a superstep
 
+    for a general engine built with ``faults`` (engine.py
+    ``FaultCounts``; an engine without has none of the keys)::
+
+        {"fault_cut": int,       # sends across a live partition
+         "fault_down": int,      # sends due inside the destination's
+                                 # down window
+         "fault_purged": int,    # mailbox entries a reboot lost: the
+                                 # three sum to ``fault_dropped``'s
+                                 # growth over the call
+         "fault_degraded": int,  # sends whose delay a link window
+                                 # changed
+         "fault_restarts": int,  # reboots consumed
+         "fault_table_lanes": int}  # what a world's masks compared,
+                                 # from the tables' shapes and the
+                                 # rungs taken (``JaxEngine.
+                                 # _fault_table_lanes``)
+
+    (a fleet's first five are sums over its worlds, with a
+    ``world_fault_*`` list of each beside them)
+
     and, for a fleet (``batch=BatchSpec``) only::
 
         {"world_supersteps": [int] * B,  # executed by each world
@@ -589,6 +626,7 @@ class RunStatsMixin:
                 self.last_run_stats[key] = chunks[0][key]
         for key in ("rung_lanes", "sender_lanes", "fleet_iterations",
                     "rung_steps", "world_supersteps",
+                    *_FAULT_COUNTS, *("world_" + k for k in _FAULT_COUNTS[:5]),
                     "dense_stage_steps", "wide_tail_steps",
                     "scatter_lanes", "dense_lanes", "tail_lanes",
                     "net_rows", "boundary_msgs", "remote_msgs",

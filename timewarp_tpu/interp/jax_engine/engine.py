@@ -64,6 +64,7 @@ from ...obs.flight import FlightRecorderMixin
 from ...speculate.runner import SpeculativeRunMixin
 
 __all__ = ["JaxEngine", "EngineState", "Horizon", "RouteCounts",
+           "FaultCounts",
            "BatchSpec"]
 
 #: the name of the fleet's ``vmap`` axis (``_vstep``): what a world
@@ -238,6 +239,20 @@ class Horizon(NamedTuple):
     node_next: jax.Array
 
 
+class FaultCounts(NamedTuple):
+    """What the fault schedule did, summed over the iterations of a
+    driver's loop (int64[] each; a fleet's lead with the world axis,
+    a world's own): carried as :class:`RouteCounts`' last field by an
+    engine built with ``faults`` and by no other. The first three sum
+    to the growth of ``EngineState.fault_dropped``; every one is a
+    reduction of a mask the superstep has made already."""
+    cut: jax.Array        # sends across a live partition
+    down: jax.Array       # sends due inside the destination's down window
+    purged: jax.Array     # mailbox entries a reboot lost
+    degraded: jax.Array   # sends whose delay a link window changed
+    restarts: jax.Array   # reboots consumed (``restart_done`` rows)
+
+
 class RouteCounts(NamedTuple):
     """What the routing stage did, summed over the iterations of a
     driver's loop: every driver loop carries it beside the state and
@@ -298,6 +313,9 @@ class RouteCounts(NamedTuple):
     #: above)
     remote_msgs: Any = None
     bucket_fill_peak: Any = None
+    #: :class:`FaultCounts` — carried by an engine built with
+    #: ``faults`` and by no other (None elsewhere, as above)
+    faults: Any = None
 
 
 class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
@@ -826,11 +844,11 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         mbits = msg_bits(self.s0, self.s1, src, dst, tmsg, slot) \
             if self.link.needs_key else None
         delay, _ = self.link.sample(src, dst, tmsg, mbits)
+        degraded = None
         if self._faulted:
             # degradation windows transform the sampled delay BEFORE
             # the flight clamp (faults/apply.py; oracle order matches)
-            from ...faults.apply import degrade
-            delay = degrade(self._ft, delay, src, dst, tmsg)
+            delay, degraded = self._degrade(delay, src, dst, tmsg, ok)
         flight = jnp.maximum(delay, jnp.int64(1))       # contract #4
         drel64 = woff.astype(jnp.int64) + flight
         bad = jnp.sum(ok & (drel64 > jnp.int64(_I32MAX - 1)),
@@ -851,7 +869,35 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
                                       tmsg + flight, jnp.int64(NEVER)))
         drel = jnp.minimum(drel64,
                            jnp.int64(_I32MAX - 1)).astype(jnp.int32)
-        return flight, drel, bad, short, strag
+        return flight, drel, bad, short, strag, degraded
+
+    @jax.named_scope("fault")
+    def _degrade(self, delay, src, dst, tmsg, ok):
+        """The schedule's link windows on the sampled delays
+        (faults/apply.py ``degrade``), and how many of the ``ok``
+        messages' delays a window changed: the call's
+        ``fault_degraded``."""
+        from ...faults.apply import degrade
+        slowed = degrade(self._ft, delay, src, dst, tmsg)
+        return slowed, jnp.sum(ok & (slowed != delay), dtype=jnp.int32)
+
+    def _fault_table_lanes(self, iterations: int, rung_lanes: int) -> int:
+        """The lanes a world's fault masks compared over a call of
+        ``iterations`` supersteps whose rungs sum to ``rung_lanes``
+        senders (``last_run_stats`` ``fault_table_lanes``), from the
+        tables' shapes alone: every crash row against every node in
+        ``_horizon`` (``defer_next``) and once more where a reboot can
+        fire (``restart_fire``), every partition row gathered at both
+        ends of every outbox lane (``cut_mask``), and every crash row
+        (``down_mask``) and link row (``degrade``) against every
+        message lane of the rung taken. What per-node tables would
+        bring down (ROADMAP M4)."""
+        ft = self._ft if self._ftv is None else self._ftv
+        C, Pn, L = (getattr(ft, f).shape[-1] for f in (
+            "crash_node", "part_start", "link_start"))
+        n, M = self.comm.n_local, self.scenario.max_out
+        return iterations * n * (C * (1 + self._has_reset) + Pn * 2 * M) \
+            + rung_lanes * M * (C + L)
 
     def _stages_dense(self, lanes: int) -> bool:
         """Whether ``_stage_by_rank`` takes its dense form for a call
@@ -1297,9 +1343,10 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
             # partition cuts are sample-independent: kill them before
             # compaction (counted; the oracle drops the same set)
             from ...faults.apply import cut_mask
-            cutm = (pdst >= 0) & cut_mask(
-                self._ft, node_ids[None, :], pdst, now_vec[None, :])
-            fault_cut = jnp.sum(cutm, dtype=jnp.int32)
+            with jax.named_scope("fault"):
+                cutm = (pdst >= 0) & cut_mask(
+                    self._ft, node_ids[None, :], pdst, now_vec[None, :])
+                fault_cut = jnp.sum(cutm, dtype=jnp.int32)
             self._rec_cut(rec_full, cutm, node_ids[None, :], pdst,
                           now_vec[None, :])
             pdst = jnp.where(cutm, jnp.int32(-1), pdst)
@@ -1380,13 +1427,14 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
                     if W > 1 else jnp.zeros((SA,), jnp.int32)
                 src_l = smrank // jnp.int32(M)
                 tmsg_l = t + woff_f.astype(jnp.int64)
-                flight, drel, bad_delay_step, short_step, strag = \
-                    self._sample_nodrop(src_l, dst_f, tmsg_l,
-                                        smrank % jnp.int32(M),
-                                        woff_f, ok)
-                downm = ok & down_mask(self._ft, dst_f,
-                                       tmsg_l + flight)
-                fault_down = jnp.sum(downm, dtype=jnp.int32)
+                flight, drel, bad_delay_step, short_step, strag, \
+                    degraded = self._sample_nodrop(
+                        src_l, dst_f, tmsg_l, smrank % jnp.int32(M),
+                        woff_f, ok)
+                with jax.named_scope("fault"):
+                    downm = ok & down_mask(self._ft, dst_f,
+                                           tmsg_l + flight)
+                    fault_down = jnp.sum(downm, dtype=jnp.int32)
                 ok2 = ok & ~downm
                 sent_count = jnp.sum(ok2, dtype=jnp.int32)
                 if with_trace:
@@ -1415,7 +1463,7 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
                 ret = insert(sd, ok_s, drel_s, src_s, pay_s) + (
                     bad_dst_step, bad_delay_step, short_step,
                     jnp.int32(0), sent_count, sent_hash,
-                    fault_cut + fault_down)
+                    (fault_cut, fault_down, degraded))
                 if strag is not None:
                     # the causality plane's straggler min rides the
                     # switch return like the send capture below (the
@@ -1454,7 +1502,7 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
                 tmsg_s = t + woff_s.astype(jnp.int64)
                 # sample only the rung's lanes; invalid lanes are fed
                 # the sentinel and masked (`sample` is elementwise)
-                flight_s, drel_s, bad_delay_step, short_step, strag = \
+                flight_s, drel_s, bad_delay_step, short_step, strag, _ = \
                     self._sample_nodrop(src_s, sd, tmsg_s,
                                         smrank_s % jnp.int32(M),
                                         woff_s, ok_s)
@@ -1545,8 +1593,9 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         node_next = self._node_next(st, nnr)
         if self._faulted:
             from ...faults.apply import defer_next
-            node_next = defer_next(self._ft, self.comm.node_ids(),
-                                   node_next, st.restart_done)
+            with jax.named_scope("fault"):
+                node_next = defer_next(self._ft, self.comm.node_ids(),
+                                       node_next, st.restart_done)
         return Horizon(self.comm.all_min(node_next.min()), node_next)
 
     def _superstep(self, st: EngineState, with_trace: bool
@@ -1648,7 +1697,8 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
             Wv = jnp.clip(self._dyn.window, jnp.int64(1), jnp.int64(W))
             if self._faulted:
                 from ...faults.apply import window_floor
-                Wv = window_floor(self._ft, t, Wv, W)
+                with jax.named_scope("fault"):
+                    Wv = window_floor(self._ft, t, Wv, W)
         else:
             Wv = W
         self._w_now = Wv
@@ -1674,24 +1724,22 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         # init template below, and mailbox entries older than the
         # crash are purged (memory loss — counted, never delivered)
         restart_done = st.restart_done
-        fault_purged = jnp.int32(0)
-        purge = None
-        states_in = st.states
+        fault_purged = fault_restarts = jnp.int32(0)
+        purge = reset_now = None
         if self._faulted and self._has_reset:
             from ...faults.apply import consume_restarts, restart_fire
-            reset_now, purge_before = restart_fire(
-                self._ft, fire, now_vec, node_ids, st.restart_done)
-            restart_done = consume_restarts(
-                self._ft, fire, now_vec, node_ids, st.restart_done)
-            purge = mb_live & (
-                (base + st.mb_rel.astype(jnp.int64))
-                < purge_before[None, :])
-            fault_purged = comm.all_sum(jnp.sum(purge, dtype=jnp.int32))
-            states_in = jax.tree.map(
-                lambda cur, init: jnp.where(
-                    reset_now.reshape((n,) + (1,) * (cur.ndim - 1)),
-                    init, cur),
-                st.states, self._reset_states)
+            with jax.named_scope("fault"):
+                reset_now, purge_before = restart_fire(
+                    self._ft, fire, now_vec, node_ids, st.restart_done)
+                restart_done = consume_restarts(
+                    self._ft, fire, now_vec, node_ids, st.restart_done)
+                purge = mb_live & (
+                    (base + st.mb_rel.astype(jnp.int64))
+                    < purge_before[None, :])
+                fault_purged = comm.all_sum(
+                    jnp.sum(purge, dtype=jnp.int32))
+                fault_restarts = jnp.sum(
+                    restart_done & ~st.restart_done, dtype=jnp.int32)
             if rec_full:
                 # fault actions: the injected reboot firing, and every
                 # mailbox entry the reboot's memory loss purged (the
@@ -1771,6 +1819,15 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         if sc.needs_key:
             with jax.named_scope("entropy"):
                 bits = fire_bits(self.s0, self.s1, node_ids, now_vec)
+        states_in = st.states
+        if reset_now is not None:
+            # a rebooting node's step sees the scenario's first state
+            with jax.named_scope("fault"):
+                states_in = jax.tree.map(
+                    lambda cur, init: jnp.where(
+                        reset_now.reshape((n,) + (1,) * (cur.ndim - 1)),
+                        init, cur),
+                    st.states, self._reset_states)
         stepf = sc.step
         if self._faulted and self._has_skew:
             # the node's VIEW of time shifts; entropy keys, digests
@@ -1868,6 +1925,11 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         #: and the lanes it handed to its scatters, where it may cut
         #: them (``_cuts_scatters``)
         self._scattered = None
+        #: and what the schedule did, where there is one: the messages
+        #: it cut, dropped at a down node and purged at a reboot, those
+        #: whose delay a window changed, the reboots consumed
+        #: (``FaultCounts``)
+        self._fault_step = None
         if adaptive:
             res = self._route_adaptive(
                 out, out_valid, now_vec, t, mb_rel, mb_src,
@@ -1887,10 +1949,15 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
             (mb_rel, mb_src, mb_payload, overflow_step, bad_dst_step,
              bad_delay_step, short_step, route_drop_step, sent_count,
              sent_hash) = res[:10]
-            # the faulted routing variant appends its fault-drop count
-            # (partition cuts + down-window deliveries); the
-            # unfaulted tail returns the bare 10-tuple
-            fault_route = res[10] if len(res) > 10 else jnp.int32(0)
+            # the faulted routing variant appends its fault counts
+            # (partition cuts, down-window deliveries, delays a window
+            # changed); the unfaulted tail returns the bare 10-tuple
+            fault_route = jnp.int32(0)
+            if self._faulted:
+                cut, down, degraded = res[10]
+                fault_route = cut + down
+                self._fault_step = FaultCounts(
+                    cut, down, fault_purged, degraded, fault_restarts)
             stage("tw.finish")
             return self._finish_superstep(
                 st, live, states, wake, mb_rel, mb_src, mb_payload,
@@ -1974,7 +2041,7 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
             tmsg_s = t + woff_s.astype(jnp.int64)
             # sample the survivors; invalid lanes (sd == n) are fed the
             # sentinel and masked — `sample` is elementwise by contract
-            flight_s, drel_s, bad_delay_step, short_step, spec_strag = \
+            flight_s, drel_s, bad_delay_step, short_step, spec_strag, _ = \
                 self._sample_nodrop(src_s, sd, tmsg_s,
                                     smrank_s % jnp.int32(M), woff_s,
                                     ok_s)
@@ -1996,12 +2063,14 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
                 # partition cuts (send-time) before the flight clamp;
                 # down-window drops (deliver-time) after — the same
                 # check order as the oracle's routing loop
-                from ...faults.apply import cut_mask, degrade
-                cutm = ok & cut_mask(self._ft, src_f, dst_f, tmsg)
-                fault_eager = jnp.sum(cutm, dtype=jnp.int32)
+                from ...faults.apply import cut_mask
+                with jax.named_scope("fault"):
+                    cutm = ok & cut_mask(self._ft, src_f, dst_f, tmsg)
+                    fault_cut = jnp.sum(cutm, dtype=jnp.int32)
                 self._rec_cut(rec_full, cutm, src_f, dst_f, tmsg)
                 ok = ok & ~cutm
-                delay = degrade(self._ft, delay, src_f, dst_f, tmsg)
+                delay, degraded = self._degrade(delay, src_f, dst_f,
+                                                tmsg, ok)
             flight = jnp.maximum(delay, jnp.int64(1))  # contract #4
             drel64 = woff.astype(jnp.int64) + flight
             bad_delay_step = comm.all_sum(jnp.sum(
@@ -2032,12 +2101,17 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
                 # and before the SENT digest (the oracle never hashes
                 # it either)
                 from ...faults.apply import down_mask
-                downm = ok & down_mask(self._ft, dst_f, t + drel64)
+                with jax.named_scope("fault"):
+                    downm = ok & down_mask(self._ft, dst_f, t + drel64)
+                    fault_down = jnp.sum(downm, dtype=jnp.int32)
                 if rec_full:
                     self._rec_extra.append(self._rec_sends(
                         ok, downm, src_f, dst_f, tmsg, tmsg + flight))
-                fault_eager = comm.all_sum(
-                    fault_eager + jnp.sum(downm, dtype=jnp.int32))
+                cut, down, degraded = (comm.all_sum(x) for x in (
+                    fault_cut, fault_down, degraded))
+                fault_eager = cut + down
+                self._fault_step = FaultCounts(
+                    cut, down, fault_purged, degraded, fault_restarts)
                 ok = ok & ~downm
             elif rec_full:
                 self._rec_extra.append(self._rec_sends(
@@ -2366,9 +2440,10 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         world's own, or None) for the drivers' counts, as a solo
         superstep leaves its scalars there."""
         def world(*a):
-            return step(*a), (self._routed, self._staged, self._fan_in)
-        out, (self._routed, self._staged, self._fan_in) = \
-            self._each_world(world, ctx, *args)
+            return step(*a), (self._routed, self._staged, self._fan_in,
+                              self._fault_step)
+        out, (self._routed, self._staged, self._fan_in,
+              self._fault_step) = self._each_world(world, ctx, *args)
         return out
 
     def _identity(self) -> Optional[WorldIdentity]:
@@ -2502,7 +2577,8 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
             jnp.zeros(lanes.shape, jnp.int32) if self._ranks_fan_in()
             else None,
             lanes if self._cuts_scatters() else None,
-            *((lanes,) * 3 if self._stages_by_rank() else ()))
+            *((lanes,) * 3 if self._stages_by_rank() else (None,) * 3),
+            faults=FaultCounts(*(lanes,) * 5) if self._faulted else None)
 
     def _count_route(self, counts: RouteCounts, stepped=True
                      ) -> RouteCounts:
@@ -2529,7 +2605,11 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
             counts.scatter_lanes + jnp.where(stepped, self._scattered, 0),
             *(c + jnp.where(stepped, x, 0) for c, x in zip(
                 (counts.dense_lanes, counts.tail_lanes, counts.net_rows),
-                tail)))
+                tail)),
+            *(None,) * (3 - len(tail)),
+            faults=None if counts.faults is None else FaultCounts(*(
+                c + jnp.where(stepped, x, 0)
+                for c, x in zip(counts.faults, self._fault_step))))
 
     def _step_counted(self, carry, with_trace: bool):
         """``_step_all`` on a driver loop's ``(state, counts)`` carry.

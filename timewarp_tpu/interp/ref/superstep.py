@@ -120,6 +120,12 @@ class SuperstepOracle:
         #: deliveries + reset purges) — mirrors
         #: ``EngineState.fault_dropped``
         self.fault_dropped_total = 0
+        #: the same by cause (cut by a partition, due inside a down
+        #: window, purged at a reboot; their sum is the total), and the
+        #: sends whose delay a link window changed and the reboots
+        #: consumed: the engines' ``last_run_stats`` ``fault_*`` counts
+        self.fault_counts = dict.fromkeys(
+            ("cut", "down", "purged", "degraded", "restarts"), 0)
         self.time: Microsecond = 0
         if self._faulted and self.faults.has_reset:
             # pristine reboot template (self.states is mutated in
@@ -164,12 +170,13 @@ class SuperstepOracle:
                 bits = msg_bits(self.s0, self.s1, src_f, dst, tmsg, slot_f)
             else:
                 bits = None
-            delay, drop = link.sample(src_f, dst, tmsg, bits)
+            plain, drop = link.sample(src_f, dst, tmsg, bits)
+            delay = plain
             if self._faulted:
                 from ...faults.apply import degrade
                 ftj = jax.tree.map(jnp.asarray, self._ft)
-                delay = degrade(ftj, delay, src_f, dst, tmsg)
-            return delay, drop
+                delay = degrade(ftj, plain, src_f, dst, tmsg)
+            return delay, drop, plain
 
         self._vsample = jax.jit(_vsample)
 
@@ -251,6 +258,7 @@ class SuperstepOracle:
         for (k, d, u, r, c) in self._crash_rows:
             if r and not self._restart_done[c] and k == i and ti == u:
                 self._restart_done[c] = True
+                self.fault_counts["restarts"] += 1
                 rebooted = True
                 purge_before = max(purge_before, d)
         if rebooted:
@@ -261,6 +269,7 @@ class SuperstepOracle:
                                        self._reset_states)
             kept = [m for m in self.mailbox[i] if m[0] >= purge_before]
             self.fault_dropped_total += len(self.mailbox[i]) - len(kept)
+            self.fault_counts["purged"] += len(self.mailbox[i]) - len(kept)
             self.mailbox[i] = kept
 
     # ------------------------------------------------------------------
@@ -370,10 +379,11 @@ class SuperstepOracle:
             # route in chronological (send instant, sender, slot) order
             # — contract #3; pure sender-major for W == 1. Link entropy
             # is keyed by each message's own send instant.
-            delay, drop = self._vsample(
+            delay, drop, plain = self._vsample(
                 jnp.asarray(out_dst.reshape(-1)),
                 jnp.asarray(np.repeat(now_arr, M)))
             delay = np.asarray(delay).reshape(n, M)
+            plain = np.asarray(plain).reshape(n, M)
             drop = np.asarray(drop).reshape(n, M)
             sent_hashes: List[int] = []
             sent_count = 0
@@ -394,8 +404,11 @@ class SuperstepOracle:
                         # transit — counted, never hashed (the engine
                         # kills the same set pre-insertion)
                         self.fault_dropped_total += 1
+                        self.fault_counts["cut"] += 1
                         continue
                     flight = max(int(delay[i, slot]), 1)  # contract #4
+                    if self._faulted and delay[i, slot] != plain[i, slot]:
+                        self.fault_counts["degraded"] += 1
                     if W > 1 and flight < W:
                         # windowed-causality violation — counted loudly,
                         # mirroring EngineState.short_delay
@@ -405,6 +418,7 @@ class SuperstepOracle:
                         # would land inside the destination's down
                         # window: its NIC is off — counted, dropped
                         self.fault_dropped_total += 1
+                        self.fault_counts["down"] += 1
                         continue
                     p0 = int(out_pay[i, slot, 0]) if P else 0
                     sent_count += 1

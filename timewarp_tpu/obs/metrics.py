@@ -88,13 +88,17 @@ _RUN_COUNTS = ("cache_misses", "dispatches", "readbacks", "rung_lanes",
                "fleet_iterations", "dense_stage_steps", "wide_tail_steps",
                "fan_in_peak", "scatter_lanes", "dense_lanes", "tail_lanes",
                "net_rows", "shards", "worlds_local", "remote_msgs",
-               "bucket_fill_peak", "bucket_cap", "exchange_lanes")
+               "bucket_fill_peak", "bucket_cap", "exchange_lanes",
+               "fault_cut", "fault_down", "fault_purged", "fault_degraded",
+               "fault_restarts", "fault_table_lanes")
 #: the call's seconds on the compile path, a number like ``wall_seconds``
 _RUN_SECONDS = ("compile_seconds",)
 #: and those that are one int an entry: iterations by rung, and a
 #: world-sharded fleet's lanes and loop trips by device
 _RUN_LISTS = ("rung_steps", "device_rung_lanes", "device_sender_lanes",
-              "device_iterations")
+              "device_iterations", "world_fault_cut", "world_fault_down",
+              "world_fault_purged", "world_fault_degraded",
+              "world_fault_restarts")
 #: kind -> {required field: type tuple}; extra fields are allowed
 #: (forward-compatible), missing/badly-typed required ones are not
 _KINDS: Dict[str, Dict[str, tuple]] = {
