@@ -43,7 +43,7 @@ from reference import gossip_chaos_ref  # noqa: E402
 N, WORLDS = 512, 8
 CAUSES = ("cut", "down", "purged")
 COUNTS = ("fault_cut", "fault_down", "fault_purged", "fault_degraded",
-          "fault_restarts", "fault_table_lanes")
+          "fault_restarts", "fault_table_lanes", "fault_gather_lanes")
 
 
 def _load(kind, name):
@@ -213,13 +213,53 @@ def test_the_record_counts_the_losses_by_cause_a_world(fleet_run, cell):
     assert stats["world_fault_restarts"] == [1] * WORLDS
     assert 0 < stats["fault_degraded"] <= job["msgs"] + job["fault_dropped"]
     # from the shapes: two crash rows (one may reboot), one partition
-    # row, one link row, every rung the top one
+    # row, one link row, one message a node, every rung the top one.
+    # On the node lanes the crash rows twice and the link row's source
+    # bit, on the outbox lanes the partition row and the link row's
+    # verdict, in the rung the crash rows (until PR 54, the same sum
+    # as 2 * 2 + 2 + 2 + 1: the partition row at both ends of a lane,
+    # the link row in the rung)
     iters = stats["fleet_iterations"]
     assert stats["rung_lanes"] == iters * N
-    assert stats["fault_table_lanes"] == iters * N * (2 * 2 + 2 + 2 + 1)
+    assert stats["fault_table_lanes"] == iters * N * (
+        (2 * 2 + 1) + (1 + 1) + 2)
     assert job["fault_table_lanes"] == stats["fault_table_lanes"]
+    # and a table is read through an index once a message: the
+    # destination's packed word (until PR 54 four times: 2 * 1 + 2 * 1,
+    # a partition row and a link row at both ends of a lane)
+    assert stats["fault_gather_lanes"] == iters * N * 1
     from timewarp_tpu.obs import profiler
     assert any(set(COUNTS) <= set(c["counts"]) for c in profiler.calls())
+
+
+def test_link_rows_alone_take_the_word_in_the_rung(small):
+    # no partition row, so no look-up before the ladder's compaction
+    # to ride on (engine.py `_fault_reads`: not early): the senders'
+    # bits ride their in-window offsets through the rung's gather and
+    # the destinations' packed word is looked up on the rung's lanes.
+    # Same integers as the oracle, which keeps `degrade`'s look-ups
+    p, sc, link, *_ = small
+    sched = parse_faults(
+        "crash:5:20ms:50ms; degrade:0-31:16-63:10ms:120ms:2.0; "
+        "degrade:all:40-63:60ms:100ms:1.5:300")
+    oracle = SuperstepOracle(sc, link, seed=3, window="auto", faults=sched)
+    trace = oracle.run()
+    eng = JaxEngine(sc, link, window="auto", seed=3, faults=sched)
+    assert eng._adaptive_regime() and eng._fault_reads() == (2, False)
+    fin = jax.device_get(eng.run_quiet(1 << 20))
+    stats = eng.last_run_stats
+    assert len(trace) == int(fin.steps) and oracle.time == int(fin.time)
+    for field in ("hop", "lcg"):
+        np.testing.assert_array_equal(oracle.states[field],
+                                      fin.states[field])
+    assert oracle.fault_counts["degraded"] == stats["fault_degraded"] > 0
+    assert oracle.fault_counts["down"] == stats["fault_down"]
+    # one crash row and two link rows' source bits on the node lanes,
+    # the crash row and both link rows on the rung's lanes; one look-up
+    # a rung lane (until PR 54: 2 x 2)
+    iters, rung = stats["supersteps"], stats["rung_lanes"]
+    assert stats["fault_table_lanes"] == iters * 64 * (1 + 2) + rung * 3
+    assert stats["fault_gather_lanes"] == rung * 1
 
 
 def test_the_counts_merge_over_streamed_calls(small):

@@ -20,14 +20,19 @@ per-node instants ahead of the epoch).
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..core.scenario import NEVER
+from .schedule import dst_word_layout
 
 __all__ = [
     "defer_next", "restart_fire", "consume_restarts",
     "cut_mask", "down_mask", "degrade", "skewed_step",
     "window_floor",
+    "own_lanes", "src_link_bits", "dst_words", "cut_mask_at",
+    "link_aff_bits", "degrade_bits",
 ]
 
 
@@ -147,6 +152,122 @@ def degrade(ft, delay, src, dst, t_send):
             aff, (delay * ft.link_num[i]) // ft.link_den[i]
             + ft.link_add[i], delay)
     return delay.reshape(shape)
+
+
+# -- the same masks, each table read where it lies ------------------------
+#
+# ``cut_mask`` and ``degrade`` above look every table up at both ends
+# of every lane: they are the specification (the oracle and the edge
+# engine call them; tests/test_fault_node_lanes_law.py holds the forms
+# below to them lane for lane). Where a lane's source is the lane's own
+# node (the outbox planes of engine.py), the source's side of a table
+# is the table itself, and what a message needs of its destination is
+# one packed word (schedule.py ``pack_dst_word``): one look-up a
+# message, and the link rows' verdicts as bits that ride the message.
+
+def own_lanes(table, node_ids):
+    """The columns of a per-node ``table`` (``[..., n_global]``) that
+    are the node lanes' own: the table as it lies where the lanes are
+    all its nodes, and where they are a device's contiguous share
+    (``node_ids`` an offset iota, comm.node_ids()), that share's
+    slice. No gather either way."""
+    n = node_ids.shape[0]
+    if table.shape[-1] == n:
+        return table
+    return jax.lax.dynamic_slice_in_dim(table, node_ids[0], n, axis=-1)
+
+
+def src_link_bits(ft, node_ids, t_node, rows: int):
+    """int32[N]: bit ``i`` (``i < rows``) says that node lane ``n``,
+    sending at its instant ``t_node[n]``, is a source of link row
+    ``i`` inside that row's window. The whole time test of
+    ``degrade`` is a fact of the sender, so it is made here, on the
+    node lanes, once a superstep."""
+    bits = jnp.zeros(node_ids.shape, jnp.int32)
+    for i in range(rows):
+        live = own_lanes(ft.link_src[i], node_ids) \
+            & (ft.link_start[i] <= t_node) & (t_node < ft.link_end[i])
+        bits = bits | (live.astype(jnp.int32) << i)
+    return bits
+
+
+def dst_words(ft, dst):
+    """``ft.dst_word`` at the destinations: int32[R, *dst.shape], the
+    one look-up through an index a message's masks need (out-of-range
+    values are clipped for the gather's safety and must be masked by
+    the caller, as for ``cut_mask``)."""
+    R, n = ft.dst_word.shape
+    if R == 0:   # a host constant: nothing enters the program
+        return np.zeros((0,) + jnp.shape(dst), np.int32)
+    return jnp.take(ft.dst_word, jnp.clip(dst, 0, n - 1), axis=-1)
+
+
+def _group_bits(ft) -> int:
+    """The width of a packed word's group field (``dst_word_layout``)."""
+    return dst_word_layout(ft.dst_word.shape[-1], 0)[0]
+
+
+def _group_field(ft, words):
+    """The group fields of packed words: rank plus one, 0 = absent."""
+    return words[:ft.part_group.shape[0]] \
+        & jnp.int32((1 << _group_bits(ft)) - 1)
+
+
+def cut_mask_at(ft, node_ids, at_dst, t_send):
+    """``cut_mask`` on lanes that are the node lanes ``node_ids`` M
+    times over (``[M, N]`` planes or ``M * N`` flat, source-minor: the
+    outbox's layouts), from the destinations' packed words ``at_dst``
+    (:func:`dst_words` of those lanes) and the send instants
+    (broadcastable to the lanes). A source's group is its own word's,
+    read in place (:func:`own_lanes`); a group's rank stands for the
+    group: ranks are equal where groups are, and both ends read the
+    same numbering."""
+    lanes = at_dst.shape[1:]
+    Pn, n = ft.part_group.shape[0], node_ids.shape[0]
+    if Pn == 0:
+        return jnp.zeros(lanes, bool)
+    gs = _group_field(ft, own_lanes(ft.dst_word, node_ids))[:, None, :]
+    gd = _group_field(ft, at_dst).reshape(Pn, -1, n)
+    t = jnp.broadcast_to(t_send, lanes).reshape(-1, n)
+    act = (ft.part_start[:, None, None] <= t) \
+        & (t < ft.part_end[:, None, None])
+    return jnp.any(act & (gs != gd) & (gs > 0) & (gd > 0),
+                   axis=0).reshape(lanes)
+
+
+def link_aff_bits(ft, src_bits, at_dst0, rows: int):
+    """int32 per lane: bit ``i`` (``i < rows``) is ``degrade``'s
+    ``aff`` of link row ``i``: the senders' :func:`src_link_bits`
+    (``[n]``) against the ``link_dst`` bits of the destinations' words
+    (row 0 of :func:`dst_words`, on lanes that are the senders M times
+    over). Never negative (``rows`` is at most ``dst_word_layout``'s
+    ``packed``, under 32), so it can ride the spare high bits of a
+    valid destination id."""
+    dst_bits = (at_dst0 >> _group_bits(ft)) & jnp.int32((1 << rows) - 1)
+    return (dst_bits.reshape(-1, src_bits.shape[0])
+            & src_bits).reshape(at_dst0.shape)
+
+
+def degrade_bits(ft, delay, aff, rows: int, src=None, dst=None,
+                 t_send=None):
+    """``degrade`` with the first ``rows`` link rows' ``aff`` read
+    from the lanes' own bits (:func:`link_aff_bits`) and the rows
+    beyond them (a table of more rows than a word has room for:
+    ``src``, ``dst``, ``t_send`` are theirs alone) looked up as
+    ``degrade`` does; the same integer arithmetic in the same table
+    order."""
+    n = ft.link_src.shape[-1]
+    for i in range(ft.link_start.shape[0]):
+        if i < rows:
+            hit = ((aff >> i) & 1) != 0
+        else:
+            hit = (ft.link_start[i] <= t_send) & (t_send < ft.link_end[i]) \
+                & ft.link_src[i][jnp.clip(src, 0, n - 1)] \
+                & ft.link_dst[i][jnp.clip(dst, 0, n - 1)]
+        delay = jnp.where(
+            hit, (delay * ft.link_num[i]) // ft.link_den[i]
+            + ft.link_add[i], delay)
+    return delay
 
 
 def window_floor(ft, t, w_req, base_floor: int):

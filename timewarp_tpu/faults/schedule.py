@@ -194,6 +194,12 @@ class FaultTables(NamedTuple):
     Inert (padding) rows are windows with ``t_up <= t_down`` /
     ``t_end <= t_start`` — every mask guards on window non-emptiness,
     so padded and unpadded schedules are result-identical.
+
+    ``dst_word`` holds nothing the other fields do not: it is
+    ``part_group`` and ``link_dst`` packed one int32 word a node
+    (:func:`pack_dst_word`), so that a message's masks look its
+    destination up once (faults/apply.py, docs/faults.md "Where each
+    table is read").
     """
     crash_node: Any    # int32[C]
     crash_down: Any    # int64[C]
@@ -210,6 +216,46 @@ class FaultTables(NamedTuple):
     link_den: Any      # int64[L]
     link_add: Any      # int64[L]
     skew: Any          # int64[N]
+    dst_word: Any      # int32[max(Pn, L > 0), N]  (pack_dst_word)
+
+
+def dst_word_layout(n_nodes: int, n_link: int) -> Tuple[int, int]:
+    """``(group_bits, packed)`` of :func:`pack_dst_word` for tables of
+    ``n_nodes`` nodes and ``n_link`` link rows, from the shapes alone:
+    a group's dense rank plus one (0: in no group) takes
+    ``bit_length(n_nodes)`` bits, and the first ``packed`` link rows
+    are those a destination id leaves room for in an int32 word
+    (``31 - bit_length(n_nodes - 1)``: 14 at 2^17, 11 at 2^20), so
+    that the bits a message needs can ride its destination through a
+    compaction (engine.py ``_route_adaptive``). The two fields fit
+    one word: ``group_bits + packed <= 32``."""
+    n = int(n_nodes)
+    return n.bit_length(), min(int(n_link), 31 - (n - 1).bit_length())
+
+
+def pack_dst_word(part_group: np.ndarray, link_dst: np.ndarray) -> np.ndarray:
+    """What a message needs of its destination, one int32 word a node
+    and partition row, so that one look-up at the destination fetches
+    it (faults/apply.py ``dst_words``): row ``r``'s low
+    ``group_bits`` hold the node's group in partition row ``r`` as
+    that group's rank among the row's groups present, plus one (0 = in
+    no group; ranks keep equality, which is all a cut compares, and
+    stay under ``n_nodes`` whatever ids an out-of-range member left),
+    and row 0's next ``packed`` bits hold ``link_dst[i][node]`` for
+    the link rows ``i < packed`` (:func:`dst_word_layout`). A schedule
+    with link rows and no partition keeps one row for them; one with
+    neither has none."""
+    Pn, n = part_group.shape
+    L = link_dst.shape[0]
+    gbits, packed = dst_word_layout(n, L)
+    word = np.zeros((max(Pn, min(L, 1)), n), np.uint32)
+    for r in range(Pn):
+        present = part_group[r] >= 0
+        ranks = np.unique(part_group[r][present], return_inverse=True)[1]
+        word[r, present] = ranks.reshape(-1).astype(np.uint32) + 1
+    for i in range(packed):
+        word[0] |= link_dst[i].astype(np.uint32) << np.uint32(gbits + i)
+    return word.view(np.int32)
 
 
 @dataclass(frozen=True)
@@ -381,7 +427,8 @@ class FaultSchedule:
             crash_node, crash_down, crash_up, crash_reset,
             part_group, part_start, part_end,
             link_src, link_dst, link_start, link_end,
-            link_num, link_den, link_add, skew)
+            link_num, link_den, link_add, skew,
+            pack_dst_word(part_group, link_dst))
 
 
 @dataclass(frozen=True)

@@ -50,9 +50,10 @@ STAGES = ("tw.next_event", "tw.deliver", "tw.fire", "tw.rebase",
 
 #: the counts of a call on an engine built with ``faults`` (engine.py
 #: ``FaultCounts``; a fleet's have a ``world_`` list each beside them),
-#: and what its masks compared (``JaxEngine._fault_table_lanes``)
+#: and what its masks met and looked up (``JaxEngine._fault_lanes``)
 _FAULT_COUNTS = ("fault_cut", "fault_down", "fault_purged",
-                 "fault_degraded", "fault_restarts", "fault_table_lanes")
+                 "fault_degraded", "fault_restarts", "fault_table_lanes",
+                 "fault_gather_lanes")
 
 # whatever an engine traces, lowers and compiles from here on is in the
 # program's record, by name (obs/profiler.py ``phases()``)
@@ -305,9 +306,10 @@ class _DriverCall:
                     stats["fault_" + name] = int(np.sum(x))
                     if d.ndim:
                         stats["world_fault_" + name] = x.tolist()
-                stats["fault_table_lanes"] = self.eng._fault_table_lanes(
-                    int(np.max(counts[2].sum(axis=-1))),
-                    int(np.max(counts[0])))
+                stats["fault_table_lanes"], stats["fault_gather_lanes"] = \
+                    self.eng._fault_lanes(
+                        int(np.max(counts[2].sum(axis=-1))),
+                        int(np.max(counts[0])))
             if d.ndim:
                 # one rung for all the worlds of a superstep: every
                 # world counted the same (a world-sharded fleet: the
@@ -487,10 +489,15 @@ class RunStatsMixin:
          "fault_degraded": int,  # sends whose delay a link window
                                  # changed
          "fault_restarts": int,  # reboots consumed
-         "fault_table_lanes": int}  # what a world's masks compared,
-                                 # from the tables' shapes and the
-                                 # rungs taken (``JaxEngine.
-                                 # _fault_table_lanes``)
+         "fault_table_lanes": int,  # the lanes a world's masks met a
+                                 # table row at, from the tables'
+                                 # shapes and the rungs taken
+                                 # (``JaxEngine._fault_lanes``)
+         "fault_gather_lanes": int}  # and those at which they read a
+                                 # table through an index: the
+                                 # destination's packed word, once a
+                                 # message (PR 54; until then both ends
+                                 # of every lane, a row at a time)
 
     (a fleet's first five are sums over its worlds, with a
     ``world_fault_*`` list of each beside them)

@@ -834,7 +834,7 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         return rungs
 
     @jax.named_scope("sample")
-    def _sample_nodrop(self, src, dst, tmsg, slot, woff, ok):
+    def _sample_nodrop(self, src, dst, tmsg, slot, woff, ok, aff=None):
         """Shared link-sampling tail for the no-drop routing paths
         (lazy and adaptive): derive per-message entropy, apply the
         contract-#4 ``>= 1 µs`` flight clamp, saturate the epoch-
@@ -848,7 +848,8 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         if self._faulted:
             # degradation windows transform the sampled delay BEFORE
             # the flight clamp (faults/apply.py; oracle order matches)
-            delay, degraded = self._degrade(delay, src, dst, tmsg, ok)
+            delay, degraded = self._degrade(delay, src, dst, tmsg, ok,
+                                            aff)
         flight = jnp.maximum(delay, jnp.int64(1))       # contract #4
         drel64 = woff.astype(jnp.int64) + flight
         bad = jnp.sum(ok & (drel64 > jnp.int64(_I32MAX - 1)),
@@ -872,32 +873,74 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         return flight, drel, bad, short, strag, degraded
 
     @jax.named_scope("fault")
-    def _degrade(self, delay, src, dst, tmsg, ok):
+    def _degrade(self, delay, src, dst, tmsg, ok, aff=None):
         """The schedule's link windows on the sampled delays
-        (faults/apply.py ``degrade``), and how many of the ``ok``
-        messages' delays a window changed: the call's
-        ``fault_degraded``."""
-        from ...faults.apply import degrade
-        slowed = degrade(self._ft, delay, src, dst, tmsg)
+        (faults/apply.py ``degrade_bits``: ``degrade`` with the
+        verdicts of the first ``_fault_reads`` rows read from the
+        lanes' own ``aff`` bits, where the caller brought them), and
+        how many of the ``ok`` messages' delays a window changed: the
+        call's ``fault_degraded``."""
+        from ...faults.apply import degrade_bits
+        slowed = degrade_bits(
+            self._ft, delay, aff, 0 if aff is None else
+            self._fault_reads()[0], src, dst, tmsg)
         return slowed, jnp.sum(ok & (slowed != delay), dtype=jnp.int32)
 
-    def _fault_table_lanes(self, iterations: int, rung_lanes: int) -> int:
-        """The lanes a world's fault masks compared over a call of
-        ``iterations`` supersteps whose rungs sum to ``rung_lanes``
-        senders (``last_run_stats`` ``fault_table_lanes``), from the
-        tables' shapes alone: every crash row against every node in
-        ``_horizon`` (``defer_next``) and once more where a reboot can
-        fire (``restart_fire``), every partition row gathered at both
-        ends of every outbox lane (``cut_mask``), and every crash row
-        (``down_mask``) and link row (``degrade``) against every
-        message lane of the rung taken. What per-node tables would
-        bring down (ROADMAP M4)."""
+    def _fault_reads(self) -> Tuple[int, bool]:
+        """``(rows, early)``: where the routing masks read the
+        schedule's per-node tables, from shapes alone. The first
+        ``rows`` link rows reach ``degrade`` as bits a message carries
+        (``link_aff_bits``), the rest are looked up at both ends of a
+        lane as ``degrade`` does. ``early``: the destination's packed
+        word (``FaultTables.dst_word``) is looked up on the outbox
+        lanes, before any compaction: wherever a partition row makes
+        ``cut_mask`` look there anyway, and on the eager path, which
+        compacts nothing; the bits then ride the destination id's
+        spare high bits. Otherwise (the ladder with link rows alone)
+        the word is looked up inside the rung, on the rung's lanes,
+        and the sender's bits ride the spare bits of its in-window
+        offset, which bound ``rows`` too."""
+        from ...faults.schedule import dst_word_layout
+        ft = self._ft if self._ftv is None else self._ftv
+        Pn, L = ft.part_start.shape[-1], ft.link_start.shape[-1]
+        rows = dst_word_layout(self.comm.n_global, L)[1]
+        early = Pn > 0 or not self._adaptive_regime()
+        if not early:
+            rows = min(rows, 31 - (self.window - 1).bit_length())
+        return rows, early
+
+    def _fault_lanes(self, iterations: int, rung_lanes: int
+                     ) -> Tuple[int, int]:
+        """``last_run_stats`` ``fault_table_lanes`` and
+        ``fault_gather_lanes`` of a call of ``iterations`` supersteps
+        whose rungs sum to ``rung_lanes`` senders, from the tables'
+        shapes alone (host arithmetic). The first: the lanes a world's
+        masks met a table row at. On the node lanes every crash row
+        (``defer_next`` in ``_horizon``, and once more where a reboot
+        can fire: ``restart_fire``) and every packed link row's source
+        bit and window (``src_link_bits``); on the outbox lanes every
+        partition row (``cut_mask_at``) and, where the packed word is
+        read there, the packed link rows (``link_aff_bits``); on the
+        lanes of the rung taken every crash row (``down_mask``) and
+        the link rows not yet met. The second: the lanes at which a
+        table was read **through an index**: the destination's packed
+        word, a row a partition row on the outbox lanes (or once in
+        the rung), and both ends of every link row beyond the packed
+        ones. Until PR 54 the masks gathered at both ends of every
+        lane: ``n * Pn * 2 * M + rung * M * 2 * L`` an iteration."""
         ft = self._ft if self._ftv is None else self._ftv
         C, Pn, L = (getattr(ft, f).shape[-1] for f in (
             "crash_node", "part_start", "link_start"))
         n, M = self.comm.n_local, self.scenario.max_out
-        return iterations * n * (C * (1 + self._has_reset) + Pn * 2 * M) \
-            + rung_lanes * M * (C + L)
+        rows, early = self._fault_reads()
+        met_early = rows if early else 0   # link rows met on the outbox
+        words = max(Pn, min(rows, 1))      # rows of the packed word
+        table = iterations * n * (
+            C * (1 + self._has_reset) + rows + M * (Pn + met_early)) \
+            + rung_lanes * M * (C + L - met_early)
+        gather = (iterations * n if early else rung_lanes) * M * words \
+            + rung_lanes * M * 2 * (L - rows)
+        return table, gather
 
     def _stages_dense(self, lanes: int) -> bool:
         """Whether ``_stage_by_rank`` takes its dense form for a call
@@ -1339,17 +1382,48 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         bad_dst_step = jnp.sum(out_valid & ~dst_okf, dtype=jnp.int32)
         pdst = jnp.where(out_valid & dst_okf, dst32, -1)        # [M, N]
         fault_cut = jnp.int32(0)
-        if self._faulted and self._ft.part_group.shape[0]:
+        #: the link rows whose verdicts reach the rung as bits, and the
+        #: word they ride through the compaction (``_fault_reads``):
+        #: above a destination id's ``dbits`` where the destination's
+        #: packed word is looked up here, above the sender's offset's
+        #: ``wbits`` where it is looked up in the rung
+        aff_rows = aff_early = 0
+        dbits, wbits = (n_glob - 1).bit_length(), (W - 1).bit_length()
+        if self._faulted:
+            from ...faults.apply import (cut_mask_at, dst_words,
+                                         link_aff_bits, src_link_bits)
+            ft = self._ft
+            aff_rows, aff_early = self._fault_reads()
+            if aff_rows:
+                with jax.named_scope("fault"):
+                    # a link row's source bit AND its whole time test
+                    # are facts of the sender: one bit a row and node,
+                    # on the node lanes, no look-up
+                    src_bits = src_link_bits(ft, node_ids, now_vec,
+                                             aff_rows)
+        if aff_early:
             # partition cuts are sample-independent: kill them before
-            # compaction (counted; the oracle drops the same set)
-            from ...faults.apply import cut_mask
+            # compaction (counted; the oracle drops the same set). The
+            # source is the node lanes themselves: its group is its
+            # own packed word's, read in place; the destination's
+            # word is the ONE look-up a message's masks make
             with jax.named_scope("fault"):
-                cutm = (pdst >= 0) & cut_mask(
-                    self._ft, node_ids[None, :], pdst, now_vec[None, :])
+                at_dst = dst_words(ft, pdst)                 # [Pn, M, N]
+                cutm = (pdst >= 0) & cut_mask_at(ft, node_ids, at_dst,
+                                                 now_vec)
                 fault_cut = jnp.sum(cutm, dtype=jnp.int32)
             self._rec_cut(rec_full, cutm, node_ids[None, :], pdst,
                           now_vec[None, :])
             pdst = jnp.where(cutm, jnp.int32(-1), pdst)
+            if aff_rows:
+                # every packed link row's verdict, from the same word:
+                # the bits ride the destination id through the rung's
+                # gather (a valid id is under n_global; -1 stays -1)
+                with jax.named_scope("fault"):
+                    aff = link_aff_bits(ft, src_bits, at_dst[0],
+                                        aff_rows)
+                    pdst = jnp.where(pdst >= 0, pdst | (aff << dbits),
+                                     jnp.int32(-1))
         sender_live = jnp.any(pdst >= 0, axis=0)                # [N]
         n_active = jnp.sum(sender_live, dtype=jnp.int32)
         with jax.named_scope("senders"):
@@ -1361,6 +1435,10 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         # precomputed int32 in-window offsets: the branches gather one
         # int32 word per sender instead of an int64
         woff_n = (now_vec - t).astype(jnp.int32)                # [N]
+        if aff_rows and not aff_early:
+            # no look-up before compaction to ride on: the sender's
+            # bits ride its offset (under W) through that gather
+            woff_n = woff_n | (src_bits << wbits)
 
         staged = self._stages_by_rank()
 
@@ -1422,6 +1500,22 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
                 # is keyed per message, not per lane position.
                 from ...faults.apply import down_mask
                 SA, woff_a, dst_f, ok, smrank, pay_f = gather(A)
+                aff = None
+                if aff_rows and aff_early:
+                    # the verdicts came with the destination
+                    aff = jnp.where(dst_f >= 0, dst_f >> dbits, 0)
+                    dst_f = jnp.where(
+                        dst_f >= 0, dst_f & jnp.int32((1 << dbits) - 1),
+                        jnp.int32(-1))
+                elif aff_rows:
+                    # the sender's bits came with its offset; the
+                    # destination's word is looked up here, on the
+                    # rung's lanes
+                    with jax.named_scope("fault"):
+                        aff = link_aff_bits(
+                            ft, woff_a >> wbits,
+                            dst_words(ft, dst_f)[0], aff_rows)
+                    woff_a = woff_a & jnp.int32((1 << wbits) - 1)
                 woff_f = jnp.broadcast_to(
                     woff_a[None, :], (M, A)).reshape(SA) \
                     if W > 1 else jnp.zeros((SA,), jnp.int32)
@@ -1430,7 +1524,7 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
                 flight, drel, bad_delay_step, short_step, strag, \
                     degraded = self._sample_nodrop(
                         src_l, dst_f, tmsg_l, smrank % jnp.int32(M),
-                        woff_f, ok)
+                        woff_f, ok, aff)
                 with jax.named_scope("fault"):
                     downm = ok & down_mask(self._ft, dst_f,
                                            tmsg_l + flight)
@@ -2062,15 +2156,28 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
             if self._faulted:
                 # partition cuts (send-time) before the flight clamp;
                 # down-window drops (deliver-time) after — the same
-                # check order as the oracle's routing loop
-                from ...faults.apply import cut_mask
+                # check order as the oracle's routing loop. The lanes
+                # are the node lanes M times over: a source's side of
+                # a table is the table in place, the destination's
+                # packed word the one look-up (``_fault_reads``:
+                # always early here)
+                from ...faults.apply import (cut_mask_at, dst_words,
+                                             link_aff_bits,
+                                             src_link_bits)
+                ft = self._ft
+                aff_rows = self._fault_reads()[0]
                 with jax.named_scope("fault"):
-                    cutm = ok & cut_mask(self._ft, src_f, dst_f, tmsg)
+                    at_dst = dst_words(ft, dst_f)               # [R, S]
+                    cutm = ok & cut_mask_at(ft, node_ids, at_dst, tmsg)
                     fault_cut = jnp.sum(cutm, dtype=jnp.int32)
+                    aff = link_aff_bits(
+                        ft, src_link_bits(ft, node_ids, now_vec,
+                                          aff_rows),
+                        at_dst[0], aff_rows) if aff_rows else None
                 self._rec_cut(rec_full, cutm, src_f, dst_f, tmsg)
                 ok = ok & ~cutm
                 delay, degraded = self._degrade(delay, src_f, dst_f,
-                                                tmsg, ok)
+                                                tmsg, ok, aff)
             flight = jnp.maximum(delay, jnp.int64(1))  # contract #4
             drel64 = woff.astype(jnp.int64) + flight
             bad_delay_step = comm.all_sum(jnp.sum(

@@ -90,7 +90,8 @@ _RUN_COUNTS = ("cache_misses", "dispatches", "readbacks", "rung_lanes",
                "net_rows", "shards", "worlds_local", "remote_msgs",
                "bucket_fill_peak", "bucket_cap", "exchange_lanes",
                "fault_cut", "fault_down", "fault_purged", "fault_degraded",
-               "fault_restarts", "fault_table_lanes")
+               "fault_restarts", "fault_table_lanes",
+               "fault_gather_lanes")
 #: the call's seconds on the compile path, a number like ``wall_seconds``
 _RUN_SECONDS = ("compile_seconds",)
 #: and those that are one int an entry: iterations by rung, and a
