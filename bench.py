@@ -987,7 +987,10 @@ def bench_praos_1m_b4(n, steps):
     """Praos as a 4-world fleet sweeping BOTH seed and link model per
     world (lognormal median 18/20/22/24 ms — a Monte-Carlo link study
     in one engine, via BatchSpec.link_params), exactness-gated like
-    the gossip fleet; aggregate delivered-msg/s/chip."""
+    the gossip fleet; aggregate delivered-msg/s/chip. The benchmark's
+    cell ``praos_1m.fleet4`` is this row (PR 55), one slot a job from a
+    seeded genesis to the quiescence of the slowest world, with the
+    plain reference of every world's own seed and link beside it."""
     import numpy as np
     from timewarp_tpu.interp.jax_engine.engine import (BatchSpec,
                                                        JaxEngine)
@@ -1059,8 +1062,9 @@ def _praos_consensus(n):
     ``mailbox_cap`` 24, not the 16 this row had until PR 33: at 2^20
     some 20 tips are in flight to one node at the height of a slot's
     flood, and 16 slots dropped the rest, silently (PERF.md, Findings
-    PR 33). The benchmark's cell ``praos_1m.slots`` is this row, two
-    slots a job, with the plain reference beside it."""
+    PR 33). The benchmark's cell ``praos_1m.slots`` is this row, one
+    slot a job (two took 2.82 s on the chip: PERF.md, Findings PR 33),
+    with the plain reference beside it."""
     from timewarp_tpu.models.praos import praos
     from timewarp_tpu.net.delays import LogNormalDelay, Quantize
     sc = praos(n, slot_us=1_000_000, n_slots=1 << 30,

@@ -44,7 +44,7 @@ BAD_LINKS = [
     "pareto:0:1.5",              # XM must be >= 1
     "pareto:4000:0",             # ALPHA must be > 0
     "pareto:4000:-1.5",          # negative ALPHA
-    "pareto:4000:1.5:9",         # excess params
+    "pareto:4000:1.5:9:10:11",   # excess params (FLOOR and CAP: PR 55)
     "quantize:500:pareto:4000",  # damaged inner pareto arity
 ]
 

@@ -318,6 +318,21 @@ def test_two_devices_took_different_rungs(uneven):
     assert scan["device_rung_lanes"] == both
     assert scan["rung_lanes"] == max(both)
     assert (scan["shards"], scan["worlds_local"]) == (SHARDS, LOCAL)
+    # each world's own senders (PR 55), a world a row on whichever
+    # device: at most its device's busiest's (which is one of them in
+    # every iteration, so their sum is at least it), and the scan
+    # driver's are the two streamed calls' sums
+    for stats in (*calls[:2], scan):
+        own = stats["world_sender_lanes"]
+        assert len(own) == WORLDS
+        assert all(own[b] <= stats["device_sender_lanes"][b // LOCAL]
+                   for b in range(WORLDS))
+        assert all(sum(own[d * LOCAL:(d + 1) * LOCAL])
+                   >= stats["device_sender_lanes"][d]
+                   for d in range(SHARDS))
+    assert scan["world_sender_lanes"] == [
+        a + b for a, b in zip(calls[0]["world_sender_lanes"],
+                              calls[1]["world_sender_lanes"])]
 
 
 @pytest.mark.parametrize("key", ["device_rung_lanes", "device_sender_lanes",
@@ -518,16 +533,24 @@ def _edge_sharded_quiet():
 #: exchange's ``[shards, bucket_cap]`` buffers are slices of the
 #: planes sorted by shard and no longer scatters, the same words
 #: (``tests/test_exchange_bucket_law.py``); the other five drivers
-#: run no sharded exchange and lower to the parent's text. A
-#: PR that changes what these drivers compute changes the
+#: run no sharded exchange and lower to the parent's text. PR 55
+#: re-pinned the three fleet drivers (``fleet_quiet`` was
+#: db301bff740d…, ``world_sharded_quiet`` 984d8edf41b9…,
+#: ``world_sharded_scan`` 27b7df94d44f…): a fleet's carry holds one
+#: count more, ``world_sender_lanes``, each world's own senders
+#: before the ``pmax`` that picks the rung (a sum the ladder has made
+#: already; the state it computes is the parent's:
+#: ``tests/test_zzzzzzzzzzzzzzzrecord.py``); the three solo and
+#: node-sharded drivers carry nothing new and kept their constants.
+#: A PR that changes what these drivers compute changes the
 #: constants, and says so.
 _PARENT_LOWERING = {
     "world_sharded_quiet":
-        "984d8edf41b944c58b127ed4b3ad98df6658e652cd42f234577c5c8fcadd630f",
+        "8a96e6cec6997cf215ecb63c64dc7353f213d51f912e919b4b3fd508dc35dfb8",
     "world_sharded_scan":
-        "27b7df94d44faa2b8b3587bb8def97175a6a622dab6e3b624d53a3a9062f4071",
+        "1ae8364d96e34efa957bb9912f90f1fb3d0dfe9081257096b7a714b3acfde8f4",
     "fleet_quiet":
-        "db301bff740d6fe57f6cec804850ddacb82ba2afb440aff351e07f56fcc3e161",
+        "afabbf17cb9fe0d491a352e4e9434be0e1922dcd242f6a2c705138791b92c5e5",
     "solo_quiet":
         "d68a4763b0010823b89082a3e929a77ec8d598a69404acc41cb3cacafe4bc6b5",
     "node_sharded_quiet":

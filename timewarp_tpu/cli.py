@@ -71,14 +71,17 @@ def _seeds_arg(s: str):
 BATCH_ENGINES = ("general", "sharded-batched")
 
 
-def build_batch(args):
-    """The world-axis spec from --batch/--seeds, or None (solo)."""
+def build_batch(args, link_params=None):
+    """The world-axis spec from --batch/--seeds, or None (solo);
+    ``link_params`` is :func:`build_link`'s second value, the worlds'
+    link values where --link was given once a world."""
     if args.batch is None and args.seeds is None:
         return None
     from .interp.jax_engine.batched import BatchSpec
     try:
         return BatchSpec.of(args.batch, args.seeds,
-                            base_seed=args.seed)
+                            base_seed=args.seed,
+                            link_params=link_params)
     except ValueError as e:
         raise SystemExit(str(e)) from None
 
@@ -88,6 +91,39 @@ def build_batch(args):
 # loader, so the grammar cannot drift between surfaces); re-exported
 # here because this was their historical import path
 from .net.links import LINK_GRAMMAR, parse_link  # noqa: F401,E402
+
+
+def build_link(args):
+    """``(link, link_params)`` from --link. Given once, every world
+    runs that link and ``link_params`` is None: today's engine, today's
+    executable. Given once a world (with --batch B / --seeds: B of
+    them), world b runs the b-th: a link study in one engine
+    (docs/engines.md "Batched multi-world execution"). The links must
+    share one structure (sweep/spec.py ``link_signature``); their
+    sweepable fields that differ become ``BatchSpec.link_params``
+    (``fleet_link_params``, the sweep buckets' own path) and the
+    engine's link is world 0's. Any other count is refused."""
+    if isinstance(args.link, str):
+        return parse_link(args.link), None
+    batch = build_batch(args)
+    worlds = 1 if batch is None else batch.B
+    if len(args.link) != worlds:
+        raise SystemExit(
+            f"--link was given {len(args.link)} times and the run has "
+            f"{worlds} world{'s' * (worlds > 1)}: give it once (every "
+            "world runs that link) or once a world, in world order "
+            "(--batch B / --seeds)")
+    if args.engine not in BATCH_ENGINES:
+        raise SystemExit(
+            f"--link once a world needs a world axis; only the general "
+            f"XLA engines carry one ({', '.join(BATCH_ENGINES)}) — "
+            f"{args.engine} runs exactly one world and one link")
+    from .sweep.spec import fleet_link_params
+    links = [parse_link(spec) for spec in args.link]
+    try:
+        return links[0], fleet_link_params(links, that_differ=True)
+    except ValueError as e:
+        raise SystemExit(f"--link once a world: {e}") from None
 
 
 def build_scenario(args):
@@ -173,8 +209,8 @@ def build_controller(args):
 RECORD_ENGINES = ("general", "edge", "sharded-batched")
 
 
-def build_engine(args, sc, link):
-    batch = build_batch(args)
+def build_engine(args, sc, link, link_params=None):
+    batch = build_batch(args, link_params)
     faults = build_faults(args)
     telemetry = getattr(args, "telemetry", "off")
     verify = getattr(args, "verify", "off")
@@ -657,13 +693,21 @@ def main(argv=None) -> int:
     p.add_argument("--nodes", type=int, default=64)
     p.add_argument("--steps", type=int, default=1000,
                    help="max supersteps to run")
-    p.add_argument("--link", default="uniform:1000:5000",
-                   help="fixed:D | uniform:LO:HI | lognormal:MED:SIGMA"
-                        " | drop:P:<inner> | quantize:Q:<inner> | "
-                        "never (stationary loss: drop:P wraps any "
+    p.add_argument("--link", default=None, action="append",
+                   help="fixed:D | uniform:LO:HI | "
+                        "lognormal:MED:SIGMA[:FLOOR[:CAP]] | "
+                        "pareto:XM:ALPHA[:FLOOR[:CAP]] | "
+                        "drop:P:<inner> | quantize:Q:<inner> | "
+                        "never (default uniform:1000:5000; FLOOR and "
+                        "CAP clamp a sample, µs, default 1 and "
+                        "60000000; stationary loss: drop:P wraps any "
                         "inner model with i.i.d. loss probability P; "
                         "never severs the link entirely — the old "
-                        "NeverConnected)")
+                        "NeverConnected). Once (every world of a "
+                        "--batch runs it) or once a world, world b "
+                        "the b-th: links of one structure whose "
+                        "values differ, a link study in one engine "
+                        "(BatchSpec.link_params)")
     p.add_argument("--faults", default=None, action="append",
                    help="deterministic fault schedule (faults/); once "
                         "(every world of a --batch runs it) or once a "
@@ -844,6 +888,10 @@ def main(argv=None) -> int:
         # given once: the one string every later reader of the flag
         # has always had (a checkpoint's meta, the repro line)
         args.faults, = args.faults
+    if args.link is None:
+        args.link = "uniform:1000:5000"
+    elif len(args.link) == 1:
+        args.link, = args.link           # given once: the one string
     if args.telemetry == "off" and (args.metrics_out or args.trace_out):
         raise SystemExit(
             "--metrics-out/--trace-out need --telemetry counters|full "
@@ -972,8 +1020,8 @@ def main(argv=None) -> int:
     load_log_config(args.log_config)
 
     sc = build_scenario(args)
-    link = parse_link(args.link)
-    engine = build_engine(args, sc, link)
+    link, link_params = build_link(args)
+    engine = build_engine(args, sc, link, link_params)
 
     if args.engine == "oracle":
         if args.save or args.resume:
@@ -1016,7 +1064,7 @@ def main(argv=None) -> int:
                 # the RNG stream is part of the state: resuming under a
                 # different seed would silently diverge from both runs
                 args.seed = ck_meta["seed"]
-                engine = build_engine(args, sc, link)
+                engine = build_engine(args, sc, link, link_params)
         if args.metrics_out:
             # attach BEFORE the run (the sweep service's pattern):
             # chunked drivers (run_controlled) then flush every

@@ -268,8 +268,9 @@ class _DriverCall:
         from a solo one on one device), the three of the staging None
         but from an insertion staged by rank, the last two None but
         from the node-sharded general engine (one row a shard), and
-        last a ``FaultCounts`` from an engine built with ``faults``,
-        None from any other, ``engine.py``
+        then a ``FaultCounts`` from an engine built with ``faults``,
+        None from any other, and last a fleet's ``world_sender_lanes``,
+        None from a solo engine, ``engine.py``
         ``RouteCounts``; None from an engine with no ladder to count),
         a node-sharded edge engine's boundary messages (``crossed``:
         one count a shard, ``sharded.py`` ``ShardedEdgeEngine``; None
@@ -298,7 +299,11 @@ class _DriverCall:
                          fleet_iterations=int(d.max()))
         if counts is not None:
             (*counts, fan_in, scattered, dense_lanes, tail_lanes, rows,
-             remote, fill, faults) = counts
+             remote, fill, faults, own) = counts
+            if own is not None:
+                # a fleet's: each world's own senders, beside the
+                # busiest's (`sender_lanes`) that picked the rungs
+                stats["world_sender_lanes"] = own.tolist()
             if faults is not None:
                 # a world's own counts (a fleet: a list beside each
                 # sum, as `world_supersteps` is beside `supersteps`)
@@ -505,9 +510,21 @@ class RunStatsMixin:
     and, for a fleet (``batch=BatchSpec``) only::
 
         {"world_supersteps": [int] * B,  # executed by each world
-         "fleet_iterations": int}        # the largest of them: what the
+         "fleet_iterations": int,        # the largest of them: what the
                                          # driver's loop ran, each at the
                                          # cost of all B worlds
+         "world_sender_lanes": [int] * B}  # each world's OWN active
+                                         # senders, summed over the
+                                         # iterations it stepped
+                                         # (``n_active`` before the
+                                         # ``pmax`` that picks one rung
+                                         # for all): each is at most
+                                         # ``sender_lanes``, the
+                                         # busiest's, and their sum over
+                                         # B * ``rung_lanes`` is what
+                                         # the worlds' own senders fill
+                                         # of the rungs the lockstep
+                                         # made them all take
 
     so ``supersteps / (B * fleet_iterations)`` is the share of the
     fleet's work spent on worlds that were still running,
@@ -633,6 +650,7 @@ class RunStatsMixin:
                 self.last_run_stats[key] = chunks[0][key]
         for key in ("rung_lanes", "sender_lanes", "fleet_iterations",
                     "rung_steps", "world_supersteps",
+                    "world_sender_lanes",
                     *_FAULT_COUNTS, *("world_" + k for k in _FAULT_COUNTS[:5]),
                     "dense_stage_steps", "wide_tail_steps",
                     "scatter_lanes", "dense_lanes", "tail_lanes",

@@ -316,6 +316,13 @@ class RouteCounts(NamedTuple):
     #: :class:`FaultCounts` — carried by an engine built with
     #: ``faults`` and by no other (None elsewhere, as above)
     faults: Any = None
+    #: int64[B] — each world's OWN active senders (``n_active`` before
+    #: the fleet's ``pmax``), summed over the iterations that world
+    #: stepped: against ``rung_lanes``, what the lockstep's shared
+    #: rung cost worlds that are out of step. Carried by a fleet and
+    #: by no other (None solo, as above: a solo engine's senders are
+    #: ``sender_lanes``)
+    world_sender_lanes: Any = None
 
 
 class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
@@ -1640,6 +1647,7 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
             # the branches, every rung in every world. Any rung that
             # fits is result-identical by the ladder's construction,
             # and the largest count fits every world
+            self._own_senders = n_active
             n_active = jax.lax.pmax(n_active, _FLEET_AXIS)
         idx = jnp.sum(n_active > jnp.asarray(rungs, jnp.int32))
         if self._dyn is not None:
@@ -2006,6 +2014,10 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         #: rung counts its full width; ``_route_adaptive`` puts its
         #: own where it takes one of several
         self._routed = (jnp.int32(n_glob), jnp.int32(n_glob), jnp.int32(0))
+        #: a fleet's world's own senders, before the ``pmax`` that
+        #: picks the rung for all of them (None solo)
+        self._own_senders = None if self.batch is None \
+            else jnp.int32(n_glob)
         #: and whether the arrivals were staged in the dense form, and
         #: its tail went wide (and, from there on, its lanes, its
         #: tail's width and its rows: ``_tail_counts``): the
@@ -2543,14 +2555,16 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         ``vmap`` reads ``vmap(tw.route)`` in an operation's
         ``op_name`` (docs/observability.md). What routing did in
         every world is left on ``self._routed`` and ``self._staged``
-        (int32[B] each, one value B times) and ``self._fan_in`` (a
-        world's own, or None) for the drivers' counts, as a solo
-        superstep leaves its scalars there."""
+        (int32[B] each, one value B times), ``self._fan_in`` (a
+        world's own, or None) and ``self._own_senders`` (int32[B], a
+        world's own) for the drivers' counts, as a solo superstep
+        leaves its scalars there."""
         def world(*a):
             return step(*a), (self._routed, self._staged, self._fan_in,
-                              self._fault_step)
+                              self._fault_step, self._own_senders)
         out, (self._routed, self._staged, self._fan_in,
-              self._fault_step) = self._each_world(world, ctx, *args)
+              self._fault_step, self._own_senders) = self._each_world(
+                  world, ctx, *args)
         return out
 
     def _identity(self) -> Optional[WorldIdentity]:
@@ -2685,16 +2699,19 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
             else None,
             lanes if self._cuts_scatters() else None,
             *((lanes,) * 3 if self._stages_by_rank() else (None,) * 3),
-            faults=FaultCounts(*(lanes,) * 5) if self._faulted else None)
+            faults=FaultCounts(*(lanes,) * 5) if self._faulted else None,
+            world_sender_lanes=None if self.batch is None else lanes)
 
-    def _count_route(self, counts: RouteCounts, stepped=True
-                     ) -> RouteCounts:
+    def _count_route(self, counts: RouteCounts, stepped=True,
+                     world_stepped=True) -> RouteCounts:
         """``counts`` and what the superstep just traced did in
         routing (``self._routed``: the scalars ``_route_adaptive``
         chose its branch by; a fleet's ``_vstep`` returns them a
         world). ``stepped`` is whether the iteration counts at all (a
         traced bool where the caller's loop runs on past the last
-        event)."""
+        event); ``world_stepped`` which of a fleet's worlds stepped
+        in it (bool[B]: a world that is quiet or out of budget rides
+        the others' iterations and sends nothing of its own)."""
         rung, senders, idx = self._routed
         dense, wide, *tail = self._staged
         bins = counts.rung_steps.shape[-1]
@@ -2716,7 +2733,10 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
             *(None,) * (3 - len(tail)),
             faults=None if counts.faults is None else FaultCounts(*(
                 c + jnp.where(stepped, x, 0)
-                for c, x in zip(counts.faults, self._fault_step))))
+                for c, x in zip(counts.faults, self._fault_step))),
+            world_sender_lanes=None if counts.world_sender_lanes is None
+            else counts.world_sender_lanes + jnp.where(
+                stepped & world_stepped, self._own_senders, 0))
 
     def _step_counted(self, carry, with_trace: bool):
         """``_step_all`` on a driver loop's ``(state, counts)`` carry.
@@ -2725,8 +2745,9 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         runs on to its padded length after the last event."""
         st, counts = carry
         new, y = self._step_all(st, with_trace)
-        stepped = True if y is None else jnp.any(y.valid)
-        return (new, self._count_route(counts, stepped)), y
+        valid = True if y is None else y.valid
+        stepped = True if y is None else jnp.any(valid)
+        return (new, self._count_route(counts, stepped, valid)), y
 
     def _any_world(self, x):
         """Whether any world of this device is still active: the
@@ -2779,12 +2800,13 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
             st, counts, hz = carry
             if self.batch is None:
                 new, hz = self._superstep_carried(st, hz)
-            else:
-                in_budget = st.steps - start_steps < max_steps  # [B]
-                new, hz = self._vstep(self._superstep_carried,
-                                      self._world_context(), st, hz,
-                                      in_budget)
-            return new, self._count_route(counts), hz
+                return new, self._count_route(counts), hz
+            in_budget = st.steps - start_steps < max_steps      # [B]
+            live = (hz.t < NEVER) & in_budget
+            new, hz = self._vstep(self._superstep_carried,
+                                  self._world_context(), st, hz,
+                                  in_budget)
+            return new, self._count_route(counts, True, live), hz
         return body
 
     def _quiet_loop(self, st, max_steps):

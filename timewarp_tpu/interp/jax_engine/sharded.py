@@ -267,7 +267,7 @@ class ShardedEngine(ShardedDriver, JaxEngine):
             remote_msgs=jnp.zeros((1,), jnp.int64),
             bucket_fill_peak=jnp.zeros((1,), jnp.int32))
 
-    def _count_route(self, counts, stepped=True):
+    def _count_route(self, counts, stepped=True, world_stepped=True):
         remote, fill = self._exchanged
         return super()._count_route(counts, stepped)._replace(
             remote_msgs=counts.remote_msgs + jnp.where(

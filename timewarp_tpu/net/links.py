@@ -21,18 +21,39 @@ from __future__ import annotations
 __all__ = ["LINK_GRAMMAR", "parse_link"]
 
 #: the --link grammar, named in every parse error
-LINK_GRAMMAR = ("fixed:D | uniform:LO:HI | lognormal:MEDIAN:SIGMA | "
-                "pareto:XM:ALPHA | "
+LINK_GRAMMAR = ("fixed:D | uniform:LO:HI | "
+                "lognormal:MEDIAN:SIGMA[:FLOOR[:CAP]] | "
+                "pareto:XM:ALPHA[:FLOOR[:CAP]] | "
                 "drop:P:<inner> | quantize:Q:<inner> | never  "
-                "(D/LO/HI/MEDIAN/XM/Q integer µs; P/SIGMA/ALPHA float; "
+                "(D/LO/HI/MEDIAN/XM/Q/FLOOR/CAP integer µs; "
+                "P/SIGMA/ALPHA float; FLOOR and CAP clamp a sample, "
+                "default 1 and 60000000, and FLOOR is the delay the "
+                "link promises: what --window auto takes; "
                 "never = drop probability 1, the old NeverConnected)")
 
 
+def _clamp(parts, what: str) -> dict:
+    """The optional ``[:FLOOR[:CAP]]`` tail of a float link's spec as
+    the dataclass's keywords (absent: the class's own defaults)."""
+    if not 3 <= len(parts) <= 5:
+        raise ValueError(f"{what}, then optionally FLOOR and CAP")
+    kw = dict(zip(("floor_us", "cap_us"), map(int, parts[3:])))
+    floor = kw.get("floor_us", 1)
+    if floor < 1:
+        raise ValueError(f"FLOOR must be >= 1 µs, got {floor}")
+    if "cap_us" in kw and kw["cap_us"] < floor:
+        raise ValueError(
+            f"CAP {kw['cap_us']} is under FLOOR {floor}")
+    return kw
+
+
 def parse_link(spec: str):
-    """``fixed:D`` | ``uniform:LO:HI`` | ``lognormal:MEDIAN:SIGMA`` |
-    ``pareto:XM:ALPHA`` — optionally wrapped ``drop:P:<inner>`` and/or
-    ``quantize:Q:<inner>``; ``never`` is the fully-severed link
-    (``WithDrop(.., NEVER_CONNECTED)`` ≙ the reference's
+    """``fixed:D`` | ``uniform:LO:HI`` |
+    ``lognormal:MEDIAN:SIGMA[:FLOOR[:CAP]]`` |
+    ``pareto:XM:ALPHA[:FLOOR[:CAP]]`` (the two optional integers clamp
+    a sample; left out, the classes' defaults) — optionally wrapped
+    ``drop:P:<inner>`` and/or ``quantize:Q:<inner>``; ``never`` is the
+    fully-severed link (``WithDrop(.., NEVER_CONNECTED)`` ≙ the reference's
     ``NeverConnected`` outcome). Malformed specs die with a message
     naming the grammar, never a raw IndexError/ValueError."""
     from .delays import (NEVER_CONNECTED, FixedDelay, LogNormalDelay,
@@ -66,20 +87,17 @@ def parse_link(spec: str):
                 raise ValueError("uniform takes exactly LO and HI")
             return UniformDelay(int(parts[1]), int(parts[2]))
         if kind == "lognormal":
-            if len(parts) != 3:
-                raise ValueError("lognormal takes exactly MEDIAN "
-                                 "and SIGMA")
-            return LogNormalDelay(int(parts[1]), float(parts[2]))
+            kw = _clamp(parts, "lognormal takes MEDIAN and SIGMA")
+            return LogNormalDelay(int(parts[1]), float(parts[2]), **kw)
         if kind == "pareto":
-            if len(parts) != 3:
-                raise ValueError("pareto takes exactly XM and ALPHA")
+            kw = _clamp(parts, "pareto takes XM and ALPHA")
             xm, alpha = int(parts[1]), float(parts[2])
             if xm < 1:
                 raise ValueError(f"pareto XM must be >= 1 µs, got {xm}")
             if not alpha > 0:
                 raise ValueError(
                     f"pareto ALPHA must be > 0, got {alpha}")
-            return ParetoDelay(xm, alpha)
+            return ParetoDelay(xm, alpha, **kw)
     except SystemExit:
         raise                   # an inner spec already produced the
     except (IndexError, ValueError) as e:        # grammar-named error

@@ -59,7 +59,7 @@ import numpy as np
 
 from ..sweep.journal import SweepJournal
 from ..sweep.spec import (DIGEST_ZERO, RunConfig, build_scenario,
-                          chain_digest, link_sweep_params, world_result)
+                          chain_digest, fleet_link_params, world_result)
 
 __all__ = ["OpenBucketRunner", "checkpoint_meta"]
 
@@ -208,12 +208,9 @@ class OpenBucketRunner:
         from ..interp.jax_engine.batched import BatchSpec
         cfg0 = next(m for m in self.members if m is not None)
         links = [(m or cfg0).parse_link() for m in self.members]
-        rows = [link_sweep_params(lk) for lk in links]
-        link_params = {path: np.asarray([r[path] for r in rows])
-                       for path in rows[0]} if rows[0] else None
         spec = BatchSpec(
             seeds=tuple(m.seed if m else 0 for m in self.members),
-            link_params=link_params)
+            link_params=fleet_link_params(links))
         scheds = [(m.parse_faults() or FaultSchedule(())) if m
                   else FaultSchedule(()) for m in self.members]
         pad = self._fault_pad(scheds)

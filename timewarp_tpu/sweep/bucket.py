@@ -47,7 +47,7 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 from .spec import (RunConfig, build_scenario, link_signature,
-                   link_sweep_params, resolve_window)
+                   fleet_link_params, resolve_window)
 
 __all__ = ["Bucket", "plan_buckets", "build_bucket_engine",
            "tile_world_state"]
@@ -177,11 +177,8 @@ def build_bucket_engine(bucket: Bucket, *, lint: str = "warn",
     cfgs = bucket.configs
     sc = build_scenario(cfgs[0].family, cfgs[0].params)
     links = [c.parse_link() for c in cfgs]
-    rows = [link_sweep_params(lk) for lk in links]
-    link_params = {path: np.asarray([r[path] for r in rows])
-                   for path in rows[0]} if rows[0] else None
     spec = BatchSpec(seeds=tuple(c.seed for c in cfgs),
-                     link_params=link_params)
+                     link_params=fleet_link_params(links))
     scheds = [c.parse_faults() or FaultSchedule(()) for c in cfgs]
     pad = bucket.fault_pad
     if pad is not None and tuple(pad) != (0, 0, 0):

@@ -42,6 +42,7 @@ from typing import Any, Dict, List, Optional, Tuple
 __all__ = [
     "RunConfig", "SweepPack", "SweepConfigError",
     "build_scenario", "link_signature", "link_sweep_params",
+    "fleet_link_params",
     "resolve_window", "solo_engine", "solo_result",
     "chain_digest", "DIGEST_ZERO", "world_result",
 ]
@@ -418,6 +419,32 @@ def link_sweep_params(link, prefix: str = "") -> Dict[str, Any]:
         elif f.name in sweep:
             out[prefix + f.name] = v
     return out
+
+
+def fleet_link_params(links, *, that_differ: bool = False):
+    """A fleet's ``BatchSpec.link_params`` from its worlds' links,
+    world b the b-th: each sweepable field a ``[B]`` vector by its
+    dotted path, or None where the links have no such field. The
+    links must share one :func:`link_signature` (``ValueError``
+    otherwise: a field outside the vectors cannot differ by world).
+    A bucket carries every field (one executable for whatever values
+    are admitted later); ``that_differ`` keeps those alone whose
+    values differ, which is what a study written out by hand names
+    (the CLI's ``--link`` once a world)."""
+    import numpy as np
+    sigs = [link_signature(lk) for lk in links]
+    for b, sig in enumerate(sigs):
+        if sig != sigs[0]:
+            raise ValueError(
+                f"world {b}'s link differs from world 0's in more "
+                f"than its sweepable values: {sig} against {sigs[0]} "
+                "(one batched engine runs links of one structure; "
+                f"sweepable by model: {_SWEEPABLE})")
+    rows = [link_sweep_params(lk) for lk in links]
+    params = {path: np.asarray([r[path] for r in rows])
+              for path in rows[0]
+              if not that_differ or len({r[path] for r in rows}) > 1}
+    return params or None
 
 
 def resolve_window(cfg: RunConfig) -> int:
