@@ -161,12 +161,18 @@ def test_the_adaptive_drivers_have_no_sort_scope(kw):
 #: PR 55 changed the fleet's (it was 2948d0bc4acc…): a fleet's carry
 #: holds one count more, ``world_sender_lanes`` (each world's own
 #: senders before the ``pmax`` that picks the rung); the solo
-#: driver carries nothing new and keeps its constant. A
+#: driver carries nothing new and keeps its constant. PR 56 changed
+#: both (they were f738f2c7dadc… and 1bcc81c31b5c…): the ladder's
+#: top rung (2^11 of the two here) reads the outbox where it lies
+#: and gathers no sender word; the rung below lowers to what it
+#: lowered to, and the lanes after the sort are the same words
+#: (``tests/test_top_rung_in_place_law.py``; the final states of
+#: ``tests/test_zzzzzzzzzzzzzzzrecord.py`` are unmoved). A
 #: PR that changes what these drivers compute changes the
 #: constants, and says so.
 _PARENT_LOWERING = {
-    "solo": "f738f2c7dadc59269a121ac8fd9543d788fdae22c1b5e25225eefd12a89bd798",
-    "fleet": "1bcc81c31b5ccb6a5f9b435d04af6a018bf628552c6dedeb896acac6fd9848a7",
+    "solo": "b3aae876281b43e4cb24128a1066eda165037787099a5170b238bdd533efec4f",
+    "fleet": "094e6b1b24f9f94916275f7f0c6cca6bda22aae6aa1feed9305b1cc1da6cd8ea",
 }
 
 

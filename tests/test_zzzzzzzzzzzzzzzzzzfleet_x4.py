@@ -542,17 +542,25 @@ def _edge_sharded_quiet():
 #: already; the state it computes is the parent's:
 #: ``tests/test_zzzzzzzzzzzzzzzrecord.py``); the three solo and
 #: node-sharded drivers carry nothing new and kept their constants.
+#: PR 56 re-pinned the four drivers that take the ladder
+#: (``world_sharded_quiet`` was 8a96e6cec699…, ``world_sharded_scan``
+#: 1ae8364d96e3…, ``fleet_quiet`` afabbf17cb9f…, ``solo_quiet``
+#: d68a4763b001…): the ladder's top rung reads the outbox where it
+#: lies and gathers no sender word, the rungs below lower to what
+#: they lowered to, and the lanes after the sort are the same words
+#: (``tests/test_top_rung_in_place_law.py``); the node-sharded and
+#: the edge-sharded driver take no ladder and kept their constants.
 #: A PR that changes what these drivers compute changes the
 #: constants, and says so.
 _PARENT_LOWERING = {
     "world_sharded_quiet":
-        "8a96e6cec6997cf215ecb63c64dc7353f213d51f912e919b4b3fd508dc35dfb8",
+        "41d0b00f1a1085a216a0025af98db9d5cdbd621e57d0128433fbfb18497de38a",
     "world_sharded_scan":
-        "1ae8364d96e34efa957bb9912f90f1fb3d0dfe9081257096b7a714b3acfde8f4",
+        "71cbea785631f01bb5883294a103d016309e61fdd0ea7c9862c2af26b6c3d704",
     "fleet_quiet":
-        "afabbf17cb9fe0d491a352e4e9434be0e1922dcd242f6a2c705138791b92c5e5",
+        "be61e23f5f72dffc78264d55214e2635cc434d353c0fc5cc83427fe3d7737dc3",
     "solo_quiet":
-        "d68a4763b0010823b89082a3e929a77ec8d598a69404acc41cb3cacafe4bc6b5",
+        "9c72772f5f8dfbc227c8806cdc1ab925afbaee539264bb247056e6ab363dcf35",
     "node_sharded_quiet":
         "786fb1206f5e785538452595eb8c4b170035dbfda1224494a04bbae449070c06",
     "edge_sharded_quiet":

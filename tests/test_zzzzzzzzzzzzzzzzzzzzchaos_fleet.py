@@ -311,6 +311,104 @@ def test_no_faults_no_counts_no_scope(batch):
         assert scope in text, scope
 
 
+# -- the ladder's top rung reads the outbox in place (PR 56) -----------------
+
+def _gathers(eng):
+    """Every ``gather`` of the quiet driver as it is traced for
+    lowering: ``(branch, scopes)``, ``branch`` the index of the routing
+    switch's branch it lies in (None outside it, and where the ladder
+    has one rung and no switch), ``scopes`` its name stack."""
+    traced = type(eng)._run_while.trace(
+        eng, eng.init_state(), eng._coerce_budget(8)[0], eng._identity())
+
+    def inner(eqn):
+        for v in eqn.params.values():
+            for x in v if isinstance(v, (tuple, list)) else (v,):
+                x = getattr(x, "jaxpr", x)
+                if hasattr(x, "eqns"):
+                    yield x
+
+    def walk(jaxpr, branch, outer):
+        for eqn in jaxpr.eqns:
+            # an inner program's name stacks start at its call
+            scopes = f"{outer}/{eqn.source_info.name_stack}"
+            if eqn.primitive.name == "gather":
+                yield branch, scopes
+            rungs = eqn.primitive.name == "cond" and branch is None \
+                and scopes.rstrip("/").endswith(
+                    "vmap(tw.route)" if eng.batch else "tw.route")
+            for i, sub in enumerate(inner(eqn)):
+                yield from walk(sub, i if rungs else branch, scopes)
+    return list(walk(traced.jaxpr.jaxpr, None, ""))
+
+
+def _routing(gathers, branch):
+    """The scopes of a branch's gathers that are the ladder's own:
+    under ``tw.route``, outside the insertion and the fault masks."""
+    return [s for b, s in gathers if b == branch and "tw.route" in s
+            and "insert" not in s and "fault" not in s]
+
+
+def test_the_top_rung_gathers_nothing_and_the_rungs_below_what_they_did():
+    # the chaos fleet at 4 096 nodes: rungs of 1 024, 2 048 and 4 096
+    config, _ = _config(4096)
+    p = config["params"]
+    sc, link = gossip_chaos.scenario_and_link(p)
+    eng = JaxEngine(sc, link, window="auto",
+                    faults=FaultFleet(tuple(map(parse_faults, p["faults"]))),
+                    batch=BatchSpec(seeds=tuple(range(WORLDS))))
+    rungs = eng._sender_rungs(4096)
+    assert rungs == [1024, 2048, 4096] and eng._fault_reads() == (1, True)
+    gathers = _gathers(eng)
+    assert {b for b, _ in gathers} == {None, 0, 1, 2}
+    # one outbox slot, one payload word: a rung under the top gathers
+    # the sender's offset, its destination and its payload word, as it
+    # did; the top rung reads all three where they lie
+    assert [len(_routing(gathers, b)) for b in range(3)] == [3, 3, 0]
+    # the insertion's gathers (a fleet's: the destination's hole words)
+    # are every rung's alike, the top one's too (ROADMAP B1 (e))
+    inserts = [sum(b == i and "insert" in s for b, s in gathers)
+               for i in range(3)]
+    assert inserts[0] == inserts[1] == inserts[2] > 0
+    # and the masks still look one table up, in front of the switch
+    assert [b for b, s in gathers if "fault" in s] == [None]
+    text = _lowered(eng)
+    assert "vmap(tw.route)/cond/branch_2_fun/inplace" in text
+    assert "branch_1_fun/inplace" not in text
+
+
+def test_a_solo_engine_of_512_nodes_routes_without_a_gather():
+    # one rung, and it is the top one: no switch, no sender gather
+    config, _ = _config(512)
+    sc, link = gossip_chaos.scenario_and_link(config["params"])
+    eng = JaxEngine(sc, link, window="auto")
+    assert eng._adaptive_regime() and eng._sender_rungs(512) == [512]
+    # (a solo commutative inbox stages its arrivals and fills its
+    # holes on the node lanes: no gather in its insertion either)
+    assert not [s for _, s in _gathers(eng) if "tw.route" in s]
+    assert "tw.route/inplace" in _lowered(eng)
+
+
+def test_the_record_names_the_iterations_routed_in_place():
+    # steady mongering at 2 048 nodes climbs the ladder's two rungs
+    config, _ = _config(2048)
+    sc, link = gossip_chaos.scenario_and_link(config["params"])
+    eng = JaxEngine(sc, link, window="auto")
+    eng.run_quiet(1 << 20)
+    stats = eng.last_run_stats
+    low, top = stats["rung_steps"]
+    assert low > 0 and top > 0 and stats["inplace_rung_steps"] == top
+    # summed over a chunked run's calls, and a run_summary line carries it
+    merged = eng._stats_merge([stats, stats])
+    assert merged["inplace_rung_steps"] == 2 * top
+    from timewarp_tpu.obs.metrics import METRICS_SCHEMA, validate_line
+    validate_line({"schema": METRICS_SCHEMA, "kind": "run_summary",
+                   "label": "ladder",
+                   **{k: merged[k] for k in (
+                       "supersteps", "wall_seconds", "compiles",
+                       "inplace_rung_steps")}})
+
+
 # -- the CLI ------------------------------------------------------------------
 
 _CLI = ["gossip", "--nodes", "64", "--steady", "--fanout", "1", "--batch",

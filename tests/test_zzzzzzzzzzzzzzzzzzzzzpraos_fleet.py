@@ -333,9 +333,10 @@ def test_a_malformed_floor_or_cap_names_the_grammar(bad, said):
 # -- a solo engine carries nothing new ----------------------------------------
 
 #: tests/test_praos_lowering.py's constant: the quiet driver of
-#: ``praos_1m.slots``' engine at 2^11 nodes, two slots, as PR 48 left it
+#: ``praos_1m.slots``' engine at 2^11 nodes, two slots, as PR 56 left it
+#: (the top rung reads the outbox in place; 2655443c024a… from PR 48 on)
 _SLOTS_LOWERING = \
-    "2655443c024af8df6c2ff4e37d250739899ef70d616dd1f17b818d37c193f286"
+    "756f016e125d243e511de9d42383762c71472edfb5c5bd4404590cf127330543"
 
 
 def test_a_solo_engine_lowers_to_the_text_it_had():

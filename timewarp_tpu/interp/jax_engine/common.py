@@ -346,6 +346,12 @@ class _DriverCall:
                          rung_steps=by_rung.tolist(),
                          dense_stage_steps=int(dense),
                          wide_tail_steps=int(wide))
+            if self.eng._adaptive_regime():
+                # the ladder's top rung is the node axis itself and
+                # reads the outbox where it lies (`_route_adaptive`
+                # `gather`, scope `inplace`): the last bin by its own
+                # name, no count of its own in the loop's carry
+                stats.update(inplace_rung_steps=int(by_rung[-1]))
             if fan_in is not None:
                 # a fleet's: its worlds' largest
                 stats.update(fan_in_peak=int(np.max(fan_in)))
@@ -409,6 +415,16 @@ class RunStatsMixin:
          "dense_stage_steps": int,  # iterations that staged their
                                     # arrivals in the dense form
          "wide_tail_steps": int}    # of those, with a full-width tail
+
+    for one whose routing takes the ladder (``_adaptive_regime``)::
+
+        {"inplace_rung_steps": int}  # iterations routed on the top
+                                     # rung, whose width is the node
+                                     # axis: the outbox read in place,
+                                     # no sender gather (PR 56). It is
+                                     # ``rung_steps[-1]``, and every
+                                     # iteration where the ladder has
+                                     # one rung (n_nodes <= 1024)
 
     for a general engine whose scenario keeps the default ordered
     inbox (``commutative_inbox=False``: insertion ranks the arrivals
@@ -649,8 +665,8 @@ class RunStatsMixin:
             if chunks and all(key in c for c in chunks):
                 self.last_run_stats[key] = chunks[0][key]
         for key in ("rung_lanes", "sender_lanes", "fleet_iterations",
-                    "rung_steps", "world_supersteps",
-                    "world_sender_lanes",
+                    "rung_steps", "inplace_rung_steps",
+                    "world_supersteps", "world_sender_lanes",
                     *_FAULT_COUNTS, *("world_" + k for k in _FAULT_COUNTS[:5]),
                     "dense_stage_steps", "wide_tail_steps",
                     "scatter_lanes", "dense_lanes", "tail_lanes",

@@ -29,7 +29,9 @@ epoch (``EngineState.time``), inbox ordering and mailbox compaction are
 single variadic ``lax.sort`` calls instead of lexsort+gather chains,
 the ladder's sender compaction is a prefix count and a log N shift
 network on the node lanes and no sort at all (PR 48;
-ops/numeric.py ``compress_lanes``), and trace digests exist only in
+ops/numeric.py ``compress_lanes``), the ladder's top rung, whose width
+is the node axis, reads the outbox where it lies and gathers nothing
+(PR 56), and trace digests exist only in
 the traced driver (``run``) — the ``run_quiet`` benchmark path
 compiles them out.
 """
@@ -393,8 +395,11 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
     count, so insertion cost tracks instantaneous load instead of the
     workload's peak. The top rung is always n — no message can ever be
     dropped (``route_drop`` stays 0 by construction), so no capacity
-    knob needs hand-tuning. Event semantics, arrival order (contract
-    #3) and digests are identical to the eager path.
+    knob needs hand-tuning — and being the node axis itself it gathers
+    nothing: the outbox planes are its lanes where they lie (the sort's
+    last key, a message's own source and slot, makes the order the
+    lanes come in immaterial; PR 56). Event semantics, arrival order
+    (contract #3) and digests are identical to the eager path.
 
     Mailbox insertion has one form, ``_insert_sorted``, held to the
     oracle by tests/test_insert_law.py (docs/engines.md "Mailbox
@@ -829,9 +834,11 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
     def _sender_rungs(n: int):
         """Geometric x2 ladder of static sender-count widths for the
         adaptive routing switch: 1024, 2048, …, n. The top rung is
-        always n, so the adaptive path can never drop a message; the
-        x2 spacing bounds gather/scatter overshoot at 2x the active
-        count (the branch cost is linear in the rung)."""
+        always n, so the adaptive path can never drop a message (and
+        being the node axis itself it reads the outbox in place,
+        ``_route_adaptive`` ``gather``); the x2 spacing bounds
+        gather/scatter overshoot at 2x the active count (the branch
+        cost is linear in the rung)."""
         rungs = []
         a = 1024
         while a < n:
@@ -1367,7 +1374,11 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         then gather/sort/sample/rank/scatter at the smallest
         ladder rung that fits this superstep's active-sender count
         (``lax.switch`` — every branch is static-shape, so this is
-        XLA-legal). All ``max_out`` lanes of a sender share its firing
+        XLA-legal). The top rung (``A == n``; the only one of an
+        engine of at most 1 024 nodes) gathers nothing: its lanes are
+        the outbox planes where they lie, scope ``tw.route/inplace``
+        (PR 56; ``last_run_stats["inplace_rung_steps"]``). All
+        ``max_out`` lanes of a sender share its firing
         instant, so per-sender compaction preserves contract #3's
         (window offset, sender-major rank) arrival order exactly.
         Single-chip, no-drop links only; counters and digests match
@@ -1480,6 +1491,25 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
 
         def tail(A):
             def gather(A):
+                if A == n:
+                    # the top rung's width is the node axis itself:
+                    # `sid_sorted[:n]` would only permute what lies on
+                    # the node lanes already (dead senders last, as
+                    # copies of node 0 that `ok` masks), in front of a
+                    # sort whose last key, `smrank`, is unique on every
+                    # valid lane. Read the outbox in place: the lanes
+                    # are the nodes, a dead sender's hold no valid
+                    # destination, and no word is gathered
+                    with jax.named_scope("inplace"):
+                        SA = n * M
+                        dst_f = pdst.reshape(SA)
+                        smrank = (jnp.arange(n, dtype=jnp.int32)[None, :]
+                                  * jnp.int32(M)
+                                  + jnp.arange(M, dtype=jnp.int32)[:, None]
+                                  ).reshape(SA)
+                        pay_f = tuple(out.payload[:, p, :].reshape(SA)
+                                      for p in range(P))
+                        return SA, woff_n, dst_f, dst_f >= 0, smrank, pay_f
                 sids = jax.lax.slice_in_dim(sid_sorted, 0, A)
                 real = sids < n
                 sidc = jnp.where(real, sids, 0)  # safe gather index

@@ -19,7 +19,9 @@ Kinds:
   ``last_run_stats`` (supersteps, wall seconds, driver compiles; what
   the call launched and read back; the routing stage's counts where
   the engine keeps them: ``rung_lanes``, ``sender_lanes``,
-  ``rung_steps``, ``dense_stage_steps``, ``wide_tail_steps``, a
+  ``rung_steps``, ``inplace_rung_steps`` (a ladder's iterations on its
+  top rung, the outbox read in place), ``dense_stage_steps``,
+  ``wide_tail_steps``, a
   fleet's ``fleet_iterations``, an ordered inbox's ``fan_in_peak`` and,
   solo on one device, its ``scatter_lanes``, a staged insertion's
   ``dense_lanes``, ``tail_lanes``, ``net_rows``, a mesh's ``shards``
@@ -85,6 +87,7 @@ _NUM = (int, float)
 #: where the driver call counted them (common.py ``RunStatsMixin``)
 _RUN_COUNTS = ("cache_misses", "dispatches", "readbacks", "rung_lanes",
                "sender_lanes",
+               "inplace_rung_steps",
                "fleet_iterations", "dense_stage_steps", "wide_tail_steps",
                "fan_in_peak", "scatter_lanes", "dense_lanes", "tail_lanes",
                "net_rows", "shards", "worlds_local", "remote_msgs",
