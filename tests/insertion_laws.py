@@ -1,11 +1,11 @@
 """What the mailbox-insertion laws share: no test lives here.
 
 ``_insert_sorted`` (engine.py) is the one insertion form, reached from
-three call sites of the superstep's routing stage. The laws run the
-engine and the host oracle side by side and compare the *state* at two
-horizons (every node's scenario state and wake time, the clock, the
-mailbox contents message by message, the never-silent counters) and the
-trace over both. The state comparison is what the trace laws elsewhere
+the two call sites of the superstep's routing stage (the ladder's rungs
+and the eager path). The laws run the engine and the host oracle side
+by side and compare the *state* at two horizons (every node's scenario
+state and wake time, the clock, the mailbox contents message by
+message, the never-silent counters) and the trace over both. The state comparison is what the trace laws elsewhere
 do not make: a message in the wrong slot, or a payload word scattered
 to a neighbour, shows in the mailbox one superstep before it shows in
 a digest.
@@ -20,9 +20,8 @@ builders of lanes for one call of the insertion or the staging.
 
 The laws, a file a section:
 
-- tests/test_insert_oracle_adaptive.py, ``_eager.py``, ``_lazy.py``:
-  call site x inbox x mailbox x n against the oracle, a file a call
-  site;
+- tests/test_insert_oracle_adaptive.py, ``_eager.py``: call site x
+  inbox x mailbox x n against the oracle, a file a call site;
 - tests/test_insert_slot_law.py, ``_slot_both_stagings.py``,
   ``_slot_on_lanes.py``: the slot itself, against the parent's
   program and on built lanes;
@@ -36,9 +35,9 @@ The laws, a file a section:
 
 Left out as covered: small-n trace parity of the token ring, ping-pong
 and invalid destinations (test_parity.py); windowed against classic
-semantics, ``route_cap`` over the load and the sharded forms
-(test_windowed.py); mixed fault schedules on the solo engines and the
-fleet-slice-against-solo-*engine* law (test_zfault_parity.py,
+semantics and the sharded forms (test_windowed.py); mixed fault
+schedules on the solo engines and the fleet-slice-against-solo-*engine*
+law (test_zfault_parity.py,
 test_world_batch.py, whose reference is another engine configuration,
 never the oracle at these widths).
 """
@@ -222,8 +221,6 @@ INBOX = {
 SITE = {
     "adaptive": (lambda link: link, lambda sc: {}, True),
     "eager": (lambda link: WithDrop(link, 0.1), lambda sc: {}, False),
-    "lazy": (lambda link: link,
-             lambda sc: {"route_cap": sc.n_nodes * sc.max_out}, False),
 }
 
 
@@ -235,8 +232,7 @@ SITE = {
 # form (PR 29; they are at 193bc01), walked against the oracle instead:
 #
 # - call site: ``adaptive`` (windowed, drop-free link: the ladder's
-#   tail), ``eager`` (a ``WithDrop`` link), ``lazy`` (``route_cap`` above
-#   the load);
+#   tail), ``eager`` (a ``WithDrop`` link);
 # - inbox: commutative (the gossip burst: the r-th message takes the
 #   destination's r-th hole; and the same at two words of holes, below)
 #   and ordered (the observer token ring,
@@ -253,8 +249,8 @@ SITE = {
 # words, fitting and not (``INBOX`` has the sizes).
 
 def insertion_equals_oracle(site, inbox, mailbox, n):
-    """One case of the matrix (tests/test_insert_oracle_adaptive.py,
-    ``_eager.py`` and ``_lazy.py``: a file a call site)."""
+    """One case of the matrix (tests/test_insert_oracle_adaptive.py
+    and ``_eager.py``: a file a call site)."""
     make, link, fits, small = INBOX[inbox]
     sc = make(n, fits if mailbox == "fits" else small)
     assert sc.commutative_inbox == inbox.startswith("commutative")
@@ -311,8 +307,8 @@ def parent_insert_sorted(self, mb_rel, mb_src, mb_payload, sd, ok_s,
 
 
 class ParentInsert(JaxEngine):
-    """The engine with the parent's insertion at all three call sites:
-    the eager and the lazy path call ``_insert_sorted``; a ladder rung
+    """The engine with the parent's insertion at both call sites:
+    the eager path calls ``_insert_sorted``; a ladder rung
     calls ``_stage_by_rank`` and the nodes ``_fill_staged`` after the
     switch, so here the rung inserts into the mailbox ``_route_adaptive``
     was handed and the fill passes that through."""

@@ -88,10 +88,9 @@ def _ring_faults():
     ))
 
 
-#: (scenario, link, constructor keywords, fault schedule or None) in
-#: the three routing regimes, for a commutative and an ordered inbox,
-#: at ``window`` 1 and ``"auto"``. ``route_cap`` (the lazy regime)
-#: takes no fault schedule (``JaxEngine.__init__`` refuses the pair).
+#: (scenario, link, constructor keywords) in the two routing regimes,
+#: for a commutative and an ordered inbox, at ``window`` 1 and
+#: ``"auto"``.
 CASES = {
     "eager-commutative-w1": (lambda: _gossip(False), UNI, {}),
     "eager-ordered-w1": (_ring, token_ring_links(16), {}),
@@ -102,10 +101,6 @@ CASES = {
                                   {"window": "auto"}),
     "adaptive-ordered-auto": (_ring, UniformDelay(1000, 5000),
                               {"window": "auto"}),
-    "lazy-commutative-w1": (lambda: _gossip(True), UNI,
-                            {"route_cap": 4 * N}),
-    "lazy-ordered-auto": (_ring, UniformDelay(1000, 5000),
-                          {"window": "auto", "route_cap": 64}),
     # the edge engines (per-edge queues, no ladder, window 1): on one
     # device, and node-sharded over the mesh of eight, which takes no
     # fault schedule
@@ -131,9 +126,7 @@ def _case(name, faulted):
 def _regime(eng):
     if isinstance(eng, EdgeEngine):
         return "edge"
-    if eng._adaptive_regime():
-        return "adaptive"
-    return "lazy" if eng.route_cap is not None else "eager"
+    return "adaptive" if eng._adaptive_regime() else "eager"
 
 
 def _same(a, b, tag):
@@ -196,7 +189,7 @@ def law_cases(*regimes):
     return pytest.mark.parametrize("name, faulted", [
         (name, faulted) for name in sorted(CASES) for faulted in (False, True)
         if name.split("-")[0] in regimes
-        and not (faulted and ("lazy" in name or "mesh8" in name))],
+        and not (faulted and "mesh8" in name)],
         ids=lambda v: v if isinstance(v, str) else ("unfaulted", "faulted")[v])
 
 

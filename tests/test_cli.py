@@ -47,9 +47,7 @@ def test_cli_windowed_burst_oracle_engine_agree(capsys):
               "--steps", "300", "--end-us", "300000"]
     rows = {
         "oracle": run_cli(capsys, *common, "--engine", "oracle"),
-        # route_cap is a general-engine knob (the oracle CLI rejects it)
-        "general": run_cli(capsys, *common, "--engine", "general",
-                           "--route-cap", "192"),
+        "general": run_cli(capsys, *common, "--engine", "general"),
     }
     assert rows["oracle"]["delivered"] == rows["general"]["delivered"]
     assert rows["oracle"]["supersteps"] == rows["general"]["supersteps"]
@@ -61,8 +59,6 @@ def test_cli_rejects_ignored_knobs():
     from timewarp_tpu.cli import main
     with pytest.raises(SystemExit, match="general engines only"):
         main(["token-ring", "--engine", "edge", "--window", "3000"])
-    with pytest.raises(SystemExit, match="general engines only"):
-        main(["token-ring", "--engine", "oracle", "--route-cap", "8"])
 
 
 def test_cli_sharded_engines(capsys):

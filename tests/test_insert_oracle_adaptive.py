@@ -1,10 +1,9 @@
 """The mailbox-insertion law, the matrix at the adaptive call site:
 ``JaxEngine`` against ``SuperstepOracle`` (tests/insertion_laws.py has
 the view both are held to, the matrix and the case's body). A case is
-an engine of its own, and a file is one worker's: the other two call
-sites are tests/test_insert_oracle_eager.py and ``_lazy.py``, and
-each site's inbox of two hole words, the costliest to compile, is its
-``_two_words.py``.
+an engine of its own, and a file is one worker's: the other call
+site is tests/test_insert_oracle_eager.py, and each site's inbox
+of two hole words, the costliest to compile, is its ``_two_words.py``.
 """
 
 import pytest

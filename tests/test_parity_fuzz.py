@@ -118,7 +118,7 @@ def _scenario_parity(data):
 def test_randomized_windowed_parity(examples, derandomize):
     """The windowed path under randomized timers/links: engine ≡
     windowed oracle bit-for-bit for any window ≤ the link's declared
-    delay floor, with and without a route_cap."""
+    delay floor."""
     _hold(_windowed_parity, examples, derandomize)
 
 
@@ -134,17 +134,11 @@ def _windowed_parity(data):
     W = int(data.draw(st.sampled_from([2, 3])) ) * 1_000
     W = min(W, link.min_delay_us)
     seed = int(data.draw(st.integers(0, 1000)))
-    cap = data.draw(st.sampled_from([None, N]))  # N < S: slicing active
 
     sc = _rand_scenario(periods, rng.integers(0, N, N), 25_000,
                         commutative)
     ot = SuperstepOracle(sc, link, seed=seed, window=W).run(4_000)
-    st_, gt = JaxEngine(sc, link, seed=seed, window=W,
-                        route_cap=cap).run(160)
+    st_, gt = JaxEngine(sc, link, seed=seed, window=W).run(160)
     assert_traces_equal(ot, gt, "windowed-oracle", "windowed-general",
                         limit=len(gt))
     assert int(st_.short_delay) == 0
-    if cap is not None:
-        # cap == N ≥ the per-superstep active count (each node sends
-        # at most 1 message per firing), so slicing must be a no-op
-        assert int(st_.route_drop) == 0

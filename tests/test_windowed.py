@@ -146,24 +146,8 @@ def test_short_delay_counter_catches_lying_link():
     assert oracle.short_delay_total > 0
 
 
-def test_route_cap_exact_when_under_and_counted_when_over():
-    """A generous route_cap changes nothing (bit-for-bit trace); an
-    undersized one drops messages but counts every drop."""
-    sc = _gossip_sparse(64)
-    otrace = SuperstepOracle(sc, LINK, window=W).run(600)
-    # generous: S = 64*4 = 256, cap 256 -> no-op by construction
-    state, etrace = JaxEngine(sc, LINK, window=W, route_cap=256).run(600)
-    assert_traces_equal(otrace, etrace)
-    assert int(state.route_drop) == 0
-    # undersized: some supersteps route more than 8 messages
-    tight = JaxEngine(sc, LINK, window=W, route_cap=8)
-    st = tight.run_quiet(600)
-    assert int(st.route_drop) > 0
-    assert int(st.delivered) < otrace.total_delivered()
-
-
 def test_stake_weighted_burst_praos_windowed_parity():
-    """Stake weighting composes with burst + window + route_cap: whales
+    """Stake weighting composes with burst + window: whales
     mint, zero-stake nodes never do, and the trace stays bit-exact."""
     n = 48
     stake = np.zeros(n, np.int64)
@@ -172,7 +156,7 @@ def test_stake_weighted_burst_praos_windowed_parity():
                stake=stake, fanout=4, burst=True, mailbox_cap=16)
     oracle = SuperstepOracle(sc, LINK, window=W)
     otrace = oracle.run(600)
-    engine = JaxEngine(sc, LINK, window=W, route_cap=96)
+    engine = JaxEngine(sc, LINK, window=W)
     state, etrace = engine.run(600)
     assert_traces_equal(otrace, etrace)
     assert otrace.total_delivered() > 0
@@ -185,20 +169,6 @@ def test_stake_weighted_burst_praos_windowed_parity():
     st0 = JaxEngine(sc0, LINK, window=W).run_quiet(600)
     assert int(st0.delivered) == 0
     assert int(np.asarray(st0.states["best"]).max()) == 0
-
-
-def test_sharded_route_cap_with_dropfree_link_stays_exact():
-    """Regression: the single-chip lazy-sampling fast path (route_cap +
-    drop-free link) must NOT engage on the sharded engine (MeshComm
-    subclasses LocalComm — a naive isinstance guard would skip the
-    all_to_all exchange and misroute every cross-shard message)."""
-    sc = _gossip_sparse(64)
-    mesh = make_mesh(8)
-    sharded = ShardedEngine(sc, LINK, mesh, window=W, route_cap=256)
-    st, strace = sharded.run(400)
-    otrace = SuperstepOracle(sc, LINK, window=W).run(400)
-    assert_traces_equal(otrace, strace)
-    assert int(st.route_drop) == 0
 
 
 @pytest.mark.parametrize("mesh_spec", [

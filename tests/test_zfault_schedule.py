@@ -184,8 +184,6 @@ def test_engine_fault_guards():
     sc = _sc()
     link = FixedDelay(500)
     sched = FaultSchedule((NodeCrash(1, 0, 10),))
-    with pytest.raises(ValueError, match="route_cap"):
-        JaxEngine(sc, link, faults=sched, route_cap=64)
     with pytest.raises(ValueError, match="FaultSchedule"):
         JaxEngine(sc, link, faults="crash:1:0:10")
     with pytest.raises(ValueError, match="batch=BatchSpec"):

@@ -21,7 +21,8 @@ from timewarp_tpu.interp.jax_engine.engine import JaxEngine
 from timewarp_tpu.interp.jax_engine.fused_ring import FusedRingEngine
 from timewarp_tpu.models.gossip import gossip
 from timewarp_tpu.models.token_ring import token_ring
-from timewarp_tpu.net.delays import FixedDelay, Quantize, UniformDelay
+from timewarp_tpu.net.delays import (FixedDelay, Quantize, UniformDelay,
+                                     WithDrop)
 from timewarp_tpu.obs.profiler import profile_session, span
 
 
@@ -57,17 +58,20 @@ def _scopes(names) -> set:
     return out
 
 
-# the two routing regimes of JaxEngine._superstep, and what marks each
+# the two routing regimes of JaxEngine._superstep: what marks each (a
+# link that can drop is the eager regime's, as tests/insertion_laws.py
+# ``SITE`` has it) and the nested scope that only it has
 REGIMES = {
-    "adaptive": dict(window="auto"),
-    "dense": dict(window=1, route_cap=64),
+    "adaptive": (lambda link: link, "tw.route/sample"),
+    "dense": (lambda link: WithDrop(link, 0.1), "tw.route/sort"),
 }
 
 
 @pytest.mark.parametrize("regime", sorted(REGIMES))
 def test_every_stage_is_a_scope_of_the_general_driver(regime):
     sc, link = _gossip(64)
-    eng = JaxEngine(sc, link, lint="off", **REGIMES[regime])
+    relink, its_own = REGIMES[regime]
+    eng = JaxEngine(sc, relink(link), lint="off", window="auto")
     assert eng._adaptive_regime() == (regime != "dense")
     names = _op_names(eng, eng.init_state(), jnp.int64(8), eng._identity())
     scopes = _scopes(names)
@@ -79,8 +83,9 @@ def test_every_stage_is_a_scope_of_the_general_driver(regime):
     assert any("/body/tw.next_event" in n for n in names)
     assert not [n for n in names if "/cond/" in n and "tw.next_event" in n]
     # the parts that have a function of their own are nested scopes
-    assert "tw.route/sample" in scopes
-    assert "tw.route/insert" in scopes
+    assert {"tw.route/insert", its_own} <= scopes
+    the_others, = {mark for _, mark in REGIMES.values()} - {its_own}
+    assert the_others not in scopes
     # a stage is never opened inside another
     assert not [s for s in scopes if s.count("tw.") > 1]
 

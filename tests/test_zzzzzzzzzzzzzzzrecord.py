@@ -146,14 +146,19 @@ def test_the_record_is_bounded():
 def test_the_record_costs_microseconds_a_call():
     """Host-only: three spans and a record, as a driver call opens
     them. Generous (a loaded test host); PERF.md has the measured
-    number."""
-    t0 = time.perf_counter()
-    for _ in range(200):
+    number. One call is made before the clock starts: a process's
+    first pays a lazy import (it read 1.69 ms a call once: ROADMAP
+    D27)."""
+    def a_call():
         with profiler.call("tw.test.call") as rec:
             with span("tw.dispatch", run=rec["run"]):
                 pass
             with span("tw.wait", run=rec["run"]):
                 pass
+    a_call()
+    t0 = time.perf_counter()
+    for _ in range(200):
+        a_call()
     assert (time.perf_counter() - t0) / 200 < 500e-6
 
 

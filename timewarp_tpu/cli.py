@@ -280,11 +280,6 @@ def build_engine(args, sc, link, link_params=None):
         raise SystemExit(
             f"--window applies to the general engines only; "
             f"{args.engine} runs classic supersteps")
-    if (args.engine not in ("general", "sharded", "sharded-batched")
-            and args.route_cap is not None):
-        raise SystemExit(
-            f"--route-cap applies to the XLA general engines only; "
-            f"{args.engine} has no XLA insertion stage to bound")
     if args.engine != "sharded" and args.bucket_cap is not None:
         raise SystemExit(
             f"--bucket-cap applies to the node-sharded general engine "
@@ -300,7 +295,6 @@ def build_engine(args, sc, link, link_params=None):
         try:
             return JaxEngine(sc, link, seed=args.seed,
                              window=args.window,
-                             route_cap=args.route_cap,
                              record_events=args.record_events,
                              lint=args.lint, batch=batch,
                              faults=faults,
@@ -323,8 +317,7 @@ def build_engine(args, sc, link, link_params=None):
             return ShardedBatchedEngine(
                 sc, link, make_mesh(args.devices, axis="worlds"),
                 batch=batch, seed=args.seed, window=args.window,
-                route_cap=args.route_cap, lint=args.lint,
-                faults=faults, telemetry=telemetry,
+                lint=args.lint, faults=faults, telemetry=telemetry,
                 controller=controller, verify=verify, record=record,
                 record_cap=record_cap, speculate=speculate)
         except ValueError as e:
@@ -353,7 +346,6 @@ def build_engine(args, sc, link, link_params=None):
         return ShardedEngine(sc, link, mesh, seed=args.seed,
                              bucket_cap=args.bucket_cap,
                              window=args.window,
-                             route_cap=args.route_cap,
                              lint=args.lint, telemetry=telemetry,
                              verify=verify)
     raise SystemExit(f"unknown engine {args.engine!r}")
@@ -746,9 +738,6 @@ def main(argv=None) -> int:
                         "'auto' to use the link model's declared "
                         "minimum delay (requires link min delay >= "
                         "window)")
-    p.add_argument("--route-cap", type=int, default=None,
-                   help="static active-message budget for the insertion "
-                        "stage (clipped messages are counted)")
     p.add_argument("--bucket-cap", type=int, default=None,
                    help="--engine sharded: lanes of one (source shard, "
                         "destination shard) bucket of the all_to_all "

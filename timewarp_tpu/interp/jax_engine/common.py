@@ -28,13 +28,13 @@ __all__ = ["LocalComm", "StepOut", "I32MAX", "group_rank", "u32sum",
 #: ``fused_ring.py``; the ring's kernel sits under ``tw.ring_kernel``).
 #: A scope is metadata: it adds no equation, and a profile shows it in
 #: each operation's ``op_name`` (benchmark/span_reduce.py ``stage_ns``).
-#: Nested in ``tw.route`` (``engine.py``): ``sample`` (the no-drop
-#: paths' link draw), ``exchange`` (in the node-sharded general
+#: Nested in ``tw.route`` (``engine.py``): ``sample`` (the adaptive
+#: ladder's link draw), ``exchange`` (in the node-sharded general
 #: engine's, sharded.py, ``bucket``: the sort by destination shard,
 #: the ranks, the scatters into a bucket a shard; and ``swap``: the
-#: ``all_to_all``s), ``sort`` (the eager and the lazy
-#: regime's one variadic sort by destination; the adaptive ladder's
-#: sorts are the stage's own) and ``insert``; nested in ``tw.deliver``:
+#: ``all_to_all``s), ``sort`` (the eager regime's one variadic sort
+#: by destination; the adaptive ladder's sorts are the stage's own)
+#: and ``insert``; nested in ``tw.deliver``:
 #: ``sort`` (an ordered inbox's variadic sort along the mailbox's
 #: slots, due time then arrival slot), and in ``tw.rebase``:
 #: ``compact`` (an ordered inbox's second one, which closes the gaps

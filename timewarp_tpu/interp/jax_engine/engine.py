@@ -193,9 +193,9 @@ class EngineState(NamedTuple):
     #: int32[] — delays < the superstep window (would violate the
     #: windowed-execution causality precondition; see JaxEngine.window)
     short_delay: jax.Array
-    #: int32[] — routed messages beyond ``route_cap`` dropped at the
-    #: insertion stage (an engine capacity limit, not a semantic one —
-    #: a parity run must keep this 0; see JaxEngine.route_cap)
+    #: int32[] — zero on every path since PR 57 took the capped
+    #: insertion stage away (neither routing regime can drop); the leaf
+    #: stays for the carries and checkpoints that hold it (ROADMAP D28)
     route_drop: jax.Array
     delivered: jax.Array   # int64[] — total delivered messages
     steps: jax.Array       # int64[] — supersteps executed
@@ -361,26 +361,22 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
     gossip waves — SURVEY.md §5.7 time-bucketed batching) gain up to
     window/grid × messages per superstep at the same superstep cost.
 
-    Two throughput knobs for wide-outbox scenarios (burst diffusion —
+    The throughput knob for wide-outbox scenarios (burst diffusion —
     ``max_out`` ≥ 8 makes the S = N·max_out routing arrays dominate):
+    ``commutative_inbox`` scenarios skip the contract-#2 inbox sort
+    entirely (the step reduces over the inbox commutatively, so slot
+    order is unobservable; digests are order-independent) — the same
+    waiver the edge engine already exercises.
 
-    - ``commutative_inbox`` scenarios skip the contract-#2 inbox sort
-      entirely (the step reduces over the inbox commutatively, so slot
-      order is unobservable; digests are order-independent) — the same
-      waiver the edge engine already exercises;
-    - ``route_cap`` statically bounds the insertion stage: after the
-      routing sort (valid messages first), only the first ``route_cap``
-      entries are ranked/scattered. Exact whenever the per-superstep
-      active message count stays under the cap; beyond it messages are
-      dropped and counted in ``EngineState.route_drop`` (an engine
-      capacity limit the oracle does not model — a parity run must
-      keep the counter 0, like ``short_delay``).
-
-    Adaptive sender-compacted routing (round 5, the default sparse
-    path): when ``route_cap`` is None, the link cannot drop, the engine
-    is single-chip, and the workload is windowed or wide-outbox
-    (``window > 1 or max_out > 1``), routing never touches the
-    S = N·max_out flattened arrays. All ``max_out`` lanes of a sender
+    Routing has two regimes, picked from what the engine sees
+    (``_adaptive_regime``) and by no option. The eager one samples and
+    sorts all S = N·max_out outbox lanes: a link that can drop, a mesh,
+    and ``window == 1 and max_out == 1`` (S = N: nothing to compact).
+    Adaptive sender-compacted routing (round 5) is every other
+    engine's: when the link cannot drop, the engine is single-chip, and
+    the workload is windowed or wide-outbox (``window > 1 or
+    max_out > 1``), routing never touches the S = N·max_out flattened
+    arrays. All ``max_out`` lanes of a sender
     share ``(src, send instant)``, so the engine compacts *senders*
     (the active node ids put in front in ascending order by a prefix
     count and a log N shift network on the node lanes, ops/numeric.py
@@ -394,16 +390,18 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
     variant that fits this superstep's device-computed active-sender
     count, so insertion cost tracks instantaneous load instead of the
     workload's peak. The top rung is always n — no message can ever be
-    dropped (``route_drop`` stays 0 by construction), so no capacity
-    knob needs hand-tuning — and being the node axis itself it gathers
+    dropped, and there is no capacity to tune (the capped third regime
+    and its keyword went in PR 57; ``EngineState.route_drop`` is 0 on
+    every path) — and being the node axis itself it gathers
     nothing: the outbox planes are its lanes where they lie (the sort's
     last key, a message's own source and slot, makes the order the
     lanes come in immaterial; PR 56). Event semantics, arrival order
     (contract #3) and digests are identical to the eager path.
 
-    Mailbox insertion has one form, ``_insert_sorted``, held to the
-    oracle by tests/test_insert_law.py (docs/engines.md "Mailbox
-    insertion"). A commutative inbox's free slots are bit words
+    Mailbox insertion has one form, ``_insert_sorted``, called by
+    both regimes and held to the oracle in each by
+    tests/insertion_laws.py (docs/engines.md "Mailbox insertion"). A
+    commutative inbox's free slots are bit words
     (``ceil(mailbox_cap / 32)`` uint32 a node, built in
     ``tw.rebase``). A solo engine stages its arrivals by their rank
     at the destination in buffers of their own (one flat 1D scatter a
@@ -434,8 +432,9 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
     for all of them: the smallest that holds the busiest world's
     senders (``_route_adaptive``; ``last_run_stats["rung_lanes"]``
     sums the rungs taken, solo and fleet alike). On a v5e eight gossip
-    worlds deliver 0.95 of one solo wave's rate together
-    (docs/engines.md "Multi-world batching"; PERF.md, Findings PR 28). ``record_events`` is
+    worlds deliver 0.74 of one solo wave's rate together ([ledger,
+    PR 56]: 9.47 against 12.78×10⁶ msg/s; docs/engines.md
+    "Multi-world batching"). ``record_events`` is
     solo-only (the ring decoder is
     a single-run debug artifact — record world b's events by running
     it solo, which is bit-identical by the law above).
@@ -473,7 +472,6 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
 
     def __init__(self, scenario: Scenario, link: LinkModel, *,
                  seed: int = 0, window=1,
-                 route_cap: Optional[int] = None,
                  record_events: int = 0,
                  lint: str = "warn",
                  batch: Optional[BatchSpec] = None,
@@ -569,12 +567,6 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
                 "insertion form; the other strategies were removed in "
                 "PR 29 (the kernels are at 193bc01)")
         if self._faulted:
-            if route_cap is not None:
-                raise ValueError(
-                    "faults and route_cap cannot combine: the capped "
-                    "lazy-sampling path slices before delays (and so "
-                    "before down-window drops) exist — run the fault "
-                    "study uncapped (adaptive routing never drops)")
             # a shrink-degradation window can undercut the link's
             # declared floor: windowed validation (and "auto") must
             # use the degraded worst case, never silently reorder.
@@ -668,11 +660,8 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
                 window = _I32MAX - 1
         if window >= _I32MAX:
             raise ValueError("window must fit int32")
-        if route_cap is not None and route_cap < 1:
-            raise ValueError(f"route_cap must be >= 1, got {route_cap}")
         # (self.scenario / self.link were assigned before _setup_faults)
         self.window = int(window)
-        self.route_cap = None if route_cap is None else int(route_cap)
         #: event-ring capacity (0 = recording off): with it on, every
         #: superstep appends per-event (time, kind, node, src,
         #: payload) records on-device — the engine-side mirror of
@@ -821,12 +810,12 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
 
     def _adaptive_regime(self) -> bool:
         """Whether routing takes the adaptive sender-compacted path
-        (class docstring) — the ONE predicate shared by _superstep's
-        routing dispatch and the dispatch controller's rung ladder
-        (dispatch/controller.py ``begin``). Evaluated per call because
-        the sharded subclasses replace ``comm`` after construction."""
-        return (self.route_cap is None
-                and not self.link.can_drop
+        and not the eager one: a function of the link, the comm, the
+        window and ``max_out`` alone. The ONE predicate shared by
+        _superstep's routing dispatch and the dispatch controller's
+        rung ladder (dispatch/controller.py ``begin``). Evaluated per
+        call: the sharded subclasses replace ``comm`` once built."""
+        return (not self.link.can_drop
                 and type(self.comm) is LocalComm
                 and (self.window > 1 or self.scenario.max_out > 1))
 
@@ -849,12 +838,13 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
 
     @jax.named_scope("sample")
     def _sample_nodrop(self, src, dst, tmsg, slot, woff, ok, aff=None):
-        """Shared link-sampling tail for the no-drop routing paths
-        (lazy and adaptive): derive per-message entropy, apply the
+        """The link-sampling tail of the no-drop routing path (the
+        ladder's rungs): derive per-message entropy, apply the
         contract-#4 ``>= 1 µs`` flight clamp, saturate the epoch-
         relative deliver time to int32, and count the never-silent
         ``bad_delay`` / ``short_delay`` violations. One implementation
-        so the regimes cannot drift apart bit-wise."""
+        so the faulted and the unfaulted branch cannot drift apart
+        bit-wise."""
         mbits = msg_bits(self.s0, self.s1, src, dst, tmsg, slot) \
             if self.link.needs_key else None
         delay, _ = self.link.sample(src, dst, tmsg, mbits)
@@ -1271,8 +1261,8 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         of arrivals to one destination and (where it may cut them)
         the lanes its scatters took (``_take_fan_in`` takes both off
         again). Both commutative forms put every message in the same
-        slot; held to the oracle, and to each other, by
-        tests/test_insert_law.py."""
+        slot; held to the oracle at its two call sites (a ladder rung,
+        the eager path), and to each other, by tests/insertion_laws.py."""
         sc = self.scenario
         K, P = sc.mailbox_cap, sc.payload_width
         n = self.comm.n_local
@@ -1533,8 +1523,9 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
                 # needs each message's deliver time, and insertion
                 # ranks must count only genuinely inserted messages
                 # (a post-sort mask would corrupt per-dst slot ranks).
-                # Value-identical to the lazy ordering — link entropy
-                # is keyed per message, not per lane position.
+                # Value-identical to sampling after the sort (the
+                # unfaulted branch) — link entropy is keyed per
+                # message, not per lane position.
                 from ...faults.apply import down_mask
                 SA, woff_a, dst_f, ok, smrank, pay_f = gather(A)
                 aff = None
@@ -1646,10 +1637,8 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
                     sent_hash = _u32sum(jnp.where(ok_s, sent_mix, 0))
                 else:
                     sent_hash = jnp.uint32(0)
-                # route_drop ≡ 0 here (the top rung is always n): the
-                # slot keeps this return the shape of the legacy
-                # paths' (where route_cap can drop), for the one
-                # unpacking in _staged_superstep
+                # route_drop ≡ 0, as on the eager path: the slot feeds
+                # EngineState.route_drop, which stays (ROADMAP D28)
                 ret = inserted + (bad_dst_step, bad_delay_step,
                                   short_step, jnp.int32(0), sent_count,
                                   sent_hash)
@@ -2029,14 +2018,14 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
             holes = None
 
         stage("tw.route")
-        # 6. route outboxes — three regimes. Adaptive sender-compacted
-        #    routing (class docstring) never materializes the
-        #    S = N·max_out flattened arrays at all; the legacy paths
-        #    below flatten slot-major (arrival order is fixed later by
-        #    the (window offset, sender-major rank) keys, so the
-        #    flatten order is free — no transpose of the [M, N]
-        #    outbox). Each message is stamped with its sender's firing
-        #    instant (== t for W == 1), which keys the link entropy.
+        # 6. route outboxes — two regimes (``_adaptive_regime``).
+        #    Adaptive sender-compacted routing (class docstring) never
+        #    materializes the S = N·max_out flattened arrays; the eager
+        #    path below flattens slot-major (the (window offset,
+        #    sender-major rank) keys fix arrival order later, so the
+        #    flatten order is free — no transpose of the [M, N] outbox).
+        #    Each message is stamped with its sender's firing instant
+        #    (== t for W == 1), which keys the link entropy.
         adaptive = self._adaptive_regime()
         #: what this superstep's routing adds to the drivers' counts
         #: (``_count_route``): the rung in senders, the senders it was
@@ -2120,183 +2109,107 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         # sortable value (init guards n_glob * M < 2^31)
         smrank = src_f * jnp.int32(M) + slot_f
 
-        # Lazy link sampling: when the link cannot drop (validity then
-        # never depends on the sample) and a route_cap is set, sort
-        # FIRST and sample only the sliced prefix — sampling cost and
-        # one sort operand scale with active messages, not outbox
-        # slots. Single-chip only (the sharded exchange ships sampled
-        # deliver-times between devices). With route_drop > 0 the SENT
-        # digest covers only the sliced prefix — already outside the
-        # parity regime by definition.
-        # type check, NOT isinstance: MeshComm subclasses LocalComm, and
-        # the lazy path must never run sharded — it skips _exchange, so
-        # global destinations would be read as local mailbox rows
-        lazy = (self.route_cap is not None
-                and not self.link.can_drop
-                and type(comm) is LocalComm)
         #: routed messages the fault schedule killed this superstep
-        #: (the lazy path never runs faulted: faults reject route_cap)
         fault_eager = jnp.int32(0)
-
-        def slice_cap(ops, ok_mask):
-            """route_cap: valid messages sort to the front (sentinel
-            row n is the largest key), so ranking + scattering only a
-            static prefix is exact while the active count fits; the
-            excess is counted."""
-            drop_step = jnp.int32(0)
-            A = self.route_cap
-            if A is not None and A < ops[0].shape[0]:
-                total_ok = jnp.sum(ok_mask, dtype=jnp.int32)
-                ops = tuple(o[:A] for o in ops)
-                drop_step = total_ok - jnp.sum(
-                    ops[0] < n, dtype=jnp.int32)
-            return ops, comm.all_sum(drop_step)
-
-        if lazy:
-            ok = v_f & dst_ok
-            sort_dst = jnp.where(ok, dst_f, n)
-            with jax.named_scope("sort"):
-                if W > 1:
-                    opsL = jax.lax.sort(
-                        (sort_dst, woff, smrank) + pay_cols,
-                        dimension=0, num_keys=3)
-                else:
-                    opsL = jax.lax.sort(
-                        (sort_dst, smrank) + pay_cols, dimension=0,
-                        num_keys=2)
-            opsL, route_drop_step = slice_cap(opsL, ok)
-            if W > 1:
-                sd, woff_s, smrank_s = opsL[0], opsL[1], opsL[2]
-                pay_s = opsL[3:]
-            else:
-                sd, smrank_s = opsL[0], opsL[1]
-                woff_s = jnp.zeros_like(sd)
-                pay_s = opsL[2:]
-            ok_s = sd < n
-            src_s = smrank_s // jnp.int32(M)
-            tmsg_s = t + woff_s.astype(jnp.int64)
-            # sample the survivors; invalid lanes (sd == n) are fed the
-            # sentinel and masked — `sample` is elementwise by contract
-            flight_s, drel_s, bad_delay_step, short_step, spec_strag, _ = \
-                self._sample_nodrop(src_s, sd, tmsg_s,
-                                    smrank_s % jnp.int32(M), woff_s,
-                                    ok_s)
-            bad_delay_step = comm.all_sum(bad_delay_step)
-            short_step = comm.all_sum(short_step)
-            bucket_ovf = jnp.int32(0)
+        mbits = msg_bits(self.s0, self.s1, src_f, dst_f, tmsg,
+                         slot_f) if self.link.needs_key else None
+        delay, drop = self.link.sample(src_f, dst_f, tmsg, mbits)
+        ok = v_f & ~drop & dst_ok
+        if self._faulted:
+            # partition cuts (send-time) before the flight clamp;
+            # down-window drops (deliver-time) after — the same check
+            # order as the oracle's routing loop. The lanes are the
+            # node lanes M times over: a source's side of a table is
+            # the table in place, the destination's packed word the
+            # one look-up (``_fault_reads``: always early here)
+            from ...faults.apply import (cut_mask_at, dst_words,
+                                         link_aff_bits, src_link_bits)
+            ft = self._ft
+            aff_rows = self._fault_reads()[0]
+            with jax.named_scope("fault"):
+                at_dst = dst_words(ft, dst_f)               # [R, S]
+                cutm = ok & cut_mask_at(ft, node_ids, at_dst, tmsg)
+                fault_cut = jnp.sum(cutm, dtype=jnp.int32)
+                aff = link_aff_bits(
+                    ft, src_link_bits(ft, node_ids, now_vec, aff_rows),
+                    at_dst[0], aff_rows) if aff_rows else None
+            self._rec_cut(rec_full, cutm, src_f, dst_f, tmsg)
+            ok = ok & ~cutm
+            delay, degraded = self._degrade(delay, src_f, dst_f, tmsg,
+                                            ok, aff)
+        flight = jnp.maximum(delay, jnp.int64(1))  # contract #4
+        drel64 = woff.astype(jnp.int64) + flight
+        bad_delay_step = comm.all_sum(jnp.sum(
+            ok & (drel64 > jnp.int64(_I32MAX - 1)), dtype=jnp.int32))
+        # windowed-causality violation: a delay shorter than the window
+        # means this message should have been visible to a node that
+        # already fired in this very window — counted, never silent
+        # (against the effective window, see _sample_nodrop)
+        short_step = comm.all_sum(jnp.sum(
+            ok & (flight < self._w_now), dtype=jnp.int32)) \
+            if W > 1 else jnp.int32(0)
+        # the causality plane's straggler column — same set as
+        # short_step (post-cut, pre-down: a down-dropped straggler never
+        # lands, but the detector stays conservative and flags the send
+        # anyway, docs/speculation.md)
+        spec_strag = None
+        if self.speculate != "off" and W > 1:
+            spec_strag = comm.all_min(jnp.min(jnp.where(
+                ok & (flight < self._w_now), tmsg + flight,
+                jnp.int64(NEVER))))
+        drel = jnp.minimum(drel64,
+                           jnp.int64(_I32MAX - 1)).astype(jnp.int32)
+        if self._faulted:
+            # deliver-time drop: the destination's NIC is off for the
+            # whole down window, so a message landing inside it is lost
+            # — before the exchange (it never ships) and before the
+            # SENT digest (the oracle never hashes it either)
+            from ...faults.apply import down_mask
+            with jax.named_scope("fault"):
+                downm = ok & down_mask(self._ft, dst_f, t + drel64)
+                fault_down = jnp.sum(downm, dtype=jnp.int32)
             if rec_full:
-                # lazy path is single-chip and never faulted: the
-                # sliced survivors ARE the sent set (route_drop > 0
-                # runs are outside the parity regime by definition)
                 self._rec_extra.append(self._rec_sends(
-                    ok_s, None, src_s, sd, tmsg_s, tmsg_s + flight_s))
-        else:
-            mbits = msg_bits(self.s0, self.s1, src_f, dst_f, tmsg,
-                             slot_f) if self.link.needs_key else None
-            delay, drop = self.link.sample(src_f, dst_f, tmsg, mbits)
-            ok = v_f & ~drop & dst_ok
-            if self._faulted:
-                # partition cuts (send-time) before the flight clamp;
-                # down-window drops (deliver-time) after — the same
-                # check order as the oracle's routing loop. The lanes
-                # are the node lanes M times over: a source's side of
-                # a table is the table in place, the destination's
-                # packed word the one look-up (``_fault_reads``:
-                # always early here)
-                from ...faults.apply import (cut_mask_at, dst_words,
-                                             link_aff_bits,
-                                             src_link_bits)
-                ft = self._ft
-                aff_rows = self._fault_reads()[0]
-                with jax.named_scope("fault"):
-                    at_dst = dst_words(ft, dst_f)               # [R, S]
-                    cutm = ok & cut_mask_at(ft, node_ids, at_dst, tmsg)
-                    fault_cut = jnp.sum(cutm, dtype=jnp.int32)
-                    aff = link_aff_bits(
-                        ft, src_link_bits(ft, node_ids, now_vec,
-                                          aff_rows),
-                        at_dst[0], aff_rows) if aff_rows else None
-                self._rec_cut(rec_full, cutm, src_f, dst_f, tmsg)
-                ok = ok & ~cutm
-                delay, degraded = self._degrade(delay, src_f, dst_f,
-                                                tmsg, ok, aff)
-            flight = jnp.maximum(delay, jnp.int64(1))  # contract #4
-            drel64 = woff.astype(jnp.int64) + flight
-            bad_delay_step = comm.all_sum(jnp.sum(
-                ok & (drel64 > jnp.int64(_I32MAX - 1)), dtype=jnp.int32))
-            # windowed-causality violation: a delay shorter than the
-            # window means this message should have been visible to a
-            # node that already fired in this very window — counted,
-            # never silent (against the effective window, see
-            # _sample_nodrop)
-            short_step = comm.all_sum(jnp.sum(
-                ok & (flight < self._w_now), dtype=jnp.int32)) \
-                if W > 1 else jnp.int32(0)
-            # the causality plane's straggler column — same set as
-            # short_step (post-cut, pre-down: a down-dropped straggler
-            # never lands, but the detector stays conservative and
-            # flags the send anyway, docs/speculation.md)
-            spec_strag = None
-            if self.speculate != "off" and W > 1:
-                spec_strag = comm.all_min(jnp.min(jnp.where(
-                    ok & (flight < self._w_now), tmsg + flight,
-                    jnp.int64(NEVER))))
-            drel = jnp.minimum(drel64,
-                               jnp.int64(_I32MAX - 1)).astype(jnp.int32)
-            if self._faulted:
-                # deliver-time drop: the destination's NIC is off for
-                # the whole down window, so a message landing inside
-                # it is lost — before the exchange (it never ships)
-                # and before the SENT digest (the oracle never hashes
-                # it either)
-                from ...faults.apply import down_mask
-                with jax.named_scope("fault"):
-                    downm = ok & down_mask(self._ft, dst_f, t + drel64)
-                    fault_down = jnp.sum(downm, dtype=jnp.int32)
-                if rec_full:
-                    self._rec_extra.append(self._rec_sends(
-                        ok, downm, src_f, dst_f, tmsg, tmsg + flight))
-                cut, down, degraded = (comm.all_sum(x) for x in (
-                    fault_cut, fault_down, degraded))
-                fault_eager = cut + down
-                self._fault_step = FaultCounts(
-                    cut, down, fault_purged, degraded, fault_restarts)
-                ok = ok & ~downm
-            elif rec_full:
-                self._rec_extra.append(self._rec_sends(
-                    ok, None, src_f, dst_f, tmsg, tmsg + flight))
+                    ok, downm, src_f, dst_f, tmsg, tmsg + flight))
+            cut, down, degraded = (comm.all_sum(x) for x in (
+                fault_cut, fault_down, degraded))
+            fault_eager = cut + down
+            self._fault_step = FaultCounts(
+                cut, down, fault_purged, degraded, fault_restarts)
+            ok = ok & ~downm
+        elif rec_full:
+            self._rec_extra.append(self._rec_sends(
+                ok, None, src_f, dst_f, tmsg, tmsg + flight))
 
-            # 6.5. hand each message to the device that owns its
-            # destination (identity single-chip; bucket + all_to_all
-            # sharded) — rows come back device-local
-            (ok_r, drel_r, src_r, row_r, smrank_r, woff_r, pay_r,
-             bucket_ovf) = self._exchange(
-                ok, drel, src_f, dst_f, smrank, woff, pay_cols)
+        # 6.5. hand each message to the device that owns its destination
+        # (identity single-chip; bucket + all_to_all sharded) — rows
+        # come back device-local
+        (ok_r, drel_r, src_r, row_r, smrank_r, woff_r, pay_r,
+         bucket_ovf) = self._exchange(
+            ok, drel, src_f, dst_f, smrank, woff, pay_cols)
 
-            # 7. insert: ONE variadic sort by (destination, send
-            #    instant, sender-major rank) — chronological routing
-            #    order, contract #3 (for W == 1 all offsets are 0 and
-            #    the key is elided); values ride along, replacing the
-            #    argsort + gather chain. Sort operands are pruned to
-            #    the minimum: validity is derived from the destination
-            #    sentinel (sd < n ⇔ ok) and the sender from the rank
-            #    key (src = smrank // M).
-            sort_dst = jnp.where(ok_r, row_r, n)  # invalid -> row n
-            with jax.named_scope("sort"):
-                if W > 1:
-                    ops3 = jax.lax.sort(
-                        (sort_dst, woff_r, smrank_r, drel_r) + pay_r,
-                        dimension=0, num_keys=3)
-                    ops3 = ops3[:1] + ops3[2:]  # drop woff; layout below
-                else:
-                    ops3 = jax.lax.sort(
-                        (sort_dst, smrank_r, drel_r) + pay_r,
-                        dimension=0, num_keys=2)
-            ops3, route_drop_step = slice_cap(ops3, ok_r)
-            sd, drel_s = ops3[0], ops3[2]
-            ok_s = sd < n
-            src_s = ops3[1] // jnp.int32(M)   # smrank = src * M + slot
-            pay_s = ops3[3:]
+        # 7. insert: ONE variadic sort by (destination, send instant,
+        #    sender-major rank) — chronological routing order, contract
+        #    #3 (for W == 1 all offsets are 0 and the key is elided);
+        #    values ride along, replacing the argsort + gather chain.
+        #    Sort operands are pruned to the minimum: validity is
+        #    derived from the destination sentinel (sd < n ⇔ ok) and
+        #    the sender from the rank key (src = smrank // M).
+        sort_dst = jnp.where(ok_r, row_r, n)  # invalid -> row n
+        with jax.named_scope("sort"):
+            if W > 1:
+                ops3 = jax.lax.sort(
+                    (sort_dst, woff_r, smrank_r, drel_r) + pay_r,
+                    dimension=0, num_keys=3)
+                ops3 = ops3[:1] + ops3[2:]  # drop woff; layout below
+            else:
+                ops3 = jax.lax.sort(
+                    (sort_dst, smrank_r, drel_r) + pay_r,
+                    dimension=0, num_keys=2)
+        sd, drel_s = ops3[0], ops3[2]
+        ok_s = sd < n
+        src_s = ops3[1] // jnp.int32(M)   # smrank = src * M + slot
+        pay_s = ops3[3:]
         mb_rel, mb_src, mb_payload, overflow_local = self._take_fan_in(
             self._insert_sorted(
                 mb_rel, mb_src, mb_payload, sd, ok_s, drel_s, src_s,
@@ -2305,31 +2218,17 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
 
         sent_count = sent_hash = None
         if with_trace:
-            if lazy:
-                # delays exist only for the sorted/sliced survivors;
-                # with route_drop == 0 (the parity regime) this is
-                # every sent message — and count and hash cover the
-                # SAME (sliced) set even when drops occur
-                dt_abs = tmsg_s + flight_s  # send instant + flight
-                sent_mix = mix32_jnp(SENT, src_s, sd, _tlo(dt_abs),
-                                     _thi(dt_abs), pay_s[0])
-                sent_hash = comm.all_sum(
-                    _u32sum(jnp.where(ok_s, sent_mix, 0)))
-                sent_count = comm.all_sum(
-                    jnp.sum(ok_s, dtype=jnp.int32))
-            else:
-                dt_abs = t + drel64  # send instant + flight time
-                sent_mix = mix32_jnp(SENT, src_f, dst_f, _tlo(dt_abs),
-                                     _thi(dt_abs), pay_cols[0])
-                sent_hash = comm.all_sum(
-                    _u32sum(jnp.where(ok, sent_mix, 0)))
-                sent_count = comm.all_sum(jnp.sum(ok, dtype=jnp.int32))
+            dt_abs = t + drel64  # send instant + flight time
+            sent_mix = mix32_jnp(SENT, src_f, dst_f, _tlo(dt_abs),
+                                 _thi(dt_abs), pay_cols[0])
+            sent_hash = comm.all_sum(_u32sum(jnp.where(ok, sent_mix, 0)))
+            sent_count = comm.all_sum(jnp.sum(ok, dtype=jnp.int32))
         stage("tw.finish")
         return self._finish_superstep(
             st, live, states, wake, mb_rel, mb_src, mb_payload,
             deliver, fire, node_ids, t, base, now_vec,
             overflow_step, bad_dst_step, bad_delay_step, short_step,
-            route_drop_step, sent_count, sent_hash, with_trace,
+            jnp.int32(0), sent_count, sent_hash, with_trace,
             fault_dropped_step=fault_purged + fault_eager,
             restart_done=restart_done, spec_strag=spec_strag)
 
@@ -2341,7 +2240,7 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
                           fault_dropped_step=None, restart_done=None,
                           spec_strag=None):
         """Assemble the post-superstep state and (optionally) the trace
-        row — shared by all routing regimes. ``sent_count`` /
+        row — shared by both routing regimes. ``sent_count`` /
         ``sent_hash`` are computed by the caller (their inputs live at
         regime-specific widths) and may be None when tracing is off."""
         sc, comm = self.scenario, self.comm
@@ -2779,12 +2678,6 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
         stepped = True if y is None else jnp.any(valid)
         return (new, self._count_route(counts, stepped, valid)), y
 
-    def _any_world(self, x):
-        """Whether any world of this device is still active: the
-        quiet loop's liveness. Identity, and no engine overrides it:
-        worlds on other devices run their own loop."""
-        return x
-
     def _horizon_all(self, st) -> Horizon:
         """The horizon of a driver's state: the solo state's, or each
         world's (``t`` int64[B], ``node_next`` int64[B, N]). The one
@@ -2814,7 +2707,7 @@ class JaxEngine(RunStatsMixin, ControlledRunMixin, VerifiedRunMixin,
                 (st.steps - start_steps < max_steps)
             if self.batch is None:
                 return active
-            return self._any_world(jnp.any(active))
+            return jnp.any(active)
         return cond
 
     def _while_body_fn(self, start_steps, max_steps):

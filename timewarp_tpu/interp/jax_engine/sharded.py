@@ -172,13 +172,11 @@ class ShardedEngine(ShardedDriver, JaxEngine):
                  mesh: Mesh, *, axis: AxisName = "nodes", seed: int = 0,
                  bucket_cap: Optional[int] = None,
                  window: int = 1,
-                 route_cap: Optional[int] = None,
                  lint: str = "warn", telemetry: str = "off",
                  verify: str = "off", record: str = "off") -> None:
         _refuse_record(record, type(self).__name__)
         super().__init__(scenario, link, seed=seed, window=window,
-                         route_cap=route_cap, lint=lint,
-                         telemetry=telemetry, verify=verify)
+                         lint=lint, telemetry=telemetry, verify=verify)
         self.mesh = mesh
         self.axis = axis
         D = axis_size(mesh, axis)
@@ -322,8 +320,7 @@ class ShardedBatchedEngine(ShardedDriver, JaxEngine):
     def __init__(self, scenario: Scenario, link: LinkModel,
                  mesh: Mesh, *, batch: BatchSpec,
                  axis: AxisName = "worlds", seed: int = 0,
-                 window=1, route_cap: Optional[int] = None,
-                 lint: str = "warn", faults=None,
+                 window=1, lint: str = "warn", faults=None,
                  telemetry: str = "off", controller=None,
                  verify: str = "off", record: str = "off",
                  record_cap=None, speculate: str = "off") -> None:
@@ -334,8 +331,8 @@ class ShardedBatchedEngine(ShardedDriver, JaxEngine):
         # are device-local, so the violation decode sees the gathered
         # [T, B] columns exactly like the single-chip fleet's
         super().__init__(scenario, link, seed=seed, window=window,
-                         route_cap=route_cap, lint=lint, batch=batch,
-                         faults=faults, telemetry=telemetry,
+                         lint=lint, batch=batch, faults=faults,
+                         telemetry=telemetry,
                          controller=controller, verify=verify,
                          record=record, record_cap=record_cap,
                          speculate=speculate)

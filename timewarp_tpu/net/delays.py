@@ -64,9 +64,9 @@ class LinkModel:
     @property
     def can_drop(self) -> bool:
         """Whether ``sample`` can ever return ``drop=True``. Drop-free
-        models let the general engine defer link sampling until after
-        the routing sort + route_cap slice (sampling cost ∝ active
-        messages, not outbox slots — engine.py lazy-sampling path).
+        models let the general engine compact the senders first and
+        sample on the ladder's rung (sampling cost ∝ active senders,
+        not outbox slots — engine.py ``_adaptive_regime``).
         Conservative default: True."""
         return True
 

@@ -10,8 +10,9 @@ models, so equality is exact across backends (core/rng.py, SURVEY.md
 Configs: ping-pong (BASELINE config 1), token-ring 64 fixed-latency
 (config 2, edge engine), token-ring 64 w/ observer + uniform links
 (general engine), gossip-64 w/ drops, the round-4 execution modes:
-burst-gossip under a multi-instant window and burst-praos under a
-window with route_cap (all integer link models), plus — round 6 —
+burst-gossip and burst-praos under a multi-instant window (all
+integer link models; the praos row ran under a generous ``route_cap``
+until PR 57 took the knob away: the same trace), plus — round 6 —
 socket-state (BASELINE config 3's batched twin, models/socket_state.py)
 at the baseline shape and at the 1024-node windowed hub-fan-in shape.
 
@@ -103,16 +104,16 @@ def main() -> int:
                    end_us=5_000_000),
             WithDrop(UniformDelay(2_000, 30_000), 0.15), JaxEngine, 800, {}),
         # round-4 execution modes: multi-instant windows, burst
-        # diffusion, route_cap — the sparse-regime machinery, proven on
-        # the real chip
+        # diffusion — the sparse-regime machinery, proven on the real
+        # chip
         "gossip-64-burst-windowed": (
             gossip(64, fanout=4, think_us=700, burst=True,
                    end_us=400_000, mailbox_cap=16),
             wlink, JaxEngine, 600, {"window": 3_000}),
-        "praos-48-burst-windowed-routecap": (
+        "praos-48-burst-windowed": (
             praos(48, slot_us=20_000, n_slots=6, leader_prob=2.0 / 48,
                   fanout=4, burst=True, mailbox_cap=16),
-            wlink, JaxEngine, 600, {"window": 3_000, "route_cap": 96}),
+            wlink, JaxEngine, 600, {"window": 3_000}),
         # round 6: BASELINE config 3's batched twin — the per-socket
         # user-state example, value-stream-tied to the net world in
         # tests/test_cross_world_socket_state.py; here it holds the
